@@ -3,16 +3,21 @@ environment). Currently: the JSONL metrics-ingest parser (SURVEY.md C18)
 and the RB1 binary-ingest frame walker (ISSUE 7, rtap_tpu/ingest/).
 
 Each shared library is compiled on demand from its adjacent .c source
-with the system compiler into ``_build/`` (atomic rename, so concurrent
-processes can race the build safely) and cached until the source
-changes. Callers must treat ImportError/OSError from the loaders as
-"native path unavailable" and fall back to pure Python — the service
-must run (slower) on hosts without a toolchain.
+with the system compiler into ``_build/<name>-<sha256 of the source>.so``
+(atomic rename, so concurrent processes can race the build safely). The
+artefact is keyed on the source's CONTENT, never its mtime: a copied or
+checked-out tree carries fresh mtimes on stale ignored binaries, and only
+a binary built from the present .c may ever be loaded. Callers that pass
+``native=None`` treat a loader failure as "native path unavailable" and
+fall back to pure Python; the device path (serve --backend tpu) passes
+``native=True``, so a missing compiler is a loud error there, not a
+silent 10-50x slower parser.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -23,16 +28,23 @@ import numpy as np
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "jsonl_parser.c")
 _BUILD_DIR = os.path.join(_DIR, "_build")
-_SO = os.path.join(_BUILD_DIR, "jsonl_parser.so")
 _FW_SRC = os.path.join(_DIR, "frame_walker.c")
-_FW_SO = os.path.join(_BUILD_DIR, "frame_walker.so")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _fw_lib: ctypes.CDLL | None = None
 
 
-def _compile(src: str = _SRC, so: str = _SO) -> None:
+def _built(src: str) -> str:
+    """Path of the shared library built from `src` AS IT IS NOW, compiling
+    it first if that exact content was never built here. Raises on any
+    failure (no toolchain, compile error)."""
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    stem = os.path.splitext(os.path.basename(src))[0]
+    so = os.path.join(_BUILD_DIR, f"{stem}-{digest}.so")
+    if os.path.exists(so):
+        return so
     os.makedirs(_BUILD_DIR, exist_ok=True)
     fd, tmp = tempfile.mkstemp(suffix=".so", dir=_BUILD_DIR)
     os.close(fd)
@@ -45,20 +57,18 @@ def _compile(src: str = _SRC, so: str = _SO) -> None:
     finally:
         if os.path.exists(tmp):
             os.unlink(tmp)
+    return so
 
 
 def load() -> ctypes.CDLL:
-    """The parser library, compiling it first if missing or stale.
-    Raises on any failure (no toolchain, compile error) — callers fall
-    back to the pure-Python parser."""
+    """The parser library, built from the present source (see
+    :func:`_built`). Raises on any failure (no toolchain, compile error) —
+    ``native=None`` callers fall back to the pure-Python parser."""
     global _lib
     with _lock:
         if _lib is not None:
             return _lib
-        if (not os.path.exists(_SO)
-                or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-            _compile()
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(_built(_SRC))
         lib.rtap_parser_new.restype = ctypes.c_void_p
         lib.rtap_parser_new.argtypes = [
             ctypes.c_char_p, ctypes.POINTER(ctypes.c_int32), ctypes.c_int32]
@@ -207,17 +217,14 @@ _FW_CAP = 4096
 
 
 def load_frame_walker() -> ctypes.CDLL:
-    """The frame-walker library, compiling it first if missing or
-    stale. Raises on any failure — callers fall back to the pure-Python
-    walker (rtap_tpu/ingest/protocol.py)."""
+    """The frame-walker library, built from the present source (see
+    :func:`_built`). Raises on any failure — ``native=None`` callers fall
+    back to the pure-Python walker (rtap_tpu/ingest/protocol.py)."""
     global _fw_lib
     with _lock:
         if _fw_lib is not None:
             return _fw_lib
-        if (not os.path.exists(_FW_SO)
-                or os.path.getmtime(_FW_SO) < os.path.getmtime(_FW_SRC)):
-            _compile(_FW_SRC, _FW_SO)
-        lib = ctypes.CDLL(_FW_SO)
+        lib = ctypes.CDLL(_built(_FW_SRC))
         i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
         u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
         lib.rtap_fw_scan.restype = ctypes.c_longlong

@@ -8,10 +8,13 @@ stays disabled so everything is 32-bit on TPU (VPU-friendly integer ops).
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
-_C1 = jnp.uint32(0x85EBCA6B)
-_C2 = jnp.uint32(0xC2B2AE35)
-_GOLDEN = jnp.uint32(0x9E3779B9)
+# numpy scalars, not jnp: a jnp constant at import would initialize the
+# backend (and claim the chip) in every process that imports this module
+_C1 = np.uint32(0x85EBCA6B)
+_C2 = np.uint32(0xC2B2AE35)
+_GOLDEN = np.uint32(0x9E3779B9)
 
 
 def fmix32(x: jnp.ndarray) -> jnp.ndarray:

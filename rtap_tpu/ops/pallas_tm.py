@@ -35,30 +35,35 @@ first learn_cap learning segments in ascending (c, k, s) order) is
 reproduced exactly by `tm_learn_pallas`'s mask prep, and every arithmetic
 expression mirrors tm_tpu.py's f32 forms (integer-valued in quantized
 domains, exact below 2^24). Asserted by tests/parity/test_pallas_tm.py via
-interpreter mode on CPU, across the perm domains and under vmap.
+interpreter mode on CPU, across the perm domains and under vmap, and on the
+chip by chip_smoke.py's score phase (scaled_cluster_preset(32) against the
+default path, bit for bit).
 
 Strategy wiring: RTAP_TM_SCATTER=pallas (ops/tm_tpu.py mode table). OFF by
 default — shipping an unmeasured kernel as the default would repeat the
-round-1 mistake; scripts/hw_session.py carries the silicon A/B steps
-(profile_mega*) and the measured winner becomes the default, same protocol
-as the r4 flat/matmul flip. Incompatible with RTAP_TM_DENDRITE=forward
+round-1 mistake; it becomes the default or is deleted on a chip A/B
+(ROADMAP queue 1 item 1). Incompatible with RTAP_TM_DENDRITE=forward
 (the kernel computes dendrite counts itself) and RTAP_TM_SWEEP=compact
 (it fuses the DENSE punish/death semantics); tm_step rejects both combos
 loudly. Inference ticks (learn=False) keep the XLA dendrite path — the
 learning pass is ~99% of the tick, the dendrite pass is already cheap.
 
-Known v1 caveats for the silicon A/B (documented, not guessed around):
-the [n_seg, M] layout leaves M (<= 32) lanes per row, which the TPU tiler
-pads to 128 — VMEM cost ~128/M x the dense bytes (~43 MB-equivalent at the
-cluster preset's M=12: still inside the guard only for sub-preset shapes;
-measured viability on silicon decides whether v2 re-blocks lanes to
-[C, K*S*M]). The winner-loop unrolls W = col_cap * cells_per_column times —
-fine at the cluster preset (80), guarded off at NAB scale (1280).
+Known v1 limits (compiled for v5e, ISSUE 21 — docs/KERNELS.md has the
+numbers): the [n_seg, M] layout leaves M (<= 32) lanes per row, which the
+TPU tiler pads to 128, and the unrolled winner loops keep ~2 such arrays
+live per iteration — so the compiler's scoped-VMEM charge is ~n_seg x 512 B
+x (18 + 2W). It compiles at scaled_cluster_preset(32) and (64) under the
+raised limit below; scaled_cluster_preset(128) (108 MiB) and the cluster
+preset itself (~370 MiB against a 128 MiB core) are refused by the guard
+before the compiler is asked. Whether v2 re-blocks lanes to [M, n_seg]
+waits for the chip A/B (ROADMAP queue 1 item 1). The winner-loop unrolls
+W = col_cap * cells_per_column times and is guarded off at NAB scale (1280).
 
-Interpreter-mode caveat (same as the retired dendrite kernel): off-TPU the
-kernel runs the Pallas interpreter, orders of magnitude slower than XLA —
-fine for small parity tests, pathological beyond them; the guards refuse
-large shapes instead of hanging.
+Interpreter mode is for small parity tests only — orders of magnitude
+slower than XLA — and is something a test asks for by argument
+(``set_scatter_mode("pallas", interpret=True)``): off a TPU the
+non-interpreted kernel raises at trace time instead of silently
+interpreting, and the guards refuse large shapes instead of hanging.
 """
 
 from __future__ import annotations
@@ -70,9 +75,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# The whole per-stream pool plus temporaries must fit VMEM (no grid/blocking
-# in this v1 kernel), with lane padding to 128 accounted: ~12 MiB budget.
-_VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+# Scoped-VMEM limit handed to Mosaic (its default is 16 MiB; a v5e core has
+# 128 MiB). One stream's pools plus the kernel's temporaries must fit it —
+# under vmap the batch becomes a grid axis, so one stream is resident at a
+# time. What the v5e compiler actually charges, fitted to its own refusals
+# in described-topology compiles at M=12 (ISSUE 21; docs/KERNELS.md has the
+# table): every [n_seg, <=128] value is lane-padded to 128 lanes (512 B per
+# row), ~18 of them are live at once plus ~2 per unrolled winner iteration,
+# and up to ~14 MiB more that follows the batch size and the limit itself.
+_VMEM_LIMIT_BYTES = 100 * 1024 * 1024
+_VMEM_SLACK_BYTES = 16 * 1024 * 1024
 # Interpreter mode (off-TPU) is for parity tests only; refuse big shapes
 # instead of silently hanging for minutes.
 _INTERPRET_MAX_SYNAPSES = 1 << 18
@@ -214,16 +226,17 @@ def _guard_shapes(C, K, S, M, W, interpret):
             "this preset (col_cap * cells_per_column too large, e.g. the NAB "
             "preset) needs the XLA path"
         )
-    # v1 has no grid/blocking: pools + temporaries must fit VMEM, with the
-    # [n_seg, M] rows lane-padded to 128 on real hardware
-    lanes = M if interpret else max(M, 128)
-    block_bytes = C * K * S * (lanes * 4 * 6 + _META_COLS * 4 + 3 * 4)
-    if block_bytes > _VMEM_BUDGET_BYTES:
+    if interpret:
+        return  # the interpreter has no VMEM; its size guard is above
+    charged = C * K * S * max(M, 128) * 4 * (18 + 2 * W) + _VMEM_SLACK_BYTES
+    if charged > _VMEM_LIMIT_BYTES:
         raise ValueError(
-            f"Pallas TM megakernel needs ~{block_bytes >> 20} MiB VMEM for "
-            f"[C={C}, K={K}, S={S}, M={M}] incl. lane padding (budget "
-            f"~{_VMEM_BUDGET_BYTES >> 20} MiB): this preset is too large for "
-            "the unblocked v1 kernel — keep RTAP_TM_SCATTER=matmul for it"
+            f"Pallas TM megakernel: the TPU compiler would charge "
+            f"~{charged >> 20} MiB of scoped VMEM for one stream at "
+            f"[C={C}, K={K}, S={S}, M={M}], winner list {W} (rows are "
+            f"lane-padded to 128; limit {_VMEM_LIMIT_BYTES >> 20} MiB of "
+            "the core's 128) — this preset is too large for the unblocked "
+            "v1 kernel; keep RTAP_TM_SCATTER=matmul for it"
         )
 
 
@@ -248,7 +261,7 @@ def tm_learn_pallas(
     winner_ids: jnp.ndarray,  # [Ac*K] prev winner cell ids (fills = N)
     acol_ids: jnp.ndarray,  # [Ac] packed CURRENT active cells (dendrite)
     acol_masks: jnp.ndarray,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """XLA-side harness for the megakernel: reproduce the workspace
     truncation as dense masks, call the kernel, apply the [C, K, S]-scale
@@ -266,8 +279,16 @@ def tm_learn_pallas(
     L, Ac = cfg.learn_cap, cfg.col_cap
     W = winner_ids.shape[0]
     G = cfg.new_synapse_count
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    if not interpret:
+        from rtap_tpu.ops.tm_tpu import _tpu_paths
+
+        if not _tpu_paths():
+            raise ValueError(
+                "RTAP_TM_SCATTER=pallas compiles for a TPU only and this "
+                f"backend is {jax.default_backend()!r}; interpreter mode is "
+                "for parity tests, which ask for it by argument "
+                "(set_scatter_mode('pallas', interpret=True))"
+            )
     _guard_shapes(C, K, S, M, W, interpret)
 
     # --- the workspace truncation, as dense masks: the XLA path captures
@@ -343,6 +364,8 @@ def tm_learn_pallas(
             pl.BlockSpec(memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pltpu.VMEM),
         ),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(
         presyn.reshape(n_seg, M).astype(i32),

@@ -57,11 +57,14 @@ from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from rtap_tpu.config import TMConfig
 from rtap_tpu.models.perm import tm_domain
 
-INF = jnp.float32(jnp.inf)
+# numpy scalar, not jnp: a jnp constant at import would initialize the
+# backend (and claim the chip) in every process that imports this module
+INF = np.float32(np.inf)
 _HI = jax.lax.Precision.HIGHEST
 
 
@@ -127,7 +130,7 @@ _ENV_NAMES = {
     "fwd_impl": "RTAP_TM_FWD_IMPL",
 }
 # Defaults are the measured silicon winners (SCALING.md round-4 A/B,
-# hw_results/ 2026-07-31): flat layout beat aos by 13% on the full
+# 2026-07-31 chip run): flat layout beat aos by 13% on the full
 # learning step (31.9k vs 28.1k metrics/s at G=1024) and matmul scatter
 # beat indexed by 1.55x — the reverse of the CPU-drive signal.
 _MODE_DEFAULTS = {
@@ -184,8 +187,17 @@ def fwd_impl() -> str:
     return _mode("fwd_impl")
 
 
-def set_scatter_mode(mode: str | None) -> None:
-    """Override the workspace-movement strategy AND clear jit caches."""
+# Pallas interpreter mode is what a TEST asks for (set_scatter_mode's
+# argument) — never what a missing TPU selects: off a TPU the kernel raises.
+_PALLAS_INTERPRET = False
+
+
+def set_scatter_mode(mode: str | None, interpret: bool = False) -> None:
+    """Override the workspace-movement strategy AND clear jit caches.
+    `interpret=True` runs the "pallas" megakernel in the Pallas interpreter
+    (CPU parity tests only; orders of magnitude slower than XLA)."""
+    global _PALLAS_INTERPRET
+    _PALLAS_INTERPRET = bool(interpret) and mode == "pallas"
     _set_mode("scatter", mode)
 
 
@@ -253,8 +265,6 @@ def _reduce_matrix(ks: int, m: int):
     [s*m, (s+1)*m) — the per-segment Σ_M reduction as one MXU matmul.
     (Moved here from the retired dendrite-only Pallas kernel: it is the
     flat layout's seg_sum operand, load-bearing independent of Pallas.)"""
-    import numpy as np
-
     r = np.zeros((ks * m, ks), np.float32)
     for s in range(ks):
         r[s * m : (s + 1) * m, s] = 1.0
@@ -654,7 +664,7 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
             seg_pot4, matching_seg4, learn_mask, alloc,
             active_cols, have_winners, it,
             pcol_ids, pcol_masks, p_cols, winner_ids,
-            acol_ids, acol_masks,
+            acol_ids, acol_masks, interpret=_PALLAS_INTERPRET,
         )
         presyn = presyn_n.astype(presyn_dt).reshape(*pool_shape)
         perm_w = jnp.round(perm_n) if dom.bits else perm_n  # exact already

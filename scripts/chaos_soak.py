@@ -61,7 +61,7 @@ import tempfile
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from rtap_tpu.utils.platform import maybe_force_cpu  # noqa: E402
+from rtap_tpu.utils.platform import maybe_force_cpu, require_device  # noqa: E402
 
 VERIFY_FAILED_EXIT = 5
 
@@ -521,6 +521,8 @@ def main() -> int:
                          "level incident pages, not N per-stream alerts")
     args = ap.parse_args()
     maybe_force_cpu()
+    if args.backend == "tpu":
+        require_device()  # no TPU and no explicit CPU choice -> fail here
     if sum((args.supervise, args.replication, args.topology_burst)) > 1:
         log("--supervise, --replication and --topology-burst are "
             "separate drills")

@@ -46,7 +46,7 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from rtap_tpu.utils.platform import maybe_force_cpu  # noqa: E402
+from rtap_tpu.utils.platform import maybe_force_cpu, require_device  # noqa: E402
 from scripts.fleet_verdict import (  # noqa: E402
     classify_downs,
     final_tick_check,
@@ -69,6 +69,8 @@ def run_child(args) -> int:
     their journal/checkpoints/alerts behind; completing children append
     a stats line to --stats-out."""
     maybe_force_cpu()
+    if args.backend == "tpu":
+        require_device()  # no TPU and no explicit CPU choice -> fail here
 
     import numpy as np
 

@@ -22,9 +22,7 @@ import numpy as np
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from rtap_tpu.utils.platform import init_backend_or_die, maybe_force_cpu  # noqa: E402
-
-maybe_force_cpu()
+from rtap_tpu.utils.platform import require_device  # noqa: E402
 
 
 def main() -> int:
@@ -33,7 +31,7 @@ def main() -> int:
     ap.add_argument("--ticks", type=int, default=48)
     args = ap.parse_args()
 
-    init_backend_or_die()
+    require_device()  # no TPU and no explicit CPU choice -> fail here
     import jax
 
     from rtap_tpu.config import scaled_cluster_preset

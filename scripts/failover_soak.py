@@ -51,7 +51,7 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from rtap_tpu.utils.platform import maybe_force_cpu  # noqa: E402
+from rtap_tpu.utils.platform import maybe_force_cpu, require_device  # noqa: E402
 from scripts.fleet_verdict import (  # noqa: E402
     final_tick_check,
     promotion_epoch_truth,
@@ -75,6 +75,8 @@ def run_child(args) -> int:
     to the peer, fenced by the lease. ``--ref`` runs the plain
     single-process reference instead (no lease, no replication)."""
     maybe_force_cpu()
+    if args.backend == "tpu":
+        require_device()  # no TPU and no explicit CPU choice -> fail here
 
     import threading
 

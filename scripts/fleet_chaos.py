@@ -50,7 +50,7 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from rtap_tpu.utils.platform import maybe_force_cpu  # noqa: E402
+from rtap_tpu.utils.platform import maybe_force_cpu, require_device  # noqa: E402
 from scripts.fleet_verdict import (  # noqa: E402
     final_tick_check,
     member_counter,
@@ -78,6 +78,8 @@ def run_child(args) -> int:
     reason=drain). ``--ref`` runs the plain single-process reference for
     the shard's feed instead (no lease, no control plane)."""
     maybe_force_cpu()
+    if args.backend == "tpu":
+        require_device()  # no TPU and no explicit CPU choice -> fail here
 
     import threading
 

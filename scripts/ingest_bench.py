@@ -1,7 +1,8 @@
 """Host ingest-path benchmark: JSONL (native C / pure Python) vs the RB1
 binary batch protocol (socket and shared-memory ring).
 
-The chip can score ~245k metrics/s (BENCH_LKG.json headline); the host
+The chip scored ~245k metrics/s at its fastest bench rung (2026-08, a
+32-column model learning one tick in four); the host
 core that feeds it must ingest at least that many records/s while ALSO
 driving the device and computing likelihoods. Per-record JSONL tops out
 near ~100k records/s end-to-end on this class of host — the binding

@@ -28,12 +28,9 @@ import time
 from functools import partial
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from rtap_tpu.utils.platform import (  # noqa: E402
-    enable_compile_cache, init_backend_or_die, maybe_force_cpu,
-)
+from rtap_tpu.utils.platform import enable_compile_cache, require_device  # noqa: E402
 
-maybe_force_cpu()
-init_backend_or_die()
+require_device()  # no TPU and no explicit CPU choice -> fail here
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -66,7 +63,7 @@ def scanned(body):
 
 
 def main() -> None:
-    enable_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    enable_compile_cache()
     print(json.dumps({"platform": jax.devices()[0].device_kind}), file=sys.stderr, flush=True)
     rng = np.random.Generator(np.random.Philox(key=(4, 4)))
     pool4 = jnp.asarray(rng.integers(-1, K * C, (G, C, K, S, M)), jnp.int32)

@@ -31,13 +31,10 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from rtap_tpu.utils.platform import (  # noqa: E402
-    enable_compile_cache, init_backend_or_die, maybe_force_cpu,
-)
+from rtap_tpu.utils.platform import enable_compile_cache, require_device  # noqa: E402
 
-maybe_force_cpu()
-init_backend_or_die()
-enable_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+require_device()  # no TPU and no explicit CPU choice -> fail here
+enable_compile_cache()
 
 import numpy as np  # noqa: E402
 

@@ -22,12 +22,9 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from rtap_tpu.utils.platform import (  # noqa: E402
-    enable_compile_cache, init_backend_or_die, maybe_force_cpu,
-)
+from rtap_tpu.utils.platform import enable_compile_cache, require_device  # noqa: E402
 
-maybe_force_cpu()
-init_backend_or_die()
+require_device()  # no TPU and no explicit CPU choice -> fail here
 
 
 def main() -> None:
@@ -39,7 +36,7 @@ def main() -> None:
     ap.add_argument("--chunks", type=int, default=4)
     args = ap.parse_args()
 
-    enable_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    enable_compile_cache()
     from rtap_tpu.config import cluster_preset
     from rtap_tpu.service.registry import StreamGroup
     from rtap_tpu.utils.measure import make_sine_feed, measure_pipelined

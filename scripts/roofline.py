@@ -33,9 +33,7 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from rtap_tpu.utils.platform import (  # noqa: E402
-    enable_compile_cache, init_backend_or_die, maybe_force_cpu,
-)
+from rtap_tpu.utils.platform import enable_compile_cache, require_device  # noqa: E402
 
 # v5e-1 peaks (public spec: 394 TOPS int8 / 197 TFLOPs bf16 per chip,
 # 819 GB/s HBM BW, 16 GiB HBM)
@@ -46,13 +44,13 @@ PEAK_HBM_BPS = 819e9
 # Committed silicon measurements (ms/tick, T=32 chunked, full learning
 # unless noted) — the provenance strings name the artifact logs.
 MEASURED = {
-    "preset_256col_G1024": (31.95, "hw_results/profile_flat.log: G=1024 "
+    "preset_256col_G1024": (31.95, "2026-08 chip run, profile_flat step (log in git history): G=1024 "
                                    "31.95 ms/tick (32,050 metrics/s)"),
-    "eighth_32col_G1024": (14.65, "hw_results/profile_eighth.log: G=1024 "
+    "eighth_32col_G1024": (14.65, "2026-08 chip run, profile_eighth step (log in git history): G=1024 "
                                   "14.65 ms/tick (69,876 metrics/s)"),
-    "eighth_32col_k2_G1024": (7.85, "hw_results/profile_eighth_k2.log: "
+    "eighth_32col_k2_G1024": (7.85, "2026-08 chip run, profile_eighth_k2 step (log in git history): "
                                     "G=1024 7.85 ms/tick (130,380 metrics/s)"),
-    "eighth_32col_G65536": (1555.4, "hw_results/profile_32col_bigg.log: "
+    "eighth_32col_G65536": (1555.4, "2026-08 chip run, profile_32col_bigg step (log in git history): "
                                     "G=65536 1555.4 ms/tick (42,134 "
                                     "metrics/s) — the residency frontier"),
 }
@@ -123,11 +121,10 @@ def main() -> int:
                          "(cheap CPU drives skip the G=65536 compile)")
     args = ap.parse_args()
 
-    maybe_force_cpu()
-    init_backend_or_die()
+    require_device()  # no TPU and no explicit CPU choice -> fail here
     import jax
 
-    enable_compile_cache(REPO)
+    enable_compile_cache()
     platform = jax.devices()[0].platform
 
     configs = {

@@ -29,9 +29,7 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from rtap_tpu.utils.platform import (  # noqa: E402
-    enable_compile_cache, init_backend_or_die, maybe_force_cpu,
-)
+from rtap_tpu.utils.platform import enable_compile_cache, require_device  # noqa: E402
 
 
 def log(msg: str) -> None:
@@ -49,11 +47,10 @@ def main() -> int:
                          "best (least host-noise) and all raw values")
     args = ap.parse_args()
 
-    maybe_force_cpu()
-    init_backend_or_die()
+    require_device()  # no TPU and no explicit CPU choice -> fail here
     import jax
 
-    enable_compile_cache(REPO)
+    enable_compile_cache()
     from rtap_tpu.config import cluster_preset
     from rtap_tpu.ops.tm_tpu import layout_mode, scatter_mode, sweep_mode
     from rtap_tpu.service.registry import StreamGroup

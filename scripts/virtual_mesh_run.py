@@ -40,7 +40,7 @@ def main() -> None:
     from rtap_tpu.utils.platform import enable_compile_cache, force_virtual_devices
 
     force_virtual_devices(args.devices)
-    enable_compile_cache(REPO)
+    enable_compile_cache()
     import jax
 
     import numpy as np

@@ -42,7 +42,7 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from rtap_tpu.utils.platform import maybe_force_cpu  # noqa: E402
+from rtap_tpu.utils.platform import maybe_force_cpu, require_device  # noqa: E402
 
 VERIFY_FAILED_EXIT = 5
 INFRA_FAILED_EXIT = 3
@@ -79,6 +79,8 @@ def run_child(args) -> int:
     journal + checkpoints + incident correlation armed (crash_soak's
     child shape — killed children leave their trail behind)."""
     maybe_force_cpu()
+    if args.backend == "tpu":
+        require_device()  # no TPU and no explicit CPU choice -> fail here
 
     import dataclasses
 

@@ -1,0 +1,154 @@
+"""Described-topology compiles: the main path's programs, compiled by the
+TPU's own compiler for a v5e that is described and not attached
+(`on-chip-measurement` guide §2.3; ISSUE 21).
+
+Interpret mode and the CPU lowering cannot show what these do: a kernel that
+had passed every interpreter parity test was refused here for more VMEM than
+a kernel may use. Nothing runs — a compile that passes is not a chip run —
+but what the chip's compiler refuses costs no chip time to find. Model
+widths are the real ones (cluster_preset: 256 columns x 8 cells;
+scaled_cluster_preset(32)); the stream batch is 128, which keeps each
+compile to seconds (G=1024 compiles were rehearsed for the PR, CHANGES.md).
+
+`jax.default_backend()` is still the CPU here, so the test — not a program
+option — steers the kernels onto their TPU branches (tm_tpu.FORCE_TPU_PATHS).
+The persistent compilation cache is off around these compiles: an entry
+written for a described device cannot be read back without a chip.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import rtap_tpu.ops.tm_tpu as tm_tpu
+from rtap_tpu.config import cluster_preset, scaled_cluster_preset
+from rtap_tpu.models.state import init_state
+
+G = 128
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """SingleDeviceSharding on one described v5e chip, TPU branches on."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed here
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    paths_was = tm_tpu.FORCE_TPU_PATHS
+    tm_tpu.FORCE_TPU_PATHS = True
+    yield SingleDeviceSharding(topo.devices[0])
+    tm_tpu.FORCE_TPU_PATHS = paths_was
+    tm_tpu.set_scatter_mode(None)
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    compilation_cache.reset_cache()
+
+
+def _shapes(tree: dict, sharding) -> dict:
+    return {k: jax.ShapeDtypeStruct((G, *np.shape(v)), np.asarray(v).dtype,
+                                    sharding=sharding)
+            for k, v in tree.items()}
+
+
+def _step_args(cfg, sharding, T=None, predict=0):
+    state = _shapes(init_state(cfg, 0, predict_horizon=predict), sharding)
+    lead = () if T is None else (T,)
+    vals = jax.ShapeDtypeStruct((*lead, G, cfg.n_fields), jnp.float32,
+                                sharding=sharding)
+    ts = jax.ShapeDtypeStruct((*lead, G), jnp.int32, sharding=sharding)
+    return state, vals, ts
+
+
+@pytest.mark.parametrize("preset", ["cluster", "scaled32"])
+def test_default_chunk_step_compiles_for_v5e(v5e, preset):
+    """The default program (replay/bench/chip_smoke score path), learning
+    on, at both supported presets: pure XLA, no custom call."""
+    from rtap_tpu.ops.step import chunk_step
+
+    cfg = cluster_preset() if preset == "cluster" else scaled_cluster_preset(32)
+    compiled = chunk_step.lower(*_step_args(cfg, v5e, T=2), cfg,
+                                learn=True).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    # the state is an argument the program really holds on the device
+    per_stream = sum(np.asarray(v).nbytes for v in init_state(cfg, 0).values())
+    assert compiled.memory_analysis().argument_size_in_bytes >= G * per_stream
+
+
+def test_serve_group_step_with_reducers_compiles_for_v5e(v5e):
+    """serve's per-tick program with the in-step health and predict
+    reducers armed (--health --predict), inference branch."""
+    from rtap_tpu.ops.step import group_step
+
+    cfg = cluster_preset()
+    compiled = group_step.lower(*_step_args(cfg, v5e, predict=8), cfg,
+                                learn=False, health=True,
+                                predict=True).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes > 0
+
+
+def _tm_only(cfg, sharding):
+    from tests.parity.test_tm_parity import TM_KEYS
+
+    st = init_state(cfg, 0)
+    state = _shapes(tm_tpu.to_kernel_layout({k: st[k] for k in TM_KEYS}),
+                    sharding)
+    active = jax.ShapeDtypeStruct((G, cfg.sp.columns), jnp.bool_,
+                                  sharding=sharding)
+    step = jax.jit(jax.vmap(
+        lambda s, a: tm_tpu.tm_step(s, a, cfg.tm, learn=True)))
+    return step, state, active
+
+
+def test_pallas_megakernel_compiles_under_vmap_at_scaled32(v5e):
+    """RTAP_TM_SCATTER=pallas at the width the v5e compiler accepts: the
+    vmapped learning pass lowers to one Mosaic custom call (the batch
+    becomes a grid axis; one stream's pools are resident at a time)."""
+    tm_tpu.set_scatter_mode("pallas")
+    try:
+        step, state, active = _tm_only(scaled_cluster_preset(32), v5e)
+        assert "tpu_custom_call" in step.lower(state, active).compile().as_text()
+    finally:
+        tm_tpu.set_scatter_mode(None)
+
+
+def test_pallas_megakernel_refuses_cluster_preset_at_trace_time(v5e):
+    """At the default preset the kernel raises a shape-naming error before
+    the compiler is asked (it would charge ~390 MiB of a 128 MiB VMEM) —
+    never a compiler stack from inside a serve tick."""
+    tm_tpu.set_scatter_mode("pallas")
+    try:
+        step, state, active = _tm_only(cluster_preset(), v5e)
+        with pytest.raises(ValueError, match=r"scoped VMEM.*C=256, K=8, S=2, M=12"):
+            step.lower(state, active)
+    finally:
+        tm_tpu.set_scatter_mode(None)
+
+
+def test_pallas_off_tpu_raises_instead_of_interpreting(monkeypatch):
+    """Interpreter mode is what a test asks for by argument
+    (set_scatter_mode("pallas", interpret=True)); with no TPU and no such
+    request the user-set strategy is an error, not a silent fallback."""
+    from tests.parity.test_tm_parity import _init_tm_state
+
+    monkeypatch.setattr(tm_tpu, "FORCE_TPU_PATHS", None)  # the real backend: cpu
+    cfg = scaled_cluster_preset(32).tm
+    C = 32
+    state = tm_tpu.to_kernel_layout(
+        {k: jnp.asarray(v) for k, v in _init_tm_state(C, cfg).items()})
+    tm_tpu.set_scatter_mode("pallas")
+    try:
+        with pytest.raises(ValueError, match="compiles for a TPU only"):
+            tm_tpu.tm_step(state, jnp.zeros(C, bool), cfg, learn=True)
+    finally:
+        tm_tpu.set_scatter_mode(None)

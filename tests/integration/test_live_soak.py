@@ -22,7 +22,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 def test_live_soak_smoke(tmp_path):
     out = tmp_path / "soak.json"
     env = {**os.environ, "RTAP_FORCE_CPU": "1"}
-    # invoked exactly as hw_session/hw_watch invoke it: script path, repo cwd
+    # invoked exactly as hw_session invokes it: script path, repo cwd
     proc = subprocess.run(
         [sys.executable, "scripts/live_soak.py",
          "--streams", "8", "--ticks", "4", "--cadence", "0.5",

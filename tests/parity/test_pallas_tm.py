@@ -33,7 +33,7 @@ def small_cfg(perm_bits: int = 0, K: int = 8, S: int = 4, M: int = 16) -> ModelC
 
 @pytest.fixture
 def pallas_scatter():
-    tm_tpu.set_scatter_mode("pallas")
+    tm_tpu.set_scatter_mode("pallas", interpret=True)
     yield
     tm_tpu.set_scatter_mode(None)
 

@@ -220,7 +220,11 @@ def test_live_serving_stack_over_mesh_bitexact():
                               alert_path=f.name, pipeline_depth=2,
                               dispatch_threads=2, micro_chunk=3,
                               chunk_stagger=True)
-            lines = sorted(f.read().splitlines())
+            # alert lines only: watchdog event lines (missed_tick) share
+            # the file and carry a wall-clock elapsed_s, which differs
+            # between any two runs and says nothing about the mesh
+            lines = sorted(ln for ln in f.read().splitlines()
+                           if not ln.startswith('{"event"'))
         assert stats["scored"] == n * ticks
         assert stats["alerts"] > 0, "emission comparison must be non-vacuous"
         final = [jax.device_get(g.state) for g in reg.groups]

@@ -280,13 +280,20 @@ def test_partial_multigroup_resume_still_works(tmp_path):
 
 @pytest.mark.quick
 def test_occupancy_sums_over_every_local_device(monkeypatch):
-    """Regression for the ISSUE 15 device-scope finding: _occupancy read
-    local_devices()[0] only, under-reporting HBM by the shard count on a
-    multi-device host. It must SUM bytes over the local device list (and
-    stay numerically identical on single-device hosts)."""
+    """Regression for the ISSUE 15 device-scope finding: the stats line's
+    occupancy read local_devices()[0] only, under-reporting HBM by the shard
+    count on a multi-device host. It must SUM bytes over the local device
+    list (and stay numerically identical on single-device hosts)."""
     import jax
 
-    from rtap_tpu.service.loop import _occupancy
+    from rtap_tpu.service.loop import _device_stats
+
+    class _Grp:
+        backend = "tpu"
+
+    def _occupancy():
+        return {k: v for k, v in _device_stats([_Grp()]).items()
+                if k.startswith("hbm_")}
 
     class _Dev:
         def __init__(self, stats):
