@@ -651,6 +651,13 @@ def _cmd_serve(args: argparse.Namespace) -> int:
 
         jax.profiler.start_trace(args.jax_trace)
         jax_tracing = True
+        if trace is not None:
+            # the one point the two clocks share: an `rtap.sync` annotation
+            # in the device trace and a `profiler_sync` instant in the host
+            # trace, at the same perf_counter reading (obs/trace.py)
+            t_sync = time.perf_counter()
+            with jax.profiler.TraceAnnotation("rtap.sync"):
+                trace.profiler_sync(t_sync)
         print(f"serve: jax profiler tracing to {args.jax_trace}",
               file=sys.stderr)
     try:
@@ -1422,10 +1429,16 @@ def main(argv: list[str] | None = None) -> int:
                         "multivariate models, cheap either way")
     p.add_argument("--jax-trace", default=None,
                    help="wrap the serve window in jax.profiler.trace "
-                        "writing the XLA device trace to this directory "
-                        "(pairs with --trace-out: host + device timelines "
-                        "of the same ticks — the hw_session device-trace "
-                        "step)")
+                        "writing the XLA device trace (.xplane.pb) to this "
+                        "directory: device ops named by the step's "
+                        "`rtap.*` scopes, and the stream groups' "
+                        "`rtap.group.*` phases on its host plane. Pairs "
+                        "with --trace-out (the loop's own spans, Chrome "
+                        "JSON on perf_counter): the device trace's "
+                        "`rtap.sync` annotation starts at the host "
+                        "trace's `profiler_sync` instant "
+                        "(otherData.profiler_sync_perf), which puts the "
+                        "two files on one clock")
     p.add_argument("--freeze", action="store_true",
                    help="inference-only serving (NuPIC disableLearning "
                         "parity): SP/TM/classifier state is bit-frozen, raw "

@@ -97,6 +97,9 @@ class TraceRecorder:
         # anchor lets a reader align the trace with alert-line timestamps
         self.epoch_perf = time.perf_counter()
         self.epoch_unix = time.time()
+        #: perf_counter reading shared with a JAX profiler trace
+        #: (:meth:`profiler_sync`); None while no such trace was started
+        self.profiler_sync_perf: float | None = None
         self._shards: dict[int, _Shard] = {}
         self._names: dict[str, int] = {"<other>": 0}
         self._names_rev: list[str] = ["<other>"]
@@ -137,15 +140,18 @@ class TraceRecorder:
         shard.n += 1
 
     def add_instant(self, name: str, tick: int, fields: dict | None = None,
-                    group: int = -1) -> None:
+                    group: int = -1, t: float | None = None) -> None:
         """Record one instant event (watchdog/resilience marks). `fields`
         is serialized now, truncated to `max_arg_bytes` — bounded memory
         beats a perfectly preserved payload (the full event also rides
-        the alert JSONL stream)."""
+        the alert JSONL stream). `t` is a ``time.perf_counter()`` reading
+        the caller already holds; None reads the clock here."""
         shard = self._shard()
         i = shard.n % self.capacity
+        if t is None:
+            t = time.perf_counter()
         shard.recs[i] = (self._name_id(name), _KIND_INSTANT, tick,
-                         time.perf_counter() - self.epoch_perf, 0.0, group)
+                         t - self.epoch_perf, 0.0, group)
         aux = None
         if fields:
             try:
@@ -154,6 +160,18 @@ class TraceRecorder:
                 aux = repr(fields)[: self.max_arg_bytes]
         shard.aux[i] = aux
         shard.n += 1
+
+    def profiler_sync(self, t: float) -> None:
+        """The one point this timeline shares with a JAX profiler trace:
+        `t` is the ``time.perf_counter()`` reading at which the caller
+        opened an ``rtap.sync`` ``jax.profiler.TraceAnnotation`` in a trace
+        it had just started (``serve --jax-trace``). Recorded as the
+        ``profiler_sync`` instant and as ``otherData["profiler_sync_perf"]``
+        of :meth:`chrome_trace`: shift the device trace so that its
+        ``rtap.sync`` event starts at this instant and the two files lie
+        on one clock."""
+        self.profiler_sync_perf = t
+        self.add_instant("profiler_sync", -1, {"perf_counter": t}, t=t)
 
     # ------------------------------------------------------------- read --
     def _shard_list(self) -> list[_Shard]:
@@ -286,6 +304,7 @@ class TraceRecorder:
                 "process_name": self.process_name or f"rtap-{pid}",
                 "epoch_unix": self.epoch_unix,
                 "epoch_perf": self.epoch_perf,
+                "profiler_sync_perf": self.profiler_sync_perf,
                 "total_records": self.total,
                 "dropped_records": self.dropped,
             },

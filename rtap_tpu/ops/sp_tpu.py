@@ -159,8 +159,12 @@ def sp_learn(
 def sp_step(state: dict, sdr: jnp.ndarray, cfg: SPConfig, learn: bool = True):
     """One SP step -> (new_state, bool[C] active columns). Pure."""
     pool = state["members"] if cfg.sparse_pool else state["potential"]
-    overlap = sp_overlap(state["perm"], pool, sdr, cfg)
-    active = sp_inhibit(overlap, state["boost"], cfg)
+    # the scope names are the step's vocabulary (ops/step.py SCOPES)
+    with jax.named_scope("rtap.sp.overlap"):
+        overlap = sp_overlap(state["perm"], pool, sdr, cfg)
+    with jax.named_scope("rtap.sp.inhibit"):
+        active = sp_inhibit(overlap, state["boost"], cfg)
     if learn:
-        state = sp_learn(state, sdr, overlap, active, cfg)
+        with jax.named_scope("rtap.sp.learn"):
+            state = sp_learn(state, sdr, overlap, active, cfg)
     return state, active

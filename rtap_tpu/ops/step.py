@@ -33,6 +33,32 @@ from rtap_tpu.ops.encoders_tpu import bind_offsets, encode_device
 from rtap_tpu.ops.sp_tpu import sp_step
 from rtap_tpu.ops.tm_tpu import tm_step
 
+#: The names the step writes into the profiler's trace: every stage of a
+#: tick runs under `jax.named_scope(<name>)`, which lands in the `op_name`
+#: metadata of each XLA op it produces (and nowhere else: no operand, fusion
+#: decision or output changes). This tuple is the one place that lists the
+#: vocabulary; a reader of a device trace matches the prefix `rtap.` in an
+#: op's name, innermost match first (under vmap/scan/cond JAX wraps entries:
+#: `vmap(rtap.sp.overlap)`, `while/body/...`).
+#:
+#:   rtap.encode         bind_offsets + encode_device (+ enc_prev)  (_step_impl)
+#:   rtap.sp.overlap     sp_overlap incl. the member gather         (sp_tpu.sp_step)
+#:   rtap.sp.inhibit     sp_inhibit                                 (sp_tpu.sp_step)
+#:   rtap.sp.learn       sp_learn                                   (sp_tpu.sp_step)
+#:   rtap.tm.activate    cell activation, winners, raw score        (tm_tpu.tm_step)
+#:   rtap.tm.learn       reinforce/punish/grow, Pallas path too     (tm_tpu.tm_step)
+#:   rtap.tm.dendrite    dendrite activity for t+1                  (tm_tpu.tm_step)
+#:   rtap.reduce.health, rtap.reduce.predict, rtap.classifier
+#:                       the optional reducers / classifier         (_tick, _step_impl)
+#:   rtap.layout         to/from_kernel_layout, once per program   (fused_step, group_step, _scan_chunk)
+SCOPES = (
+    "rtap.encode",
+    "rtap.sp.overlap", "rtap.sp.inhibit", "rtap.sp.learn",
+    "rtap.tm.activate", "rtap.tm.learn", "rtap.tm.dendrite",
+    "rtap.reduce.health", "rtap.reduce.predict", "rtap.classifier",
+    "rtap.layout",
+)
+
 
 def _step_impl(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: ModelConfig, learn: bool,
               inv: dict | None = None):
@@ -46,25 +72,27 @@ def _step_impl(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: Mode
     tick-invariant operands (ops/tm_tpu.tm_invariants) when the caller
     hoists them out of a scan; None rebuilds them in-trace.
     """
-    enc_offset, enc_bound = bind_offsets(values, state["enc_offset"], state["enc_bound"])
-    state = {**state, "enc_offset": enc_offset, "enc_bound": enc_bound}
-    enc_prev = state.get("enc_prev")  # composite delta fields only
-    sdr = encode_device(cfg, values, ts_unix, enc_offset,
-                        state["enc_resolution"], enc_prev)
-    if enc_prev is not None:
-        # the delta predecessor advances to the last FINITE value AFTER
-        # encoding (this tick encoded against the pre-tick predecessor);
-        # NaN gaps keep the pre-gap baseline, mirroring offset binding
-        state["enc_prev"] = jnp.where(jnp.isfinite(values), values, enc_prev)
+    with jax.named_scope("rtap.encode"):
+        enc_offset, enc_bound = bind_offsets(values, state["enc_offset"], state["enc_bound"])
+        state = {**state, "enc_offset": enc_offset, "enc_bound": enc_bound}
+        enc_prev = state.get("enc_prev")  # composite delta fields only
+        sdr = encode_device(cfg, values, ts_unix, enc_offset,
+                            state["enc_resolution"], enc_prev)
+        if enc_prev is not None:
+            # the delta predecessor advances to the last FINITE value AFTER
+            # encoding (this tick encoded against the pre-tick predecessor);
+            # NaN gaps keep the pre-gap baseline, mirroring offset binding
+            state["enc_prev"] = jnp.where(jnp.isfinite(values), values, enc_prev)
     pattern_prev = state["prev_active"]  # TM active cells at t-1
     state, active = sp_step(state, sdr, cfg.sp, learn)
     state, raw = tm_step(state, active, cfg.tm, learn, inv=inv)
     if cfg.classifier.enabled:
         from rtap_tpu.ops.classifier_tpu import classifier_step
 
-        state, pred, conf = classifier_step(
-            state, pattern_prev, state["prev_active"], values[0], cfg, learn
-        )
+        with jax.named_scope("rtap.classifier"):
+            state, pred, conf = classifier_step(
+                state, pattern_prev, state["prev_active"], values[0], cfg, learn
+            )
         return state, (raw, pred, conf)
     return state, raw
 
@@ -76,8 +104,11 @@ def fused_step(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: Mode
     """Single-stream fused step (see :func:`_step_impl`)."""
     from rtap_tpu.ops.tm_tpu import from_kernel_layout, to_kernel_layout
 
-    state, out = _step_impl(to_kernel_layout(state), values, ts_unix, cfg, learn)
-    return from_kernel_layout(state, cfg.tm), out
+    with jax.named_scope("rtap.layout"):
+        state = to_kernel_layout(state)
+    state, out = _step_impl(state, values, ts_unix, cfg, learn)
+    with jax.named_scope("rtap.layout"):
+        return from_kernel_layout(state, cfg.tm), out
 
 
 def _tick(s: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: ModelConfig, learn: bool,
@@ -125,12 +156,14 @@ def _tick(s: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: ModelConfig, 
     if predict:
         from rtap_tpu.ops.predict_tpu import predict_update
 
-        s, pleaf = predict_update(s, values, cfg)
+        with jax.named_scope("rtap.reduce.predict"):
+            s, pleaf = predict_update(s, values, cfg)
     if health:
         from rtap_tpu.ops.health_tpu import health_reduce
 
         raw = out[0] if cfg.classifier.enabled else out
-        out = (out, health_reduce(s, raw, values, cfg))
+        with jax.named_scope("rtap.reduce.health"):
+            out = (out, health_reduce(s, raw, values, cfg))
     if predict:
         out = (out, pleaf)
     return s, out
@@ -151,9 +184,12 @@ def group_step(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: Mode
     """
     from rtap_tpu.ops.tm_tpu import from_kernel_layout, to_kernel_layout
 
-    state, out = _tick(to_kernel_layout(state), values, ts_unix, cfg, learn,
+    with jax.named_scope("rtap.layout"):
+        state = to_kernel_layout(state)
+    state, out = _tick(state, values, ts_unix, cfg, learn,
                        health=health, predict=predict)
-    return from_kernel_layout(state, cfg.tm), out
+    with jax.named_scope("rtap.layout"):
+        return from_kernel_layout(state, cfg.tm), out
 
 
 def _scan_chunk(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: ModelConfig, learn: bool,
@@ -179,8 +215,11 @@ def _scan_chunk(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: Mod
         return _tick(s, v, t, cfg, learn, inv, health=health,
                      predict=predict)
 
-    state, out = jax.lax.scan(body, to_kernel_layout(state), (values, ts_unix))
-    return from_kernel_layout(state, cfg.tm), out
+    with jax.named_scope("rtap.layout"):
+        state = to_kernel_layout(state)
+    state, out = jax.lax.scan(body, state, (values, ts_unix))
+    with jax.named_scope("rtap.layout"):
+        return from_kernel_layout(state, cfg.tm), out
 
 
 # rtap: twin[oracle_record_step] — time-scanned form of the oracle chain

@@ -571,38 +571,40 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
     seg_last = state["seg_last"]
     it = state["tm_iter"] + 1
 
-    # 4-D views of the SMALL segment tensors for the categorization logic
-    # (32 KB each — cheap to repack; the MB-scale pools never leave flat)
-    active_seg4 = state["active_seg"].reshape(C, K, S)
-    matching_seg4 = state["matching_seg"].reshape(C, K, S)
-    seg_pot4 = state["seg_pot"].reshape(C, K, S)
-    seg_last4 = seg_last.reshape(C, K, S)
+    # the scope names are the step's vocabulary (ops/step.py SCOPES)
+    with jax.named_scope("rtap.tm.activate"):
+        # 4-D views of the SMALL segment tensors for the categorization logic
+        # (32 KB each — cheap to repack; the MB-scale pools never leave flat)
+        active_seg4 = state["active_seg"].reshape(C, K, S)
+        matching_seg4 = state["matching_seg"].reshape(C, K, S)
+        seg_pot4 = state["seg_pot"].reshape(C, K, S)
+        seg_last4 = seg_last.reshape(C, K, S)
 
-    prev_predictive = active_seg4.any(-1)  # [C, K]
-    prev_pred_cols = prev_predictive.any(-1)
-    n_active = active_cols.sum()
-    raw = jnp.where(
-        n_active > 0,
-        1.0 - (active_cols & prev_pred_cols).sum() / jnp.maximum(n_active, 1).astype(jnp.float32),
-        0.0,
-    )
+        prev_predictive = active_seg4.any(-1)  # [C, K]
+        prev_pred_cols = prev_predictive.any(-1)
+        n_active = active_cols.sum()
+        raw = jnp.where(
+            n_active > 0,
+            1.0 - (active_cols & prev_pred_cols).sum() / jnp.maximum(n_active, 1).astype(jnp.float32),
+            0.0,
+        )
 
-    have_winners = state["prev_winner"].any()
+        have_winners = state["prev_winner"].any()
 
-    predicted_cols, learn_mask, alloc, winner_extra, burst = _segment_learning_mask(
-        cfg, active_cols, active_seg4, matching_seg4, seg_pot4,
-        seg_last4, have_winners,
-    )
+        predicted_cols, learn_mask, alloc, winner_extra, burst = _segment_learning_mask(
+            cfg, active_cols, active_seg4, matching_seg4, seg_pot4,
+            seg_last4, have_winners,
+        )
 
-    # cell activation / winner selection (pure function of prev state)
-    active_cells = (
-        jnp.where((active_cols & predicted_cols)[:, None], prev_predictive, False)
-        | burst[:, None]
-    )
-    winner_cells = (
-        jnp.where((active_cols & predicted_cols)[:, None], prev_predictive, False)
-        | winner_extra
-    )
+        # cell activation / winner selection (pure function of prev state)
+        active_cells = (
+            jnp.where((active_cols & predicted_cols)[:, None], prev_predictive, False)
+            | burst[:, None]
+        )
+        winner_cells = (
+            jnp.where((active_cols & predicted_cols)[:, None], prev_predictive, False)
+            | winner_extra
+        )
 
     # Strategy resolution for this trace. The forward index cannot survive a
     # dense death sweep (presyn mutates without index updates), so forward
@@ -649,386 +651,388 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
 
     overflow_learn = jnp.bool_(False)
     conn_count = pot_count = tm_overflow = None
-    if pallas_learn:
-        # --- the whole learning pass as ONE Pallas kernel, VMEM-resident
-        # (ops/pallas_tm.py): decisions stay here on [C, K, S]-scale
-        # tensors; the kernel owns every pool traversal including the
-        # dendrite counts for t+1 ---
-        from rtap_tpu.ops.pallas_tm import tm_learn_pallas
+    with jax.named_scope("rtap.tm.learn"):
+        if pallas_learn:
+            # --- the whole learning pass as ONE Pallas kernel, VMEM-resident
+            # (ops/pallas_tm.py): decisions stay here on [C, K, S]-scale
+            # tensors; the kernel owns every pool traversal including the
+            # dendrite counts for t+1 ---
+            from rtap_tpu.ops.pallas_tm import tm_learn_pallas
 
-        pcol_ids, pcol_masks, p_cols = _pack_active(state["prev_active"], Ac)
-        winner_ids = _winner_id_list(state["prev_winner"], Ac)  # [Ac*K]
-        acol_ids, acol_masks, a_cols = _pack_active(active_cells, Ac)
-        presyn_n, perm_n, sl, conn_f, pot_f, overflow_learn = tm_learn_pallas(
-            cfg, dom, presyn, syn_perm, seg_last,
-            seg_pot4, matching_seg4, learn_mask, alloc,
-            active_cols, have_winners, it,
-            pcol_ids, pcol_masks, p_cols, winner_ids,
-            acol_ids, acol_masks, interpret=_PALLAS_INTERPRET,
-        )
-        presyn = presyn_n.astype(presyn_dt).reshape(*pool_shape)
-        perm_w = jnp.round(perm_n) if dom.bits else perm_n  # exact already
-        syn_perm = perm_w.astype(p_dt).reshape(*pool_shape)
-        seg_last = sl.reshape(*seg_shape)
-        conn_count = conn_f.reshape(*seg_shape)
-        pot_count = pot_f.reshape(*seg_shape)
-        tm_overflow = state["tm_overflow"] + (
-            overflow_learn | (a_cols > Ac)
-        ).astype(jnp.int32)
-    if learn and not pallas_learn:
-        alloc_col, bn_k, bn_s = alloc
-        burst_new = alloc_col < C  # [C]
-
-        # --- gather the active columns into the [Ac, ...] workspace ---
-        indexed = scatter_mode() == "indexed"
-        col_ids = _compact_ids(active_cols, Ac)  # [Ac], fills = C
-        col_oh_b = col_ids[:, None] == jnp.arange(C, dtype=jnp.int32)  # [Ac, C]
-        col_oh = col_oh_b.astype(jnp.float32)
-        hit_cols = col_oh_b.any(0)  # [C] columns actually captured (== active_cols sans overflow)
-
-        if indexed:
-            # move only the <= Ac touched rows; fill slots (id C) clamp to a
-            # junk copy of row C-1 that is masked out of learning (ws_learn /
-            # ws_alloc are False there) and dropped at scatter-back
-            idx_c = jnp.clip(col_ids, 0, C - 1)
-            ws_presyn = presyn.reshape(C, -1)[idx_c].astype(jnp.int32)
-            ws_perm = syn_perm.reshape(C, -1)[idx_c].astype(jnp.float32)
-            ws_last = seg_last.reshape(C, -1)[idx_c].reshape(Ac, K, S)
-            ws_pot = state["seg_pot"].reshape(C, -1)[idx_c].astype(jnp.int32).reshape(Ac, K, S)
-            ws_learn = (
-                learn_mask.reshape(C, -1)[idx_c] & (col_ids < C)[:, None]
-            ).reshape(Ac, K, S)
-        else:
-            # ONE one-hot MXU pass gathers presyn + perm + seg_pot together
-            # (fused-region consolidation: each output element of the
-            # concatenated matmul touches only its own operand block, so
-            # the values are bitwise those of the three separate gathers;
-            # seg_pot <= M << 2^24 and cell ids < 2^24 are f32-exact)
-            KSM = K * S * M
-            cat = jnp.concatenate(
-                [
-                    presyn.reshape(C, -1).astype(jnp.float32),
-                    syn_perm.reshape(C, -1).astype(jnp.float32),
-                    state["seg_pot"].reshape(C, -1).astype(jnp.float32),
-                ],
-                axis=1,
-            )  # [C, 2*KSM + K*S]
-            g = _gather_rows_f32(cat, col_oh)  # [Ac, 2*KSM + K*S]
-            ws_presyn = jnp.round(g[:, :KSM]).astype(jnp.int32)  # [Ac, K*S*M]
-            ws_perm = g[:, KSM:2 * KSM]  # [Ac, K*S*M]
-            ws_pot = jnp.round(g[:, 2 * KSM:]).astype(jnp.int32).reshape(Ac, K, S)
-            # seg_last carries unbounded iteration stamps (> 2^24 possible):
-            # it keeps the exact integer gather
-            ws_last = _gather_rows_i32(seg_last.reshape(C, -1), col_oh_b).reshape(Ac, K, S)
-            ws_learn = (
-                (col_oh_b[:, :, None] & learn_mask.reshape(C, -1)[None]).any(1).reshape(Ac, K, S)
+            pcol_ids, pcol_masks, p_cols = _pack_active(state["prev_active"], Ac)
+            winner_ids = _winner_id_list(state["prev_winner"], Ac)  # [Ac*K]
+            acol_ids, acol_masks, a_cols = _pack_active(active_cells, Ac)
+            presyn_n, perm_n, sl, conn_f, pot_f, overflow_learn = tm_learn_pallas(
+                cfg, dom, presyn, syn_perm, seg_last,
+                seg_pot4, matching_seg4, learn_mask, alloc,
+                active_cols, have_winners, it,
+                pcol_ids, pcol_masks, p_cols, winner_ids,
+                acol_ids, acol_masks, interpret=_PALLAS_INTERPRET,
             )
+            presyn = presyn_n.astype(presyn_dt).reshape(*pool_shape)
+            perm_w = jnp.round(perm_n) if dom.bits else perm_n  # exact already
+            syn_perm = perm_w.astype(p_dt).reshape(*pool_shape)
+            seg_last = sl.reshape(*seg_shape)
+            conn_count = conn_f.reshape(*seg_shape)
+            pot_count = pot_f.reshape(*seg_shape)
+            tm_overflow = state["tm_overflow"] + (
+                overflow_learn | (a_cols > Ac)
+            ).astype(jnp.int32)
+        if learn and not pallas_learn:
+            alloc_col, bn_k, bn_s = alloc
+            burst_new = alloc_col < C  # [C]
 
-        # original pool content of the workspace (pre alloc-clear): the
-        # forward-index maintenance diffs learned rows against it
-        ws_presyn0_r = ws_presyn.reshape(Ac * K * S, M) if forward else None
+            # --- gather the active columns into the [Ac, ...] workspace ---
+            indexed = scatter_mode() == "indexed"
+            col_ids = _compact_ids(active_cols, Ac)  # [Ac], fills = C
+            col_oh_b = col_ids[:, None] == jnp.arange(C, dtype=jnp.int32)  # [Ac, C]
+            col_oh = col_oh_b.astype(jnp.float32)
+            hit_cols = col_oh_b.any(0)  # [C] columns actually captured (== active_cols sans overflow)
 
-        # --- burst-new allocation inside the workspace: clear slot + stamp ---
-        ws_bn = (col_oh_b & burst_new[None, :]).any(-1)  # [Ac]
-        ws_bnk = jnp.where(col_oh_b, bn_k[None, :], 0).sum(-1)  # [Ac]
-        ws_bns = jnp.where(col_oh_b, bn_s[None, :], 0).sum(-1)
-        sel_k = jnp.arange(K, dtype=jnp.int32)[None, :] == ws_bnk[:, None]  # [Ac, K]
-        sel_s = jnp.arange(S, dtype=jnp.int32)[None, :] == ws_bns[:, None]  # [Ac, S]
-        ws_alloc = ws_bn[:, None, None] & sel_k[:, :, None] & sel_s[:, None, :]  # [Ac, K, S]
-        alloc_lanes = jnp.repeat(ws_alloc.reshape(Ac, K * S), M, axis=-1)  # [Ac, K*S*M]
-        ws_presyn = jnp.where(alloc_lanes, -1, ws_presyn)
-        ws_perm = jnp.where(alloc_lanes, 0.0, ws_perm)
-        ws_pot = jnp.where(ws_alloc, 0, ws_pot)
-        ws_last = jnp.where(ws_alloc, it, ws_last)
-        ws_learn = ws_learn | ws_alloc
-
-        # --- compact the <= learn_cap learning segments within the workspace ---
-        R2 = Ac * K * S
-        idx = _compact_ids(ws_learn.reshape(-1), L)  # [L], fills = R2
-        valid_l = idx < R2
-        ws_presyn_r = ws_presyn.reshape(R2, M)
-        ws_perm_r = ws_perm.reshape(R2, M)
-        presyn_l0 = None
-        if indexed:
-            idx_r = jnp.clip(idx, 0, R2 - 1)
-            presyn_l = ws_presyn_r[idx_r]  # [L, M]; fill rows junk, see below
-            perm_l = ws_perm_r[idx_r]
-            pot_l = jnp.where(valid_l, ws_pot.reshape(-1)[idx_r], 0)  # [L]
-            if forward:
-                presyn_l0 = ws_presyn0_r[idx_r]
-        else:
-            row_oh_b = idx[:, None] == jnp.arange(R2, dtype=jnp.int32)  # [L, R2]
-            row_oh = row_oh_b.astype(jnp.float32)
-            # presyn + perm (+ the forward diff base) compact in ONE
-            # [L, R2] MXU pass — same consolidation as the column gather
-            parts = [ws_presyn_r.astype(jnp.float32), ws_perm_r]
-            if forward:
-                parts.append(ws_presyn0_r.astype(jnp.float32))
-            gl = _gather_rows_f32(jnp.concatenate(parts, axis=1), row_oh)  # [L, 2-3M]
-            presyn_l = jnp.round(gl[:, :M]).astype(jnp.int32)  # [L, M]
-            perm_l = gl[:, M:2 * M]  # [L, M]
-            pot_l = jnp.where(row_oh_b, ws_pot.reshape(-1)[None, :], 0).sum(-1)  # [L]
-            if forward:
-                presyn_l0 = jnp.round(gl[:, 2 * M:]).astype(jnp.int32)
-
-        # prev-step active cells, column-compact (shared by reinforce + punish)
-        pcol_ids, pcol_masks, p_cols = _pack_active(state["prev_active"], Ac)
-
-        # reinforce: +inc on synapses to prev-active cells, -dec on the rest
-        exists = presyn_l >= 0
-        act = _presyn_active_packed(presyn_l, pcol_ids, pcol_masks, K)
-        perm_l = jnp.clip(
-            perm_l + p_inc * act - p_dec * (exists & ~act),
-            0.0,
-            p_one,
-        )
-
-        # grow toward previous winner cells (ascending id)
-        winner_ids = _winner_id_list(state["prev_winner"], Ac)  # [Ac*K]
-        n_grow = (cfg.new_synapse_count - pot_l).astype(jnp.int32)
-        grown_presyn, grown_perm = _grow_compact(
-            cfg, presyn_l, perm_l, n_grow, winner_ids, N, p_init
-        )
-        grow_ok = have_winners & valid_l
-        presyn_l = jnp.where(grow_ok[:, None], grown_presyn, presyn_l)
-        perm_l = jnp.where(grow_ok[:, None], grown_perm, perm_l)
-
-        last_l = jnp.full((L,), 1, jnp.int32) * it  # [L] seg_last of learned rows
-        if compact_sweep:
-            # Synapse death (perm <= 0 after reinforce) and empty-segment
-            # death applied IN the workspace: learned rows are the only
-            # active-column rows whose perms moved this step, so handling
-            # them here (and punished rows below) makes the dense full-pool
-            # death sweep redundant — that equivalence is the compact-sweep
-            # contract (tests/parity/test_sweep_parity.py).
-            dead_l = (presyn_l >= 0) & (perm_l <= jnp.float32(dom.zero))
-            presyn_l = jnp.where(dead_l, -1, presyn_l)
-            last_l = jnp.where((presyn_l >= 0).sum(-1) == 0, -1, last_l)
-
-        # --- scatter learned rows back into the workspace ---
-        if indexed:
-            hit_rows = jnp.zeros(R2, bool).at[idx].set(True, mode="drop")
-            ws_presyn_r = ws_presyn_r.at[idx].set(presyn_l, mode="drop")
-            ws_perm_r = ws_perm_r.at[idx].set(perm_l, mode="drop")
-        else:
-            hit_rows = row_oh_b.any(0)  # [R2]
-            # presyn + perm scatter back in ONE transposed one-hot MXU pass
-            scat = jax.lax.dot(
-                row_oh.T,
-                jnp.concatenate([presyn_l.astype(jnp.float32), perm_l], axis=1),
-                precision=_HI,
-            )  # [R2, 2M]
-            scat_presyn = jnp.round(scat[:, :M]).astype(jnp.int32)
-            scat_perm = scat[:, M:]
-            ws_presyn_r = jnp.where(hit_rows[:, None], scat_presyn, ws_presyn_r)
-            ws_perm_r = jnp.where(hit_rows[:, None], scat_perm, ws_perm_r)
-        if indexed:
-            ws_last = (
-                ws_last.reshape(R2).at[idx].set(last_l, mode="drop").reshape(Ac, K, S)
-            )
-        else:
-            last_scat = jnp.where(row_oh_b, last_l[:, None], 0).sum(0)  # [R2]
-            ws_last = jnp.where(
-                hit_rows.reshape(Ac, K, S), last_scat.reshape(Ac, K, S), ws_last
-            )
-
-        # --- scatter the workspace back to the pools ---
-        if indexed:
-            # only the <= Ac touched rows are written; fill ids (C) drop
-            presyn = (
-                presyn.reshape(C, -1)
-                .at[col_ids]
-                .set(ws_presyn_r.reshape(Ac, -1).astype(presyn_dt), mode="drop")
-                .reshape(*pool_shape)
-            )
-            ws_perm_w = ws_perm_r.reshape(Ac, -1)
-            if dom.bits:
-                ws_perm_w = jnp.round(ws_perm_w)  # exact already; belt+braces
-            syn_perm = (
-                syn_perm.reshape(C, -1)
-                .at[col_ids]
-                .set(ws_perm_w.astype(p_dt), mode="drop")
-                .reshape(*pool_shape)
-            )
-            seg_last = (
-                seg_last.reshape(C, -1)
-                .at[col_ids]
-                .set(ws_last.reshape(Ac, -1), mode="drop")
-                .reshape(*seg_shape)
-            )
-        else:
-            hit_pool = hit_cols.reshape(C, *([1] * (len(pool_shape) - 1)))
-            hit_seg = hit_cols.reshape(C, *([1] * (len(seg_shape) - 1)))
-            # presyn + perm pools restored in ONE [C, Ac] x [Ac, 2*KSM] pass
-            KSM = K * S * M
-            pools = jax.lax.dot(
-                col_oh.T,
-                jnp.concatenate(
+            if indexed:
+                # move only the <= Ac touched rows; fill slots (id C) clamp to a
+                # junk copy of row C-1 that is masked out of learning (ws_learn /
+                # ws_alloc are False there) and dropped at scatter-back
+                idx_c = jnp.clip(col_ids, 0, C - 1)
+                ws_presyn = presyn.reshape(C, -1)[idx_c].astype(jnp.int32)
+                ws_perm = syn_perm.reshape(C, -1)[idx_c].astype(jnp.float32)
+                ws_last = seg_last.reshape(C, -1)[idx_c].reshape(Ac, K, S)
+                ws_pot = state["seg_pot"].reshape(C, -1)[idx_c].astype(jnp.int32).reshape(Ac, K, S)
+                ws_learn = (
+                    learn_mask.reshape(C, -1)[idx_c] & (col_ids < C)[:, None]
+                ).reshape(Ac, K, S)
+            else:
+                # ONE one-hot MXU pass gathers presyn + perm + seg_pot together
+                # (fused-region consolidation: each output element of the
+                # concatenated matmul touches only its own operand block, so
+                # the values are bitwise those of the three separate gathers;
+                # seg_pot <= M << 2^24 and cell ids < 2^24 are f32-exact)
+                KSM = K * S * M
+                cat = jnp.concatenate(
                     [
-                        ws_presyn_r.reshape(Ac, -1).astype(jnp.float32),
-                        ws_perm_r.reshape(Ac, -1),
+                        presyn.reshape(C, -1).astype(jnp.float32),
+                        syn_perm.reshape(C, -1).astype(jnp.float32),
+                        state["seg_pot"].reshape(C, -1).astype(jnp.float32),
                     ],
                     axis=1,
-                ),
-                precision=_HI,
-            )  # [C, 2*KSM]
-            pool_presyn = jnp.round(pools[:, :KSM]).astype(presyn_dt).reshape(*pool_shape)
-            pool_perm_f = pools[:, KSM:]
-            if dom.bits:
-                pool_perm_f = jnp.round(pool_perm_f)  # exact already; belt+braces
-            pool_perm = pool_perm_f.astype(p_dt).reshape(*pool_shape)
-            pool_last = jnp.where(
-                col_oh_b[:, :, None], ws_last.reshape(Ac, 1, -1), 0
-            ).sum(0).reshape(*seg_shape)
-            presyn = jnp.where(hit_pool, pool_presyn, presyn)
-            syn_perm = jnp.where(hit_pool, pool_perm, syn_perm)
-            seg_last = jnp.where(hit_seg, pool_last, seg_last)
+                )  # [C, 2*KSM + K*S]
+                g = _gather_rows_f32(cat, col_oh)  # [Ac, 2*KSM + K*S]
+                ws_presyn = jnp.round(g[:, :KSM]).astype(jnp.int32)  # [Ac, K*S*M]
+                ws_perm = g[:, KSM:2 * KSM]  # [Ac, K*S*M]
+                ws_pot = jnp.round(g[:, 2 * KSM:]).astype(jnp.int32).reshape(Ac, K, S)
+                # seg_last carries unbounded iteration stamps (> 2^24 possible):
+                # it keeps the exact integer gather
+                ws_last = _gather_rows_i32(seg_last.reshape(C, -1), col_oh_b).reshape(Ac, K, S)
+                ws_learn = (
+                    (col_oh_b[:, :, None] & learn_mask.reshape(C, -1)[None]).any(1).reshape(Ac, K, S)
+                )
 
-        overflow_learn = (
-            (n_active > Ac) | (p_cols > Ac) | (ws_learn.sum() > L)
-        )
+            # original pool content of the workspace (pre alloc-clear): the
+            # forward-index maintenance diffs learned rows against it
+            ws_presyn0_r = ws_presyn.reshape(Ac * K * S, M) if forward else None
 
-        slots_p = old_p = rem_p = None
-        if compact_sweep:
-            # --- compact punish/death (RTAP_TM_SWEEP=compact): gather the
-            # <= punish_cap matching segments in non-active columns, punish
-            # + kill them there, scatter back. Together with the in-workspace
-            # death above this covers every synapse whose permanence moved
-            # this step (learned rows and punished rows are disjoint by
-            # column), so the full-pool punish/death sweeps are skipped
-            # entirely — the dense sweeps re-derive death for ALL synapses,
-            # but an untouched synapse can never newly satisfy perm <= 0
-            # (death ran last learn step; inference leaves perms alone). ---
-            if cfg.predicted_segment_decrement > 0.0:
-                pdec = dom.rate(cfg.predicted_segment_decrement)
-                P = min(cfg.punish_cap, n_seg)
-                pmask_seg = (matching_seg4 & ~active_cols[:, None, None]).reshape(-1)
-                pids = _compact_ids(pmask_seg, P)  # [P], fills = n_seg
-                valid_p = pids < n_seg
-                pidc = jnp.clip(pids, 0, n_seg - 1)
-                pres_p = presyn.reshape(n_seg, M)[pidc].astype(jnp.int32)  # [P, M]
-                perm_p = syn_perm.reshape(n_seg, M)[pidc]
-                pact_p = _presyn_active_packed(pres_p, pcol_ids, pcol_masks, K)
-                sp_c = perm_p.astype(dom.compute_dtype)
-                perm_pn = jnp.where(pact_p, jnp.maximum(sp_c - pdec, dom.zero), sp_c)
-                dead_p = (pres_p >= 0) & (perm_pn <= dom.zero)
-                pres_pn = jnp.where(dead_p, -1, pres_p)
-                sl_p = seg_last.reshape(-1)[pidc]
-                sl_pn = jnp.where((sl_p >= 0) & ((pres_pn >= 0).sum(-1) == 0), -1, sl_p)
-                drop_ids = jnp.where(valid_p, pids, n_seg)  # fills -> dropped
-                syn_perm = (
-                    syn_perm.reshape(n_seg, M)
-                    .at[drop_ids]
-                    .set(perm_pn.astype(p_dt), mode="drop")
+            # --- burst-new allocation inside the workspace: clear slot + stamp ---
+            ws_bn = (col_oh_b & burst_new[None, :]).any(-1)  # [Ac]
+            ws_bnk = jnp.where(col_oh_b, bn_k[None, :], 0).sum(-1)  # [Ac]
+            ws_bns = jnp.where(col_oh_b, bn_s[None, :], 0).sum(-1)
+            sel_k = jnp.arange(K, dtype=jnp.int32)[None, :] == ws_bnk[:, None]  # [Ac, K]
+            sel_s = jnp.arange(S, dtype=jnp.int32)[None, :] == ws_bns[:, None]  # [Ac, S]
+            ws_alloc = ws_bn[:, None, None] & sel_k[:, :, None] & sel_s[:, None, :]  # [Ac, K, S]
+            alloc_lanes = jnp.repeat(ws_alloc.reshape(Ac, K * S), M, axis=-1)  # [Ac, K*S*M]
+            ws_presyn = jnp.where(alloc_lanes, -1, ws_presyn)
+            ws_perm = jnp.where(alloc_lanes, 0.0, ws_perm)
+            ws_pot = jnp.where(ws_alloc, 0, ws_pot)
+            ws_last = jnp.where(ws_alloc, it, ws_last)
+            ws_learn = ws_learn | ws_alloc
+
+            # --- compact the <= learn_cap learning segments within the workspace ---
+            R2 = Ac * K * S
+            idx = _compact_ids(ws_learn.reshape(-1), L)  # [L], fills = R2
+            valid_l = idx < R2
+            ws_presyn_r = ws_presyn.reshape(R2, M)
+            ws_perm_r = ws_perm.reshape(R2, M)
+            presyn_l0 = None
+            if indexed:
+                idx_r = jnp.clip(idx, 0, R2 - 1)
+                presyn_l = ws_presyn_r[idx_r]  # [L, M]; fill rows junk, see below
+                perm_l = ws_perm_r[idx_r]
+                pot_l = jnp.where(valid_l, ws_pot.reshape(-1)[idx_r], 0)  # [L]
+                if forward:
+                    presyn_l0 = ws_presyn0_r[idx_r]
+            else:
+                row_oh_b = idx[:, None] == jnp.arange(R2, dtype=jnp.int32)  # [L, R2]
+                row_oh = row_oh_b.astype(jnp.float32)
+                # presyn + perm (+ the forward diff base) compact in ONE
+                # [L, R2] MXU pass — same consolidation as the column gather
+                parts = [ws_presyn_r.astype(jnp.float32), ws_perm_r]
+                if forward:
+                    parts.append(ws_presyn0_r.astype(jnp.float32))
+                gl = _gather_rows_f32(jnp.concatenate(parts, axis=1), row_oh)  # [L, 2-3M]
+                presyn_l = jnp.round(gl[:, :M]).astype(jnp.int32)  # [L, M]
+                perm_l = gl[:, M:2 * M]  # [L, M]
+                pot_l = jnp.where(row_oh_b, ws_pot.reshape(-1)[None, :], 0).sum(-1)  # [L]
+                if forward:
+                    presyn_l0 = jnp.round(gl[:, 2 * M:]).astype(jnp.int32)
+
+            # prev-step active cells, column-compact (shared by reinforce + punish)
+            pcol_ids, pcol_masks, p_cols = _pack_active(state["prev_active"], Ac)
+
+            # reinforce: +inc on synapses to prev-active cells, -dec on the rest
+            exists = presyn_l >= 0
+            act = _presyn_active_packed(presyn_l, pcol_ids, pcol_masks, K)
+            perm_l = jnp.clip(
+                perm_l + p_inc * act - p_dec * (exists & ~act),
+                0.0,
+                p_one,
+            )
+
+            # grow toward previous winner cells (ascending id)
+            winner_ids = _winner_id_list(state["prev_winner"], Ac)  # [Ac*K]
+            n_grow = (cfg.new_synapse_count - pot_l).astype(jnp.int32)
+            grown_presyn, grown_perm = _grow_compact(
+                cfg, presyn_l, perm_l, n_grow, winner_ids, N, p_init
+            )
+            grow_ok = have_winners & valid_l
+            presyn_l = jnp.where(grow_ok[:, None], grown_presyn, presyn_l)
+            perm_l = jnp.where(grow_ok[:, None], grown_perm, perm_l)
+
+            last_l = jnp.full((L,), 1, jnp.int32) * it  # [L] seg_last of learned rows
+            if compact_sweep:
+                # Synapse death (perm <= 0 after reinforce) and empty-segment
+                # death applied IN the workspace: learned rows are the only
+                # active-column rows whose perms moved this step, so handling
+                # them here (and punished rows below) makes the dense full-pool
+                # death sweep redundant — that equivalence is the compact-sweep
+                # contract (tests/parity/test_sweep_parity.py).
+                dead_l = (presyn_l >= 0) & (perm_l <= jnp.float32(dom.zero))
+                presyn_l = jnp.where(dead_l, -1, presyn_l)
+                last_l = jnp.where((presyn_l >= 0).sum(-1) == 0, -1, last_l)
+
+            # --- scatter learned rows back into the workspace ---
+            if indexed:
+                hit_rows = jnp.zeros(R2, bool).at[idx].set(True, mode="drop")
+                ws_presyn_r = ws_presyn_r.at[idx].set(presyn_l, mode="drop")
+                ws_perm_r = ws_perm_r.at[idx].set(perm_l, mode="drop")
+            else:
+                hit_rows = row_oh_b.any(0)  # [R2]
+                # presyn + perm scatter back in ONE transposed one-hot MXU pass
+                scat = jax.lax.dot(
+                    row_oh.T,
+                    jnp.concatenate([presyn_l.astype(jnp.float32), perm_l], axis=1),
+                    precision=_HI,
+                )  # [R2, 2M]
+                scat_presyn = jnp.round(scat[:, :M]).astype(jnp.int32)
+                scat_perm = scat[:, M:]
+                ws_presyn_r = jnp.where(hit_rows[:, None], scat_presyn, ws_presyn_r)
+                ws_perm_r = jnp.where(hit_rows[:, None], scat_perm, ws_perm_r)
+            if indexed:
+                ws_last = (
+                    ws_last.reshape(R2).at[idx].set(last_l, mode="drop").reshape(Ac, K, S)
+                )
+            else:
+                last_scat = jnp.where(row_oh_b, last_l[:, None], 0).sum(0)  # [R2]
+                ws_last = jnp.where(
+                    hit_rows.reshape(Ac, K, S), last_scat.reshape(Ac, K, S), ws_last
+                )
+
+            # --- scatter the workspace back to the pools ---
+            if indexed:
+                # only the <= Ac touched rows are written; fill ids (C) drop
+                presyn = (
+                    presyn.reshape(C, -1)
+                    .at[col_ids]
+                    .set(ws_presyn_r.reshape(Ac, -1).astype(presyn_dt), mode="drop")
                     .reshape(*pool_shape)
                 )
-                presyn = (
-                    presyn.reshape(n_seg, M)
-                    .at[drop_ids]
-                    .set(pres_pn.astype(presyn_dt), mode="drop")
+                ws_perm_w = ws_perm_r.reshape(Ac, -1)
+                if dom.bits:
+                    ws_perm_w = jnp.round(ws_perm_w)  # exact already; belt+braces
+                syn_perm = (
+                    syn_perm.reshape(C, -1)
+                    .at[col_ids]
+                    .set(ws_perm_w.astype(p_dt), mode="drop")
                     .reshape(*pool_shape)
                 )
                 seg_last = (
-                    seg_last.reshape(-1)
-                    .at[drop_ids]
-                    .set(sl_pn, mode="drop")
+                    seg_last.reshape(C, -1)
+                    .at[col_ids]
+                    .set(ws_last.reshape(Ac, -1), mode="drop")
                     .reshape(*seg_shape)
                 )
-                overflow_learn = overflow_learn | (pmask_seg.sum() > P)
-                if forward:
-                    slots_p = pidc[:, None] * M + jnp.arange(M, dtype=jnp.int32)
-                    old_p = pres_p
-                    rem_p = valid_p[:, None] & dead_p
-        else:
-            # --- dense punish: matching segments in columns that did not
-            # activate, over the full pool ---
-            if cfg.predicted_segment_decrement > 0.0:
-                pdec = dom.rate(cfg.predicted_segment_decrement)
-                acols_seg = active_cols.reshape(C, *([1] * (len(seg_shape) - 1)))
-                pmask = state["matching_seg"] & ~acols_seg  # [*seg_shape]
-                pact = _presyn_active_packed(presyn, pcol_ids, pcol_masks, K)
-                sp_c = syn_perm.astype(dom.compute_dtype)
-                syn_perm = jnp.where(
-                    seg_expand(pmask) & pact,
-                    jnp.maximum(sp_c - pdec, dom.zero),
-                    sp_c,
-                ).astype(p_dt)
-
-            # --- synapse death at permanence <= 0, then empty-segment death ---
-            dead = (presyn >= 0) & (syn_perm <= dom.zero)
-            presyn = jnp.where(dead, -1, presyn)
-            nsyn = seg_sum(presyn >= 0)
-            seg_last = jnp.where((seg_last >= 0) & (nsyn == 0), -1, seg_last)
-
-        if forward:
-            # --- forward-index maintenance: diff the touched rows against
-            # their original pool content and apply removals, then appends
-            # (ops/fwd_index.py). Touched rows = the L learned workspace rows
-            # (evictions, alloc-clears, growth, reinforce-death) + the P
-            # punished rows (death only). ---
-            from rtap_tpu.ops.fwd_index import apply_appends, apply_removals
-
-            a_i = idx // (K * S)
-            gcol = jnp.where(valid_l, col_ids[jnp.clip(a_i, 0, Ac - 1)], C)
-            vs_l = valid_l & (gcol < C)  # [L]
-            seg_flat_l = jnp.where(vs_l, gcol * (K * S) + (idx % (K * S)), n_seg)
-            slots_l = seg_flat_l[:, None] * M + jnp.arange(M, dtype=jnp.int32)  # [L, M]
-            changed = presyn_l0 != presyn_l
-            rem_l = vs_l[:, None] & changed & (presyn_l0 >= 0)
-            add_l = vs_l[:, None] & changed & (presyn_l >= 0)
-            if slots_p is not None:
-                slots_all = jnp.concatenate([slots_l.reshape(-1), slots_p.reshape(-1)])
-                old_all = jnp.concatenate([presyn_l0.reshape(-1), old_p.reshape(-1)])
-                rem_all = jnp.concatenate([rem_l.reshape(-1), rem_p.reshape(-1)])
             else:
-                slots_all = slots_l.reshape(-1)
-                old_all = presyn_l0.reshape(-1)
-                rem_all = rem_l.reshape(-1)
-            fwd_slots, fwd_pos = apply_removals(
-                fwd_slots, fwd_pos, slots_all, old_all, rem_all
-            )
-            fwd_slots, fwd_pos, ndrop = apply_appends(
-                fwd_slots, fwd_pos, slots_l.reshape(-1),
-                presyn_l.reshape(-1), add_l.reshape(-1),
-            )
-            fwd_of = fwd_of + ndrop
+                hit_pool = hit_cols.reshape(C, *([1] * (len(pool_shape) - 1)))
+                hit_seg = hit_cols.reshape(C, *([1] * (len(seg_shape) - 1)))
+                # presyn + perm pools restored in ONE [C, Ac] x [Ac, 2*KSM] pass
+                KSM = K * S * M
+                pools = jax.lax.dot(
+                    col_oh.T,
+                    jnp.concatenate(
+                        [
+                            ws_presyn_r.reshape(Ac, -1).astype(jnp.float32),
+                            ws_perm_r.reshape(Ac, -1),
+                        ],
+                        axis=1,
+                    ),
+                    precision=_HI,
+                )  # [C, 2*KSM]
+                pool_presyn = jnp.round(pools[:, :KSM]).astype(presyn_dt).reshape(*pool_shape)
+                pool_perm_f = pools[:, KSM:]
+                if dom.bits:
+                    pool_perm_f = jnp.round(pool_perm_f)  # exact already; belt+braces
+                pool_perm = pool_perm_f.astype(p_dt).reshape(*pool_shape)
+                pool_last = jnp.where(
+                    col_oh_b[:, :, None], ws_last.reshape(Ac, 1, -1), 0
+                ).sum(0).reshape(*seg_shape)
+                presyn = jnp.where(hit_pool, pool_presyn, presyn)
+                syn_perm = jnp.where(hit_pool, pool_perm, syn_perm)
+                seg_last = jnp.where(hit_seg, pool_last, seg_last)
 
-    # --- dendrite activity for t+1 over existing segments ---
-    exists_seg = seg_last >= 0
-    if pallas_learn:
-        pass  # the megakernel already produced conn/pot counts + overflow
-    elif forward:
-        # forward index: gather only the <= Ac*K active cells' fanout rows
-        # (ops/fwd_index.py) instead of sweeping the pools
-        from rtap_tpu.ops.fwd_index import dendrite_counts
+            overflow_learn = (
+                (n_active > Ac) | (p_cols > Ac) | (ws_learn.sum() > L)
+            )
 
-        a_cols = active_cells.any(-1).sum()
-        tm_overflow = state["tm_overflow"] + (
-            overflow_learn | (a_cols > Ac)
-        ).astype(jnp.int32)
-        act_ids = _winner_id_list(active_cells, Ac)  # [Ac*K], fills = N
-        conn_c, pot_c = dendrite_counts(
-            fwd_slots, syn_perm.reshape(-1), act_ids, p_connected,
-            n_seg, M, fwd_impl(),
-        )
-        conn_count = conn_c.reshape(*seg_shape)
-        pot_count = pot_c.reshape(*seg_shape)
-    else:
-        acol_ids, acol_masks, a_cols = _pack_active(active_cells, Ac)
-        # the packed-column truncation applies under inference too — count it always
-        tm_overflow = state["tm_overflow"] + (
-            overflow_learn | (a_cols > Ac)
-        ).astype(jnp.int32)
-        syn_act = _presyn_active_packed(presyn, acol_ids, acol_masks, K)
-        conn_count, pot_count = seg_sum2(
-            syn_act & (syn_perm >= p_connected), syn_act
-        )
-    active_seg = exists_seg & (conn_count >= cfg.activation_threshold)
-    matching_seg = exists_seg & (pot_count >= cfg.min_threshold)
-    seg_pot = jnp.where(exists_seg, pot_count, 0).astype(jnp.int16)
-    if learn:
-        # LRU stamp for active segments (NuPIC stamps under learn only)
-        seg_last = jnp.where(active_seg, it, seg_last)
+            slots_p = old_p = rem_p = None
+            if compact_sweep:
+                # --- compact punish/death (RTAP_TM_SWEEP=compact): gather the
+                # <= punish_cap matching segments in non-active columns, punish
+                # + kill them there, scatter back. Together with the in-workspace
+                # death above this covers every synapse whose permanence moved
+                # this step (learned rows and punished rows are disjoint by
+                # column), so the full-pool punish/death sweeps are skipped
+                # entirely — the dense sweeps re-derive death for ALL synapses,
+                # but an untouched synapse can never newly satisfy perm <= 0
+                # (death ran last learn step; inference leaves perms alone). ---
+                if cfg.predicted_segment_decrement > 0.0:
+                    pdec = dom.rate(cfg.predicted_segment_decrement)
+                    P = min(cfg.punish_cap, n_seg)
+                    pmask_seg = (matching_seg4 & ~active_cols[:, None, None]).reshape(-1)
+                    pids = _compact_ids(pmask_seg, P)  # [P], fills = n_seg
+                    valid_p = pids < n_seg
+                    pidc = jnp.clip(pids, 0, n_seg - 1)
+                    pres_p = presyn.reshape(n_seg, M)[pidc].astype(jnp.int32)  # [P, M]
+                    perm_p = syn_perm.reshape(n_seg, M)[pidc]
+                    pact_p = _presyn_active_packed(pres_p, pcol_ids, pcol_masks, K)
+                    sp_c = perm_p.astype(dom.compute_dtype)
+                    perm_pn = jnp.where(pact_p, jnp.maximum(sp_c - pdec, dom.zero), sp_c)
+                    dead_p = (pres_p >= 0) & (perm_pn <= dom.zero)
+                    pres_pn = jnp.where(dead_p, -1, pres_p)
+                    sl_p = seg_last.reshape(-1)[pidc]
+                    sl_pn = jnp.where((sl_p >= 0) & ((pres_pn >= 0).sum(-1) == 0), -1, sl_p)
+                    drop_ids = jnp.where(valid_p, pids, n_seg)  # fills -> dropped
+                    syn_perm = (
+                        syn_perm.reshape(n_seg, M)
+                        .at[drop_ids]
+                        .set(perm_pn.astype(p_dt), mode="drop")
+                        .reshape(*pool_shape)
+                    )
+                    presyn = (
+                        presyn.reshape(n_seg, M)
+                        .at[drop_ids]
+                        .set(pres_pn.astype(presyn_dt), mode="drop")
+                        .reshape(*pool_shape)
+                    )
+                    seg_last = (
+                        seg_last.reshape(-1)
+                        .at[drop_ids]
+                        .set(sl_pn, mode="drop")
+                        .reshape(*seg_shape)
+                    )
+                    overflow_learn = overflow_learn | (pmask_seg.sum() > P)
+                    if forward:
+                        slots_p = pidc[:, None] * M + jnp.arange(M, dtype=jnp.int32)
+                        old_p = pres_p
+                        rem_p = valid_p[:, None] & dead_p
+            else:
+                # --- dense punish: matching segments in columns that did not
+                # activate, over the full pool ---
+                if cfg.predicted_segment_decrement > 0.0:
+                    pdec = dom.rate(cfg.predicted_segment_decrement)
+                    acols_seg = active_cols.reshape(C, *([1] * (len(seg_shape) - 1)))
+                    pmask = state["matching_seg"] & ~acols_seg  # [*seg_shape]
+                    pact = _presyn_active_packed(presyn, pcol_ids, pcol_masks, K)
+                    sp_c = syn_perm.astype(dom.compute_dtype)
+                    syn_perm = jnp.where(
+                        seg_expand(pmask) & pact,
+                        jnp.maximum(sp_c - pdec, dom.zero),
+                        sp_c,
+                    ).astype(p_dt)
+
+                # --- synapse death at permanence <= 0, then empty-segment death ---
+                dead = (presyn >= 0) & (syn_perm <= dom.zero)
+                presyn = jnp.where(dead, -1, presyn)
+                nsyn = seg_sum(presyn >= 0)
+                seg_last = jnp.where((seg_last >= 0) & (nsyn == 0), -1, seg_last)
+
+            if forward:
+                # --- forward-index maintenance: diff the touched rows against
+                # their original pool content and apply removals, then appends
+                # (ops/fwd_index.py). Touched rows = the L learned workspace rows
+                # (evictions, alloc-clears, growth, reinforce-death) + the P
+                # punished rows (death only). ---
+                from rtap_tpu.ops.fwd_index import apply_appends, apply_removals
+
+                a_i = idx // (K * S)
+                gcol = jnp.where(valid_l, col_ids[jnp.clip(a_i, 0, Ac - 1)], C)
+                vs_l = valid_l & (gcol < C)  # [L]
+                seg_flat_l = jnp.where(vs_l, gcol * (K * S) + (idx % (K * S)), n_seg)
+                slots_l = seg_flat_l[:, None] * M + jnp.arange(M, dtype=jnp.int32)  # [L, M]
+                changed = presyn_l0 != presyn_l
+                rem_l = vs_l[:, None] & changed & (presyn_l0 >= 0)
+                add_l = vs_l[:, None] & changed & (presyn_l >= 0)
+                if slots_p is not None:
+                    slots_all = jnp.concatenate([slots_l.reshape(-1), slots_p.reshape(-1)])
+                    old_all = jnp.concatenate([presyn_l0.reshape(-1), old_p.reshape(-1)])
+                    rem_all = jnp.concatenate([rem_l.reshape(-1), rem_p.reshape(-1)])
+                else:
+                    slots_all = slots_l.reshape(-1)
+                    old_all = presyn_l0.reshape(-1)
+                    rem_all = rem_l.reshape(-1)
+                fwd_slots, fwd_pos = apply_removals(
+                    fwd_slots, fwd_pos, slots_all, old_all, rem_all
+                )
+                fwd_slots, fwd_pos, ndrop = apply_appends(
+                    fwd_slots, fwd_pos, slots_l.reshape(-1),
+                    presyn_l.reshape(-1), add_l.reshape(-1),
+                )
+                fwd_of = fwd_of + ndrop
+
+    with jax.named_scope("rtap.tm.dendrite"):
+        # --- dendrite activity for t+1 over existing segments ---
+        exists_seg = seg_last >= 0
+        if pallas_learn:
+            pass  # the megakernel already produced conn/pot counts + overflow
+        elif forward:
+            # forward index: gather only the <= Ac*K active cells' fanout rows
+            # (ops/fwd_index.py) instead of sweeping the pools
+            from rtap_tpu.ops.fwd_index import dendrite_counts
+
+            a_cols = active_cells.any(-1).sum()
+            tm_overflow = state["tm_overflow"] + (
+                overflow_learn | (a_cols > Ac)
+            ).astype(jnp.int32)
+            act_ids = _winner_id_list(active_cells, Ac)  # [Ac*K], fills = N
+            conn_c, pot_c = dendrite_counts(
+                fwd_slots, syn_perm.reshape(-1), act_ids, p_connected,
+                n_seg, M, fwd_impl(),
+            )
+            conn_count = conn_c.reshape(*seg_shape)
+            pot_count = pot_c.reshape(*seg_shape)
+        else:
+            acol_ids, acol_masks, a_cols = _pack_active(active_cells, Ac)
+            # the packed-column truncation applies under inference too — count it always
+            tm_overflow = state["tm_overflow"] + (
+                overflow_learn | (a_cols > Ac)
+            ).astype(jnp.int32)
+            syn_act = _presyn_active_packed(presyn, acol_ids, acol_masks, K)
+            conn_count, pot_count = seg_sum2(
+                syn_act & (syn_perm >= p_connected), syn_act
+            )
+        active_seg = exists_seg & (conn_count >= cfg.activation_threshold)
+        matching_seg = exists_seg & (pot_count >= cfg.min_threshold)
+        seg_pot = jnp.where(exists_seg, pot_count, 0).astype(jnp.int16)
+        if learn:
+            # LRU stamp for active segments (NuPIC stamps under learn only)
+            seg_last = jnp.where(active_seg, it, seg_last)
 
     new_state = {
         **state,

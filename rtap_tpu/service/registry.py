@@ -14,6 +14,7 @@ CPU default, TPU opt-in per group (BASELINE.json north star).
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -364,6 +365,23 @@ class StreamGroup:
             pred = None if pred is None else pred[0]
         return raw, pred
 
+    def _phase(self, name: str, seq: int):
+        """One phase of the chunk path as a `jax.profiler.TraceAnnotation`
+        on the host plane of a running JAX profiler trace (`serve
+        --jax-trace`, the benchmark's `--trace 1`): `rtap.group.stage`
+        (host arrays -> device), `rtap.group.enqueue` (the step program up
+        to the handle), `rtap.group.fetch` (the blocking device -> host
+        read), `rtap.group.likelihood` (host likelihood + debounce). `group`
+        (the first stream id) and `seq` (the handle's) tie one chunk's four
+        spans together. With no trace running it is a flag check; the
+        cpu-oracle backend, which never loads JAX, records nothing."""
+        if self.backend != "tpu":
+            return contextlib.nullcontext()
+        import jax
+
+        return jax.profiler.TraceAnnotation(
+            name, group=self.stream_ids[0], seq=seq)
+
     def dispatch_chunk(self, values: np.ndarray, ts: np.ndarray, learn: bool = True) -> dict:
         """Enqueue T ticks on the device WITHOUT blocking on the result.
 
@@ -377,39 +395,44 @@ class StreamGroup:
         On the CPU backend there is no async device; the chunk is computed
         here and the handle carries the finished scores.
         """
-        values = np.asarray(values, np.float32)
-        if values.ndim == 2:
-            values = values[..., None]
-        T = values.shape[0]
+        seq = self._seq + 1  # the handle's, so one chunk's phases share it
+        with self._phase("rtap.group.stage", seq):
+            values = np.asarray(values, np.float32)
+            if values.ndim == 2:
+                values = values[..., None]
+            T = values.shape[0]
+            if self.backend == "tpu":
+                dev_values = self._put(values, axis=1)
+                dev_ts = self._put(ts.astype(np.int32), axis=1)
         if self.backend == "tpu":
-            if self.mesh is not None:
-                from rtap_tpu.ops.step import sharded_chunk_step
+            with self._phase("rtap.group.enqueue", seq):
+                if self.mesh is not None:
+                    from rtap_tpu.ops.step import sharded_chunk_step
 
-                self.state, out = sharded_chunk_step(
-                    self.state, self._put(values, axis=1),
-                    self._put(ts.astype(np.int32), axis=1), self.cfg, self.mesh,
-                    learn=learn,
-                )
-            else:
-                from rtap_tpu.ops.step import chunk_step
+                    self.state, out = sharded_chunk_step(
+                        self.state, dev_values, dev_ts, self.cfg, self.mesh,
+                        learn=learn,
+                    )
+                else:
+                    from rtap_tpu.ops.step import chunk_step
 
-                self.state, out = chunk_step(
-                    self.state, self._put(values, axis=1), self._put(ts.astype(np.int32), axis=1),
-                    self.cfg, learn=learn, health=self.health,
-                    predict=bool(self.predict),
-                )
-            health = None
-            predict = None
-            if self.predict and self.mesh is None:
-                # predict wraps outermost (ops/step.py _tick)
-                out, predict = out
-            if self.health and self.mesh is None:
-                out, health = out
-            # seq advances only on successful dispatch: a raise above must
-            # leave the pipeline collectable, not permanently desynced
-            self._seq += 1
-            return {"out": out, "health": health, "predict": predict,
-                    "T": T, "seq": self._seq, "device": True}
+                    self.state, out = chunk_step(
+                        self.state, dev_values, dev_ts,
+                        self.cfg, learn=learn, health=self.health,
+                        predict=bool(self.predict),
+                    )
+                health = None
+                predict = None
+                if self.predict and self.mesh is None:
+                    # predict wraps outermost (ops/step.py _tick)
+                    out, predict = out
+                if self.health and self.mesh is None:
+                    out, health = out
+                # seq advances only on successful dispatch: a raise above must
+                # leave the pipeline collectable, not permanently desynced
+                self._seq = seq
+                return {"out": out, "health": health, "predict": predict,
+                        "T": T, "seq": seq, "device": True}
         outs = []
         hticks = []
         pticks = []
@@ -447,30 +470,32 @@ class StreamGroup:
                 f"collect_chunk out of order: handle seq {handle['seq']}, "
                 f"expected {self._collected + 1} (likelihood state is sequential)"
             )
-        if handle["device"]:
-            # the blocking fetch can surface a device error — only a chunk
-            # whose scores actually materialized counts as collected
-            raw, pred = self._unpack_out(handle["out"], time_axis=False)
-        else:
-            raw, pred = handle["raw"], handle["pred"]
-        if handle.get("health") is not None:
-            # fetch rides the same blocking boundary as the scores — no
-            # extra device round trip (the leaf is ~200 B/tick)
-            self.last_health = {
-                k: np.asarray(v) for k, v in handle["health"].items()}
-        if handle.get("predict") is not None:
-            # same boundary; 13 B/stream/tick (predict_nbytes)
-            self.last_predict = {
-                k: np.asarray(v) for k, v in handle["predict"].items()}
+        with self._phase("rtap.group.fetch", handle["seq"]):
+            if handle["device"]:
+                # the blocking fetch can surface a device error — only a chunk
+                # whose scores actually materialized counts as collected
+                raw, pred = self._unpack_out(handle["out"], time_axis=False)
+            else:
+                raw, pred = handle["raw"], handle["pred"]
+            if handle.get("health") is not None:
+                # fetch rides the same blocking boundary as the scores — no
+                # extra device round trip (the leaf is ~200 B/tick)
+                self.last_health = {
+                    k: np.asarray(v) for k, v in handle["health"].items()}
+            if handle.get("predict") is not None:
+                # same boundary; 13 B/stream/tick (predict_nbytes)
+                self.last_predict = {
+                    k: np.asarray(v) for k, v in handle["predict"].items()}
         self._collected = handle["seq"]
         T = handle["T"]
         self.last_predictions = pred
         self.ticks += T
-        loglik = np.empty((T, self.G))
-        alerts = np.empty((T, self.G), bool)
-        for i in range(T):
-            _, loglik[i] = self.likelihood.update(raw[i])
-            alerts[i] = self._debounced(loglik[i])
+        with self._phase("rtap.group.likelihood", handle["seq"]):
+            loglik = np.empty((T, self.G))
+            alerts = np.empty((T, self.G), bool)
+            for i in range(T):
+                _, loglik[i] = self.likelihood.update(raw[i])
+                alerts[i] = self._debounced(loglik[i])
         return raw, loglik, alerts
 
     def run_chunk(self, values: np.ndarray, ts: np.ndarray, learn: bool = True) -> tuple[np.ndarray, np.ndarray, np.ndarray]:

@@ -27,7 +27,13 @@ def enable_compile_cache() -> str:
     directory is set in code; otherwise the fixed ``<repo>/.jax_cache`` (the
     path is part of the cache key, so it never carries a pid, time or temp
     name). serve/replay, bench.py's children, the scripts and chip_smoke.py
-    all come through here, so they share entries."""
+    all come through here, so they share entries.
+
+    Op metadata is part of the key here (JAX strips it by default): the
+    step's `rtap.*` scopes (ops/step.py SCOPES) live in `op_name` only, so
+    with stripped keys a cache shared with a commit that names its work
+    differently, or not at all, would hand this one that commit's executable
+    and a profiler trace would carry the wrong names."""
     import jax
 
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
@@ -36,6 +42,7 @@ def enable_compile_cache() -> str:
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path
 
 

@@ -161,6 +161,8 @@ def test_compile_cache_dir_env_wins_else_fixed_in_checkout(monkeypatch, env_dir)
         assert enable_compile_cache() == env_dir
         assert "jax_compilation_cache_dir" not in updates
     assert updates["jax_persistent_cache_min_compile_time_secs"] == 0.0
+    # the step's scope names live in op metadata: they are part of the key
+    assert updates["jax_compilation_cache_include_metadata_in_key"] is True
 
 
 # ------------------------------------------------- one process per chip ----

@@ -1,0 +1,120 @@
+"""The names the program writes into a profiler trace: `jax.named_scope`s
+around the stages of the fused step (in every XLA op's `op_name`) and
+`jax.profiler.TraceAnnotation`s around the phases of StreamGroup's chunk
+path. The names are spelled out here on purpose — a benchmark reader matches
+them in recorded traces, so a rename has to fail a test."""
+
+import contextlib
+import glob
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from rtap_tpu.config import scaled_cluster_preset
+from rtap_tpu.models.state import init_state
+from rtap_tpu.ops import tm_tpu
+from rtap_tpu.ops.step import chunk_step, fused_step, group_step, replicate_state
+from rtap_tpu.service.registry import StreamGroup
+
+ALWAYS = ("rtap.encode", "rtap.sp.overlap", "rtap.sp.inhibit",
+          "rtap.tm.activate", "rtap.tm.dendrite")
+LEARNING = ("rtap.sp.learn", "rtap.tm.learn")
+G, T = 2, 2
+
+
+def _group_state(cfg):
+    return {k: jnp.asarray(v) for k, v in
+            replicate_state(init_state(cfg, 0), G).items()}
+
+
+@pytest.mark.parametrize("learn", [True, False])
+def test_compiled_chunk_step_carries_the_scopes(learn):
+    cfg = scaled_cluster_preset(32)
+    hlo = chunk_step.lower(
+        _group_state(cfg), jnp.zeros((T, G, 1), jnp.float32),
+        jnp.zeros((T, G), jnp.int32), cfg, learn=learn).compile().as_text()
+    # under vmap JAX wraps the outermost entry: `vmap(rtap.encode)/...`,
+    # but `vmap(jit(sp_step))/rtap.sp.overlap/...`
+    found = set(re.findall(r"rtap\.[a-z_.]+", " ".join(
+        re.findall(r'op_name="([^"]*)"', hlo))))
+    # (the layout adapters' reshapes may compile away: not required here)
+    assert found - {"rtap.layout"} == set(ALWAYS + (LEARNING if learn else ()))
+
+
+@pytest.mark.parametrize("entry", ["group_step", "fused_step", "chunk_step"])
+def test_layout_adapters_are_scoped(entry):
+    # the adapters reshape under the flat kernel layout (the default)
+    cfg = scaled_cluster_preset(32)
+    tm_tpu.set_layout_mode("flat")
+    try:
+        if entry == "fused_step":
+            single = {k: jnp.asarray(v) for k, v in init_state(cfg, 0).items()}
+            low = fused_step.lower(single, jnp.zeros((1,), jnp.float32),
+                                   jnp.int32(0), cfg, learn=False)
+        elif entry == "group_step":
+            low = group_step.lower(
+                _group_state(cfg), jnp.zeros((G, 1), jnp.float32),
+                jnp.zeros((G,), jnp.int32), cfg, learn=False)
+        else:
+            low = chunk_step.lower(
+                _group_state(cfg), jnp.zeros((T, G, 1), jnp.float32),
+                jnp.zeros((T, G), jnp.int32), cfg, learn=False)
+        text = low.as_text(debug_info=True)
+    finally:
+        tm_tpu.set_layout_mode(None)
+    assert "rtap.layout/reshape" in text
+
+
+def test_optional_reducers_are_scoped():
+    cfg = scaled_cluster_preset(32)
+    text = group_step.lower(
+        _group_state(cfg), jnp.zeros((G, 1), jnp.float32),
+        jnp.zeros((G,), jnp.int32), cfg, learn=False,
+        health=True).as_text(debug_info=True)
+    assert "rtap.reduce.health/" in text
+
+
+def _chunk(c):
+    v = (50 + np.arange(T * G).reshape(T, G) + c).astype(np.float32)
+    ts = (1_700_000_000 + c * T + np.arange(T)[:, None]
+          + np.zeros((1, G))).astype(np.int64)
+    return v, ts
+
+
+def test_chunk_path_phases_land_in_a_profiler_trace(tmp_path):
+    group = StreamGroup(scaled_cluster_preset(32), ["a0", "a1"], backend="tpu")
+    group.collect_chunk(group.dispatch_chunk(*_chunk(0)))  # compile outside
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        handle = group.dispatch_chunk(*_chunk(1))
+        group.collect_chunk(handle)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    seen = {}
+    for plane in data.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("rtap.group."):
+                    seen[ev.name] = dict(ev.stats)
+    assert sorted(seen) == ["rtap.group.enqueue", "rtap.group.fetch",
+                            "rtap.group.likelihood", "rtap.group.stage"]
+    assert {a["seq"] for a in seen.values()} == {handle["seq"]}
+    assert {a["group"] for a in seen.values()} == {"a0"}
+
+
+def test_no_trace_no_record_and_the_oracle_backend_stays_off_jax():
+    # the cpu-oracle backend's phases are a plain no-op context
+    group = StreamGroup(scaled_cluster_preset(32), ["a0"], backend="cpu")
+    assert isinstance(group._phase("rtap.group.stage", 1),
+                      contextlib.nullcontext)

@@ -80,6 +80,23 @@ def test_chrome_trace_schema_spans_instants_and_group_tracks():
 
 
 @pytest.mark.quick
+def test_profiler_sync_reading_is_in_other_data_and_on_the_timeline():
+    # serve --jax-trace: one instant at the reading the device trace's
+    # `rtap.sync` annotation stands for, kept whatever the tick window
+    tr = TraceRecorder(capacity=64)
+    assert tr.chrome_trace()["otherData"]["profiler_sync_perf"] is None
+    t = time.perf_counter()
+    tr.profiler_sync(t)
+    tr.add_span("tick", 9, t + 1.0, 0.5)
+    ct = json.loads(json.dumps(tr.chrome_trace(last_ticks=1)))
+    assert ct["otherData"]["profiler_sync_perf"] == t
+    (mark,) = [e for e in ct["traceEvents"] if e["name"] == "profiler_sync"]
+    assert mark["ph"] == "i" and mark["args"]["perf_counter"] == t
+    assert mark["ts"] == pytest.approx(
+        (t - ct["otherData"]["epoch_perf"]) * 1e6, abs=0.01)
+
+
+@pytest.mark.quick
 def test_last_ticks_window_filters_by_tick_not_position():
     tr = TraceRecorder(capacity=64)
     t0 = time.perf_counter()
