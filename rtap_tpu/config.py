@@ -219,7 +219,7 @@ class SPConfig:
     # `potential` bool [C, n_in] mask + `perm` [C, n_in] plane with a
     # member-index table `members` [C, P] (P potential inputs per column,
     # -1 = empty slot) + `perm` [C, P] over the members only. Overlap and
-    # learning become gathers over the member table (ops/sp_tpu.py); bytes
+    # learning become passes over the member table (ops/sp_tpu.py); bytes
     # and the per-tick sweep shrink from C*n_in to C*P. SDR theory says
     # sparsity, not pool width, carries capacity (PAPERS.md 1503.07469).
     # False (default) keeps the dense layout — every pre-existing config,

@@ -13,7 +13,7 @@ SP state — two structurally different pool layouts (SPConfig.sparse_pool):
   dense (default; NuPIC-shaped):
     potential   bool [C, n_in]   fixed potential pool mask
     perm        P_sp [C, n_in]   permanences (0 outside potential)
-  sparse (ISSUE 18; gather-addressed member-index pools):
+  sparse (ISSUE 18; member-index pools):
     members     i16/i32 [C, P]   presynaptic INPUT indices of each column's
                                  P potential synapses, ascending; -1 = empty
                                  slot (only dense->sparse migration pads —

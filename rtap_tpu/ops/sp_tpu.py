@@ -7,9 +7,10 @@ The reference's SP hot loop is SpatialPooler.cpp's sparse matvec + inhibition
   overlap is a 0/1 matmul that XLA tiles onto the MXU (counts < 2^24, so f32
   accumulation is exact).
 * sparse (ISSUE 18): the pool is a member-index table [C, P] of input
-  indices (-1 = empty slot) + perm [C, P]; overlap gathers the SDR at the
-  member indices and reduces over the P lane — an O(C*P) VPU
-  gather-and-count instead of the O(C*n_in) matmul, and the learning pass
+  indices (-1 = empty slot) + perm [C, P]; overlap tests each member's bit
+  in the SDR packed into uint32 words (`_sdr_at_members`: selects and
+  shifts on the VPU, no gather) and reduces over the P lane — an O(C*P)
+  test-and-count instead of the O(C*n_in) matmul, and the learning pass
   sweeps C*P instead of C*n_in permanence slots. On a memory-bound step the
   byte traffic, not the flop count, is the cost (docs/KERNELS.md roofline
   section), so shrinking the swept plane is both the HBM and the
@@ -34,12 +35,27 @@ from rtap_tpu.config import SPConfig
 from rtap_tpu.models.perm import sp_domain
 
 
-def _gather_sdr(pool: jnp.ndarray, sdr: jnp.ndarray) -> jnp.ndarray:
-    """SDR bits at each member slot: bool [C, P]. Empty slots (-1) gather
-    index 0 and are masked out by every caller via ``pool >= 0`` — the
-    clamp keeps the gather in-bounds so the backend never sees the
-    sentinel (out-of-bounds gather semantics are backend-defined)."""
-    return sdr[jnp.maximum(pool, 0).astype(jnp.int32)]
+def _sdr_at_members(pool: jnp.ndarray, sdr: jnp.ndarray) -> jnp.ndarray:
+    """SDR bits at each member slot: bool [C, P] = sdr[pool], with no gather.
+
+    The SDR is packed once into W = ceil(n_in / 32) uint32 words; each slot
+    picks its word (index >> 5) through a chain of W - 1 selects against the
+    stream's scalars and tests bit (index & 31) — a dozen integer VPU ops an
+    element at W = 4 that XLA fuses into the caller's pass over [C, P]. An
+    element-wise XLA gather of the same bits ran at ~10 ns an element on a
+    v5e (docs/KERNELS.md). W is static, so one form serves every n_in.
+    Empty slots (-1) read index 0 and are masked out by every caller via
+    ``pool >= 0``."""
+    n_in = sdr.shape[0]
+    n_words = -(-n_in // 32)
+    bits = jnp.pad(sdr, (0, n_words * 32 - n_in)).reshape(n_words, 32).astype(jnp.uint32)
+    words = jnp.sum(bits << jnp.arange(32, dtype=jnp.uint32), axis=1, dtype=jnp.uint32)
+    idx = jnp.maximum(pool, 0).astype(jnp.int32)
+    word_of = idx >> 5
+    word = words[n_words - 1]
+    for j in range(n_words - 2, -1, -1):
+        word = jnp.where(word_of == j, words[j], word)
+    return ((word >> (idx & 31).astype(jnp.uint32)) & 1).astype(bool)
 
 
 # rtap: twin[sp_overlap] — explicit-tensor calling convention vs the
@@ -49,12 +65,12 @@ def sp_overlap(perm: jnp.ndarray, pool: jnp.ndarray, sdr: jnp.ndarray, cfg: SPCo
 
     `pool` is the layout-defining tensor: dense bool potential mask
     [C, n_in], or the sparse member-index table [C, P]. Exact integer
-    counts either way (dense: 0/1 f32 matmul -> MXU; sparse: gather +
+    counts either way (dense: 0/1 f32 matmul -> MXU; sparse: bit test +
     masked popcount on the VPU)."""
     thr = sp_domain(cfg).threshold(cfg.syn_perm_connected)
     if cfg.sparse_pool:
         connected = (perm >= thr) & (pool >= 0)
-        hit = _gather_sdr(pool, sdr)
+        hit = _sdr_at_members(pool, sdr)
         return jnp.sum((connected & hit).astype(jnp.int32), axis=1)
     connected = ((perm >= thr) & pool).astype(jnp.float32)
     return jnp.dot(connected, sdr.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST).astype(jnp.int32)
@@ -104,13 +120,13 @@ def sp_learn(
     clip); inc/dec masks are disjoint so the fused expression is bit-equal to
     the oracle's sequential += / -=. Quantized domains compute in int32
     (bit-equal to the oracle's int32 by construction). Sparse layout: the
-    per-slot SDR bit comes from the member-index gather and the valid mask
+    per-slot SDR bit comes from `_sdr_at_members` and the valid mask
     (members >= 0) plays the dense potential mask's role in every term."""
     dom = sp_domain(cfg)
     if cfg.sparse_pool:
         pool = state["members"]
         valid = pool >= 0
-        hit = _gather_sdr(pool, sdr)
+        hit = _sdr_at_members(pool, sdr)
         inc_mask = active[:, None] & valid & hit
         dec_mask = active[:, None] & valid & ~hit
         bump_pool = valid

@@ -42,7 +42,7 @@ from rtap_tpu.ops.tm_tpu import tm_step
 #: `vmap(rtap.sp.overlap)`, `while/body/...`).
 #:
 #:   rtap.encode         bind_offsets + encode_device (+ enc_prev)  (_step_impl)
-#:   rtap.sp.overlap     sp_overlap incl. the member gather         (sp_tpu.sp_step)
+#:   rtap.sp.overlap     sp_overlap incl. the member bit test       (sp_tpu.sp_step)
 #:   rtap.sp.inhibit     sp_inhibit                                 (sp_tpu.sp_step)
 #:   rtap.sp.learn       sp_learn                                   (sp_tpu.sp_step)
 #:   rtap.tm.activate    cell activation, winners, raw score        (tm_tpu.tm_step)

@@ -796,11 +796,11 @@ STEPS: list[tuple[str, list[str]] | tuple[str, list[str], float]] = [
     # ---------------- round 16 (ISSUE 18: sparse synapse pools) -------
     # First silicon numbers for the member-index SP layout: the profiler
     # at the bench's measured-optimal rung (G=1024, T=32), sweeping the
-    # kernel strategies on the NEW default (sparse gather overlap +
+    # kernel strategies on the NEW default (sparse member-index overlap +
     # S=2 TM lanes, 302,101 B/stream u16 vs 564,245 dense). The CPU
     # path is proven bit-exact against the oracle twins
     # (tests/parity/test_sparse_sp.py); this step answers the only open
-    # question — whether the O(C*P) VPU gather beats the O(C*n_in) MXU
+    # question — whether the O(C*P) VPU pass beats the O(C*n_in) MXU
     # matmul on real HBM at the roofline (docs/KERNELS.md), and how far
     # the smaller state pushes the G-sweep OOM frontier.
     ("r16_sparse", [sys.executable, "scripts/profile_step.py",

@@ -94,7 +94,11 @@ def test_serve_group_step_with_reducers_compiles_for_v5e(v5e):
     compiled = group_step.lower(*_step_args(cfg, v5e, predict=8), cfg,
                                 learn=False, health=True,
                                 predict=True).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes > 0
+    # no floor on temp_size_in_bytes: since the SP reads its member bits
+    # with no gather (ISSUE 26) this program's temporaries fit its outputs
+    state = init_state(cfg, 0, predict_horizon=8)
+    per_stream = sum(np.asarray(v).nbytes for v in state.values())
+    assert compiled.memory_analysis().output_size_in_bytes >= G * per_stream
 
 
 def _tm_only(cfg, sharding):

@@ -7,6 +7,10 @@ models/migrate.sparsify_sp_state scores bit-identically to the dense
 original forever (same synapses, same permanences, order-independent
 integer overlap).
 
+The per-slot SDR bit is computed with no gather (ISSUE 26): the helper is
+held to the plain `sdr[members]` over every shape class it serves, and the
+lowered sparse `sp_step` may hold no `gather` op.
+
 Twin coverage: `sp_overlap` and `sp_compute` (oracle names) against
 ops/sp_tpu.py's `sp_overlap` / `sp_step` — the same pairs the dense parity
 file exercises, now on the sparse branch of each kernel.
@@ -24,7 +28,7 @@ from rtap_tpu.config import ModelConfig, RDSEConfig, SPConfig, cluster_preset, d
 from rtap_tpu.models.migrate import sparse_pool_width, sparsify_config, sparsify_sp_state
 from rtap_tpu.models.oracle.spatial_pooler import sp_compute, sp_overlap
 from rtap_tpu.models.state import init_state, members_dtype
-from rtap_tpu.ops.sp_tpu import sp_step
+from rtap_tpu.ops.sp_tpu import _sdr_at_members, sp_step
 
 SP_KEYS = ("perm", "boost", "overlap_duty", "active_duty", "sp_iter", "members")
 
@@ -156,3 +160,49 @@ def test_migrated_pool_scores_match_dense(perm_bits):
     np.testing.assert_array_equal(
         np.where(valid, np.take_along_axis(dense["perm"], order, axis=-1), 0),
         sparse["perm"])
+
+
+def _members(rng, rows: str, C: int, P: int, n_in: int, dtype) -> np.ndarray:
+    pool = rng.integers(0, n_in, size=(C, P))
+    pool[:, -1] = n_in - 1  # the last input, in the padded tail of the last word
+    if rows == "empty":
+        pool[:] = -1
+    elif rows == "mixed":
+        pool[rng.random((C, P)) < 0.4] = -1
+        pool[0, :] = -1
+    return pool.astype(dtype)
+
+
+@pytest.mark.parametrize("vmapped", [False, True], ids=["single", "vmapped"])
+@pytest.mark.parametrize("sdr_kind", ["zeros", "ones", "random"])
+@pytest.mark.parametrize("rows", ["empty", "full", "mixed"])
+@pytest.mark.parametrize("dtype", [np.int16, np.int32], ids=["i16", "i32"])
+@pytest.mark.parametrize("n_in", [33, 100, 128, 400])
+def test_sdr_at_members_equals_gather(n_in, dtype, rows, sdr_kind, vmapped):
+    """The packed-word bit test is the gather `sdr[max(members, 0)]`, bit
+    for bit: n_in below, at and across word boundaries, both member dtypes,
+    empty / full / mixed rows, and the two constant SDRs."""
+    rng = np.random.default_rng(n_in)
+    G, C, P = (3 if vmapped else 1), 6, 9
+    pool = np.stack([_members(rng, rows, C, P, n_in, dtype) for _ in range(G)])
+    sdr = {"zeros": np.zeros((G, n_in), bool), "ones": np.ones((G, n_in), bool),
+           "random": rng.random((G, n_in)) < 0.3}[sdr_kind]
+    want = np.stack([sdr[g][np.maximum(pool[g], 0)] for g in range(G)])
+    if vmapped:
+        got = jax.vmap(_sdr_at_members)(jnp.asarray(pool), jnp.asarray(sdr))
+    else:
+        got = _sdr_at_members(jnp.asarray(pool[0]), jnp.asarray(sdr[0]))[None]
+    assert got.dtype == jnp.bool_
+    np.testing.assert_array_equal(want, np.asarray(got))
+
+
+def test_sparse_sp_step_lowers_without_gather():
+    """The element-wise gather ran at 0.05 % of its bytes' cost on a v5e
+    (PERF.md §6, PR 26): the lowered sparse step, learning on, vmapped as
+    the chunk kernel runs it, holds no gather op at all."""
+    cfg = cluster_preset()
+    host = init_state(cfg, seed=0)
+    dev = {k: jnp.stack([jnp.asarray(host[k])] * 2) for k in SP_KEYS}
+    sdrs = jnp.zeros((2, cfg.input_size), bool)
+    step = jax.jit(jax.vmap(lambda st, sdr: sp_step(st, sdr, cfg.sp, learn=True)))
+    assert "gather" not in step.lower(dev, sdrs).as_text()
