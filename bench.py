@@ -201,7 +201,7 @@ def run_attempt(group_size: int, chunk_ticks: int, measure_chunks: int = 3) -> d
     value, dt = measure_pipelined(grp, vals, ts, measure_chunks, novel=((2026, 7), phase))
     from rtap_tpu.ops.tm_tpu import layout_mode, scatter_mode, sweep_mode
 
-    modes = f"{layout_mode()}/{scatter_mode()}/{sweep_mode()}"
+    modes = f"{layout_mode(cfg.tm)}/{scatter_mode(cfg.tm)}/{sweep_mode()}"
     if columns:
         modes += f"/cols={columns}"
     if learn_every > 1:

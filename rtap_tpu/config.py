@@ -733,10 +733,27 @@ def nab_preset(min_val: float = 0.0, max_val: float = 100.0) -> ModelConfig:
     """NuPIC/NAB-scale model for detection-quality runs.
 
     Mirrors the NAB Numenta-detector parameter family (SURVEY.md §5 key
-    defaults): RDSE n=400/w=21 with resolution (max-min)/130, SP 2048
+    defaults; the values of NuPIC's
+    `best_single_metric_anomaly_params_cpp.json`, which NAB's
+    numenta_detector.py loads with tmImplementation="cpp" — not those of
+    `..._tm_cpp.json`, the NumentaTM detector's): RDSE n=400/w=21 with resolution (max-min)/130, SP 2048
     columns / 40 winners, TM 32 cells per column. Segment pools are bounded
-    at 16x32 (vs NuPIC's loose 128-segment cap) — dense-pool capacity
-    actually reached by single-metric streams is far below the cap.
+    at 16x32 (vs NuPIC's loose 128-segment cap): on the chip, 17 streams x
+    336 learning ticks of a noisy diurnal metric left no cell with more
+    than ONE of its 16 slots in use (`StreamGroup.capacity_stats()` over
+    the whole group, one seed; the benchmark's `tm_full_cells.nab` reads 0
+    over the 3 streams it samples in every run; in the numpy reference no
+    cell held more than one segment after 1,600 ticks; PERF.md s6 PR 27).
+    State is 281,628,693 B a stream; a v5e steps 17 such streams in ~158 ms
+    a tick (benchmark cell `nab-2048-replay`).
+
+    `learn_cap` is 40 x 32 = 1,280, one learning segment for every cell of
+    every active column: the default 128 truncated learning on that feed (2
+    of 17 streams had 345-506 learning segments a tick within 400 ticks and
+    1,061 by 1,600, one active segment on most cells of every predicted
+    column; `tm_overflow` 412 in one 50 s run); it costs 27 ms of that tick.
+    A stream that grows a second active segment on the cells of one column
+    can still pass it; the counter says so.
     """
     resolution = rdse_resolution(min_val, max_val)
     return ModelConfig(
@@ -744,7 +761,8 @@ def nab_preset(min_val: float = 0.0, max_val: float = 100.0) -> ModelConfig:
         date=DateConfig(time_of_day_width=21, time_of_day_size=54, weekend_width=0),
         sp=SPConfig(columns=2048, num_active_columns=40),
         tm=TMConfig(cells_per_column=32, max_segments_per_cell=16,
-                    max_synapses_per_segment=32, col_cap=40),
+                    max_synapses_per_segment=32, col_cap=40,
+                    learn_cap=40 * 32),
         likelihood=LikelihoodConfig(mode="window"),
     )
 
@@ -779,9 +797,12 @@ def scaled_nab_preset(columns: int, min_val: float = 0.0,
     measured the CLUSTER preset heavily oversized on node-metric streams;
     this preset asks the same question of the NAB-family model on the
     diverse-profile stand-in corpus (scripts/nab_standin_report.py
-    --columns), where the full-size 2048-column model is the 10.5 s/tick
-    CPU-infeasible config. Cells per column stay at the preset's 32 — width
-    is the measured axis; the cells axis is deliberately unexplored here.
+    --columns), where the full-size 2048-column model costs 10.5 s a tick
+    on the CPU backend (on a v5e it steps 17 streams in ~158 ms a tick:
+    benchmark cell `nab-2048-replay`). Cells per column stay at the preset's
+    32 — width is the measured axis; the cells axis is deliberately
+    unexplored here. `learn_cap` follows the winners like nab_preset's: one
+    learning segment for every cell of every active column.
     """
     base = nab_preset(min_val, max_val)
     k = max(4, _round_half_up(columns * base.sp.num_active_columns
@@ -799,7 +820,8 @@ def scaled_nab_preset(columns: int, min_val: float = 0.0,
         sp=dataclasses.replace(base.sp, columns=columns, num_active_columns=k),
         tm=dataclasses.replace(base.tm, activation_threshold=act,
                                min_threshold=mn, new_synapse_count=ns,
-                               col_cap=k),
+                               col_cap=k,
+                               learn_cap=k * base.tm.cells_per_column),
     )
 
 

@@ -47,6 +47,9 @@ from rtap_tpu.ops.tm_tpu import tm_step
 #:   rtap.sp.learn       sp_learn                                   (sp_tpu.sp_step)
 #:   rtap.tm.activate    cell activation, winners, raw score        (tm_tpu.tm_step)
 #:   rtap.tm.learn       reinforce/punish/grow, Pallas path too     (tm_tpu.tm_step)
+#:   rtap.tm.learn.rows  the workspace's rows moved by index: the form
+#:                       wide pool rows take (tm_tpu.wide_rows); absent
+#:                       where one-hot matmuls move them              (tm_tpu.tm_step)
 #:   rtap.tm.dendrite    dendrite activity for t+1                  (tm_tpu.tm_step)
 #:   rtap.reduce.health, rtap.reduce.predict, rtap.classifier
 #:                       the optional reducers / classifier         (_tick, _step_impl)
@@ -54,7 +57,8 @@ from rtap_tpu.ops.tm_tpu import tm_step
 SCOPES = (
     "rtap.encode",
     "rtap.sp.overlap", "rtap.sp.inhibit", "rtap.sp.learn",
-    "rtap.tm.activate", "rtap.tm.learn", "rtap.tm.dendrite",
+    "rtap.tm.activate", "rtap.tm.learn", "rtap.tm.learn.rows",
+    "rtap.tm.dendrite",
     "rtap.reduce.health", "rtap.reduce.predict", "rtap.classifier",
     "rtap.layout",
 )
@@ -105,7 +109,7 @@ def fused_step(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: Mode
     from rtap_tpu.ops.tm_tpu import from_kernel_layout, to_kernel_layout
 
     with jax.named_scope("rtap.layout"):
-        state = to_kernel_layout(state)
+        state = to_kernel_layout(state, cfg.tm)
     state, out = _step_impl(state, values, ts_unix, cfg, learn)
     with jax.named_scope("rtap.layout"):
         return from_kernel_layout(state, cfg.tm), out
@@ -185,7 +189,7 @@ def group_step(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: Mode
     from rtap_tpu.ops.tm_tpu import from_kernel_layout, to_kernel_layout
 
     with jax.named_scope("rtap.layout"):
-        state = to_kernel_layout(state)
+        state = to_kernel_layout(state, cfg.tm)
     state, out = _tick(state, values, ts_unix, cfg, learn,
                        health=health, predict=predict)
     with jax.named_scope("rtap.layout"):
@@ -216,7 +220,7 @@ def _scan_chunk(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: Mod
                      predict=predict)
 
     with jax.named_scope("rtap.layout"):
-        state = to_kernel_layout(state)
+        state = to_kernel_layout(state, cfg.tm)
     state, out = jax.lax.scan(body, state, (values, ts_unix))
     with jax.named_scope("rtap.layout"):
         return from_kernel_layout(state, cfg.tm), out

@@ -101,6 +101,7 @@ def _tpu_paths() -> bool:
 #                                     tiny trailing dims up to ~20x) vs
 #                                     [C, K*S*M] with block-diagonal-matmul
 #                                     per-segment reductions
+# SCATTER and LAYOUT, where unset, follow the pool's row width: `wide_rows`.
 #   RTAP_TM_SWEEP    dense|compact    punish/death as full-pool sweeps vs
 #                                     gather/update/scatter of the <=
 #                                     punish_cap + learn_cap touched segment
@@ -132,20 +133,21 @@ _ENV_NAMES = {
 # Defaults are the measured silicon winners (SCALING.md round-4 A/B,
 # 2026-07-31 chip run): flat layout beat aos by 13% on the full
 # learning step (31.9k vs 28.1k metrics/s at G=1024) and matmul scatter
-# beat indexed by 1.55x — the reverse of the CPU-drive signal.
+# beat indexed by 1.55x — the reverse of the CPU-drive signal. Those runs
+# were at narrow pool rows; None = computed from the shape (`wide_rows`).
 _MODE_DEFAULTS = {
-    "scatter": "matmul",
-    "layout": "flat",
+    "scatter": None,
+    "layout": None,
     "sweep": "dense",
     "dendrite": "scan",
     "fwd_impl": "scatter",
 }
 # start-of-process env snapshot (read once; see block comment above)
-_MODES: dict[str, str] = {
+_MODES: dict[str, str | None] = {
     k: _os.environ.get(env, _MODE_DEFAULTS[k]) for k, env in _ENV_NAMES.items()
 }
 for _k, _v in _MODES.items():
-    if _v not in _MODE_CHOICES[_k]:
+    if _v is not None and _v not in _MODE_CHOICES[_k]:
         raise ValueError(
             f"{_ENV_NAMES[_k]} must be one of {_MODE_CHOICES[_k]}, got {_v!r}"
         )
@@ -167,12 +169,43 @@ def _set_mode(kind: str, mode: str | None) -> None:
     jax.clear_caches()
 
 
-def scatter_mode() -> str:
-    return _mode("scatter")
+#: The one line the shape-chosen forms are drawn at. A pool row is one
+#: column's K*S*M synapse lanes. Measured at the two ends on a v5e: at 192
+#: lanes (cluster presets, a 384 B row) the flat layout beats aos by 13 % and
+#: one-hot matmul moves beat indexed row moves 1.55x (SCALING.md round 4); at
+#: 16,384 lanes (nab_preset, a 64 KiB row, G = 17) the matmul moves' f32
+#: copies of both pools do not fit the chip (RESOURCE_EXHAUSTED at compile,
+#: 15.76 of 15.75 GB), and with indexed moves aos steps a tick in 158.2 ms
+#: against flat's 202.2 (131.7 against 154.6 at `learn_cap` 128; my chip
+#: runs, PR 27; PERF.md s6). The line is the geometric middle of the two
+#: points, 85x apart, rounded to a power of two; nothing between them has
+#: been measured.
+WIDE_ROW_LANES = 2048
 
 
-def layout_mode() -> str:
-    return _mode("layout")
+def wide_rows(cfg: TMConfig) -> bool:
+    """Does this shape take the wide-row forms (indexed workspace moves, aos
+    pools) rather than the narrow-row ones (one-hot matmul moves, flat
+    pools)? All forms are bit-identical to the oracle (tests/parity)."""
+    return (cfg.cells_per_column * cfg.max_segments_per_cell
+            * cfg.max_synapses_per_segment) >= WIDE_ROW_LANES
+
+
+def _shape_mode(kind: str, cfg: TMConfig, wide: str, narrow: str) -> str:
+    """RTAP_TM_<KIND> / set_<kind>_mode where given, else the form of the
+    shape: one answer per shape, so every caller names its `cfg`."""
+    explicit = _mode(kind)
+    if explicit is not None:
+        return explicit
+    return wide if wide_rows(cfg) else narrow
+
+
+def scatter_mode(cfg: TMConfig) -> str:
+    return _shape_mode("scatter", cfg, "indexed", "matmul")
+
+
+def layout_mode(cfg: TMConfig) -> str:
+    return _shape_mode("layout", cfg, "aos", "flat")
 
 
 def sweep_mode() -> str:
@@ -233,11 +266,12 @@ _FLAT_KEYS = {
 }
 
 
-def to_kernel_layout(state: dict) -> dict:
+def to_kernel_layout(state: dict, cfg: TMConfig) -> dict:
     """Public state layout -> kernel layout (no-op in "aos" mode). Shape
     change only — values are untouched, so checkpoints, the oracle, and the
-    parity harness all keep the public [C, K, S, M] layout."""
-    if layout_mode() != "flat":
+    parity harness all keep the public [C, K, S, M] layout. The layout
+    follows `cfg`'s shape (`layout_mode`), as `tm_step(cfg)` reads it."""
+    if layout_mode(cfg) != "flat":
         return state
     out = dict(state)
     for k, nd in _FLAT_KEYS.items():
@@ -248,7 +282,7 @@ def to_kernel_layout(state: dict) -> dict:
 
 def from_kernel_layout(state: dict, cfg: TMConfig) -> dict:
     """Kernel layout -> public state layout (no-op in "aos" mode)."""
-    if layout_mode() != "flat":
+    if layout_mode(cfg) != "flat":
         return state
     K, S, M = cfg.cells_per_column, cfg.max_segments_per_cell, cfg.max_synapses_per_segment
     tails = {3: (K, S, M), 2: (K, S)}
@@ -278,7 +312,7 @@ def tm_invariants(cfg: TMConfig) -> dict | None:  # rtap: allow[twin-parity] —
     whole T-tick chunk instead of rematerializing as per-iteration
     constants. None when the current layout needs none (aos reduces on the
     trailing dim directly)."""
-    if layout_mode() != "flat":
+    if layout_mode(cfg) != "flat":
         return None
     K, S, M = cfg.cells_per_column, cfg.max_segments_per_cell, cfg.max_synapses_per_segment
     return {"red": jnp.asarray(_reduce_matrix(K * S, M))}
@@ -499,7 +533,8 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
     caller hoists them out of its loop body; None rebuilds them as
     in-trace constants (single-dispatch callers).
     """
-    flat = layout_mode() == "flat"
+    flat = layout_mode(cfg) == "flat"
+    scatter = scatter_mode(cfg)
     if flat:
         K, S, M = cfg.cells_per_column, cfg.max_segments_per_cell, cfg.max_synapses_per_segment
         if state["presyn"].ndim != 2:
@@ -634,8 +669,8 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
     fwd_of = state.get("fwd_of")
     n_seg = C * K * S
 
-    pallas_learn = learn and scatter_mode() == "pallas"
-    if scatter_mode() == "pallas":
+    pallas_learn = learn and scatter == "pallas"
+    if scatter == "pallas":
         if forward:
             raise ValueError(
                 "RTAP_TM_SCATTER=pallas is incompatible with "
@@ -683,7 +718,7 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
             burst_new = alloc_col < C  # [C]
 
             # --- gather the active columns into the [Ac, ...] workspace ---
-            indexed = scatter_mode() == "indexed"
+            indexed = scatter == "indexed"
             col_ids = _compact_ids(active_cols, Ac)  # [Ac], fills = C
             col_oh_b = col_ids[:, None] == jnp.arange(C, dtype=jnp.int32)  # [Ac, C]
             col_oh = col_oh_b.astype(jnp.float32)
@@ -694,13 +729,14 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
                 # junk copy of row C-1 that is masked out of learning (ws_learn /
                 # ws_alloc are False there) and dropped at scatter-back
                 idx_c = jnp.clip(col_ids, 0, C - 1)
-                ws_presyn = presyn.reshape(C, -1)[idx_c].astype(jnp.int32)
-                ws_perm = syn_perm.reshape(C, -1)[idx_c].astype(jnp.float32)
-                ws_last = seg_last.reshape(C, -1)[idx_c].reshape(Ac, K, S)
-                ws_pot = state["seg_pot"].reshape(C, -1)[idx_c].astype(jnp.int32).reshape(Ac, K, S)
-                ws_learn = (
-                    learn_mask.reshape(C, -1)[idx_c] & (col_ids < C)[:, None]
-                ).reshape(Ac, K, S)
+                with jax.named_scope("rtap.tm.learn.rows"):
+                    ws_presyn = presyn.reshape(C, -1)[idx_c].astype(jnp.int32)
+                    ws_perm = syn_perm.reshape(C, -1)[idx_c].astype(jnp.float32)
+                    ws_last = seg_last.reshape(C, -1)[idx_c].reshape(Ac, K, S)
+                    ws_pot = state["seg_pot"].reshape(C, -1)[idx_c].astype(jnp.int32).reshape(Ac, K, S)
+                    ws_learn = (
+                        learn_mask.reshape(C, -1)[idx_c] & (col_ids < C)[:, None]
+                    ).reshape(Ac, K, S)
             else:
                 # ONE one-hot MXU pass gathers presyn + perm + seg_pot together
                 # (fused-region consolidation: each output element of the
@@ -838,27 +874,28 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
             # --- scatter the workspace back to the pools ---
             if indexed:
                 # only the <= Ac touched rows are written; fill ids (C) drop
-                presyn = (
-                    presyn.reshape(C, -1)
-                    .at[col_ids]
-                    .set(ws_presyn_r.reshape(Ac, -1).astype(presyn_dt), mode="drop")
-                    .reshape(*pool_shape)
-                )
-                ws_perm_w = ws_perm_r.reshape(Ac, -1)
-                if dom.bits:
-                    ws_perm_w = jnp.round(ws_perm_w)  # exact already; belt+braces
-                syn_perm = (
-                    syn_perm.reshape(C, -1)
-                    .at[col_ids]
-                    .set(ws_perm_w.astype(p_dt), mode="drop")
-                    .reshape(*pool_shape)
-                )
-                seg_last = (
-                    seg_last.reshape(C, -1)
-                    .at[col_ids]
-                    .set(ws_last.reshape(Ac, -1), mode="drop")
-                    .reshape(*seg_shape)
-                )
+                with jax.named_scope("rtap.tm.learn.rows"):
+                    presyn = (
+                        presyn.reshape(C, -1)
+                        .at[col_ids]
+                        .set(ws_presyn_r.reshape(Ac, -1).astype(presyn_dt), mode="drop")
+                        .reshape(*pool_shape)
+                    )
+                    ws_perm_w = ws_perm_r.reshape(Ac, -1)
+                    if dom.bits:
+                        ws_perm_w = jnp.round(ws_perm_w)  # exact already; belt+braces
+                    syn_perm = (
+                        syn_perm.reshape(C, -1)
+                        .at[col_ids]
+                        .set(ws_perm_w.astype(p_dt), mode="drop")
+                        .reshape(*pool_shape)
+                    )
+                    seg_last = (
+                        seg_last.reshape(C, -1)
+                        .at[col_ids]
+                        .set(ws_last.reshape(Ac, -1), mode="drop")
+                        .reshape(*seg_shape)
+                    )
             else:
                 hit_pool = hit_cols.reshape(C, *([1] * (len(pool_shape) - 1)))
                 hit_seg = hit_cols.reshape(C, *([1] * (len(seg_shape) - 1)))

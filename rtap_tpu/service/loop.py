@@ -287,6 +287,9 @@ def replay_streams(
         # static bound and its scores deviate from the oracle — surface it
         # in the replay stats instead of leaving it buried in device state
         stats["tm_overflow_total"] = overflow
+    # how near the dense segment pools came to max_segments_per_cell:
+    # nonzero tm_full_columns means LRU eviction may have dropped a segment
+    stats.update(_capacity_total(reg.groups))
     if resumed_from:
         stats["resumed_from"] = resumed_from
     return ReplayResult(
@@ -2218,6 +2221,19 @@ def _overflow_total(groups) -> int | None:
         if "fwd_of" in st:
             total += int(np.asarray(st["fwd_of"]).sum())
     return total if saw_device else None
+
+
+def _capacity_total(groups) -> dict:
+    """Segment-pool headroom over all groups (registry.segment_capacity):
+    ``tm_full_cells`` and ``tm_full_columns`` summed, ``tm_max_segments``
+    the largest count on any cell."""
+    per_group = [g.capacity_stats() for g in groups]
+    return {
+        "tm_full_cells": sum(c["full_cells"] for c in per_group),
+        "tm_full_columns": sum(c["full_columns"] for c in per_group),
+        "tm_max_segments": max(
+            (c["max_segments_on_a_cell"] for c in per_group), default=0),
+    }
 
 
 def _device_stats(groups) -> dict:

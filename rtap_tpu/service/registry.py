@@ -37,6 +37,28 @@ class TickResult:
 PAD_PREFIX = "__pad"
 
 
+def segment_capacity(in_use: np.ndarray) -> dict[str, int]:
+    """How near the TM's dense segment pool is to its cap: `in_use` bool
+    [..., C, K, S] (a slot holds a segment: ``seg_last >= 0``) ->
+
+    - ``full_cells``: cells whose every ``max_segments_per_cell`` slot is in
+      use — the next segment such a cell grows evicts its LRU one;
+    - ``full_columns``: columns whose every cell is full. A burst allocates
+      on the column's emptiest cell, so a segment is evicted for want of a
+      slot only in such a column (segments also die, so a 0 read late says
+      "not now", and with ``max_segments_on_a_cell`` far under the cap,
+      "never");
+    - ``max_segments_on_a_cell``: the pool's high-water mark.
+
+    Pure host arithmetic on state a group already holds; never on the
+    step's path."""
+    per_cell = np.asarray(in_use).sum(-1)
+    full = per_cell == np.shape(in_use)[-1]
+    return {"full_cells": int(full.sum()),
+            "full_columns": int(full.all(-1).sum()),
+            "max_segments_on_a_cell": int(per_cell.max(initial=0))}
+
+
 class StreamGroup:
     """G lockstep streams sharing one compiled device step (or one oracle loop).
 
@@ -179,6 +201,17 @@ class StreamGroup:
 
     def free_slot_count(self) -> int:
         return self.G - self.n_live
+
+    # rtap: host-boundary — end-of-run stats fetch of seg_last ([G, C, K, S]
+    # i32); off the step's path, like loop._overflow_total's
+    def capacity_stats(self) -> dict[str, int]:
+        """:func:`segment_capacity` of this group's streams, fetched from
+        the state it holds (off the step's path, like ``tm_overflow``)."""
+        if self.backend == "tpu":
+            seg_last = np.asarray(self.state["seg_last"])
+        else:
+            seg_last = np.stack([s["seg_last"] for s in self._states])
+        return segment_capacity(seg_last >= 0)
 
     def claim_slot(self, stream_id: str) -> int:
         """Assign `stream_id` to a pad slot mid-run -> slot index.

@@ -180,7 +180,7 @@ def tm_only(state, actives, cfg: ModelConfig, learn=True):
         return s, raw
     def step(s, a):
         return jax.vmap(body)(s, a)
-    state, out = jax.lax.scan(step, to_kernel_layout(state), actives)
+    state, out = jax.lax.scan(step, to_kernel_layout(state, cfg.tm), actives)
     return from_kernel_layout(state, cfg.tm), out
 
 
@@ -316,7 +316,7 @@ def main():
         dendrite_mode, layout_mode, scatter_mode, sweep_mode,
     )
 
-    report["modes"] = (f"{layout_mode()}/{scatter_mode()}/{sweep_mode()}"
+    report["modes"] = (f"{layout_mode(cfg.tm)}/{scatter_mode(cfg.tm)}/{sweep_mode()}"
                        f"/{dendrite_mode()}")
 
     log("\n== G scaling, full step (learn=True) ==")

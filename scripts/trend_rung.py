@@ -60,7 +60,7 @@ def main() -> int:
     ids = [f"trend{i:04d}" for i in range(args.G)]
     platform = jax.devices()[0].platform
     log(f"platform={platform} G={args.G} T={args.T} "
-        f"modes={layout_mode()}/{scatter_mode()}/{sweep_mode()}")
+        f"modes={layout_mode(cfg.tm)}/{scatter_mode(cfg.tm)}/{sweep_mode()}")
 
     results: dict[str, list[float]] = {"novel": [], "repeated": []}
     for protocol in ("novel", "repeated"):

@@ -24,7 +24,7 @@ import numpy as np
 import pytest
 
 import rtap_tpu.ops.tm_tpu as tm_tpu
-from rtap_tpu.config import cluster_preset, scaled_cluster_preset
+from rtap_tpu.config import cluster_preset, nab_preset, scaled_cluster_preset
 from rtap_tpu.models.state import init_state
 
 G = 128
@@ -51,22 +51,23 @@ def v5e():
     yield SingleDeviceSharding(topo.devices[0])
     tm_tpu.FORCE_TPU_PATHS = paths_was
     tm_tpu.set_scatter_mode(None)
+    tm_tpu.set_layout_mode(None)
     jax.config.update("jax_enable_compilation_cache", cache_was)
     compilation_cache.reset_cache()
 
 
-def _shapes(tree: dict, sharding) -> dict:
-    return {k: jax.ShapeDtypeStruct((G, *np.shape(v)), np.asarray(v).dtype,
+def _shapes(tree: dict, sharding, g: int = G) -> dict:
+    return {k: jax.ShapeDtypeStruct((g, *np.shape(v)), np.asarray(v).dtype,
                                     sharding=sharding)
             for k, v in tree.items()}
 
 
-def _step_args(cfg, sharding, T=None, predict=0):
-    state = _shapes(init_state(cfg, 0, predict_horizon=predict), sharding)
+def _step_args(cfg, sharding, T=None, predict=0, g: int = G):
+    state = _shapes(init_state(cfg, 0, predict_horizon=predict), sharding, g)
     lead = () if T is None else (T,)
-    vals = jax.ShapeDtypeStruct((*lead, G, cfg.n_fields), jnp.float32,
+    vals = jax.ShapeDtypeStruct((*lead, g, cfg.n_fields), jnp.float32,
                                 sharding=sharding)
-    ts = jax.ShapeDtypeStruct((*lead, G), jnp.int32, sharding=sharding)
+    ts = jax.ShapeDtypeStruct((*lead, g), jnp.int32, sharding=sharding)
     return state, vals, ts
 
 
@@ -83,6 +84,35 @@ def test_default_chunk_step_compiles_for_v5e(v5e, preset):
     # the state is an argument the program really holds on the device
     per_stream = sum(np.asarray(v).nbytes for v in init_state(cfg, 0).values())
     assert compiled.memory_analysis().argument_size_in_bytes >= G * per_stream
+
+
+@pytest.mark.parametrize("forms", ["by_shape", "narrow_forced"])
+def test_nab_width_chunk_step_fits_a_v5e_only_in_the_wide_row_forms(v5e, forms):
+    """The published NAB width (2048 x 32 x 16 x 32: 16,384-lane pool rows)
+    at the benchmark cell's batch of 17 streams. In the forms the shape rule
+    picks (tm_tpu.wide_rows: indexed row moves, aos pools) the program fits
+    the chip; in the narrow-row forms the cluster presets run, forced here,
+    the chip's compiler refuses it for memory — the reason the line exists."""
+    from rtap_tpu.ops.step import chunk_step
+
+    cfg = nab_preset(0.0, 100.0)
+    assert tm_tpu.wide_rows(cfg.tm) and not tm_tpu.wide_rows(cluster_preset().tm)
+    args = _step_args(cfg, v5e, T=2, g=17)
+    if forms == "by_shape":
+        compiled = chunk_step.lower(*args, cfg, learn=True).compile()
+        mem = compiled.memory_analysis()
+        assert "tpu_custom_call" not in compiled.as_text()
+        assert mem.argument_size_in_bytes >= 17 * 281_628_693
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14 * 2 ** 30
+        return
+    tm_tpu.set_scatter_mode("matmul")
+    tm_tpu.set_layout_mode("flat")
+    try:
+        with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|[Oo]ut of memory"):
+            chunk_step.lower(*args, cfg, learn=True).compile()
+    finally:
+        tm_tpu.set_scatter_mode(None)
+        tm_tpu.set_layout_mode(None)
 
 
 def test_serve_group_step_with_reducers_compiles_for_v5e(v5e):
@@ -105,7 +135,7 @@ def _tm_only(cfg, sharding):
     from tests.parity.test_tm_parity import TM_KEYS
 
     st = init_state(cfg, 0)
-    state = _shapes(tm_tpu.to_kernel_layout({k: st[k] for k in TM_KEYS}),
+    state = _shapes(tm_tpu.to_kernel_layout({k: st[k] for k in TM_KEYS}, cfg.tm),
                     sharding)
     active = jax.ShapeDtypeStruct((G, cfg.sp.columns), jnp.bool_,
                                   sharding=sharding)
@@ -149,7 +179,7 @@ def test_pallas_off_tpu_raises_instead_of_interpreting(monkeypatch):
     cfg = scaled_cluster_preset(32).tm
     C = 32
     state = tm_tpu.to_kernel_layout(
-        {k: jnp.asarray(v) for k, v in _init_tm_state(C, cfg).items()})
+        {k: jnp.asarray(v) for k, v in _init_tm_state(C, cfg).items()}, cfg)
     tm_tpu.set_scatter_mode("pallas")
     try:
         with pytest.raises(ValueError, match="compiles for a TPU only"):

@@ -50,7 +50,8 @@ def _run_tm_parity(C, cfg, sequences, learn=True):
     from rtap_tpu.ops.tm_tpu import from_kernel_layout, tm_step, to_kernel_layout
 
     host = _init_tm_state(C, cfg)
-    dev = to_kernel_layout({k: jnp.asarray(v) for k, v in copy.deepcopy(host).items()})
+    dev = to_kernel_layout(
+        {k: jnp.asarray(v) for k, v in copy.deepcopy(host).items()}, cfg)
     oracle = TMOracle(host, cfg)
     for step, cols in enumerate(sequences):
         active = np.zeros(C, bool)
@@ -191,14 +192,14 @@ def test_megakernel_rejects_incompatible_strategies(pallas_scatter):
     try:
         with pytest.raises(ValueError, match="SWEEP=compact"):
             tm_tpu.tm_step(
-                tm_tpu.to_kernel_layout(state), active, cfg, learn=True)
+                tm_tpu.to_kernel_layout(state, cfg), active, cfg, learn=True)
     finally:
         tm_tpu.set_sweep_mode(None)
     tm_tpu.set_dendrite_mode("forward")
     try:
         with pytest.raises(ValueError, match="DENDRITE=forward"):
             tm_tpu.tm_step(
-                tm_tpu.to_kernel_layout(state), active, cfg, learn=True)
+                tm_tpu.to_kernel_layout(state, cfg), active, cfg, learn=True)
     finally:
         tm_tpu.set_dendrite_mode(None)
 
@@ -217,7 +218,8 @@ def test_megakernel_guards_reject_oversized_shapes(pallas_scatter):
         {k: jnp.asarray(v) for k, v in init_state(cfg, seed=0).items()
          if k not in ("potential", "perm", "boost", "overlap_duty",
                       "active_duty", "sp_iter", "enc_offset", "enc_bound",
-                      "enc_resolution")})
+                      "enc_resolution")},
+        cfg.tm)
     active = jnp.zeros(cfg.sp.columns, bool)
     with pytest.raises(ValueError, match="INTERPRETER|winner-list|VMEM"):
         tm_step(st, active, cfg.tm, learn=True)

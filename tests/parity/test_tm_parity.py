@@ -56,7 +56,8 @@ def _run_parity(C, cfg, sequences, learn=True):
     # the kernel runs whatever layout is the process default (flat since the
     # r4 silicon A/B); the public [C, K, S, M] layout crosses the boundary
     # via the same reshape adapters ops/step.py uses
-    dev = to_kernel_layout({k: jnp.asarray(v) for k, v in copy.deepcopy(host).items()})
+    dev = to_kernel_layout(
+        {k: jnp.asarray(v) for k, v in copy.deepcopy(host).items()}, cfg)
     oracle = TMOracle(host, cfg)
     for step, cols in enumerate(sequences):
         active = np.zeros(C, bool)

@@ -116,6 +116,11 @@ def test_replay_streams_end_to_end(tmp_path):
                          chunk_ticks=50, alert_path=path)
     assert res.raw.shape == (500, 3)
     assert res.throughput["scored"] == 1500
+    # segment-pool headroom rides beside tm_overflow_total (500 ticks of a
+    # 2-slot pool: cells fill, and the stats say so)
+    assert res.throughput["tm_overflow_total"] == 0
+    assert 0 < res.throughput["tm_max_segments"] <= cfg.tm.max_segments_per_cell
+    assert res.throughput["tm_full_cells"] >= res.throughput["tm_full_columns"] >= 0
     # every line in the alert file is valid JSON with the expected keys
     lines = [json.loads(l) for l in open(path)]
     assert len(lines) == res.throughput["alerts"] == int(res.alerts.sum())
