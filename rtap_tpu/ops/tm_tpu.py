@@ -451,10 +451,22 @@ def _grow_compact(
 ):
     """Oracle _grow_synapses, vectorized: per segment, add the first
     min(n_grow, #eligible) winner cells (ascending id, not already
-    presynaptic), evicting weakest synapses when free slots run short."""
-    L, M = presyn_l.shape
+    presynaptic), evicting weakest synapses when free slots run short.
+
+    `n_grow` is at most cfg.new_synapse_count (the caller's
+    new_synapse_count - seg_pot).
+
+    No gather and no sort: the r-th chosen winner goes to the r-th free
+    slot, so the two ranks are matched on compare grids that fuse into
+    their reduces — first `[L, R, W]` (which winner has rank r among the
+    chosen), then `[L, M, R]` (which rank a free slot has), R =
+    min(new_synapse_count, W). An element-wise XLA gather costs ~10 ns an
+    element on a v5e, which made the two lookups this replaces 54-82 % of a
+    cluster tick; matching slots against winners in one `[L, M, W]` grid
+    (the form ops/pallas_tm.py fills with) ties at 256 columns and at the
+    NAB width and is 1.7 % of a tick slower at 32 (PERF.md §6, PR 28)."""
+    M = presyn_l.shape[1]
     W = winner_ids.shape[0]
-    G = cfg.new_synapse_count  # max grown per segment per step
 
     valid_w = winner_ids < n_cells
     # membership: winner already presynaptic on this segment?  [L, W]
@@ -464,43 +476,37 @@ def _grow_compact(
     chosen = eligible & (rank <= n_grow[:, None])
     n_new = chosen.sum(-1).astype(jnp.int32)  # [L]
 
-    # extract chosen winner positions ascending -> [L, G]
-    wpos = jnp.where(chosen, jnp.arange(W, dtype=jnp.int32), W)
-    if _tpu_paths():
-        # ascending distinct values via top_k (chosen positions are distinct;
-        # fills map to 0 and come out last) — full lax.sort serializes worse
-        # than top_k on the TPU vector unit for these tiny rows
-        wpos = W - jax.lax.top_k(W - wpos, min(G, W))[0]
-        if G > W:
-            wpos = jnp.concatenate([wpos, jnp.full((L, G - W), W, jnp.int32)], axis=1)
-    else:
-        wpos = jax.lax.sort(wpos, dimension=1)[:, :G]
-    new_ids = jnp.where(wpos < W, winner_ids[jnp.clip(wpos, 0, W - 1)], n_cells)  # [L]
-
     # evict weakest occupied synapses if short of free slots (stable by slot)
     occupied = presyn_l >= 0
     n_free = M - occupied.sum(-1)
     short = n_new - n_free  # [L]
     key = jnp.where(occupied, perm_l, INF)
-    if _tpu_paths():
-        # stable ascending rank by (key, slot) via compare-count: M is tiny
-        # (<= 32), so the [L, M, M] compare grid is cheap, branch-free VPU
-        # work — vs two serialized stable sorts
-        kj, ki = key[:, :, None], key[:, None, :]  # [L, M(j), M(i)]
-        jj = jnp.arange(M, dtype=jnp.int32)
-        before = (kj < ki) | ((kj == ki) & (jj[None, :, None] < jj[None, None, :]))
-        ranks = before.sum(1).astype(jnp.int32)  # [L, M]
-    else:
-        ranks = jnp.argsort(jnp.argsort(key, axis=-1, stable=True), axis=-1, stable=True)
+    # stable ascending rank by (key, slot) via compare-count: M is tiny
+    # (<= 32), so the [L, M, M] compare grid is cheap, branch-free VPU
+    # work — vs two serialized stable sorts
+    kj, ki = key[:, :, None], key[:, None, :]  # [L, M(j), M(i)]
+    jj = jnp.arange(M, dtype=jnp.int32)
+    before = (kj < ki) | ((kj == ki) & (jj[None, :, None] < jj[None, None, :]))
+    ranks = before.sum(1).astype(jnp.int32)  # [L, M]
     evict = occupied & (ranks < short[:, None])
     presyn_l = jnp.where(evict, -1, presyn_l)
     perm_l = jnp.where(evict, 0.0, perm_l)
 
-    # fill free slots ascending with new ids ascending
+    # fill free slots ascending with chosen winners ascending: the chosen
+    # ranks are 1..n_new, each once, so exactly one winner matches a rank
+    # r < n_new and the sum passes its id through (-2 matches no rank); a
+    # slot under `assign` has frank < n_new and takes that rank's id
     free = presyn_l < 0
     frank = jnp.cumsum(free, axis=-1) - 1  # 0-based among free slots
     assign = free & (frank < n_new[:, None])
-    fill = new_ids[jnp.arange(L)[:, None], jnp.clip(frank, 0, G - 1)]
+    r = jnp.arange(min(cfg.new_synapse_count, W), dtype=jnp.int32)
+    rank_of = jnp.where(chosen, rank - 1, -2)  # [L, W]
+    new_ids = jnp.where(
+        rank_of[:, None, :] == r[None, :, None], winner_ids[None, None, :], 0
+    ).sum(-1)  # [L, R] ascending ids of the chosen
+    fill = jnp.where(
+        frank[:, :, None] == r[None, None, :], new_ids[:, None, :], 0
+    ).sum(-1)  # [L, M]
     presyn_l = jnp.where(assign, fill, presyn_l)
     perm_l = jnp.where(assign, initial_perm, perm_l)
     return presyn_l, perm_l

@@ -17,6 +17,7 @@ written for a described device cannot be read back without a chip.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +87,19 @@ def test_default_chunk_step_compiles_for_v5e(v5e, preset):
     assert compiled.memory_analysis().argument_size_in_bytes >= G * per_stream
 
 
+@pytest.mark.parametrize("preset", ["cluster", "scaled32"])
+def test_cluster_chunk_step_holds_no_gather_at_the_cells_batch(v5e, preset):
+    """At the benchmark cells' batch of 1,024 streams the optimised program
+    holds no gather: the two in `_grow_compact` were 54-82 % of a tick on the
+    chip at ~10 ns an element (ISSUE 28; PERF.md §6)."""
+    from rtap_tpu.ops.step import chunk_step
+
+    cfg = cluster_preset() if preset == "cluster" else scaled_cluster_preset(32)
+    compiled = chunk_step.lower(*_step_args(cfg, v5e, T=2, g=1024), cfg,
+                                learn=True).compile()
+    assert not re.findall(r"= \S+ gather\(", compiled.as_text())
+
+
 @pytest.mark.parametrize("forms", ["by_shape", "narrow_forced"])
 def test_nab_width_chunk_step_fits_a_v5e_only_in_the_wide_row_forms(v5e, forms):
     """The published NAB width (2048 x 32 x 16 x 32: 16,384-lane pool rows)
@@ -104,6 +118,10 @@ def test_nab_width_chunk_step_fits_a_v5e_only_in_the_wide_row_forms(v5e, forms):
         assert "tpu_custom_call" not in compiled.as_text()
         assert mem.argument_size_in_bytes >= 17 * 281_628_693
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14 * 2 ** 30
+        # growth's [L, R, W] rank-match grid (1,280 x 20 x 1,280 a stream:
+        # 2.2 GB if it were a buffer) fuses into its reduce: the temporaries
+        # stay under the 5,111,318,528 B the gather form took (ISSUE 28)
+        assert mem.temp_size_in_bytes <= 5_111_318_528
         return
     tm_tpu.set_scatter_mode("matmul")
     tm_tpu.set_layout_mode("flat")
