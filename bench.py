@@ -40,7 +40,7 @@ import time
 TARGET = 100_000.0  # metrics/sec/chip north star (BASELINE.json)
 
 # (group_size, chunk_ticks, env_overrides): the cheap anchor first, then the
-# round-4 kernel-strategy candidates at the measured-optimal rung, then the
+# cadence and width rungs at the measured-optimal rung, then the
 # G/T exploration ladder. Attempt order is also failure-isolation order — an
 # OOM or compile stall costs only its own budget (an OOM also skips every
 # LATER rung that dominates the failed (G, T) point in both dims; smaller
@@ -49,24 +49,13 @@ TARGET = 100_000.0  # metrics/sec/chip north star (BASELINE.json)
 # and big groups add nothing), and G=16384 is past the HBM frontier (XLA
 # workspace temps on top of the 564 KB/stream state). So the ladder brackets
 # the small-G peak and probes longer chunks to amortize per-dispatch
-# overhead. The strategy candidates (all bit-identical to the default kernel
-# — tests/parity/) ride the per-attempt subprocess env. First silicon A/B
-# (2026-07-31, docs/KERNELS.md): the CPU-drive signal INVERTED on TPU — indexed
-# scatter loses big (18.1k vs matmul 28.1k metrics/s at G=1024) and Pallas
-# loses too (24.3k), while flat layout wins (31.9k). So the ladder races the
-# flat base plus the r4 learning-path cuts (compact punish/death sweep,
-# forward-index dendrite) on TOP of flat/matmul, not the CPU-guess
-# indexed base that round-3 shipped.
-# NOTE: the process default is flat/matmul since the r4 flip, so `{}` IS the
-# flat base; env overrides stay minimal because strat_key (the env tuple) is
-# also the per-strategy OOM-dominance key — a redundant RTAP_TM_LAYOUT=flat
-# would fragment dominance skipping across identical kernels.
-# BENCH_LEARN_EVERY rides the same per-attempt env as the kernel strategies:
+# overhead. The per-attempt env (strat_key) is also the OOM-dominance key.
+# BENCH_LEARN_EVERY rides the per-attempt subprocess env:
 # the learning-cadence schedule (ModelConfig.learn_every, SCALING.md operating
 # curve) measured k=4 at 86k and k=8 at 115k metrics/s/chip on silicon
 # (2026-08-01 chip run, SCALING.md) — k=8 is the first measured config
 # past the 100k north star on one chip. The cadence rungs measure the mature
-# steady state (cadence from tick 0, as profile_step does): the full-rate
+# steady state (cadence from tick 0): the full-rate
 # maturity window is a per-stream transient, not the steady state a
 # throughput bench describes. The quality trade (f1 0.741 vs 0.853 at k=8)
 # is documented in SCALING.md; the emitted line labels cadence rungs via
@@ -94,12 +83,6 @@ ATTEMPTS: list[tuple[int, int, dict]] = [
     (1024, 64, {"BENCH_COLUMNS": "32", "BENCH_LEARN_EVERY": "4"}),
     (1024, 64, {"BENCH_LEARN_EVERY": "8"}),
     (1024, 64, {"BENCH_LEARN_EVERY": "4"}),
-    (256, 64, {"RTAP_TM_LAYOUT": "aos"}),  # r3-default reference rung
-    # (the r4 compact/forward candidate rungs were retired after the
-    # 2026-08-01 chip run measured them -58%/-89% — docs/KERNELS.md. The
-    # Pallas megakernel rung went with ISSUE 21: at this preset the kernel's
-    # own guard refuses before the compiler is asked — ops/pallas_tm.py —
-    # so the rung could only ever fail; its A/B is ROADMAP queue 1 item 1)
     (256, 256, {}),
     (512, 128, {}),
     (2048, 64, {}),
@@ -129,9 +112,7 @@ def state_bytes_gate() -> int:
     from rtap_tpu.config import cluster_preset
     from rtap_tpu.models.state import init_state
 
-    # fwd_* excluded on both sides: derived state, never checkpointed and
-    # not part of the scaling-math layout model
-    st = init_state(cluster_preset(perm_bits=16), include_fwd=False)
+    st = init_state(cluster_preset(perm_bits=16))
     measured = sum(int(np.asarray(v).nbytes) for v in st.values())
     derived = derived_stream_bytes(os.path.dirname(os.path.abspath(__file__)), 16)
     log(json.dumps({"state_bytes_per_stream": measured,
@@ -179,8 +160,7 @@ def run_attempt(group_size: int, chunk_ticks: int, measure_chunks: int = 3) -> d
         import dataclasses
 
         # mature steady state: cadence from tick 0 (learn_full_until stays
-        # 0), the same measurement choice as profile_step --learn-every —
-        # the full-rate maturity window is a transient, and the service
+        # 0): the full-rate maturity window is a transient, and the service
         # applies it per stream via ModelConfig.with_learn_every
         cfg = dataclasses.replace(cfg, learn_every=learn_every)
         log(f"  learning cadence: every {learn_every} ticks (mature steady state)")
@@ -199,9 +179,9 @@ def run_attempt(group_size: int, chunk_ticks: int, measure_chunks: int = 3) -> d
     # steady state, pipelined (host likelihood + fetch overlap device compute)
     # with NOVEL values per measured chunk (genuine learning, r3 weak #8)
     value, dt = measure_pipelined(grp, vals, ts, measure_chunks, novel=((2026, 7), phase))
-    from rtap_tpu.ops.tm_tpu import layout_mode, scatter_mode, sweep_mode
+    from rtap_tpu.ops.tm_tpu import wide_rows
 
-    modes = f"{layout_mode(cfg.tm)}/{scatter_mode(cfg.tm)}/{sweep_mode()}"
+    modes = f"wide_rows={wide_rows(cfg.tm)}"
     if columns:
         modes += f"/cols={columns}"
     if learn_every > 1:
@@ -341,8 +321,8 @@ def _finish(best: dict | None) -> None:
 def main() -> None:
     budget = float(os.environ.get("BENCH_BUDGET_S", "1500"))
     per_attempt = float(os.environ.get("BENCH_ATTEMPT_BUDGET_S", "330"))
-    # layout-vs-derivation drift fails before any attempt. numpy-only
-    # (include_fwd=False): this parent must never initialize a JAX backend —
+    # layout-vs-derivation drift fails before any attempt. numpy-only:
+    # this parent must never initialize a JAX backend —
     # it would hold the chip its children need (tests pin it)
     state_bytes_gate()
     t_start = time.monotonic()

@@ -27,9 +27,7 @@ seeded feeder for ``serve`` is a thread of this JAX-free parent.
           collect_chunk, learning on; raw scores of 8 streams are compared
           with the numpy oracle (HTMModel(backend="cpu")) over the same 128
           ticks at the hardware tolerance (1e-6: TPU f32 divide rounds 1 ulp
-          differently from numpy). Then RTAP_TM_SCATTER=pallas — the Pallas
-          TM-learning megakernel — against the default path, bit for bit,
-          at scaled_cluster_preset(32), the width the v5e compiler accepts.
+          differently from numpy).
   serve   ``python -m rtap_tpu serve`` as docs/DEPLOYMENT.md §1's "<= 4k,
           max quality" row runs it: default preset, --group-size 1024, 4,096
           streams from an @ids file (4 groups, 1.24 GB of model state), 1 s
@@ -68,7 +66,6 @@ RAW_TOLERANCE = 1e-6  # verify skill: raw scores match the oracle to ~1e-7 on a 
 #: platform is not "tpu"); without it a non-TPU platform fails at once.
 SIZES = {
     "score_streams": 1024, "score_chunk": 64, "oracle_streams": 8,
-    "pallas_streams": 256, "pallas_chunk": 32,
     "serve_streams": 4096, "serve_group": 1024, "serve_ticks": 40,
     "cadence_s": 1.0,
     "mesh_streams": 4096, "mesh_chunk": 16,
@@ -131,7 +128,7 @@ def score_phase(sizes: dict) -> int:
     import jax
     import numpy as np
 
-    from rtap_tpu.config import cluster_preset, scaled_cluster_preset
+    from rtap_tpu.config import cluster_preset
     from rtap_tpu.models.htm_model import HTMModel
     from rtap_tpu.service.registry import StreamGroup
 
@@ -161,34 +158,6 @@ def score_phase(sizes: dict) -> int:
     say(f"[score] peak HBM {stats.get('peak_bytes_in_use')} B, in use "
         f"{stats.get('bytes_in_use')} B")
     del grp
-
-    # the Pallas megakernel against the default path, bit for bit
-    Gp, Tp = sizes["pallas_streams"], sizes["pallas_chunk"]
-    if Gp:
-        import rtap_tpu.ops.tm_tpu as tm_tpu
-
-        pcfg = scaled_cluster_preset(32)
-        ids = [f"p{i:05d}" for i in range(Gp)]
-        ref_grp = StreamGroup(pcfg, ids, backend="tpu")
-        _, _, raw_ref, _ = _run_chunks(ref_grp, Gp, Tp)
-        # interpreter mode only in the off-chip rehearsal, asked for here
-        tm_tpu.set_scatter_mode("pallas", interpret=device["platform"] != "tpu")
-        try:
-            pal_grp = StreamGroup(pcfg, ids, backend="tpu")
-            _, _, raw_pal, pw = _run_chunks(pal_grp, Gp, Tp)
-            same = bool(np.array_equal(raw_ref, raw_pal)) and all(
-                np.array_equal(np.asarray(ref_grp.state[k]),
-                               np.asarray(pal_grp.state[k]))
-                for k in ref_grp.state)
-            say(f"[score] pallas megakernel, scaled_cluster_preset(32) "
-                f"G={Gp}, 2 x {Tp} ticks: bit-equal to the default path: "
-                f"{same} (max |d raw| {np.abs(raw_ref - raw_pal).max():.3g}; "
-                f"first chunk {pw[0]:.1f}s, second {pw[1]:.2f}s)")
-            checks["pallas_bit_equal"] = same
-        finally:
-            tm_tpu.set_scatter_mode(None)
-    else:
-        say("[score] pallas check skipped (rehearsal size 0)")
     say(f"[score] checks {json.dumps(checks)}")
     return _result({"ok": all(checks.values()), "device": device,
                     "checks": checks})

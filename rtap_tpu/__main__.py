@@ -17,8 +17,8 @@ scripts/) since they are driver/measurement surfaces, not operator ones.
 
 Every command honors ``RTAP_FORCE_CPU=1`` (an explicit CPU run; without it
 or ``JAX_PLATFORMS=cpu``, ``--backend tpu`` refuses to start where JAX finds
-no TPU) and the kernel strategy env knobs (RTAP_TM_SCATTER / RTAP_TM_LAYOUT / RTAP_TM_SWEEP
-/ RTAP_TM_DENDRITE — docs/KERNELS.md catalogs them).
+no TPU). The kernels take no setting: the TM step's form follows the model's
+shape (docs/KERNELS.md).
 """
 
 from __future__ import annotations
@@ -645,8 +645,8 @@ def _cmd_serve(args: argparse.Namespace) -> int:
         lease.set_meta(ingest=f"{lhost}:{lport}")
     jax_tracing = False
     if args.jax_trace:
-        # device-side XLA trace paired with the host span timeline: the
-        # hw_session device-trace step loads both into Perfetto
+        # device-side XLA trace paired with the host span timeline: both
+        # load into Perfetto
         import jax
 
         jax.profiler.start_trace(args.jax_trace)

@@ -59,7 +59,7 @@ _SUPPRESS_RE = re.compile(r"#\s*rtap:\s*allow\[([A-Za-z0-9_,\s-]+)\]")
 BASELINE_NAME = "analysis_baseline.json"
 
 #: the --json artifact's schema version. Bump on any shape change to
-#: the artifact dict — soaks/hw_session archive these lines across
+#: the artifact dict — soaks archive these lines across
 #: months and the reader must be able to dispatch on shape. v3
 #: (ISSUE 14): cache gains the "warm" mode (pass-partitioned partial
 #: reuse) and per_pass covers the device-kernel pass family. v4
@@ -320,7 +320,7 @@ class Report:
         return not self.findings and not self.baseline_errors
 
     def to_dict(self) -> dict:
-        """The --json artifact line (soaks/hw_session archive this)."""
+        """The --json artifact line (soaks archive this)."""
         return {
             "analysis": {
                 "schema_version": SCHEMA_VERSION,

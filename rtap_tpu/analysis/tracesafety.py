@@ -5,9 +5,8 @@ code; this pass (ISSUE 14) extends the scope to host *decisions*. A
 ``bool()``/``int()``/``float()`` or an ``if`` on a value flowing from a
 traced operand is a TracerError under jit at best — and at worst it
 traces "successfully" on the first concrete call and silently bakes one
-branch into the compiled program. With the Pallas megakernel promotion
-(ROADMAP-2) multiplying the traced surface, these must be machine
-findings, not review catches. Four shapes, all inside traced
+branch into the compiled program. These must be machine findings, not
+review catches. Four shapes, all inside traced
 ``rtap_tpu/ops/`` functions (traced = calls into jnp/lax/pl):
 
 * ``if``/``while`` whose test reads a *tainted* name —

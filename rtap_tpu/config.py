@@ -277,22 +277,10 @@ class TMConfig:
     # K-bit per-column cell mask replaces comparing against a flat active-cell
     # id list (8-32x fewer VPU ops at preset sizes).
     col_cap: int = 40
-    # Static capacity of the RTAP_TM_SWEEP=compact punish/death pass (ops/
-    # tm_tpu.py): at most `punish_cap` matching segments in non-active columns
-    # are punished per step; overflow is counted in state["tm_overflow"].
-    # Dense-sweep mode (the round-3 semantics) ignores it.
+    # Read by nothing since PR 29 (they sized the compact sweep and the forward
+    # index, both gone); kept because benchmark/configs/*.json state them and
+    # tests/benchmark asserts the files equal to_dict() (ROADMAP D2b).
     punish_cap: int = 256
-    # Forward-index fanout capacity F (RTAP_TM_DENDRITE=forward, ops/
-    # fwd_index.py): max synapse slots per presynaptic cell tracked by
-    # fwd_slots [num_cells, F]. A cell exceeding F drops appends — counted in
-    # state["fwd_of"] (a dropped entry corrupts dendrite counts, so tests
-    # assert the counter stays zero). Memory when the index is enabled:
-    # num_cells * F * 4 B (+1-2 B/synapse slot for fwd_pos). Size F to the
-    # fanout TAIL: hot winner cells concentrate synapses (measured on the
-    # cluster preset's diurnal feed: max fanout 231-382 after 12k ticks and
-    # still rising — docs/FORWARD_INDEX_DESIGN.md round-4 measurement), so
-    # production forward-mode runs need F >= ~512 at that workload. The
-    # default stays small because the index is opt-in and tests own their F.
     fanout_cap: int = 64
 
 
@@ -508,13 +496,6 @@ class ModelConfig:
         for name, bits in (("sp", self.sp.perm_bits), ("tm", self.tm.perm_bits)):
             if bits not in (0, 8, 16):
                 raise ValueError(f"{name}.perm_bits must be 0 (f32), 8, or 16; got {bits}")
-        if self.tm.punish_cap < 1:
-            raise ValueError(f"TMConfig.punish_cap must be >= 1; got {self.tm.punish_cap}")
-        if not 1 <= self.tm.fanout_cap <= (1 << 15) - 1:
-            raise ValueError(
-                f"TMConfig.fanout_cap must be in [1, 32767] (fwd_pos is int16 at "
-                f"widest); got {self.tm.fanout_cap}"
-            )
         if self.composite is not None:
             if self.scalar is not None:
                 raise ValueError(

@@ -172,13 +172,7 @@ class HTMModel:
                 config_json=np.frombuffer(self.cfg.to_json().encode(), np.uint8),
                 seed=np.asarray(self.seed, np.int64),
                 **{f"lik_{k}": v for k, v in self.likelihood.state_dict().items()},
-                # fwd_* is derived state (ops/fwd_index.py): load() rebuilds
-                # it from presyn, so checkpoints are dendrite-mode-agnostic
-                **{
-                    f"s_{k}": np.asarray(v)
-                    for k, v in state.items()
-                    if not k.startswith("fwd_")
-                },
+                **{f"s_{k}": np.asarray(v) for k, v in state.items()},
             )
             # savez appends .npz when missing — mirror that for the temp name
             if not tmp.endswith(".npz") and os.path.exists(tmp + ".npz"):
@@ -200,22 +194,11 @@ class HTMModel:
             loaded = {
                 k[2:]: z[k]
                 for k in z.files
+                # fwd_*: a forward index an older build may have stored
                 if k.startswith("s_") and not k[2:].startswith("fwd_")
             }
             lik_state = {k[4:]: z[k] for k in z.files if k.startswith("lik_")}
             seed = int(z["seed"])
-        from rtap_tpu.ops.tm_tpu import dendrite_mode
-
-        if dendrite_mode() == "forward":
-            # rebuild the derived forward index from the restored pools
-            from rtap_tpu.ops.fwd_index import build_fwd_index
-
-            slots, pos, of = build_fwd_index(
-                np.asarray(loaded["presyn"]), cfg.num_cells, cfg.tm.fanout_cap
-            )
-            loaded["fwd_slots"] = np.asarray(slots)
-            loaded["fwd_pos"] = np.asarray(pos)
-            loaded["fwd_of"] = np.asarray(of)
         model = cls(cfg, seed=seed, backend=backend, _state=loaded)
         model.likelihood.load_state_dict(lik_state)
         return model

@@ -73,39 +73,23 @@ def members_dtype(cfg: ModelConfig):
     return np.int16 if cfg.input_size <= (1 << 15) - 1 else np.int32
 
 
-def fwd_index_arrays(cfg: ModelConfig) -> dict[str, np.ndarray]:
-    """Fresh (all-empty) forward-index arrays for an empty synapse pool
-    (RTAP_TM_DENDRITE=forward — ops/fwd_index.py): fwd_slots [N, F] i32,
-    fwd_pos [pool] i8/i16, fwd_of i32 overflow counter. Derived state —
-    checkpoints drop them and loads rebuild from `presyn`."""
-    tm = cfg.tm
-    F = tm.fanout_cap
-    pool = cfg.sp.columns * tm.cells_per_column * tm.max_segments_per_cell * tm.max_synapses_per_segment
-    return {
-        "fwd_slots": np.full((cfg.num_cells, F), -1, np.int32),  # rtap: partition[shard-streams]
-        "fwd_pos": np.full(pool, -1, np.int8 if F <= 127 else np.int16),  # rtap: partition[shard-streams]
-        "fwd_of": np.int32(0),  # rtap: partition[shard-streams]
-    }
-
-
 def init_state(
-    cfg: ModelConfig, seed: int = 0, include_fwd: bool | None = None,
+    cfg: ModelConfig, seed: int = 0, include_fwd: bool = False,
     predict_horizon: int = 0,
 ) -> dict[str, np.ndarray]:
     """Build the full per-stream state dict (see module docstring for layout).
 
-    `include_fwd` adds the forward-index arrays (None = yes iff the kernel's
-    dendrite mode is "forward", so callers stay mode-agnostic).
+    `include_fwd` has no effect: the forward synapse index it asked for is
+    gone (PR 29); the keyword stays because tests/benchmark passes False
+    (ROADMAP D2b), and True raises.
 
     `predict_horizon` > 0 adds the predictive-horizon leaves (ISSUE 16,
     ops/predict_tpu.py): a k-deep ring of predicted-active column sets, the
     divergence EWMA, and the per-stream warm-up epoch. 0 (the default) omits
     them entirely, so predict-less state trees — and their checkpoints — stay
     byte-identical to pre-predict builds (the flags-off bit-exactness pin)."""
-    if include_fwd is None:
-        from rtap_tpu.ops.tm_tpu import dendrite_mode
-
-        include_fwd = dendrite_mode() == "forward"
+    if include_fwd:
+        raise ValueError("init_state(include_fwd=True): no state carries a forward index")
     rng = np.random.Generator(np.random.Philox(key=(seed, 0xC0FFEE)))
     C, n_in = cfg.sp.columns, cfg.input_size
     K, S, M = cfg.tm.cells_per_column, cfg.tm.max_segments_per_cell, cfg.tm.max_synapses_per_segment
@@ -190,8 +174,6 @@ def init_state(
             "pred_miss_ewma": np.float32(np.nan),  # rtap: partition[shard-streams]
             "pred_tick0": np.int32(0),  # rtap: partition[shard-streams]
         } if predict_horizon else {}),
-        # forward synapse index (derived; present only in forward dendrite mode)
-        **(fwd_index_arrays(cfg) if include_fwd else {}),
         # SDR classifier (SURVEY.md C10), present only when enabled
         **(
             {

@@ -8,9 +8,9 @@ Two consumption shapes, one registry (obs/metrics.py):
   ``.address``, context-manager style as the serve path's TcpJsonlSource.
 - **File**: :func:`write_snapshot` appends one JSON line per call — the
   no-network surface for chip runs (the sealed chip machine has no scrape
-  infrastructure; scripts/hw_session.py points children at a per-step
-  snapshot path via ``RTAP_OBS_SNAPSHOT`` and reads the last line back
-  instead of scraping stdout).
+  infrastructure; a session runner points children at a snapshot path
+  via ``RTAP_OBS_SNAPSHOT`` and reads the last line back instead of
+  scraping stdout).
 """
 
 from __future__ import annotations
@@ -31,7 +31,7 @@ __all__ = [
     "write_snapshot",
 ]
 
-#: children inherit this from a session runner (scripts/hw_session.py): the
+#: children inherit this from a session runner: the
 #: default file the final snapshot lands in when no explicit path is given
 SNAPSHOT_ENV = "RTAP_OBS_SNAPSHOT"
 

@@ -112,8 +112,7 @@ class FlightRecorder:
         self._events_by_kind: dict[str, int] = {}
         self._events_total = 0
         # per-run tag in every bundle name: a re-run pointed at the same
-        # --postmortem-dir (hw_session steps hardcode theirs; chaos
-        # workdirs are reusable) must never collide with a prior run's
+        # --postmortem-dir (chaos workdirs are reusable) must never collide with a prior run's
         # bundle — os.rename onto an existing dir fails ENOTEMPTY and
         # would silently drop the NEW incident's postmortem
         self._run_tag = f"{int(time.time())}-{os.getpid()}"

@@ -46,7 +46,7 @@ from rtap_tpu.ops.tm_tpu import tm_step
 #:   rtap.sp.inhibit     sp_inhibit                                 (sp_tpu.sp_step)
 #:   rtap.sp.learn       sp_learn                                   (sp_tpu.sp_step)
 #:   rtap.tm.activate    cell activation, winners, raw score        (tm_tpu.tm_step)
-#:   rtap.tm.learn       reinforce/punish/grow, Pallas path too     (tm_tpu.tm_step)
+#:   rtap.tm.learn       reinforce/punish/grow                      (tm_tpu.tm_step)
 #:   rtap.tm.learn.rows  the workspace's rows moved by index: the form
 #:                       wide pool rows take (tm_tpu.wide_rows); absent
 #:                       where one-hot matmuls move them              (tm_tpu.tm_step)
@@ -202,9 +202,9 @@ def _scan_chunk(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: Mod
     Used identically by the single-device and shard_map entry points, so the
     two can never diverge semantically.
 
-    The kernel-layout adapters sit OUTSIDE the scan: under RTAP_TM_LAYOUT=
-    flat the carry holds flat pools for all T ticks and the public [C,K,S,M]
-    layout is restored once per chunk (shape-only reshapes — checkpoints,
+    The kernel-layout adapters sit OUTSIDE the scan: at narrow pool rows
+    (tm_tpu.wide_rows) the carry holds flat pools for all T ticks and the
+    public [C,K,S,M] layout is restored once per chunk (shape-only reshapes — checkpoints,
     oracle parity, and the service API never see kernel layout). Likewise
     the tick-invariant kernel operands (the flat layout's per-segment
     reduction matrix) are built ONCE here and closed over by the body, so

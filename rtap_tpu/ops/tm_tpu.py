@@ -15,35 +15,30 @@ same semantics down by an order of magnitude):
    flat cell-id compare at preset sizes, and no serialized gather.
 2. **Column-compact learning workspace.** Every learning segment lives in an
    active column, so the learning pass gathers the <= col_cap active columns
-   into a [Ac, K, S, M] workspace with one-hot MXU matmuls (XLA's TPU scatter
-   and row-gather on the full pool serialize — profiled in round 1), does the
-   compact reinforce/grow pass there (selecting <= learn_cap segments with a
-   cheap top_k over Ac*K*S instead of C*K*S), and scatters the workspace back
-   with the transposed one-hot matmul + column mask.
+   into a [Ac, K, S, M] workspace (with one-hot MXU matmuls at narrow pool
+   rows, by index at wide ones — below), does the compact reinforce/grow
+   pass there (selecting <= learn_cap segments with a cheap top_k over
+   Ac*K*S instead of C*K*S), and scatters the workspace back.
 
 Step outline: dense column categorization (predicted / burst-matching /
 burst-new) -> workspace learning (alloc, reinforce, grow toward previous
 winner cells with weakest-synapse eviction) -> punishment of matching
 segments in non-active columns -> synapse/segment death -> dendrite activity
 for t+1. Tie-breaks are lowest-index everywhere, matching the oracle exactly;
-parity is bit-for-bit (tests/parity/test_tm_parity.py). Punish/death run
-either as dense full-pool sweeps or as the round-4 compact touched-rows pass
-(RTAP_TM_SWEEP), and dendrite activity as a full-pool scan or through the
-forward synapse index (RTAP_TM_DENDRITE; ops/fwd_index.py) — see the switch
-table below; every combination is parity-pinned.
+parity is bit-for-bit (tests/parity/test_tm_parity.py).
 
-Round 6 (docs/KERNELS.md): the roofline pinned the step latency-bound
-(10x over the HBM floor, MXU < 0.1%) — the binding cost is the number of
-scheduled regions per scan iteration, not arithmetic. The workspace path
-is therefore region-consolidated: presyn + perm (+ seg_pot / the forward
-diff base) ride ONE one-hot MXU pass per gather/scatter stage instead of
-one pass per tensor (bitwise identical per block — each output element
-touches only its own operand columns), the dendrite conn/pot counts share
-one block-diagonal reduction, and tick-invariant operands (the flat
-layout's reduction matrix) hoist out of the chunk scan via
-:func:`tm_invariants`. The escalation beyond what XLA will fuse is the
-RTAP_TM_SCATTER=pallas megakernel (ops/pallas_tm.py): the whole learning
-pass VMEM-resident with no workspace movement at all.
+The step has two forms and the static shape picks between them in one place
+(:func:`wide_rows`): below WIDE_ROW_LANES synapse lanes a pool row, one-hot
+MXU matmuls move the workspace's rows and the pools run flat ([C, K*S*M],
+per-segment reductions as a block-diagonal matmul); at or above it, the rows
+move by index and the pools keep [C, K, S, M]. Nothing outside this module
+knows there is a choice. In the narrow form the workspace path is
+region-consolidated: presyn + perm (+ seg_pot) ride ONE one-hot MXU pass per
+gather/scatter stage instead of one pass per tensor (bitwise identical per
+block — each output element touches only its own operand columns), the
+dendrite conn/pot counts share one block-diagonal reduction, and
+tick-invariant operands (the reduction matrix) hoist out of the chunk scan
+via :func:`tm_invariants`.
 
 Capacity bounds (col_cap active columns, learn_cap learning segments per
 step) are static-shape requirements of XLA; overflow beyond the bounds is
@@ -81,94 +76,6 @@ def _tpu_paths() -> bool:
     return jax.default_backend() == "tpu"
 
 
-# ---------------------------------------------------------------------------
-# Kernel strategy switches. Each is a trace-time constant (NOT a jit cache
-# key): the env var is read ONCE at import — mutating os.environ mid-process
-# has no effect (set_*_mode() is the only supported runtime override, and it
-# clears the jit caches so stale compiled kernels can never mix modes).
-# All alternatives are bit-identical (tests/parity/); scripts/hw_session.py
-# races them on silicon and the measured winners become defaults.
-#
-#   RTAP_TM_SCATTER  matmul|indexed|pallas
-#                                     workspace row movement: one-hot MXU
-#                                     matmuls (full-pool f32 round trips) vs
-#                                     jnp.take/.at[].set of touched rows only
-#                                     vs the Pallas TM-learning megakernel
-#                                     (ops/pallas_tm.py: the whole learning
-#                                     pass fused in VMEM, dense sweeps, no
-#                                     workspace movement at all)
-#   RTAP_TM_LAYOUT   aos|flat         pools [C,K,S,M] (TPU tiling pads the
-#                                     tiny trailing dims up to ~20x) vs
-#                                     [C, K*S*M] with block-diagonal-matmul
-#                                     per-segment reductions
-# SCATTER and LAYOUT, where unset, follow the pool's row width: `wide_rows`.
-#   RTAP_TM_SWEEP    dense|compact    punish/death as full-pool sweeps vs
-#                                     gather/update/scatter of the <=
-#                                     punish_cap + learn_cap touched segment
-#                                     rows (ops/tm_tpu.py round 4)
-#   RTAP_TM_DENDRITE scan|forward     dendrite activity as a full-pool scan
-#                                     vs the forward synapse index
-#                                     (ops/fwd_index.py; state carries
-#                                     fwd_slots/fwd_pos/fwd_of)
-#   RTAP_TM_FWD_IMPL scatter|matmul   forward-index histogram accumulation:
-#                                     native scatter-add vs factored one-hot
-#                                     MXU contraction
-# ---------------------------------------------------------------------------
-import os as _os
-
-_MODE_CHOICES = {
-    "scatter": ("matmul", "indexed", "pallas"),
-    "layout": ("aos", "flat"),
-    "sweep": ("dense", "compact"),
-    "dendrite": ("scan", "forward"),
-    "fwd_impl": ("scatter", "matmul"),
-}
-_ENV_NAMES = {
-    "scatter": "RTAP_TM_SCATTER",
-    "layout": "RTAP_TM_LAYOUT",
-    "sweep": "RTAP_TM_SWEEP",
-    "dendrite": "RTAP_TM_DENDRITE",
-    "fwd_impl": "RTAP_TM_FWD_IMPL",
-}
-# Defaults are the measured silicon winners (SCALING.md round-4 A/B,
-# 2026-07-31 chip run): flat layout beat aos by 13% on the full
-# learning step (31.9k vs 28.1k metrics/s at G=1024) and matmul scatter
-# beat indexed by 1.55x — the reverse of the CPU-drive signal. Those runs
-# were at narrow pool rows; None = computed from the shape (`wide_rows`).
-_MODE_DEFAULTS = {
-    "scatter": None,
-    "layout": None,
-    "sweep": "dense",
-    "dendrite": "scan",
-    "fwd_impl": "scatter",
-}
-# start-of-process env snapshot (read once; see block comment above)
-_MODES: dict[str, str | None] = {
-    k: _os.environ.get(env, _MODE_DEFAULTS[k]) for k, env in _ENV_NAMES.items()
-}
-for _k, _v in _MODES.items():
-    if _v is not None and _v not in _MODE_CHOICES[_k]:
-        raise ValueError(
-            f"{_ENV_NAMES[_k]} must be one of {_MODE_CHOICES[_k]}, got {_v!r}"
-        )
-# runtime overrides (set_*_mode); None = keep the env snapshot value
-_OVERRIDES: dict[str, str | None] = {k: None for k in _MODES}
-
-
-def _mode(kind: str) -> str:
-    ov = _OVERRIDES[kind]
-    return _MODES[kind] if ov is None else ov
-
-
-def _set_mode(kind: str, mode: str | None) -> None:
-    if mode is not None and mode not in _MODE_CHOICES[kind]:
-        raise ValueError(
-            f"{kind} mode must be None or one of {_MODE_CHOICES[kind]}, got {mode!r}"
-        )
-    _OVERRIDES[kind] = mode
-    jax.clear_caches()
-
-
 #: The one line the shape-chosen forms are drawn at. A pool row is one
 #: column's K*S*M synapse lanes. Measured at the two ends on a v5e: at 192
 #: lanes (cluster presets, a 384 B row) the flat layout beats aos by 13 % and
@@ -184,82 +91,16 @@ WIDE_ROW_LANES = 2048
 
 
 def wide_rows(cfg: TMConfig) -> bool:
-    """Does this shape take the wide-row forms (indexed workspace moves, aos
-    pools) rather than the narrow-row ones (one-hot matmul moves, flat
-    pools)? All forms are bit-identical to the oracle (tests/parity)."""
+    """Does this shape take the wide-row form (indexed workspace moves,
+    [C, K, S, M] pools) rather than the narrow-row one (one-hot matmul moves,
+    flat [C, K*S*M] pools)? The one place the step's form is decided; both
+    forms are bit-identical to the oracle (tests/parity/test_tm_forms.py)."""
     return (cfg.cells_per_column * cfg.max_segments_per_cell
             * cfg.max_synapses_per_segment) >= WIDE_ROW_LANES
 
 
-def _shape_mode(kind: str, cfg: TMConfig, wide: str, narrow: str) -> str:
-    """RTAP_TM_<KIND> / set_<kind>_mode where given, else the form of the
-    shape: one answer per shape, so every caller names its `cfg`."""
-    explicit = _mode(kind)
-    if explicit is not None:
-        return explicit
-    return wide if wide_rows(cfg) else narrow
-
-
-def scatter_mode(cfg: TMConfig) -> str:
-    return _shape_mode("scatter", cfg, "indexed", "matmul")
-
-
-def layout_mode(cfg: TMConfig) -> str:
-    return _shape_mode("layout", cfg, "aos", "flat")
-
-
-def sweep_mode() -> str:
-    return _mode("sweep")
-
-
-def dendrite_mode() -> str:
-    return _mode("dendrite")
-
-
-def fwd_impl() -> str:
-    return _mode("fwd_impl")
-
-
-# Pallas interpreter mode is what a TEST asks for (set_scatter_mode's
-# argument) — never what a missing TPU selects: off a TPU the kernel raises.
-_PALLAS_INTERPRET = False
-
-
-def set_scatter_mode(mode: str | None, interpret: bool = False) -> None:
-    """Override the workspace-movement strategy AND clear jit caches.
-    `interpret=True` runs the "pallas" megakernel in the Pallas interpreter
-    (CPU parity tests only; orders of magnitude slower than XLA)."""
-    global _PALLAS_INTERPRET
-    _PALLAS_INTERPRET = bool(interpret) and mode == "pallas"
-    _set_mode("scatter", mode)
-
-
-def set_layout_mode(mode: str | None) -> None:
-    """Override the kernel tensor layout AND clear jit caches."""
-    _set_mode("layout", mode)
-
-
-def set_sweep_mode(mode: str | None) -> None:
-    """Override the punish/death sweep strategy AND clear jit caches."""
-    _set_mode("sweep", mode)
-
-
-def set_dendrite_mode(mode: str | None) -> None:
-    """Override the dendrite-activity strategy AND clear jit caches.
-
-    "forward" requires state built with the forward index present
-    (models/state.init_state reads this mode; checkpoint load rebuilds the
-    index from `presyn` — service/checkpoint.py)."""
-    _set_mode("dendrite", mode)
-
-
-def set_fwd_impl(mode: str | None) -> None:
-    """Override the forward-index histogram strategy AND clear jit caches."""
-    _set_mode("fwd_impl", mode)
-
-
-# TM state keys reshaped by the flat kernel layout: key -> how many trailing
-# dims collapse into one (pools: K,S,M -> K*S*M; segment tensors: K,S -> K*S).
+# TM state keys the narrow-row form runs flat: key -> how many trailing dims
+# collapse into one (pools: K,S,M -> K*S*M; segment tensors: K,S -> K*S).
 _FLAT_KEYS = {
     "presyn": 3, "syn_perm": 3,
     "seg_last": 2, "active_seg": 2, "matching_seg": 2, "seg_pot": 2,
@@ -267,11 +108,11 @@ _FLAT_KEYS = {
 
 
 def to_kernel_layout(state: dict, cfg: TMConfig) -> dict:
-    """Public state layout -> kernel layout (no-op in "aos" mode). Shape
+    """Public state layout -> kernel layout (no-op at wide rows). Shape
     change only — values are untouched, so checkpoints, the oracle, and the
     parity harness all keep the public [C, K, S, M] layout. The layout
-    follows `cfg`'s shape (`layout_mode`), as `tm_step(cfg)` reads it."""
-    if layout_mode(cfg) != "flat":
+    follows `cfg`'s shape (`wide_rows`), as `tm_step(cfg)` reads it."""
+    if wide_rows(cfg):
         return state
     out = dict(state)
     for k, nd in _FLAT_KEYS.items():
@@ -281,8 +122,8 @@ def to_kernel_layout(state: dict, cfg: TMConfig) -> dict:
 
 
 def from_kernel_layout(state: dict, cfg: TMConfig) -> dict:
-    """Kernel layout -> public state layout (no-op in "aos" mode)."""
-    if layout_mode(cfg) != "flat":
+    """Kernel layout -> public state layout (no-op at wide rows)."""
+    if wide_rows(cfg):
         return state
     K, S, M = cfg.cells_per_column, cfg.max_segments_per_cell, cfg.max_synapses_per_segment
     tails = {3: (K, S, M), 2: (K, S)}
@@ -296,9 +137,8 @@ def from_kernel_layout(state: dict, cfg: TMConfig) -> dict:
 @lru_cache(maxsize=None)
 def _reduce_matrix(ks: int, m: int):
     """Block-diagonal 0/1 [ks*m, ks] f32: column s sums synapse lanes
-    [s*m, (s+1)*m) — the per-segment Σ_M reduction as one MXU matmul.
-    (Moved here from the retired dendrite-only Pallas kernel: it is the
-    flat layout's seg_sum operand, load-bearing independent of Pallas.)"""
+    [s*m, (s+1)*m) — the per-segment Σ_M reduction as one MXU matmul (the
+    flat layout's seg_sum operand)."""
     r = np.zeros((ks * m, ks), np.float32)
     for s in range(ks):
         r[s * m : (s + 1) * m, s] = 1.0
@@ -310,9 +150,9 @@ def tm_invariants(cfg: TMConfig) -> dict | None:  # rtap: allow[twin-parity] —
     caller scanning over ticks (ops/step.py:_scan_chunk) can hoist them
     out of the scan body explicitly — they stay HBM-resident across the
     whole T-tick chunk instead of rematerializing as per-iteration
-    constants. None when the current layout needs none (aos reduces on the
-    trailing dim directly)."""
-    if layout_mode(cfg) != "flat":
+    constants. None at wide rows ([C, K, S, M] pools reduce on the trailing
+    dim directly)."""
+    if wide_rows(cfg):
         return None
     K, S, M = cfg.cells_per_column, cfg.max_segments_per_cell, cfg.max_synapses_per_segment
     return {"red": jnp.asarray(_reduce_matrix(K * S, M))}
@@ -463,8 +303,8 @@ def _grow_compact(
     min(new_synapse_count, W). An element-wise XLA gather costs ~10 ns an
     element on a v5e, which made the two lookups this replaces 54-82 % of a
     cluster tick; matching slots against winners in one `[L, M, W]` grid
-    (the form ops/pallas_tm.py fills with) ties at 256 columns and at the
-    NAB width and is 1.7 % of a tick slower at 32 (PERF.md §6, PR 28)."""
+    ties at 256 columns and at the NAB width and is 1.7 % of a tick slower
+    at 32 (PERF.md §6, PR 28)."""
     M = presyn_l.shape[1]
     W = winner_ids.shape[0]
 
@@ -539,26 +379,25 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
     caller hoists them out of its loop body; None rebuilds them as
     in-trace constants (single-dispatch callers).
     """
-    flat = layout_mode(cfg) == "flat"
-    scatter = scatter_mode(cfg)
-    if flat:
+    wide = wide_rows(cfg)
+    if wide:
+        C, K, S, M = state["presyn"].shape
+    else:
         K, S, M = cfg.cells_per_column, cfg.max_segments_per_cell, cfg.max_synapses_per_segment
         if state["presyn"].ndim != 2:
             raise ValueError(
-                "RTAP_TM_LAYOUT=flat: tm_step expects kernel-layout state "
+                "narrow pool rows: tm_step expects kernel-layout state "
                 "([C, K*S*M] pools — ops/step.py applies to_kernel_layout); "
                 f"got presyn shape {state['presyn'].shape}"
             )
         C = state["presyn"].shape[0]
-    else:
-        C, K, S, M = state["presyn"].shape
     N = C * K
     L, Ac = cfg.learn_cap, cfg.col_cap
     if K > 32:
         raise ValueError("cells_per_column > 32 unsupported (packed cell masks)")
 
-    pool_shape = (C, K * S * M) if flat else (C, K, S, M)
-    seg_shape = (C, K * S) if flat else (C, K, S)
+    pool_shape = (C, K, S, M) if wide else (C, K * S * M)
+    seg_shape = (C, K, S) if wide else (C, K * S)
 
     def _red():
         if inv is not None:
@@ -567,9 +406,9 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
 
     def seg_sum(x):
         """Per-segment count over synapse lanes -> i32 [*seg_shape]. Flat
-        layout reduces via the block-diagonal 0/1 MXU matmul (counts <= M <<
+        pools reduce via the block-diagonal 0/1 MXU matmul (counts <= M <<
         2^24: f32-exact) instead of a minor-dim sum the tiler pads."""
-        if not flat:
+        if wide:
             return x.sum(-1)
         return jnp.round(
             jax.lax.dot(x.astype(jnp.float32), _red(), precision=_HI)
@@ -577,10 +416,10 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
 
     def seg_sum2(a, b):
         """TWO per-segment counts in ONE reduction: the operands stack on
-        the row axis so the flat layout pays a single [2C, K*S*M] MXU pass
+        the row axis so flat pools pay a single [2C, K*S*M] MXU pass
         instead of two (fused-region consolidation; bitwise identical per
         block — each output element touches only its own operand rows)."""
-        if not flat:
+        if wide:
             return a.sum(-1), b.sum(-1)
         both = jnp.round(
             jax.lax.dot(
@@ -592,7 +431,7 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
 
     def seg_expand(x):
         """Broadcast a per-segment value onto its synapse lanes."""
-        return jnp.repeat(x, M, axis=-1) if flat else x[..., None]
+        return x[..., None] if wide else jnp.repeat(x, M, axis=-1)
 
     # Permanence-domain constants (models/perm.py). The learning workspace
     # computes on integer-VALUED f32 in quantized domains (quanta <= 65535
@@ -647,90 +486,19 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
             | winner_extra
         )
 
-    # Strategy resolution for this trace. The forward index cannot survive a
-    # dense death sweep (presyn mutates without index updates), so forward
-    # dendrite mode forces the compact sweep under learning.
-    forward = dendrite_mode() == "forward"
-    compact_sweep = forward or sweep_mode() == "compact"
-    if forward and "fwd_slots" not in state:
-        raise ValueError(
-            "RTAP_TM_DENDRITE=forward: state lacks the forward index "
-            "(fwd_slots/fwd_pos/fwd_of) — build it via models/state.init_state "
-            "under forward mode, or rebuild from presyn with "
-            "ops.fwd_index.build_fwd_index (checkpoint loads do this)"
-        )
-    if not forward and learn and "fwd_slots" in state:
-        # learning under scan mode mutates presyn WITHOUT index maintenance;
-        # a later switch to forward mode would then read a silently-stale
-        # index. Refuse now instead: rebuild state under the target mode
-        # (A/B runs construct one state per mode; checkpoints are
-        # mode-agnostic and rebuild on load).
-        raise ValueError(
-            "state carries a forward index but RTAP_TM_DENDRITE=scan would "
-            "learn without maintaining it (silent index corruption); "
-            "re-init the state under scan mode or run with forward dendrite"
-        )
-    fwd_slots = state.get("fwd_slots")
-    fwd_pos = state.get("fwd_pos")
-    fwd_of = state.get("fwd_of")
-    n_seg = C * K * S
-
-    pallas_learn = learn and scatter == "pallas"
-    if scatter == "pallas":
-        if forward:
-            raise ValueError(
-                "RTAP_TM_SCATTER=pallas is incompatible with "
-                "RTAP_TM_DENDRITE=forward: the megakernel computes dendrite "
-                "counts itself and maintains no forward index"
-            )
-        if sweep_mode() == "compact":
-            raise ValueError(
-                "RTAP_TM_SCATTER=pallas is incompatible with "
-                "RTAP_TM_SWEEP=compact: the megakernel fuses the DENSE "
-                "punish/death sweeps in VMEM"
-            )
-
     overflow_learn = jnp.bool_(False)
-    conn_count = pot_count = tm_overflow = None
     with jax.named_scope("rtap.tm.learn"):
-        if pallas_learn:
-            # --- the whole learning pass as ONE Pallas kernel, VMEM-resident
-            # (ops/pallas_tm.py): decisions stay here on [C, K, S]-scale
-            # tensors; the kernel owns every pool traversal including the
-            # dendrite counts for t+1 ---
-            from rtap_tpu.ops.pallas_tm import tm_learn_pallas
-
-            pcol_ids, pcol_masks, p_cols = _pack_active(state["prev_active"], Ac)
-            winner_ids = _winner_id_list(state["prev_winner"], Ac)  # [Ac*K]
-            acol_ids, acol_masks, a_cols = _pack_active(active_cells, Ac)
-            presyn_n, perm_n, sl, conn_f, pot_f, overflow_learn = tm_learn_pallas(
-                cfg, dom, presyn, syn_perm, seg_last,
-                seg_pot4, matching_seg4, learn_mask, alloc,
-                active_cols, have_winners, it,
-                pcol_ids, pcol_masks, p_cols, winner_ids,
-                acol_ids, acol_masks, interpret=_PALLAS_INTERPRET,
-            )
-            presyn = presyn_n.astype(presyn_dt).reshape(*pool_shape)
-            perm_w = jnp.round(perm_n) if dom.bits else perm_n  # exact already
-            syn_perm = perm_w.astype(p_dt).reshape(*pool_shape)
-            seg_last = sl.reshape(*seg_shape)
-            conn_count = conn_f.reshape(*seg_shape)
-            pot_count = pot_f.reshape(*seg_shape)
-            tm_overflow = state["tm_overflow"] + (
-                overflow_learn | (a_cols > Ac)
-            ).astype(jnp.int32)
-        if learn and not pallas_learn:
+        if learn:
             alloc_col, bn_k, bn_s = alloc
             burst_new = alloc_col < C  # [C]
 
             # --- gather the active columns into the [Ac, ...] workspace ---
-            indexed = scatter == "indexed"
             col_ids = _compact_ids(active_cols, Ac)  # [Ac], fills = C
             col_oh_b = col_ids[:, None] == jnp.arange(C, dtype=jnp.int32)  # [Ac, C]
             col_oh = col_oh_b.astype(jnp.float32)
             hit_cols = col_oh_b.any(0)  # [C] columns actually captured (== active_cols sans overflow)
 
-            if indexed:
+            if wide:
                 # move only the <= Ac touched rows; fill slots (id C) clamp to a
                 # junk copy of row C-1 that is masked out of learning (ws_learn /
                 # ws_alloc are False there) and dropped at scatter-back
@@ -769,10 +537,6 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
                     (col_oh_b[:, :, None] & learn_mask.reshape(C, -1)[None]).any(1).reshape(Ac, K, S)
                 )
 
-            # original pool content of the workspace (pre alloc-clear): the
-            # forward-index maintenance diffs learned rows against it
-            ws_presyn0_r = ws_presyn.reshape(Ac * K * S, M) if forward else None
-
             # --- burst-new allocation inside the workspace: clear slot + stamp ---
             ws_bn = (col_oh_b & burst_new[None, :]).any(-1)  # [Ac]
             ws_bnk = jnp.where(col_oh_b, bn_k[None, :], 0).sum(-1)  # [Ac]
@@ -793,28 +557,23 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
             valid_l = idx < R2
             ws_presyn_r = ws_presyn.reshape(R2, M)
             ws_perm_r = ws_perm.reshape(R2, M)
-            presyn_l0 = None
-            if indexed:
+            if wide:
                 idx_r = jnp.clip(idx, 0, R2 - 1)
                 presyn_l = ws_presyn_r[idx_r]  # [L, M]; fill rows junk, see below
                 perm_l = ws_perm_r[idx_r]
                 pot_l = jnp.where(valid_l, ws_pot.reshape(-1)[idx_r], 0)  # [L]
-                if forward:
-                    presyn_l0 = ws_presyn0_r[idx_r]
             else:
                 row_oh_b = idx[:, None] == jnp.arange(R2, dtype=jnp.int32)  # [L, R2]
                 row_oh = row_oh_b.astype(jnp.float32)
-                # presyn + perm (+ the forward diff base) compact in ONE
-                # [L, R2] MXU pass — same consolidation as the column gather
-                parts = [ws_presyn_r.astype(jnp.float32), ws_perm_r]
-                if forward:
-                    parts.append(ws_presyn0_r.astype(jnp.float32))
-                gl = _gather_rows_f32(jnp.concatenate(parts, axis=1), row_oh)  # [L, 2-3M]
+                # presyn + perm compact in ONE [L, R2] MXU pass — same
+                # consolidation as the column gather
+                gl = _gather_rows_f32(
+                    jnp.concatenate([ws_presyn_r.astype(jnp.float32), ws_perm_r], axis=1),
+                    row_oh,
+                )  # [L, 2M]
                 presyn_l = jnp.round(gl[:, :M]).astype(jnp.int32)  # [L, M]
                 perm_l = gl[:, M:2 * M]  # [L, M]
                 pot_l = jnp.where(row_oh_b, ws_pot.reshape(-1)[None, :], 0).sum(-1)  # [L]
-                if forward:
-                    presyn_l0 = jnp.round(gl[:, 2 * M:]).astype(jnp.int32)
 
             # prev-step active cells, column-compact (shared by reinforce + punish)
             pcol_ids, pcol_masks, p_cols = _pack_active(state["prev_active"], Ac)
@@ -839,22 +598,14 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
             perm_l = jnp.where(grow_ok[:, None], grown_perm, perm_l)
 
             last_l = jnp.full((L,), 1, jnp.int32) * it  # [L] seg_last of learned rows
-            if compact_sweep:
-                # Synapse death (perm <= 0 after reinforce) and empty-segment
-                # death applied IN the workspace: learned rows are the only
-                # active-column rows whose perms moved this step, so handling
-                # them here (and punished rows below) makes the dense full-pool
-                # death sweep redundant — that equivalence is the compact-sweep
-                # contract (tests/parity/test_sweep_parity.py).
-                dead_l = (presyn_l >= 0) & (perm_l <= jnp.float32(dom.zero))
-                presyn_l = jnp.where(dead_l, -1, presyn_l)
-                last_l = jnp.where((presyn_l >= 0).sum(-1) == 0, -1, last_l)
 
             # --- scatter learned rows back into the workspace ---
-            if indexed:
-                hit_rows = jnp.zeros(R2, bool).at[idx].set(True, mode="drop")
+            if wide:
                 ws_presyn_r = ws_presyn_r.at[idx].set(presyn_l, mode="drop")
                 ws_perm_r = ws_perm_r.at[idx].set(perm_l, mode="drop")
+                ws_last = (
+                    ws_last.reshape(R2).at[idx].set(last_l, mode="drop").reshape(Ac, K, S)
+                )
             else:
                 hit_rows = row_oh_b.any(0)  # [R2]
                 # presyn + perm scatter back in ONE transposed one-hot MXU pass
@@ -867,18 +618,13 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
                 scat_perm = scat[:, M:]
                 ws_presyn_r = jnp.where(hit_rows[:, None], scat_presyn, ws_presyn_r)
                 ws_perm_r = jnp.where(hit_rows[:, None], scat_perm, ws_perm_r)
-            if indexed:
-                ws_last = (
-                    ws_last.reshape(R2).at[idx].set(last_l, mode="drop").reshape(Ac, K, S)
-                )
-            else:
                 last_scat = jnp.where(row_oh_b, last_l[:, None], 0).sum(0)  # [R2]
                 ws_last = jnp.where(
                     hit_rows.reshape(Ac, K, S), last_scat.reshape(Ac, K, S), ws_last
                 )
 
             # --- scatter the workspace back to the pools ---
-            if indexed:
+            if wide:
                 # only the <= Ac touched rows are written; fill ids (C) drop
                 with jax.named_scope("rtap.tm.learn.rows"):
                     presyn = (
@@ -934,142 +680,38 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
                 (n_active > Ac) | (p_cols > Ac) | (ws_learn.sum() > L)
             )
 
-            slots_p = old_p = rem_p = None
-            if compact_sweep:
-                # --- compact punish/death (RTAP_TM_SWEEP=compact): gather the
-                # <= punish_cap matching segments in non-active columns, punish
-                # + kill them there, scatter back. Together with the in-workspace
-                # death above this covers every synapse whose permanence moved
-                # this step (learned rows and punished rows are disjoint by
-                # column), so the full-pool punish/death sweeps are skipped
-                # entirely — the dense sweeps re-derive death for ALL synapses,
-                # but an untouched synapse can never newly satisfy perm <= 0
-                # (death ran last learn step; inference leaves perms alone). ---
-                if cfg.predicted_segment_decrement > 0.0:
-                    pdec = dom.rate(cfg.predicted_segment_decrement)
-                    P = min(cfg.punish_cap, n_seg)
-                    pmask_seg = (matching_seg4 & ~active_cols[:, None, None]).reshape(-1)
-                    pids = _compact_ids(pmask_seg, P)  # [P], fills = n_seg
-                    valid_p = pids < n_seg
-                    pidc = jnp.clip(pids, 0, n_seg - 1)
-                    pres_p = presyn.reshape(n_seg, M)[pidc].astype(jnp.int32)  # [P, M]
-                    perm_p = syn_perm.reshape(n_seg, M)[pidc]
-                    pact_p = _presyn_active_packed(pres_p, pcol_ids, pcol_masks, K)
-                    sp_c = perm_p.astype(dom.compute_dtype)
-                    perm_pn = jnp.where(pact_p, jnp.maximum(sp_c - pdec, dom.zero), sp_c)
-                    dead_p = (pres_p >= 0) & (perm_pn <= dom.zero)
-                    pres_pn = jnp.where(dead_p, -1, pres_p)
-                    sl_p = seg_last.reshape(-1)[pidc]
-                    sl_pn = jnp.where((sl_p >= 0) & ((pres_pn >= 0).sum(-1) == 0), -1, sl_p)
-                    drop_ids = jnp.where(valid_p, pids, n_seg)  # fills -> dropped
-                    syn_perm = (
-                        syn_perm.reshape(n_seg, M)
-                        .at[drop_ids]
-                        .set(perm_pn.astype(p_dt), mode="drop")
-                        .reshape(*pool_shape)
-                    )
-                    presyn = (
-                        presyn.reshape(n_seg, M)
-                        .at[drop_ids]
-                        .set(pres_pn.astype(presyn_dt), mode="drop")
-                        .reshape(*pool_shape)
-                    )
-                    seg_last = (
-                        seg_last.reshape(-1)
-                        .at[drop_ids]
-                        .set(sl_pn, mode="drop")
-                        .reshape(*seg_shape)
-                    )
-                    overflow_learn = overflow_learn | (pmask_seg.sum() > P)
-                    if forward:
-                        slots_p = pidc[:, None] * M + jnp.arange(M, dtype=jnp.int32)
-                        old_p = pres_p
-                        rem_p = valid_p[:, None] & dead_p
-            else:
-                # --- dense punish: matching segments in columns that did not
-                # activate, over the full pool ---
-                if cfg.predicted_segment_decrement > 0.0:
-                    pdec = dom.rate(cfg.predicted_segment_decrement)
-                    acols_seg = active_cols.reshape(C, *([1] * (len(seg_shape) - 1)))
-                    pmask = state["matching_seg"] & ~acols_seg  # [*seg_shape]
-                    pact = _presyn_active_packed(presyn, pcol_ids, pcol_masks, K)
-                    sp_c = syn_perm.astype(dom.compute_dtype)
-                    syn_perm = jnp.where(
-                        seg_expand(pmask) & pact,
-                        jnp.maximum(sp_c - pdec, dom.zero),
-                        sp_c,
-                    ).astype(p_dt)
+            # --- punish: matching segments in columns that did not activate,
+            # over the full pool ---
+            if cfg.predicted_segment_decrement > 0.0:
+                pdec = dom.rate(cfg.predicted_segment_decrement)
+                acols_seg = active_cols.reshape(C, *([1] * (len(seg_shape) - 1)))
+                pmask = state["matching_seg"] & ~acols_seg  # [*seg_shape]
+                pact = _presyn_active_packed(presyn, pcol_ids, pcol_masks, K)
+                sp_c = syn_perm.astype(dom.compute_dtype)
+                syn_perm = jnp.where(
+                    seg_expand(pmask) & pact,
+                    jnp.maximum(sp_c - pdec, dom.zero),
+                    sp_c,
+                ).astype(p_dt)
 
-                # --- synapse death at permanence <= 0, then empty-segment death ---
-                dead = (presyn >= 0) & (syn_perm <= dom.zero)
-                presyn = jnp.where(dead, -1, presyn)
-                nsyn = seg_sum(presyn >= 0)
-                seg_last = jnp.where((seg_last >= 0) & (nsyn == 0), -1, seg_last)
-
-            if forward:
-                # --- forward-index maintenance: diff the touched rows against
-                # their original pool content and apply removals, then appends
-                # (ops/fwd_index.py). Touched rows = the L learned workspace rows
-                # (evictions, alloc-clears, growth, reinforce-death) + the P
-                # punished rows (death only). ---
-                from rtap_tpu.ops.fwd_index import apply_appends, apply_removals
-
-                a_i = idx // (K * S)
-                gcol = jnp.where(valid_l, col_ids[jnp.clip(a_i, 0, Ac - 1)], C)
-                vs_l = valid_l & (gcol < C)  # [L]
-                seg_flat_l = jnp.where(vs_l, gcol * (K * S) + (idx % (K * S)), n_seg)
-                slots_l = seg_flat_l[:, None] * M + jnp.arange(M, dtype=jnp.int32)  # [L, M]
-                changed = presyn_l0 != presyn_l
-                rem_l = vs_l[:, None] & changed & (presyn_l0 >= 0)
-                add_l = vs_l[:, None] & changed & (presyn_l >= 0)
-                if slots_p is not None:
-                    slots_all = jnp.concatenate([slots_l.reshape(-1), slots_p.reshape(-1)])
-                    old_all = jnp.concatenate([presyn_l0.reshape(-1), old_p.reshape(-1)])
-                    rem_all = jnp.concatenate([rem_l.reshape(-1), rem_p.reshape(-1)])
-                else:
-                    slots_all = slots_l.reshape(-1)
-                    old_all = presyn_l0.reshape(-1)
-                    rem_all = rem_l.reshape(-1)
-                fwd_slots, fwd_pos = apply_removals(
-                    fwd_slots, fwd_pos, slots_all, old_all, rem_all
-                )
-                fwd_slots, fwd_pos, ndrop = apply_appends(
-                    fwd_slots, fwd_pos, slots_l.reshape(-1),
-                    presyn_l.reshape(-1), add_l.reshape(-1),
-                )
-                fwd_of = fwd_of + ndrop
+            # --- synapse death at permanence <= 0, then empty-segment death ---
+            dead = (presyn >= 0) & (syn_perm <= dom.zero)
+            presyn = jnp.where(dead, -1, presyn)
+            nsyn = seg_sum(presyn >= 0)
+            seg_last = jnp.where((seg_last >= 0) & (nsyn == 0), -1, seg_last)
 
     with jax.named_scope("rtap.tm.dendrite"):
         # --- dendrite activity for t+1 over existing segments ---
         exists_seg = seg_last >= 0
-        if pallas_learn:
-            pass  # the megakernel already produced conn/pot counts + overflow
-        elif forward:
-            # forward index: gather only the <= Ac*K active cells' fanout rows
-            # (ops/fwd_index.py) instead of sweeping the pools
-            from rtap_tpu.ops.fwd_index import dendrite_counts
-
-            a_cols = active_cells.any(-1).sum()
-            tm_overflow = state["tm_overflow"] + (
-                overflow_learn | (a_cols > Ac)
-            ).astype(jnp.int32)
-            act_ids = _winner_id_list(active_cells, Ac)  # [Ac*K], fills = N
-            conn_c, pot_c = dendrite_counts(
-                fwd_slots, syn_perm.reshape(-1), act_ids, p_connected,
-                n_seg, M, fwd_impl(),
-            )
-            conn_count = conn_c.reshape(*seg_shape)
-            pot_count = pot_c.reshape(*seg_shape)
-        else:
-            acol_ids, acol_masks, a_cols = _pack_active(active_cells, Ac)
-            # the packed-column truncation applies under inference too — count it always
-            tm_overflow = state["tm_overflow"] + (
-                overflow_learn | (a_cols > Ac)
-            ).astype(jnp.int32)
-            syn_act = _presyn_active_packed(presyn, acol_ids, acol_masks, K)
-            conn_count, pot_count = seg_sum2(
-                syn_act & (syn_perm >= p_connected), syn_act
-            )
+        acol_ids, acol_masks, a_cols = _pack_active(active_cells, Ac)
+        # the packed-column truncation applies under inference too — count it always
+        tm_overflow = state["tm_overflow"] + (
+            overflow_learn | (a_cols > Ac)
+        ).astype(jnp.int32)
+        syn_act = _presyn_active_packed(presyn, acol_ids, acol_masks, K)
+        conn_count, pot_count = seg_sum2(
+            syn_act & (syn_perm >= p_connected), syn_act
+        )
         active_seg = exists_seg & (conn_count >= cfg.activation_threshold)
         matching_seg = exists_seg & (pot_count >= cfg.min_threshold)
         seg_pot = jnp.where(exists_seg, pot_count, 0).astype(jnp.int16)
@@ -1077,7 +719,7 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
             # LRU stamp for active segments (NuPIC stamps under learn only)
             seg_last = jnp.where(active_seg, it, seg_last)
 
-    new_state = {
+    return {
         **state,
         "presyn": presyn,
         "syn_perm": syn_perm,
@@ -1089,9 +731,4 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
         "prev_winner": winner_cells,
         "tm_iter": it.astype(jnp.int32),  # oracle increments under inference too
         "tm_overflow": tm_overflow,
-    }
-    if forward:
-        new_state["fwd_slots"] = fwd_slots
-        new_state["fwd_pos"] = fwd_pos
-        new_state["fwd_of"] = fwd_of
-    return new_state, raw
+    }, raw

@@ -59,27 +59,12 @@ def save_group(grp: StreamGroup, path: str | Path,
     t_save = time.perf_counter()
 
     path = Path(path).absolute()
-    # the forward synapse index (fwd_*) is derived state: never stored —
-    # load_group rebuilds it from `presyn` — so the on-disk schema is
-    # identical across dendrite modes (ops/fwd_index.py)
     if grp.backend == "tpu":
-        model_state = {
-            k: np.asarray(v)
-            for k, v in jax.device_get(grp.state).items()
-            if not k.startswith("fwd_")
-        }
-        tree = {"model": model_state}
+        tree = {"model": {k: np.asarray(v) for k, v in jax.device_get(grp.state).items()}}
     else:
         # per-stream state dicts include classifier cls_* arrays when enabled
         # (the oracle operates on the shared state layout, like TMOracle)
-        tree = {
-            "model": {
-                f"s{g}": {
-                    k: v for k, v in grp._states[g].items() if not k.startswith("fwd_")
-                }
-                for g in range(grp.G)
-            }
-        }
+        tree = {"model": {f"s{g}": grp._states[g] for g in range(grp.G)}}
     tree["likelihood"] = grp.likelihood.state_dict()
     tree["alert_run"] = np.asarray(grp._alert_run)  # debounce counters
 
@@ -234,27 +219,8 @@ def load_group(path: str | Path, mesh=None, sparsify: bool = False) -> StreamGro
         predict=int(meta.get("predict", 0)),
     )
     if grp.backend == "tpu":
-        from rtap_tpu.ops.tm_tpu import dendrite_mode
-
+        # fwd_*: a forward index an older build may have stored
         model = {k: v for k, v in tree["model"].items() if not k.startswith("fwd_")}
-        if dendrite_mode() == "forward":
-            # rebuild the derived forward index from the restored pools
-            # (per stream; any fanout_cap overflow lands in fwd_of and the
-            # service's overflow observability picks it up)
-            from functools import partial
-
-            from rtap_tpu.ops.fwd_index import build_fwd_index
-
-            slots, pos, of = jax.vmap(
-                partial(
-                    build_fwd_index,
-                    n_cells=cfg.num_cells,
-                    fanout_cap=cfg.tm.fanout_cap,
-                )
-            )(np.asarray(model["presyn"]))
-            model["fwd_slots"] = np.asarray(slots)
-            model["fwd_pos"] = np.asarray(pos)
-            model["fwd_of"] = np.asarray(of)
         if mesh is not None:
             from rtap_tpu.parallel.sharding import shard_state
 
@@ -265,8 +231,6 @@ def load_group(path: str | Path, mesh=None, sparsify: bool = False) -> StreamGro
         for g in range(grp.G):
             saved = tree["model"][f"s{g}"]
             for k in grp._states[g]:
-                if k.startswith("fwd_"):
-                    continue  # derived, oracle-unused; fresh arrays stay
                 grp._states[g][k] = np.asarray(saved[k])
     grp.likelihood.load_state_dict(tree["likelihood"])
     if "alert_run" in tree:  # pre-debounce checkpoints lack it (zeros then)

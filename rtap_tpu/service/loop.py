@@ -282,10 +282,10 @@ def replay_streams(
              **_device_stats(reg.groups)}
     overflow = _overflow_total(reg.groups)
     if overflow is not None:
-        # kernel capacity-overflow observability (learn_cap/col_cap/
-        # punish_cap/fanout_cap): nonzero means some stream exceeded a
-        # static bound and its scores deviate from the oracle — surface it
-        # in the replay stats instead of leaving it buried in device state
+        # kernel capacity-overflow observability (learn_cap/col_cap):
+        # nonzero means some stream exceeded a static bound and its scores
+        # deviate from the oracle — surface it in the replay stats instead
+        # of leaving it buried in device state
         stats["tm_overflow_total"] = overflow
     # how near the dense segment pools came to max_segments_per_cell:
     # nonzero tm_full_columns means LRU eviction may have dropped a segment
@@ -2207,8 +2207,8 @@ def _save_all(groups, checkpoint_dir: str, skip=(), chaos=None, tick: int = 0,
 # counters; runs once per serve exit, never on the hot path, and a mesh
 # gather of [G] i32 leaves is bytes, not state
 def _overflow_total(groups) -> int | None:
-    """Sum the per-stream kernel overflow counters (tm_overflow + fwd_of)
-    across device groups; None for CPU-oracle groups (the oracle has no
+    """Sum the per-stream kernel overflow counter (tm_overflow) across
+    device groups; None for CPU-oracle groups (the oracle has no
     capacity bounds to overflow)."""
     total = 0
     saw_device = False
@@ -2216,10 +2216,7 @@ def _overflow_total(groups) -> int | None:
         if grp.backend != "tpu":
             continue
         saw_device = True
-        st = grp.state
-        total += int(np.asarray(st["tm_overflow"]).sum())
-        if "fwd_of" in st:
-            total += int(np.asarray(st["fwd_of"]).sum())
+        total += int(np.asarray(grp.state["tm_overflow"]).sum())
     return total if saw_device else None
 
 

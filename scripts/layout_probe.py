@@ -16,7 +16,7 @@ step (2% of v5e peak). Two suspects, each probed in isolation here:
    step's exact shapes, G-batched.
 
 Prints one JSON line per probe to stdout ({"probe": ..., "us_per_stream_tick"
-: ...}); run on hardware via hw_session step 2 (or standalone).
+: ...}); run on hardware standalone.
 """
 
 from __future__ import annotations

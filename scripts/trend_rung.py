@@ -52,7 +52,7 @@ def main() -> int:
 
     enable_compile_cache()
     from rtap_tpu.config import cluster_preset
-    from rtap_tpu.ops.tm_tpu import layout_mode, scatter_mode, sweep_mode
+    from rtap_tpu.ops.tm_tpu import wide_rows
     from rtap_tpu.service.registry import StreamGroup
     from rtap_tpu.utils.measure import make_sine_feed, measure_pipelined
 
@@ -60,7 +60,7 @@ def main() -> int:
     ids = [f"trend{i:04d}" for i in range(args.G)]
     platform = jax.devices()[0].platform
     log(f"platform={platform} G={args.G} T={args.T} "
-        f"modes={layout_mode(cfg.tm)}/{scatter_mode(cfg.tm)}/{sweep_mode()}")
+        f"wide_rows={wide_rows(cfg.tm)}")
 
     results: dict[str, list[float]] = {"novel": [], "repeated": []}
     for protocol in ("novel", "repeated"):

@@ -51,8 +51,6 @@ def v5e():
     tm_tpu.FORCE_TPU_PATHS = True
     yield SingleDeviceSharding(topo.devices[0])
     tm_tpu.FORCE_TPU_PATHS = paths_was
-    tm_tpu.set_scatter_mode(None)
-    tm_tpu.set_layout_mode(None)
     jax.config.update("jax_enable_compilation_cache", cache_was)
     compilation_cache.reset_cache()
 
@@ -101,12 +99,13 @@ def test_cluster_chunk_step_holds_no_gather_at_the_cells_batch(v5e, preset):
 
 
 @pytest.mark.parametrize("forms", ["by_shape", "narrow_forced"])
-def test_nab_width_chunk_step_fits_a_v5e_only_in_the_wide_row_forms(v5e, forms):
+def test_nab_width_chunk_step_fits_a_v5e_only_in_the_wide_row_forms(v5e, forms, monkeypatch):
     """The published NAB width (2048 x 32 x 16 x 32: 16,384-lane pool rows)
-    at the benchmark cell's batch of 17 streams. In the forms the shape rule
-    picks (tm_tpu.wide_rows: indexed row moves, aos pools) the program fits
-    the chip; in the narrow-row forms the cluster presets run, forced here,
-    the chip's compiler refuses it for memory — the reason the line exists."""
+    at the benchmark cell's batch of 17 streams. In the form the shape rule
+    picks (tm_tpu.wide_rows: indexed row moves, [C, K, S, M] pools) the
+    program fits the chip; in the narrow-row form the cluster presets run —
+    the line moved over this shape, here — the chip's compiler refuses it for
+    memory: the reason the line exists."""
     from rtap_tpu.ops.step import chunk_step
 
     cfg = nab_preset(0.0, 100.0)
@@ -123,14 +122,14 @@ def test_nab_width_chunk_step_fits_a_v5e_only_in_the_wide_row_forms(v5e, forms):
         # stay under the 5,111,318,528 B the gather form took (ISSUE 28)
         assert mem.temp_size_in_bytes <= 5_111_318_528
         return
-    tm_tpu.set_scatter_mode("matmul")
-    tm_tpu.set_layout_mode("flat")
+    monkeypatch.setattr(tm_tpu, "WIDE_ROW_LANES", 1 << 30)
+    jax.clear_caches()  # the form is read at trace time
     try:
+        assert not tm_tpu.wide_rows(cfg.tm)
         with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|[Oo]ut of memory"):
             chunk_step.lower(*args, cfg, learn=True).compile()
     finally:
-        tm_tpu.set_scatter_mode(None)
-        tm_tpu.set_layout_mode(None)
+        jax.clear_caches()
 
 
 def test_serve_group_step_with_reducers_compiles_for_v5e(v5e):
@@ -147,60 +146,3 @@ def test_serve_group_step_with_reducers_compiles_for_v5e(v5e):
     state = init_state(cfg, 0, predict_horizon=8)
     per_stream = sum(np.asarray(v).nbytes for v in state.values())
     assert compiled.memory_analysis().output_size_in_bytes >= G * per_stream
-
-
-def _tm_only(cfg, sharding):
-    from tests.parity.test_tm_parity import TM_KEYS
-
-    st = init_state(cfg, 0)
-    state = _shapes(tm_tpu.to_kernel_layout({k: st[k] for k in TM_KEYS}, cfg.tm),
-                    sharding)
-    active = jax.ShapeDtypeStruct((G, cfg.sp.columns), jnp.bool_,
-                                  sharding=sharding)
-    step = jax.jit(jax.vmap(
-        lambda s, a: tm_tpu.tm_step(s, a, cfg.tm, learn=True)))
-    return step, state, active
-
-
-def test_pallas_megakernel_compiles_under_vmap_at_scaled32(v5e):
-    """RTAP_TM_SCATTER=pallas at the width the v5e compiler accepts: the
-    vmapped learning pass lowers to one Mosaic custom call (the batch
-    becomes a grid axis; one stream's pools are resident at a time)."""
-    tm_tpu.set_scatter_mode("pallas")
-    try:
-        step, state, active = _tm_only(scaled_cluster_preset(32), v5e)
-        assert "tpu_custom_call" in step.lower(state, active).compile().as_text()
-    finally:
-        tm_tpu.set_scatter_mode(None)
-
-
-def test_pallas_megakernel_refuses_cluster_preset_at_trace_time(v5e):
-    """At the default preset the kernel raises a shape-naming error before
-    the compiler is asked (it would charge ~390 MiB of a 128 MiB VMEM) —
-    never a compiler stack from inside a serve tick."""
-    tm_tpu.set_scatter_mode("pallas")
-    try:
-        step, state, active = _tm_only(cluster_preset(), v5e)
-        with pytest.raises(ValueError, match=r"scoped VMEM.*C=256, K=8, S=2, M=12"):
-            step.lower(state, active)
-    finally:
-        tm_tpu.set_scatter_mode(None)
-
-
-def test_pallas_off_tpu_raises_instead_of_interpreting(monkeypatch):
-    """Interpreter mode is what a test asks for by argument
-    (set_scatter_mode("pallas", interpret=True)); with no TPU and no such
-    request the user-set strategy is an error, not a silent fallback."""
-    from tests.parity.test_tm_parity import _init_tm_state
-
-    monkeypatch.setattr(tm_tpu, "FORCE_TPU_PATHS", None)  # the real backend: cpu
-    cfg = scaled_cluster_preset(32).tm
-    C = 32
-    state = tm_tpu.to_kernel_layout(
-        {k: jnp.asarray(v) for k, v in _init_tm_state(C, cfg).items()}, cfg)
-    tm_tpu.set_scatter_mode("pallas")
-    try:
-        with pytest.raises(ValueError, match="compiles for a TPU only"):
-            tm_tpu.tm_step(state, jnp.zeros(C, bool), cfg, learn=True)
-    finally:
-        tm_tpu.set_scatter_mode(None)

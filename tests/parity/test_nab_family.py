@@ -13,8 +13,8 @@ on, 32 cells a column. The cases cross every line the shape draws:
   pool for every column below the i16 range, on both sides alike: every
   winner, and with it every presynaptic id, then lies beyond it;
 - pool rows: K*S*M below `tm_tpu.WIDE_ROW_LANES` takes the narrow-row forms
-  (flat pools, one-hot matmul moves), at or above it the wide-row ones (aos
-  pools, indexed moves);
+  (flat pools, one-hot matmul moves), at or above it the wide-row ones
+  ([C, K, S, M] pools, indexed moves);
 - `FORCE_TPU_PATHS` both ways, so the formulations the chip runs are held to
   the same numbers as the ones the CPU backend picks.
 
@@ -73,8 +73,6 @@ def test_device_path_equals_the_reference(tpu_paths, columns, ids, S, M, wide):
     cfg = family_cfg(columns, S, M)
     assert presyn_dtype(cfg) == ids
     assert tm_tpu.wide_rows(cfg.tm) == wide
-    assert (tm_tpu.layout_mode(cfg.tm), tm_tpu.scatter_mode(cfg.tm)) == (
-        ("aos", "indexed") if wide else ("flat", "matmul"))
 
     group = StreamGroup(cfg, [f"s{i}" for i in range(G)], seed=SEED,
                         backend="tpu")

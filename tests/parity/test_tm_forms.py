@@ -1,0 +1,137 @@
+"""The TM step has two forms and `tm_tpu.wide_rows(cfg)` picks one from the
+static shape: below `WIDE_ROW_LANES` synapse lanes a pool row, one-hot matmul
+moves over flat pools; at or above it, indexed moves over [C, K, S, M] pools.
+Both are held to the numpy oracle here, end to end (encode -> SP -> TM -> raw
+score), bit for bit, in every permanence domain, under both `_compact_ids`
+formulations (`FORCE_TPU_PATHS`: the one the chip runs and the one the CPU
+backend picks), learning and inferring.
+
+Nothing but the shape selects a form: no environment variable, no setter
+(the last two tests)."""
+
+import os
+import subprocess
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+import rtap_tpu.ops.tm_tpu as tm_tpu
+from rtap_tpu.config import (
+    DateConfig, ModelConfig, RDSEConfig, SPConfig, TMConfig, scaled_cluster_preset,
+)
+from rtap_tpu.models.htm_model import HTMModel
+from rtap_tpu.models.state import init_state
+
+from tests.parity.test_e2e_parity import exact_only, make_values
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+STATE_KEYS = ("perm", "boost", "overlap_duty", "active_duty", "presyn", "syn_perm",
+              "seg_last", "active_seg", "matching_seg", "seg_pot", "prev_active",
+              "prev_winner", "tm_iter", "enc_offset")
+
+
+def form_cfg(rows: str, perm_bits: int) -> ModelConfig:
+    """narrow: 256 columns x 8 cells x 4 segments x 16 synapses = 512 lanes a
+    row; wide: 64 columns (small, so the oracle stays fast) x 8 x 8 x 32 =
+    2,048 lanes, the first width on the wide side of the line."""
+    columns, k, S, M = {"narrow": (256, 10, 4, 16), "wide": (64, 6, 8, 32)}[rows]
+    return ModelConfig(
+        rdse=RDSEConfig(size=128, active_bits=11, resolution=0.7),
+        date=DateConfig(time_of_day_width=7, time_of_day_size=18, weekend_width=3),
+        sp=SPConfig(columns=columns, num_active_columns=k, perm_bits=perm_bits),
+        tm=TMConfig(cells_per_column=8, activation_threshold=6, min_threshold=4,
+                    max_segments_per_cell=S, max_synapses_per_segment=M,
+                    new_synapse_count=8, learn_cap=48, perm_bits=perm_bits),
+    )
+
+
+@pytest.fixture(scope="module", params=[True, False], ids=["tpu_paths", "cpu_paths"])
+def tpu_paths(request):
+    old = tm_tpu.FORCE_TPU_PATHS
+    tm_tpu.FORCE_TPU_PATHS = request.param
+    jax.clear_caches()  # the formulation is baked into traced programs
+    yield request.param
+    tm_tpu.FORCE_TPU_PATHS = old
+    jax.clear_caches()
+
+
+@exact_only
+@pytest.mark.parametrize("learn", ["learning", "inferring"])
+@pytest.mark.parametrize("perm_bits", [0, 16, 8])
+@pytest.mark.parametrize("rows", ["narrow", "wide"])
+def test_form_equals_the_oracle_end_to_end(tpu_paths, rows, perm_bits, learn):
+    cfg = form_cfg(rows, perm_bits)
+    assert tm_tpu.wide_rows(cfg.tm) == (rows == "wide")
+    cpu = HTMModel(cfg, seed=17, backend="cpu")
+    dev = HTMModel(cfg, seed=17, backend="tpu")
+    n = 160
+    # "inferring": a learned warm-up, then ticks that must leave the pools alone
+    learn_until = n if learn == "learning" else 110
+    vals = make_values(n, 1, seed=29)
+    for i in range(n):
+        ts, v = 1_700_000_000 + 300 * i, float(vals[i, 0])
+        r_cpu = cpu.run(ts, v, learn=i < learn_until)
+        r_dev = dev.run(ts, v, learn=i < learn_until)
+        assert r_cpu.raw_score == pytest.approx(r_dev.raw_score, abs=0.0), f"step {i}"
+    state = jax.device_get(dev._runner.state)
+    for k in STATE_KEYS:
+        np.testing.assert_array_equal(np.asarray(state[k]), np.asarray(cpu.state[k]), err_msg=k)
+    assert int(state["tm_overflow"]) == 0
+    assert (np.asarray(state["presyn"]) >= 0).sum() > 100  # it really learned
+
+
+_LOWER = """
+import hashlib
+import jax, jax.numpy as jnp, numpy as np
+import rtap_tpu.ops.tm_tpu as tm_tpu
+from rtap_tpu.config import scaled_cluster_preset
+from rtap_tpu.models.state import init_state
+from tests.parity.test_tm_parity import TM_KEYS
+cfg = scaled_cluster_preset(32)
+st = init_state(cfg, 0)
+state = tm_tpu.to_kernel_layout({k: jnp.asarray(st[k]) for k in TM_KEYS}, cfg.tm)
+text = tm_tpu.tm_step.lower(state, jnp.zeros(cfg.sp.columns, bool), cfg.tm, learn=True).as_text()
+print("SHA", hashlib.sha256(text.encode()).hexdigest(), len(text))
+"""
+
+
+def test_no_environment_variable_selects_a_form():
+    """The strategy variables older builds read at import change nothing:
+    tm_step at cluster-32's shape lowers to the same text with all of them
+    set as with none."""
+    clean = {k: v for k, v in os.environ.items() if not k.startswith("RTAP_TM_")}
+    clean["PYTHONPATH"] = REPO
+    loud = dict(clean, RTAP_TM_SCATTER="indexed", RTAP_TM_LAYOUT="aos",
+                RTAP_TM_SWEEP="compact", RTAP_TM_DENDRITE="forward",
+                RTAP_TM_FWD_IMPL="matmul")
+    procs = [subprocess.Popen([sys.executable, "-c", _LOWER], env=env, cwd=REPO,
+                              stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+             for env in (clean, loud)]
+    shas = []
+    for p in procs:
+        out, err = p.communicate(timeout=300)
+        assert p.returncode == 0, err[-2000:]
+        shas.append(next(ln for ln in out.splitlines() if ln.startswith("SHA ")))
+    assert shas[0] == shas[1]
+    assert int(shas[0].split()[2]) > 10_000  # a whole step was lowered
+
+
+@pytest.mark.parametrize("rows", ["narrow", "wide"])
+def test_no_state_carries_a_forward_index(rows):
+    """`init_state` builds none and a learning step adds none, in either
+    form; asking for one is an error, not a silent no-op."""
+    from rtap_tpu.ops.step import fused_step
+
+    cfg = scaled_cluster_preset(32) if rows == "narrow" else form_cfg("wide", 16)
+    assert tm_tpu.wide_rows(cfg.tm) == (rows == "wide")
+    state = init_state(cfg, 3)
+    assert not [k for k in state if k.startswith("fwd_")]
+    assert init_state(cfg, 3, include_fwd=False).keys() == state.keys()
+    with pytest.raises(ValueError, match="include_fwd"):
+        init_state(cfg, 3, include_fwd=True)
+    stepped, _ = fused_step(jax.device_put(state), np.float32([41.5]),
+                            np.int32(1_700_000_000), cfg, True)
+    assert stepped.keys() == state.keys()

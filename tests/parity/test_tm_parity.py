@@ -11,12 +11,14 @@ weakest-synapse eviction.
 
 import copy
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from rtap_tpu.config import TMConfig
 from rtap_tpu.models.oracle.temporal_memory import TMOracle
+from rtap_tpu.ops import tm_tpu
 from rtap_tpu.ops.tm_tpu import from_kernel_layout, tm_step, to_kernel_layout
 
 TM_KEYS = (
@@ -51,11 +53,23 @@ def _assert_state_equal(host, dev, step):
         )
 
 
+@pytest.fixture(params=["narrow", "wide"])
+def rows(request, monkeypatch):
+    """Every scenario in both forms of the step at ITS OWN shape (the pools
+    must fill for the eviction branches, which a shape wide by itself never
+    does here): the line between the forms is moved under the shape, and the
+    caches cleared because the form is read at trace time."""
+    monkeypatch.setattr(tm_tpu, "WIDE_ROW_LANES",
+                        1 if request.param == "wide" else 1 << 30)
+    jax.clear_caches()
+    yield request.param
+    jax.clear_caches()
+
+
 def _run_parity(C, cfg, sequences, learn=True):
     host = _init_tm_state(C, cfg)
-    # the kernel runs whatever layout is the process default (flat since the
-    # r4 silicon A/B); the public [C, K, S, M] layout crosses the boundary
-    # via the same reshape adapters ops/step.py uses
+    # the public [C, K, S, M] layout crosses the boundary via the same
+    # reshape adapters ops/step.py uses
     dev = to_kernel_layout(
         {k: jnp.asarray(v) for k, v in copy.deepcopy(host).items()}, cfg)
     oracle = TMOracle(host, cfg)
@@ -74,7 +88,7 @@ def _pattern(rng, C, n_active):
 
 @pytest.mark.quick
 @pytest.mark.parametrize("learn", [True, False])
-def test_tm_parity_repeating_sequence(learn):
+def test_tm_parity_repeating_sequence(learn, rows):
     """A-B-C-D repeated: drives prediction, reinforcement, growth."""
     C, cfg = 64, TMConfig(
         cells_per_column=8, activation_threshold=3, min_threshold=2,
@@ -87,7 +101,7 @@ def test_tm_parity_repeating_sequence(learn):
     _run_parity(C, cfg, seq, learn=learn)
 
 
-def test_tm_parity_ambiguous_sequences():
+def test_tm_parity_ambiguous_sequences(rows):
     """A-B-C-D vs A-B-C-E (shared prefix) -> multiple predicted cells per
     column, multi-segment learning in predicted columns."""
     C, cfg = 64, TMConfig(
@@ -101,7 +115,7 @@ def test_tm_parity_ambiguous_sequences():
     _run_parity(C, cfg, seq)
 
 
-def test_tm_parity_random_stream_with_eviction():
+def test_tm_parity_random_stream_with_eviction(rows):
     """Random novelty: constant bursting + allocation until pools fill and
     LRU segment eviction + weakest-synapse eviction kick in."""
     C, cfg = 32, TMConfig(
@@ -115,31 +129,24 @@ def test_tm_parity_random_stream_with_eviction():
 
 
 @pytest.mark.quick
-@pytest.mark.parametrize("layout", ["aos", "flat"])
-def test_tm_parity_explicit_layouts(layout):
-    """Full state parity under BOTH kernel layouts, explicitly pinned.
-
-    The other tests run the process default (flat since the r4 silicon
-    A/B); aos is still shipped and raced as the hardware reference rung
-    (bench.py ladder), so a full-state regression in the aos path must
-    not ride on the classifier test's raw-score check alone."""
-    from rtap_tpu.ops import tm_tpu
-
+@pytest.mark.parametrize("S,M,wide", [(2, 6, False), (16, 32, True)],
+                         ids=["narrow", "wide"])
+def test_tm_parity_explicit_layouts(S, M, wide):
+    """Full state parity in BOTH forms where the shape itself picks the form
+    (the other tests move the line under one shape): 48 lanes a row, and
+    2,048."""
     C, cfg = 32, TMConfig(
         cells_per_column=4, activation_threshold=2, min_threshold=1,
-        max_segments_per_cell=2, max_synapses_per_segment=6,
+        max_segments_per_cell=S, max_synapses_per_segment=M,
         new_synapse_count=4, learn_cap=32,
     )
+    assert tm_tpu.wide_rows(cfg) == wide
     rng = np.random.default_rng(29)
     seq = [_pattern(rng, C, 4) for _ in range(60)]
-    tm_tpu.set_layout_mode(layout)
-    try:
-        _run_parity(C, cfg, seq)
-    finally:
-        tm_tpu.set_layout_mode(None)
+    _run_parity(C, cfg, seq)
 
 
-def test_tm_parity_punishment_path():
+def test_tm_parity_punishment_path(rows):
     """Alternating similar patterns so matching segments form in columns that
     then fail to activate -> predicted_segment_decrement punishment."""
     C, cfg = 48, TMConfig(
@@ -156,7 +163,7 @@ def test_tm_parity_punishment_path():
     _run_parity(C, cfg, seq)
 
 
-def test_tm_parity_empty_and_full_columns():
+def test_tm_parity_empty_and_full_columns(rows):
     """Edge cases: empty active set (raw=0) and all-columns-active steps."""
     C, cfg = 16, TMConfig(
         cells_per_column=4, activation_threshold=2, min_threshold=1,

@@ -1,9 +1,10 @@
-"""The TM kernel has two strategies for lookup/compaction ops (gather/nonzero
-vs the TPU reformulations — ops/tm_tpu.py FORCE_TPU_PATHS). The default test
-platform is CPU, which exercises the gather path; this file forces the TPU
-formulations and asserts bit-identical behavior against the oracle, so the
-code that actually runs on hardware is pinned by the same parity suite
-(SURVEY.md §4 item 2)."""
+"""The TM kernel has two formulations of `_compact_ids` (nonzero vs the TPU's
+top_k — ops/tm_tpu.py FORCE_TPU_PATHS). The default test platform is CPU,
+which exercises nonzero; this file forces the TPU formulation and asserts
+bit-identical behavior against the oracle, so the code that actually runs on
+hardware is pinned by the same parity suite (SURVEY.md §4 item 2). Both
+formulations across both forms of the step, every permanence domain:
+tests/parity/test_tm_forms.py."""
 
 import numpy as np
 import pytest
@@ -31,93 +32,6 @@ def test_e2e_parity_with_tpu_paths(force_tpu_paths):
     cpu = HTMModel(cfg, seed=3, backend="cpu")
     tpu = HTMModel(cfg, seed=3, backend="tpu")
     vals = make_values(300, 1)
-    for i in range(300):
-        r_cpu = cpu.run(1_700_000_000 + 300 * i, float(vals[i, 0]))
-        r_tpu = tpu.run(1_700_000_000 + 300 * i, float(vals[i, 0]))
-        assert r_cpu.raw_score == pytest.approx(r_tpu.raw_score, abs=0.0), f"step {i}"
-
-
-@pytest.fixture
-def indexed_scatter():
-    tm_tpu.set_scatter_mode("indexed")
-    yield
-    tm_tpu.set_scatter_mode(None)
-
-
-@pytest.fixture
-def flat_layout():
-    tm_tpu.set_layout_mode("flat")
-    yield
-    tm_tpu.set_layout_mode(None)
-
-
-@exact_only
-@pytest.mark.parametrize("perm_bits", [0, 16])
-def test_e2e_parity_with_flat_layout(flat_layout, perm_bits):
-    """RTAP_TM_LAYOUT=flat (pools carried [C, K*S*M], segment tensors
-    [C, K*S], per-segment counts via block-diagonal matmuls) is a pure
-    layout change: bit-identical to the 4-D kernel in both permanence
-    domains."""
-    from tests.parity.test_quantized_parity import quant_cfg
-
-    cfg = small_cfg() if perm_bits == 0 else quant_cfg(perm_bits)
-    cpu = HTMModel(cfg, seed=5, backend="cpu")
-    tpu = HTMModel(cfg, seed=5, backend="tpu")
-    vals = make_values(300, 1)
-    for i in range(300):
-        r_cpu = cpu.run(1_700_000_000 + 300 * i, float(vals[i, 0]))
-        r_tpu = tpu.run(1_700_000_000 + 300 * i, float(vals[i, 0]))
-        assert r_cpu.raw_score == pytest.approx(r_tpu.raw_score, abs=0.0), f"step {i}"
-
-
-@pytest.mark.quick
-@exact_only
-@pytest.mark.parametrize("perm_bits", [0, 16])
-def test_e2e_parity_flat_layout_all_tpu_paths(
-    force_tpu_paths, flat_layout, indexed_scatter, perm_bits
-):
-    """The full hardware candidate: flat layout + indexed workspace movement
-    + TPU compact-ids paths, all at once, in both permanence domains."""
-    from tests.parity.test_quantized_parity import quant_cfg
-
-    cfg = small_cfg() if perm_bits == 0 else quant_cfg(perm_bits)
-    cpu = HTMModel(cfg, seed=13, backend="cpu")
-    tpu = HTMModel(cfg, seed=13, backend="tpu")
-    vals = make_values(300, 1, seed=21)
-    for i in range(300):
-        r_cpu = cpu.run(1_700_000_000 + 300 * i, float(vals[i, 0]))
-        r_tpu = tpu.run(1_700_000_000 + 300 * i, float(vals[i, 0]))
-        assert r_cpu.raw_score == pytest.approx(r_tpu.raw_score, abs=0.0), f"step {i}"
-
-
-@exact_only
-@pytest.mark.parametrize("perm_bits", [0, 16])
-def test_e2e_parity_with_indexed_scatter(indexed_scatter, perm_bits):
-    """The indexed (take / .at[].set) workspace-movement strategy must be
-    bit-identical to the one-hot-matmul strategy — the SCATTER_MODE switch
-    is a pure layout/bandwidth experiment (ops/tm_tpu.py). Covered in both
-    the f32 and the u16 fixed-point permanence domains (the quantized branch
-    has its own round/astype epilogue)."""
-    from tests.parity.test_quantized_parity import quant_cfg
-
-    cfg = small_cfg() if perm_bits == 0 else quant_cfg(perm_bits)
-    cpu = HTMModel(cfg, seed=3, backend="cpu")
-    tpu = HTMModel(cfg, seed=3, backend="tpu")
-    vals = make_values(300, 1)
-    for i in range(300):
-        r_cpu = cpu.run(1_700_000_000 + 300 * i, float(vals[i, 0]))
-        r_tpu = tpu.run(1_700_000_000 + 300 * i, float(vals[i, 0]))
-        assert r_cpu.raw_score == pytest.approx(r_tpu.raw_score, abs=0.0), f"step {i}"
-
-
-@exact_only
-def test_e2e_parity_indexed_scatter_with_tpu_paths(force_tpu_paths, indexed_scatter):
-    """Both strategy switches together = the exact program a hardware run
-    with RTAP_TM_SCATTER=indexed would trace."""
-    cfg = small_cfg()
-    cpu = HTMModel(cfg, seed=9, backend="cpu")
-    tpu = HTMModel(cfg, seed=9, backend="tpu")
-    vals = make_values(300, 1, seed=11)
     for i in range(300):
         r_cpu = cpu.run(1_700_000_000 + 300 * i, float(vals[i, 0]))
         r_tpu = tpu.run(1_700_000_000 + 300 * i, float(vals[i, 0]))

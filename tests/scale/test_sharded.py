@@ -93,23 +93,20 @@ def test_hot_loop_is_collective_free():
 
 
 def test_sharded_matches_single_device_flat_layout():
-    """The flat kernel layout's chunk-boundary adapters run INSIDE shard_map
-    (per-shard reshapes) — sharded flat must equal single-device default."""
+    """The narrow-row form's chunk-boundary adapters (flat pools) run INSIDE
+    shard_map (per-shard reshapes) — sharded must equal single-device."""
     import rtap_tpu.ops.tm_tpu as tm_tpu
 
     cfg = cluster_preset()
+    assert not tm_tpu.wide_rows(cfg.tm)
     G, T = 16, 24
     ids = [f"f{i}" for i in range(G)]
     vals = _vals(T, G)
     ts = (1_700_000_000 + np.arange(T)[:, None] + np.zeros((1, G))).astype(np.int64)
     plain = StreamGroup(cfg, ids, backend="tpu")
     r_p, ll_p, _ = plain.run_chunk(vals, ts)
-    tm_tpu.set_layout_mode("flat")
-    try:
-        sharded = StreamGroup(cfg, ids, backend="tpu", mesh=make_stream_mesh(8))
-        r_s, ll_s, _ = sharded.run_chunk(vals, ts)
-    finally:
-        tm_tpu.set_layout_mode(None)
+    sharded = StreamGroup(cfg, ids, backend="tpu", mesh=make_stream_mesh(8))
+    r_s, ll_s, _ = sharded.run_chunk(vals, ts)
     np.testing.assert_array_equal(r_p, r_s)
     np.testing.assert_array_equal(ll_p, ll_s)
 

@@ -19,6 +19,7 @@ from rtap_tpu.analysis.core import (
     Finding,
     SourceFile,
 )
+from rtap_tpu.analysis.prints import MUST_BE_STRICT
 
 pytestmark = pytest.mark.quick
 
@@ -36,9 +37,7 @@ def lint(path, code, rules=None, docs="", extra=(), baseline=None):
 
 #: stubs for the MUST_BE_STRICT pin so full (rules=None) fixture runs
 #: don't trip strict-coverage on the synthetic context
-PIN_STUBS = tuple((p, "x = 1\n") for p in (
-    "rtap_tpu/obs/latency.py", "rtap_tpu/obs/slo.py",
-    "rtap_tpu/obs/metrics.py", "rtap_tpu/service/loop.py"))
+PIN_STUBS = tuple((p, "x = 1\n") for p in MUST_BE_STRICT)
 
 
 def rules_of(report):
@@ -354,7 +353,7 @@ def test_strict_coverage_pin():
     # a context missing the pinned modules reports each as out of
     # coverage — the rename/move tripwire
     r = lint("rtap_tpu/eval/_fx.py", "x = 1\n", ["strict-coverage"])
-    assert len(r.findings) == 4
+    assert len(r.findings) == len(MUST_BE_STRICT)
     assert all(f.rule == "strict-coverage" for f in r.findings)
 
 

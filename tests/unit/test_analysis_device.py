@@ -14,6 +14,7 @@ import pytest
 
 from rtap_tpu.analysis import run_analysis
 from rtap_tpu.analysis.core import AnalysisContext, Baseline, SourceFile
+from rtap_tpu.analysis.prints import MUST_BE_STRICT
 
 pytestmark = pytest.mark.quick
 
@@ -458,8 +459,7 @@ def _mini_repo(tmp_path, module="mod.py", code=BAD_CODE):
     """A throwaway tree run_analysis can discover: one violating serve
     module plus the strict-coverage pin stubs."""
     root = tmp_path / "repo"
-    for stub in ("rtap_tpu/obs/latency.py", "rtap_tpu/obs/slo.py",
-                 "rtap_tpu/obs/metrics.py", "rtap_tpu/service/loop.py"):
+    for stub in MUST_BE_STRICT:
         p = root / stub
         p.parent.mkdir(parents=True, exist_ok=True)
         p.write_text("x = 1\n")

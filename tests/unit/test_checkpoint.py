@@ -41,6 +41,36 @@ def test_group_checkpoint_roundtrip(backend, tmp_path):
         np.testing.assert_array_equal(r_ref.alerts, r_res.alerts)
 
 
+def test_loaders_drop_a_forward_index_an_older_build_stored(tmp_path):
+    """Builds before PR 29 could carry fwd_* leaves (a forward synapse index)
+    in a state; a stored tree that has them loads without them, as a group
+    and as a single model, and steps."""
+    import jax.numpy as jnp
+
+    from rtap_tpu.models.htm_model import HTMModel
+
+    cfg = cluster_preset()
+    grp = StreamGroup(cfg, ["a", "b"], backend="tpu")
+    grp.tick(np.float32([41.0, 43.0]), 1_700_000_000)
+    grp.state = {**grp.state, "fwd_of": jnp.zeros(2, jnp.int32),
+                 "fwd_slots": jnp.full((2, cfg.num_cells, 4), -1, jnp.int32)}
+    save_group(grp, tmp_path / "grp0")
+    back = load_group(tmp_path / "grp0")
+    assert not [k for k in back.state if k.startswith("fwd_")]
+    back.tick(np.float32([42.0, 44.0]), 1_700_000_001)
+
+    m = HTMModel(cfg, seed=4, backend="cpu")
+    m.run(1_700_000_000, 41.0)
+    m.save(str(tmp_path / "m.npz"))
+    with np.load(tmp_path / "m.npz") as z:
+        stored = {k: z[k] for k in z.files}
+    np.savez(tmp_path / "old.npz", **stored, s_fwd_of=np.int32(0),
+             s_fwd_slots=np.full((cfg.num_cells, 4), -1, np.int32))
+    old = HTMModel.load(str(tmp_path / "old.npz"), backend="tpu")
+    assert not [k for k in old._runner.state if k.startswith("fwd_")]
+    old.run(1_700_000_001, 42.0)
+
+
 def test_checkpoint_preserves_config_and_threshold(tmp_path):
     cfg = cluster_preset()
     grp = StreamGroup(cfg, ["a", "b"], backend="cpu", threshold=0.37)
@@ -140,28 +170,6 @@ class TestDenseToSparseMigration:
             np.testing.assert_array_equal(r.raw, exp["raw"][j], err_msg=f"tick {j}")
             np.testing.assert_array_equal(
                 r.log_likelihood, exp["log_likelihood"][j], err_msg=f"tick {j}")
-
-    def test_sparsify_rebuilds_fwd_index_from_migrated_state(self):
-        from functools import partial
-
-        import jax
-
-        from rtap_tpu.ops.fwd_index import build_fwd_index
-        from rtap_tpu.ops.tm_tpu import set_dendrite_mode
-
-        ckpt, _ = self._fixture()
-        set_dendrite_mode("forward")
-        try:
-            grp = load_group(ckpt, sparsify=True)
-            assert {"fwd_slots", "fwd_pos", "fwd_of"} <= set(grp.state)
-            slots, pos, of = jax.vmap(partial(
-                build_fwd_index, n_cells=grp.cfg.num_cells,
-                fanout_cap=grp.cfg.tm.fanout_cap,
-            ))(np.asarray(grp.state["presyn"]))
-            np.testing.assert_array_equal(np.asarray(grp.state["fwd_slots"]), slots)
-            np.testing.assert_array_equal(np.asarray(grp.state["fwd_pos"]), pos)
-        finally:
-            set_dendrite_mode(None)
 
     def test_sparsify_noop_on_already_sparse_checkpoint(self, tmp_path):
         cfg = cluster_preset()  # sparse layout since ISSUE 18

@@ -42,17 +42,21 @@ def _periodic_values(n, period=6, unique=False):
     return np.tile(cycle, n // period + 1)[:n]
 
 
-@pytest.mark.parametrize("layout", ["aos", "flat"])
-def test_classifier_parity_cpu_vs_device(layout):
+@pytest.mark.parametrize("rows", ["wide", "narrow"])
+def test_classifier_parity_cpu_vs_device(rows, monkeypatch):
     """Same records through the numpy oracle and the jitted device kernel:
     predictions agree to float tolerance (softmax exp may differ by ulps).
-    Covered under both kernel layouts — the classifier consumes TM cell
+    Covered in both forms of the TM step — the classifier consumes TM cell
     state (prev_active), which the flat adapters must hand over unchanged."""
+    import jax
+
     import rtap_tpu.ops.tm_tpu as tm_tpu
 
     cfg = _cfg()
     cpu = HTMModel(cfg, seed=1, backend="cpu")
-    tm_tpu.set_layout_mode(layout)
+    # the line between the forms, moved under this shape (read at trace time)
+    monkeypatch.setattr(tm_tpu, "WIDE_ROW_LANES", 1 if rows == "wide" else 1 << 30)
+    jax.clear_caches()
     try:
         dev = HTMModel(cfg, seed=1, backend="tpu")
         vals = _periodic_values(200)
@@ -63,7 +67,7 @@ def test_classifier_parity_cpu_vs_device(layout):
             assert rc.prediction == pytest.approx(rd.prediction, rel=1e-4, abs=1e-4), f"step {i}"
             assert rc.prediction_prob == pytest.approx(rd.prediction_prob, rel=1e-3, abs=1e-5), f"step {i}"
     finally:
-        tm_tpu.set_layout_mode(None)
+        jax.clear_caches()
 
 
 def _prediction_maes(vals, train=400):

@@ -46,26 +46,22 @@ def test_compiled_chunk_step_carries_the_scopes(learn):
 
 @pytest.mark.parametrize("entry", ["group_step", "fused_step", "chunk_step"])
 def test_layout_adapters_are_scoped(entry):
-    # the adapters reshape under the flat kernel layout (the default)
+    # the adapters reshape where the pools run flat: narrow pool rows
     cfg = scaled_cluster_preset(32)
-    tm_tpu.set_layout_mode("flat")
-    try:
-        if entry == "fused_step":
-            single = {k: jnp.asarray(v) for k, v in init_state(cfg, 0).items()}
-            low = fused_step.lower(single, jnp.zeros((1,), jnp.float32),
-                                   jnp.int32(0), cfg, learn=False)
-        elif entry == "group_step":
-            low = group_step.lower(
-                _group_state(cfg), jnp.zeros((G, 1), jnp.float32),
-                jnp.zeros((G,), jnp.int32), cfg, learn=False)
-        else:
-            low = chunk_step.lower(
-                _group_state(cfg), jnp.zeros((T, G, 1), jnp.float32),
-                jnp.zeros((T, G), jnp.int32), cfg, learn=False)
-        text = low.as_text(debug_info=True)
-    finally:
-        tm_tpu.set_layout_mode(None)
-    assert "rtap.layout/reshape" in text
+    assert not tm_tpu.wide_rows(cfg.tm)
+    if entry == "fused_step":
+        single = {k: jnp.asarray(v) for k, v in init_state(cfg, 0).items()}
+        low = fused_step.lower(single, jnp.zeros((1,), jnp.float32),
+                               jnp.int32(0), cfg, learn=False)
+    elif entry == "group_step":
+        low = group_step.lower(
+            _group_state(cfg), jnp.zeros((G, 1), jnp.float32),
+            jnp.zeros((G,), jnp.int32), cfg, learn=False)
+    else:
+        low = chunk_step.lower(
+            _group_state(cfg), jnp.zeros((T, G, 1), jnp.float32),
+            jnp.zeros((T, G), jnp.int32), cfg, learn=False)
+    assert "rtap.layout/reshape" in low.as_text(debug_info=True)
 
 
 def test_optional_reducers_are_scoped():
