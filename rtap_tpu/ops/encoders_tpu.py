@@ -3,11 +3,13 @@
 Twin of models/oracle/encoders.py (SURVEY.md C1/C2). The RDSE is a pure hash
 function (bucket b -> bits {hash(seed, b+k) % n}), so encoding runs on device
 with no host-side bucket table: one record is (values[F] f32, ts i32) and the
-output is a bool[input_size] SDR built by scatter. All arithmetic is f32/int32
-and bit-identical to the host oracle (tests/parity/test_encoder_parity.py).
+output is a bool[input_size] SDR, each bit index compared against the input
+iota (:func:`_bits_at`). All arithmetic is f32/int32 and bit-identical to the
+host oracle (tests/parity/test_encoder_parity.py).
 
 NaN/inf field values contribute no bits (NuPIC missing-sample behavior),
-implemented branch-free via out-of-bounds scatter indices with mode="drop".
+implemented branch-free: their indices point at input_size, which equals no
+position of the iota.
 """
 
 from __future__ import annotations
@@ -21,6 +23,15 @@ SECONDS_PER_DAY = 86400
 _EPOCH_WEEKDAY_SHIFT = 3  # 1970-01-01 was a Thursday; weekday = (days+3) % 7
 
 
+def _bits_at(idx: jnp.ndarray, n_in: int) -> jnp.ndarray:
+    """bool[n_in] with a True at every position named in `idx` (any shape,
+    i32). An index of n_in or more names no position (a missing field, a
+    weekday), and an index named twice (colliding RDSE bits) is an OR. A
+    compare grid [len(idx), n_in] folded by `any`, not `.at[idx].set(True)`:
+    the element-wise scatter costs 5-7 ns an index on a v5e (PERF.md s6)."""
+    return (idx.reshape(-1)[:, None] == jnp.arange(n_in, dtype=jnp.int32)).any(0)
+
+
 def _composite_indices(
     cfg: ModelConfig,
     values: jnp.ndarray,  # [F] f32
@@ -28,8 +39,8 @@ def _composite_indices(
     enc_resolution: jnp.ndarray,  # [F] f32
     enc_prev: jnp.ndarray | None,  # [F] f32 (delta predecessor), or None
 ) -> jnp.ndarray:
-    """Composite-family scatter indices (ISSUE 9), flattened across fields;
-    missing samples point at n_in (dropped). Static python loop over the
+    """Composite-family bit indices (ISSUE 9), flattened across fields;
+    missing samples point at n_in (no bit). Static python loop over the
     FieldSpec table — F is small and the per-field geometry (size, kind,
     seed, offset) is config-static, so this traces to straight-line code.
     Twin of the oracle's _composite_field_bits, bit-exact per field."""
@@ -97,7 +108,7 @@ def encode_device(
             enc_resolution = jnp.asarray(cfg.field_resolutions(), jnp.float32)
         idx = _composite_indices(cfg, values, enc_offset, enc_resolution,
                                  enc_prev)
-        sdr = jnp.zeros(n_in, bool).at[idx].set(True, mode="drop")
+        sdr = _bits_at(idx, n_in)
         base = cfg.composite.size
         if cfg.date.time_of_day_width:
             center = (ts_unix % SECONDS_PER_DAY) * cfg.date.time_of_day_size \
@@ -107,7 +118,7 @@ def encode_device(
                 + jnp.arange(cfg.date.time_of_day_width, dtype=jnp.int32)
                 - cfg.date.time_of_day_width // 2
             ) % cfg.date.time_of_day_size
-            sdr = sdr.at[base + tod].set(True)
+            sdr = sdr | _bits_at(base + tod, n_in)
             base += cfg.date.time_of_day_size
         if cfg.date.weekend_width:
             weekend = ((ts_unix // SECONDS_PER_DAY + _EPOCH_WEEKDAY_SHIFT)
@@ -116,7 +127,7 @@ def encode_device(
                 weekend,
                 base + jnp.arange(cfg.date.weekend_width, dtype=jnp.int32),
                 n_in)
-            sdr = sdr.at[widx].set(True, mode="drop")
+            sdr = sdr | _bits_at(widx, n_in)
         return sdr
     R = cfg.field_size
     finite = jnp.isfinite(values)
@@ -145,9 +156,9 @@ def encode_device(
         seeds = jnp.uint32(cfg.rdse.seed) + jnp.uint32(0x1000) * jnp.arange(F, dtype=jnp.uint32)
         bits = hash_bits(keys, seeds[:, None], R)  # [F, w]
     idx = bits + (jnp.arange(F, dtype=jnp.int32) * R)[:, None]
-    idx = jnp.where(finite[:, None], idx, n_in)  # missing field -> dropped scatter
+    idx = jnp.where(finite[:, None], idx, n_in)  # missing field -> no bit
 
-    sdr = jnp.zeros(n_in, bool).at[idx.reshape(-1)].set(True, mode="drop")
+    sdr = _bits_at(idx, n_in)
 
     base = F * R
     if cfg.date.time_of_day_width:
@@ -158,12 +169,12 @@ def encode_device(
             + jnp.arange(cfg.date.time_of_day_width, dtype=jnp.int32)
             - cfg.date.time_of_day_width // 2
         ) % cfg.date.time_of_day_size
-        sdr = sdr.at[base + tod].set(True)
+        sdr = sdr | _bits_at(base + tod, n_in)
         base += cfg.date.time_of_day_size
     if cfg.date.weekend_width:
         weekend = ((ts_unix // SECONDS_PER_DAY + _EPOCH_WEEKDAY_SHIFT) % 7) >= 5
         widx = jnp.where(weekend, base + jnp.arange(cfg.date.weekend_width, dtype=jnp.int32), n_in)
-        sdr = sdr.at[widx].set(True, mode="drop")
+        sdr = sdr | _bits_at(widx, n_in)
     return sdr
 
 

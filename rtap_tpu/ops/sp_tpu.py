@@ -83,6 +83,13 @@ def sp_inhibit(overlap: jnp.ndarray, boost: jnp.ndarray, cfg: SPConfig) -> jnp.n
     boost_strength == 0 (the NAB preset). Under boosting, a 1-ulp host/device
     exp() difference on an exact .5 rounding boundary of q can still flip a
     winner — statistically negligible, and tolerated by the boost parity test.
+
+    The winner mask is `score >= (the least of the k largest scores)`:
+    the scores are distinct, so exactly the k columns top_k picked pass. That
+    one [C] compare stands where the k winner indices were written into a
+    zero mask (an element-wise scatter, 5-7 ns an update on a v5e; PERF.md
+    s6 PR 31) and is cheaper than the [k, C] index compare, since top_k's
+    values are there anyway and its indices are no longer needed.
     """
     C = overlap.shape[0]
     col_rev = (C - 1 - jnp.arange(C, dtype=jnp.int32))
@@ -107,9 +114,8 @@ def sp_inhibit(overlap: jnp.ndarray, boost: jnp.ndarray, cfg: SPConfig) -> jnp.n
         score = q * C + col_rev
     else:
         score = overlap * C + col_rev
-    _, winners = jax.lax.top_k(score, cfg.num_active_columns)
-    active = jnp.zeros(C, bool).at[winners].set(True, unique_indices=True)
-    return active & (overlap >= cfg.stimulus_threshold)
+    kth = jax.lax.top_k(score, cfg.num_active_columns)[0].min()
+    return (score >= kth) & (overlap >= cfg.stimulus_threshold)
 
 
 def sp_learn(

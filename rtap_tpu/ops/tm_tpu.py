@@ -1,5 +1,12 @@
 """Temporal Memory — device kernel (functional twin of oracle/temporal_memory.py).
 
+One rule for every kernel of the step: index lists become masks by compare
+(against an iota, fused into whatever reads the mask), never by
+`.at[ids].set`, and small tables are read through such a grid too, never by
+`table[ids]`, because an element-wise gather or scatter costs 5-10 ns an
+element on a v5e (PRs 26, 28, 31; PERF.md s6). Only whole pool rows move by
+index (the wide-row form, `rtap.tm.learn.rows`).
+
 The reference's TM is Cells4.cpp/TemporalMemory.cpp over the Connections
 pointer graph (SURVEY.md C4/C5). TPU-native re-design (SURVEY.md §7 hard part
 1): fixed-capacity dense pools [C, K, S, M] of (presyn id, permanence), and a
@@ -231,9 +238,12 @@ def _segment_learning_mask(
 ):
     """Categorize columns and pick the per-column learning segments.
 
-    Returns (predicted_cols, learn_mask, alloc [C,3] (col, cell, slot) for
-    burst-new allocations with col==C when inactive, winner_cells_extra
-    [C, K] winner contributions from burst columns).
+    Returns (predicted_cols, learn_mask [C, K, S], alloc = (col [C] with C
+    where the column allocates nothing, cell [C], slot [C]) for burst-new
+    allocations, winner_cells_extra [C, K] winner contributions from burst
+    columns, burst [C]). Every per-column choice (best matching segment,
+    least-used cell) enters its mask as a compare against an iota; nothing
+    is written by index.
     """
     C, K, S = active_seg.shape
     prev_predictive = active_seg.any(-1)  # [C, K]
@@ -247,15 +257,17 @@ def _segment_learning_mask(
     # (a) predicted columns: every active segment of every predicted cell learns
     mask_pred = active_cols[:, None, None] & active_seg
 
-    # (b) burst-matching: best matching segment (max seg_pot, lowest flat index)
+    # (b) burst-matching: best matching segment (max seg_pot, lowest flat
+    # index). One bit a column, placed by comparing the flat (k, s) iota with
+    # best_flat — a column that does not burst-match sets none (its argmax
+    # over all -1 is 0, which the burst_match factor masks).
     pot = jnp.where(matching_seg, seg_pot, -1).reshape(C, K * S)
     best_flat = jnp.argmax(pot, axis=-1)  # first max — same as np.argmax
-    bm_k, bm_s = best_flat // S, best_flat % S
+    bm_k = best_flat // S
     bm_mask = (
-        jnp.zeros((C, K, S), bool)
-        .at[jnp.arange(C), bm_k, bm_s]
-        .set(burst_match)
-    )
+        burst_match[:, None]
+        & (jnp.arange(K * S, dtype=best_flat.dtype)[None, :] == best_flat[:, None])
+    ).reshape(C, K, S)
 
     # (c) burst-new: cell with fewest segments; first free slot else LRU slot
     seg_counts = (seg_last >= 0).sum(-1)  # [C, K]
@@ -269,8 +281,8 @@ def _segment_learning_mask(
     lru = jnp.argmin(row_last, axis=-1)
     bn_s = jnp.where(any_free, first_free, lru)
 
-    # burst-column winner cells, one-hot (no scatter: a False write from one
-    # branch must never clobber a True from the other)
+    # burst-column winner cells, one compare a branch (the branches are
+    # disjoint by column: burst_match has a matching segment, the other none)
     kk = jnp.arange(K, dtype=jnp.int32)[None, :]
     winner_extra = (burst_match[:, None] & (kk == bm_k[:, None])) | (
         (burst & ~col_matching)[:, None] & (kk == bn_k[:, None])  # winner even when no alloc
@@ -597,16 +609,20 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
             presyn_l = jnp.where(grow_ok[:, None], grown_presyn, presyn_l)
             perm_l = jnp.where(grow_ok[:, None], grown_perm, perm_l)
 
-            last_l = jnp.full((L,), 1, jnp.int32) * it  # [L] seg_last of learned rows
-
             # --- scatter learned rows back into the workspace ---
             if wide:
                 ws_presyn_r = ws_presyn_r.at[idx].set(presyn_l, mode="drop")
                 ws_perm_r = ws_perm_r.at[idx].set(perm_l, mode="drop")
-                ws_last = (
-                    ws_last.reshape(R2).at[idx].set(last_l, mode="drop").reshape(Ac, K, S)
-                )
+                # the learned rows' stamp is one value, so the rows in idx are
+                # named by compare, not by index: idx holds the first L set
+                # entries of ws_learn ascending (fills R2), i.e. every set
+                # entry up to its largest
+                learned = ws_learn & (
+                    jnp.arange(R2, dtype=jnp.int32) <= idx.max()
+                ).reshape(Ac, K, S)
+                ws_last = jnp.where(learned, it, ws_last)
             else:
+                last_l = jnp.full((L,), 1, jnp.int32) * it  # [L] seg_last of learned rows
                 hit_rows = row_oh_b.any(0)  # [R2]
                 # presyn + perm scatter back in ONE transposed one-hot MXU pass
                 scat = jax.lax.dot(

@@ -49,8 +49,13 @@ def v5e():
     compilation_cache.reset_cache()
     paths_was = tm_tpu.FORCE_TPU_PATHS
     tm_tpu.FORCE_TPU_PATHS = True
+    # the formulation is baked into traced programs: a `tm_step` that an
+    # earlier test file of this worker traced in the backend's forms (the
+    # CPU's `nonzero`, a scatter) must not serve these compiles
+    jax.clear_caches()
     yield SingleDeviceSharding(topo.devices[0])
     tm_tpu.FORCE_TPU_PATHS = paths_was
+    jax.clear_caches()
     jax.config.update("jax_enable_compilation_cache", cache_was)
     compilation_cache.reset_cache()
 
@@ -96,6 +101,39 @@ def test_cluster_chunk_step_holds_no_gather_at_the_cells_batch(v5e, preset):
     compiled = chunk_step.lower(*_step_args(cfg, v5e, T=2, g=1024), cfg,
                                 learn=True).compile()
     assert not re.findall(r"= \S+ gather\(", compiled.as_text())
+
+
+@pytest.mark.parametrize("program", ["chunk_step", "group_step"])
+@pytest.mark.parametrize("preset", ["cluster", "scaled32"])
+def test_cluster_step_holds_no_scatter_at_the_cells_batch(v5e, preset, program):
+    """Nor a scatter, in the replay cells' program or the live cell's: the
+    three index-list -> mask writes (TM best matching segment, SP winners,
+    encoder bits) were the largest single op of both cluster ticks, unscoped,
+    at 5.4-6.8 ns an update (ISSUE 31; PERF.md §6). Masks are compares."""
+    import rtap_tpu.ops.step as step
+
+    cfg = cluster_preset() if preset == "cluster" else scaled_cluster_preset(32)
+    T = 2 if program == "chunk_step" else None
+    compiled = getattr(step, program).lower(
+        *_step_args(cfg, v5e, T=T, g=1024), cfg, learn=True).compile()
+    assert " scatter(" not in compiled.as_text()
+
+
+def test_nab_width_step_scatters_whole_rows_only(v5e):
+    """At the NAB width the lowered step keeps its scatters — the learning
+    workspace's rows moved by index — and each of them moves a window (a
+    whole row), none a single element: every `stablehlo.scatter` states
+    non-empty `update_window_dims`. Lowering only, in the chip's forms (the
+    CPU backend's `_compact_ids` is `nonzero`, itself a scatter)."""
+    from rtap_tpu.ops.step import chunk_step
+
+    cfg = nab_preset(0.0, 100.0)
+    text = chunk_step.lower(*_step_args(cfg, v5e, T=2, g=17), cfg,
+                            learn=True).as_text()
+    dims = re.findall(r"#stablehlo\.scatter<([^>]*)>", text)
+    assert dims and len(dims) == text.count('"stablehlo.scatter"')
+    for d in dims:
+        assert re.search(r"update_window_dims = \[\d", d), d
 
 
 @pytest.mark.parametrize("forms", ["by_shape", "narrow_forced"])

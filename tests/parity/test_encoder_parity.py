@@ -4,6 +4,8 @@ The RDSE/date encoder must be bit-identical across host numpy and jitted JAX:
 every downstream parity test depends on both backends seeing the same SDR.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -123,3 +125,98 @@ def test_scalar_encoder_parity_and_properties():
         r1 = m_cpu.run(1_700_000_000 + i, v)
         r2 = m_dev.run(1_700_000_000 + i, v)
         assert r1.raw_score == r2.raw_score, i
+
+
+# ---- the SDR at the cases an index write hid (ISSUE 31) -------------------
+# Every bit index is compared against the input iota now (`_bits_at`), where
+# it was `sdr.at[idx].set(True, mode="drop")`: a dropped index (a missing
+# field, a weekday), an index named twice (colliding hash bits), a
+# time-of-day run that wraps at the ring's end, the weekend bits on and off.
+
+_MIDNIGHT = 19_676 * 86_400  # 2023-11-15 00:00:00 UTC, a Wednesday
+_STAMPS = {
+    "ring_start": _MIDNIGHT,              # centre 0: the run reaches back over the end
+    "ring_end": _MIDNIGHT + 86_399,       # centre size-1: it runs over into the start
+    "saturday": _MIDNIGHT + 3 * 86_400 + 43_200,
+    "sunday_last_second": _MIDNIGHT + 4 * 86_400 + 86_399,
+    "monday_first_second": _MIDNIGHT + 5 * 86_400,
+}
+_VALUES = {
+    "all_finite": [3.25, -17.5, 40.0],
+    "nan_first": [np.nan, -17.5, 40.0],
+    "inf_middle": [3.25, np.inf, 40.0],
+    "neg_inf_last": [3.25, -17.5, -np.inf],
+    "all_missing": [np.nan, np.inf, -np.inf],
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _edge_encoder(family: str):
+    """(cfg, jitted encode_device) of a family: one compile serves its cases."""
+    cfg = _edge_cfg(family)
+    return cfg, jax.jit(lambda *a: encode_device(cfg, *a))
+
+
+def _edge_cfg(family: str) -> ModelConfig:
+    from rtap_tpu.config import (
+        CompositeEncoderConfig, FieldSpec, ScalarEncoderConfig,
+    )
+
+    date = DateConfig(time_of_day_width=5, time_of_day_size=13, weekend_width=3)
+    if family == "rdse":
+        return ModelConfig(rdse=RDSEConfig(size=100, active_bits=7, resolution=0.5),
+                           date=date, n_fields=3)
+    if family == "rdse_colliding":  # 11 bits hashed into 12 places: they collide
+        return ModelConfig(rdse=RDSEConfig(size=12, active_bits=11, resolution=0.5),
+                           date=date, n_fields=3)
+    if family == "rdse_no_date":
+        return ModelConfig(rdse=RDSEConfig(size=100, active_bits=7, resolution=0.5),
+                           date=DateConfig(time_of_day_width=0, time_of_day_size=0,
+                                           weekend_width=0), n_fields=3)
+    if family == "scalar":
+        return ModelConfig(scalar=ScalarEncoderConfig(size=60, width=9, min_val=-20.0,
+                                                      max_val=50.0),
+                           date=date, n_fields=3)
+    if family == "composite":
+        return ModelConfig(n_fields=3, date=date, composite=CompositeEncoderConfig(fields=(
+            FieldSpec(name="v", kind="rdse", size=96, active_bits=9, resolution=0.5, seed=3),
+            FieldSpec(name="d", kind="delta", size=10, active_bits=7, resolution=0.25, seed=3),
+            FieldSpec(name="c", kind="categorical", size=80, active_bits=5, seed=3))))
+    raise AssertionError(family)
+
+
+@pytest.mark.parametrize("stamp", sorted(_STAMPS))
+@pytest.mark.parametrize("values", sorted(_VALUES))
+@pytest.mark.parametrize("family", ["rdse", "rdse_colliding", "rdse_no_date",
+                                    "scalar", "composite"])
+def test_encode_edges_match_the_oracle(family, values, stamp):
+    cfg, encode = _edge_encoder(family)
+    v, ts = np.asarray(_VALUES[values], np.float32), _STAMPS[stamp]
+    off = np.asarray([0.5, -1.0, 2.0], np.float32)
+    extra = ()
+    if family == "composite":
+        extra = (np.asarray(cfg.field_resolutions(), np.float32),
+                 np.asarray([1.0, 2.0, np.nan], np.float32))
+    host = encode_record(cfg, v.astype(np.float64), ts, off, *extra)
+    dev = np.asarray(encode(jnp.asarray(v), jnp.int32(ts), jnp.asarray(off),
+                            *map(jnp.asarray, extra)))
+    np.testing.assert_array_equal(host, dev)
+
+    layout = cfg.field_layout()  # the value fields; the date bits follow them
+    for f, (name, _kind, o, sz) in enumerate(layout):
+        if not np.isfinite(v[f]):  # a missing sample sets no bit of its field
+            assert not dev[o:o + sz].any(), name
+    if values == "all_finite" and family == "rdse_colliding":
+        _, _, o, sz = layout[0]
+        assert 0 < dev[o:o + sz].sum() < cfg.rdse.active_bits  # bits did collide
+    base = layout[-1][2] + layout[-1][3]
+    if family == "rdse_no_date":
+        assert dev.shape == (base,)
+        return
+    ring = dev[base:base + cfg.date.time_of_day_size]
+    assert ring.sum() == cfg.date.time_of_day_width
+    if stamp in ("ring_start", "ring_end"):
+        assert ring[0] and ring[-1]  # the run wrapped
+    weekend = dev[base + cfg.date.time_of_day_size:]
+    assert weekend.shape == (cfg.date.weekend_width,)
+    assert weekend.all() == weekend.any() == (stamp in ("saturday", "sunday_last_second"))

@@ -114,3 +114,55 @@ def test_device_path_equals_the_reference(tpu_paths, columns, ids, S, M, wide):
         ref_in_use.append(ref.state["seg_last"] >= 0)
     # the group's segment-pool headroom, counted from the state it holds
     assert group.capacity_stats() == segment_capacity(np.stack(ref_in_use))
+
+
+# ---- the family's input and winner masks at their edges (ISSUE 31) --------
+# SDR bits and SP winners are compares against an iota on the device, where
+# they were index writes; the reference still writes by index.
+
+_DAY0 = 19_676 * 86_400  # 2023-11-15 00:00:00 UTC
+_RECORDS = {
+    "ring_start": (_DAY0, 41.5),           # the time-of-day run reaches back over the ring's end
+    "ring_end": (_DAY0 + 86_399, 41.5),    # ... and over into its start
+    "ring_middle": (_DAY0 + 43_200, 41.5),
+    "missing_value": (_DAY0 + 43_200, float("nan")),  # date bits only
+    "infinite_value": (_DAY0 + 5, float("inf")),
+    "saturday_noon": (_DAY0 + 3 * 86_400 + 43_200, 0.0),  # no weekend bits in this family
+}
+
+
+@pytest.mark.parametrize("record", sorted(_RECORDS))
+def test_family_sdr_and_winners_equal_the_reference(record):
+    import jax.numpy as jnp
+
+    from benchmark.reference.encoders import encode_record
+    from benchmark.reference.spatial_pooler import sp_compute
+    from benchmark.reference.state import init_state as reference_state
+    from rtap_tpu.models.state import init_state
+    from rtap_tpu.ops.encoders_tpu import encode_device
+    from rtap_tpu.ops.sp_tpu import sp_step
+
+    cfg = family_cfg(256, 2, 8)
+    assert cfg.date.weekend_width == 0 and cfg.date.time_of_day_width > 0
+    ref_cfg = ReferenceConfig.from_dict(cfg.to_dict())
+    ts, v = _RECORDS[record]
+    off = np.float32([40.0])
+    want = encode_record(ref_cfg, np.float64([v]), ts, off)
+    got = np.asarray(encode_device(cfg, jnp.float32([v]), jnp.int32(ts), jnp.asarray(off)))
+    np.testing.assert_array_equal(got, want)
+    R = cfg.field_size
+    assert got[R:].sum() == cfg.date.time_of_day_width
+    if np.isfinite(v):
+        assert 0 < got[:R].sum() <= cfg.rdse.active_bits  # hash bits may collide
+    else:
+        assert not got[:R].any()  # a missing sample sets no bit
+    if record in ("ring_start", "ring_end"):
+        assert got[R] and got[-1]
+
+    ref_state = reference_state(ref_cfg, SEED)
+    state = {k: jnp.asarray(x) for k, x in init_state(cfg, SEED).items()}
+    want_active = sp_compute(ref_state, want, ref_cfg.sp, learn=True)
+    state, got_active = sp_step(state, jnp.asarray(got), cfg.sp, learn=True)
+    np.testing.assert_array_equal(np.asarray(got_active), want_active)
+    assert want_active.sum() <= cfg.sp.num_active_columns
+    np.testing.assert_array_equal(np.asarray(state["perm"]), ref_state["perm"])

@@ -67,3 +67,60 @@ def test_sp_parity_with_boost():
         sp=SPConfig(columns=128, num_active_columns=8, boost_strength=2.0),
     )
     _run_parity(cfg, n_steps=60, learn=True, atol=1e-6)
+
+
+# ---- inhibition at the cases an index write hid (ISSUE 31) ----------------
+# The winner mask was `zeros(C).at[top_k indices].set(True)`; it is
+# `score >= the least of the k largest scores` now, which holds only because
+# the scores are distinct a column. Overlaps are crafted, so the twins are
+# called on their own: ties across the k-th place, ties everywhere, a
+# stimulus threshold that cuts a winner, k = 1 and k = C.
+
+def _overlaps(case: str, C: int) -> np.ndarray:
+    rng = np.random.default_rng(13)
+    if case == "tie_across_kth_place":
+        ov = np.full(C, 2, np.int32)
+        ov[[5, 9]] = 7          # two clear winners
+        ov[[40, 3, 17, 60]] = 4  # four columns tie for the remaining places
+        return ov
+    if case == "all_equal":
+        return np.full(C, 3, np.int32)
+    if case == "all_zero":
+        return np.zeros(C, np.int32)
+    if case == "threshold_cuts_a_winner":
+        ov = np.zeros(C, np.int32)
+        ov[[1, 8, 30]] = [5, 2, 1]  # below the threshold of 2: column 30 and every 0
+        return ov
+    if case == "winners_at_both_ends":
+        ov = rng.integers(0, 4, C).astype(np.int32)
+        ov[[0, C - 1]] = 9
+        return ov
+    raise AssertionError(case)
+
+
+_INHIBIT_CASES = ["tie_across_kth_place", "all_equal", "all_zero",
+                  "threshold_cuts_a_winner", "winners_at_both_ends"]
+
+
+@pytest.mark.parametrize("boost", [0.0, 2.0], ids=["no_boost", "boost"])
+@pytest.mark.parametrize("k", [1, 4, 64], ids=["k1", "k4", "k_all"])
+@pytest.mark.parametrize("case", _INHIBIT_CASES)
+def test_sp_inhibit_edges_match_the_oracle(case, k, boost):
+    from rtap_tpu.models.oracle import spatial_pooler as oracle_sp
+    from rtap_tpu.ops.sp_tpu import sp_inhibit
+
+    C = 64
+    cfg = SPConfig(columns=C, num_active_columns=k, boost_strength=boost,
+                   stimulus_threshold=2 if case == "threshold_cuts_a_winner" else 0)
+    overlap = _overlaps(case, C)
+    # boost factors that keep the quantized scores far from a .5 boundary
+    factors = np.where(np.arange(C) % 2 == 0, 1.0, 1.5).astype(np.float32)
+    want = oracle_sp.sp_inhibit(overlap, factors, cfg)
+    got = np.asarray(sp_inhibit(jnp.asarray(overlap), jnp.asarray(factors), cfg))
+    np.testing.assert_array_equal(got, want)
+    if case == "threshold_cuts_a_winner":
+        assert got.sum() == min(k, 2) and not got[30]
+    elif case == "all_zero" or boost == 0.0:
+        assert got.sum() == k  # a tie never lets a (k+1)-th column through
+    if case == "tie_across_kth_place" and k == 4 and boost == 0.0:
+        assert sorted(np.flatnonzero(got)) == [3, 5, 9, 17]  # lowest index wins a tie

@@ -18,6 +18,7 @@ import pytest
 
 from rtap_tpu.config import TMConfig
 from rtap_tpu.models.oracle.temporal_memory import TMOracle
+from rtap_tpu.models.perm import tm_domain
 from rtap_tpu.ops import tm_tpu
 from rtap_tpu.ops.tm_tpu import from_kernel_layout, tm_step, to_kernel_layout
 
@@ -66,8 +67,8 @@ def rows(request, monkeypatch):
     jax.clear_caches()
 
 
-def _run_parity(C, cfg, sequences, learn=True):
-    host = _init_tm_state(C, cfg)
+def _run_parity(C, cfg, sequences, learn=True, host=None):
+    host = _init_tm_state(C, cfg) if host is None else host
     # the public [C, K, S, M] layout crosses the boundary via the same
     # reshape adapters ops/step.py uses
     dev = to_kernel_layout(
@@ -174,3 +175,147 @@ def test_tm_parity_empty_and_full_columns(rows):
     seq = [_pattern(rng, C, 3), np.arange(C), np.array([], np.int64),
            _pattern(rng, C, 3), np.arange(C), _pattern(rng, C, 3)] * 4
     _run_parity(C, cfg, seq)
+
+
+# ---- the best-matching-segment mask, at the cases an index write hid ------
+# (ISSUE 31: the mask was `zeros.at[arange(C), bm_k, bm_s].set(burst_match)`,
+# which also wrote a False at [c, 0, 0] of every column that does not
+# burst-match; it is a compare against the flat (k, s) iota now)
+
+_EDGE_CFG = dict(cells_per_column=4, activation_threshold=4, min_threshold=2,
+                 max_segments_per_cell=2, max_synapses_per_segment=8,
+                 new_synapse_count=5, predicted_segment_decrement=0.02,
+                 learn_cap=64, col_cap=16)
+
+
+def _crafted_state(C, cfg, segments, prev_cells, prev_winners):
+    """A state the step could have left behind: `segments` maps (c, k, s) to
+    (number of synapses onto the previously active cells, permanence); the
+    dendrite results (active / matching / potential counts) are derived from
+    the pools the way the step's last stage derives them."""
+    K = cfg.cells_per_column
+    dom = tm_domain(cfg)
+    st = _init_tm_state(C, cfg)
+    st["syn_perm"] = np.zeros(st["syn_perm"].shape, dom.dtype)
+    for c, k in prev_cells:
+        st["prev_active"][c, k] = True
+    for c, k in prev_winners:
+        st["prev_winner"][c, k] = True
+    ids = [c * K + k for c, k in prev_cells]
+    for (c, k, s), (n, perm) in segments.items():
+        st["presyn"][c, k, s, :n] = ids[:n]
+        st["syn_perm"][c, k, s, :n] = dom.rate(perm)
+        st["seg_last"][c, k, s] = 0
+    pre = st["presyn"]
+    on = (pre >= 0) & st["prev_active"].reshape(-1)[np.maximum(pre, 0)]
+    pot = on.sum(-1)
+    conn = (on & (st["syn_perm"] >= dom.threshold(cfg.connected_permanence))).sum(-1)
+    exists = st["seg_last"] >= 0
+    st["active_seg"] = exists & (conn >= cfg.activation_threshold)
+    st["matching_seg"] = exists & (pot >= cfg.min_threshold)
+    st["seg_pot"] = np.where(exists, pot, 0).astype(np.int32)
+    st["tm_iter"] = np.int32(1)
+    return st
+
+
+_PREV = [(6, 0), (6, 1), (7, 0), (7, 1), (6, 2)]
+
+_BM_CASES = {
+    # column 0 bursts with no segment at all (the index write put a False at
+    # its [0, 0]; it allocates there now) BESIDE column 1 whose best matching
+    # segment IS flat index 0; column 3's best is the last flat index
+    "none_beside_flat_zero": (
+        {(1, 0, 0): (2, 0.3), (3, 3, 1): (3, 0.3), (5, 0, 1): (2, 0.3)},
+        [0, 1, 3]),
+    # equal potential counts: the first flat index wins — (1, 1) over (2, 0)
+    # and over the weaker (0, 1); column 4 is predicted beside them
+    "ties_take_the_first_max": (
+        {(2, 0, 1): (2, 0.3), (2, 1, 1): (3, 0.3), (2, 2, 0): (3, 0.3),
+         (4, 1, 0): (4, 0.6), (4, 2, 1): (3, 0.3)},
+        [2, 4]),
+    # every column burst-matches in the same tick, each at another (k, s)
+    "every_column_at_once": (
+        {(c, c % 4, (c // 4) % 2): (2 + c % 3, 0.3) for c in range(8)},
+        list(range(8))),
+    # a matching segment in a column that stays dark is punished, not taught
+    "matching_but_not_active": (
+        {(1, 2, 1): (3, 0.3), (5, 0, 0): (3, 0.3), (5, 3, 1): (2, 0.6)},
+        [1]),
+}
+
+
+@pytest.fixture(params=[True, None], ids=["tpu_paths", "backend_paths"])
+def compact_paths(request):
+    old = tm_tpu.FORCE_TPU_PATHS
+    tm_tpu.FORCE_TPU_PATHS = request.param
+    jax.clear_caches()
+    yield request.param
+    tm_tpu.FORCE_TPU_PATHS = old
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("perm_bits", [0, 16], ids=["f32", "u16"])
+@pytest.mark.parametrize("case", sorted(_BM_CASES))
+def test_tm_parity_best_matching_segment_edges(case, perm_bits, rows, compact_paths):
+    C = 8
+    cfg = TMConfig(perm_bits=perm_bits, **_EDGE_CFG)
+    segments, first = _BM_CASES[case]
+    host = _crafted_state(C, cfg, segments, _PREV, _PREV[:3])
+    if case == "every_column_at_once":
+        assert host["matching_seg"].any((1, 2)).all() and not host["active_seg"].any()
+    rng = np.random.default_rng(41)
+    # the crafted tick, the same columns again (what it taught now predicts
+    # or matches), then novelty over the taught pools
+    seq = [np.array(first), np.array(first)] + [_pattern(rng, C, 3) for _ in range(6)]
+    _run_parity(C, cfg, seq, host=host)
+
+
+@pytest.mark.parametrize("case", sorted(_BM_CASES))
+def test_best_matching_mask_is_the_oracles_choice(case, rows):
+    """The mask itself, not only the state it leads to: one bit a
+    burst-matching column, at the first maximum of the potential counts."""
+    C = 8
+    cfg = TMConfig(**_EDGE_CFG)
+    segments, first = _BM_CASES[case]
+    st = _crafted_state(C, cfg, segments, _PREV, _PREV[:3])
+    active = np.zeros(C, bool)
+    active[first] = True
+    _, learn_mask, _, _, _ = tm_tpu._segment_learning_mask(
+        cfg, jnp.asarray(active), jnp.asarray(st["active_seg"]),
+        jnp.asarray(st["matching_seg"]), jnp.asarray(st["seg_pot"]),
+        jnp.asarray(st["seg_last"]), jnp.bool_(True))
+    want = active[:, None, None] & st["active_seg"]
+    for c in np.flatnonzero(active & ~st["active_seg"].any((1, 2))):
+        if st["matching_seg"][c].any():
+            pot = np.where(st["matching_seg"][c], st["seg_pot"][c], -1)
+            want[(c, *np.unravel_index(int(np.argmax(pot)), pot.shape))] = True
+    np.testing.assert_array_equal(np.asarray(learn_mask), want)
+
+
+def test_forms_agree_when_learning_overflows(compact_paths, monkeypatch):
+    """Past `learn_cap` the oracle (which has no cap) is no yardstick, but
+    the two forms still are for each other: the first `learn_cap` learning
+    segments learn and are stamped, the rest wait. The wide form names the
+    stamped rows by a compare against the largest compacted id, the narrow
+    one by the compacted ids' one-hot rows; same rows, same state."""
+    C, cfg = 32, TMConfig(
+        cells_per_column=4, activation_threshold=2, min_threshold=1,
+        max_segments_per_cell=2, max_synapses_per_segment=6,
+        new_synapse_count=4, learn_cap=3, col_cap=8,
+    )
+    finals = {}
+    for form, lanes in (("wide", 1), ("narrow", 1 << 30)):
+        monkeypatch.setattr(tm_tpu, "WIDE_ROW_LANES", lanes)
+        jax.clear_caches()
+        dev = to_kernel_layout(
+            {k: jnp.asarray(v) for k, v in _init_tm_state(C, cfg).items()}, cfg)
+        rng = np.random.default_rng(5)
+        for _ in range(40):
+            active = np.zeros(C, bool)
+            active[_pattern(rng, C, 6)] = True
+            dev, _ = tm_step(dev, jnp.asarray(active), cfg, learn=True)
+        finals[form] = jax.device_get(from_kernel_layout(dev, cfg))
+    jax.clear_caches()
+    assert int(finals["wide"]["tm_overflow"]) > 0  # the cap really cut
+    for key in TM_KEYS:
+        np.testing.assert_array_equal(finals["wide"][key], finals["narrow"][key], err_msg=key)

@@ -50,3 +50,34 @@ def test_compact_ids_matches_nonzero(force_tpu_paths):
             want = np.flatnonzero(mask)[:size]
             want = np.concatenate([want, np.full(size - len(want), n)]).astype(np.int32)
             np.testing.assert_array_equal(got, want, err_msg=f"n={n} size={size} d={density}")
+
+
+@pytest.mark.parametrize("program", ["chunk_step", "group_step"])
+@pytest.mark.parametrize("preset", ["cluster", "scaled32"])
+def test_cluster_step_lowers_with_no_scatter_and_no_gather(force_tpu_paths, preset, program):
+    """The CPU-only twin of tests/integration/test_chip_compile.py's pins
+    (no TPU compiler needed): in the formulations the chip runs, the fused
+    step of both cluster presets lowers to no `stablehlo.scatter` and no
+    `stablehlo.gather` — an index list becomes a mask by compare (ISSUE 31;
+    the gathers went with ISSUEs 26 and 28)."""
+    import jax
+    import jax.numpy as jnp
+
+    import rtap_tpu.ops.step as step
+    from rtap_tpu.config import cluster_preset, scaled_cluster_preset
+    from rtap_tpu.models.state import init_state
+
+    cfg = cluster_preset() if preset == "cluster" else scaled_cluster_preset(32)
+    G, lead = 4, (2,) if program == "chunk_step" else ()
+    state = {k: jax.ShapeDtypeStruct((G, *np.shape(v)), np.asarray(v).dtype)
+             for k, v in init_state(cfg, 0).items()}
+    vals = jax.ShapeDtypeStruct((*lead, G, cfg.n_fields), jnp.float32)
+    ts = jax.ShapeDtypeStruct((*lead, G), jnp.int32)
+    jax.clear_caches()  # a program traced with the backend's forms must not serve
+    try:
+        text = getattr(step, program).lower(state, vals, ts, cfg, learn=True).as_text()
+    finally:
+        jax.clear_caches()
+    assert "stablehlo.scatter" not in text
+    assert "stablehlo.gather" not in text
+    assert text.count("stablehlo.compare") > 50  # a whole step was lowered
