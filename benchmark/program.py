@@ -130,6 +130,20 @@ def overflow_total(groups) -> int:
                    for g in groups))
 
 
+def capacity_total(groups) -> dict:
+    """Every group's `StreamGroup.capacity_stats()` as one count over all
+    resident streams (full cells and columns add, the high-water mark is the
+    highest); {} for a program that has no such counter."""
+    totals: dict = {}
+    for g in groups:
+        if not hasattr(g, "capacity_stats"):
+            return {}
+        for k, v in g.capacity_stats().items():
+            totals[k] = (max(totals.get(k, 0), int(v)) if k.startswith("max_")
+                         else totals.get(k, 0) + int(v))
+    return totals
+
+
 def memory_peak_bytes() -> int:
     """peak_bytes_in_use of the fullest device (0 where the backend reports
     no memory stats — the CPU rehearsal)."""

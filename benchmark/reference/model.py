@@ -35,14 +35,15 @@ class ReferenceStream:
     def __init__(self, cfg: ModelConfig, seed: int):
         if cfg.classifier.enabled or cfg.cadence_active or (
                 cfg.composite is not None and cfg.composite.has_delta):
-            raise ValueError("the benchmark's reference covers the plain "
-                             "every-tick-learning single-field family only")
+            raise ValueError("the benchmark's reference has no SDR classifier, "
+                             "no learning cadence and no delta field")
         self.cfg = cfg
         self.state = init_state(cfg, seed, include_fwd=False)
         self._tm = TMOracle(self.state, cfg.tm)
 
-    def run(self, ts_unix: int, value: float) -> float:
-        """Score one record (NaN = missing sample), learning on."""
+    def run(self, ts_unix: int, value) -> float:
+        """Score one record — a scalar, or the `[n_fields]` row of a
+        multi-field model (NaN = missing sample) — learning on."""
         return float(record_step(
             self.cfg, self.state, self._tm,
             np.atleast_1d(np.asarray(value, np.float32)), int(ts_unix), True))

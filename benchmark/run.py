@@ -6,8 +6,10 @@ Set-up (state on the device from the seed, warm-up of the cell's own shapes,
 generator start and phase-lock) is timed as `setup_s`; then the cell's
 traffic kind measures for --seconds; then — outside both — the timed path's
 output is compared with the plain reference. The LAST stdout line is the
-result object (correct, attempted, failed, metrics, device[, breakdown]);
-earlier lines say what ran. With --trace 0 the metrics are the cell's
+result object (correct, attempted, failed, metrics, device[, breakdown],
+with reference_s, compared_ticks and, last, `compared`: each number beside
+its limit, which are also the last lines of stderr); earlier lines say what
+ran. With --trace 0 the metrics are the cell's
 end-to-end metrics, with --trace 1 (profiler on) its per-layer metrics.
 Without a TPU the run exits non-zero and prints no result."""
 
@@ -127,9 +129,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             f"{k} {d:.2f}" for k, (_t, d) in ctx.bench_spans.items())
         + f"); peak device memory {peak} B")
 
-    correct, numbers, ref_s = check.compare(
+    correct, numbers, ref_s, compared_ticks = check.compare(
         cell["config"], record["sample"], record["tm_overflow"],
         record["rows_misrouted"], say=say)
+    numbers.append({"name": "compiles_in_window", "limit": 0,
+                    "value": record["compiles_in_window"],
+                    "ok": not record["compiles_in_window"]})
     if record["compiles_in_window"]:
         say(f"[correct] FAILED: {record['compiles_in_window']} compilation(s) "
             "inside the measured window")
@@ -142,7 +147,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                "count": device["count"], "memory_peak_bytes": peak}
     result = {"correct": bool(correct), "attempted": int(record["attempted"]),
               "failed": int(record["failed"]), "metrics": {}, "device": dev_out,
-              "compared": numbers, "reference_s": ref_s}
+              "reference_s": ref_s, "compared_ticks": compared_ticks}
     if not trace:
         values = dict(record["end_to_end"])
         values["setup_s"] = record["setup_s"]
@@ -170,6 +175,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                     "value": float(value), "unit": m["unit"]}
         say(f"[trace] window {reduced['window_s']:.3f}s, device busy "
             f"{reduced['busy_s']:.3f}s; programs {reduced['modules']}")
+    result["compared"] = numbers  # each number beside its limit, last
     return 0, result, record
 
 
@@ -187,6 +193,8 @@ def main(argv=None) -> int:
                                    bool(a.trace), control=bool(a.control),
                                    t0=_T0)
     say(json.dumps(result))
+    for n in result["compared"]:
+        print("[correct] " + check.describe(n), file=sys.stderr, flush=True)
     return rc
 
 

@@ -4,7 +4,17 @@ Seeded chunks of `chunk_ticks` ticks go round-robin through EVERY resident
 group with learning on, depth-2 pipelined (chunk i+1 is dispatched before
 chunk i is collected), until `--seconds` have passed; every dispatched chunk
 is collected before anything is counted. Rows per second is taken over the
-time from the first dispatch to the last collect of those whole chunks."""
+time from the first dispatch to the last collect of those whole chunks.
+
+A configuration that states `correct_ticks` N (a whole multiple of
+`chunk_ticks`) has its sampled streams compared over their first N ticks,
+counted from the making of their state (warm-up chunk included), and their
+permanences compared AT tick N: before a sampled group's chunk N/T is
+dispatched the run drains what is in flight, reads the sampled slots' rows
+and goes on. That pause is out of the clock (the window holds `--seconds`
+of stepping, rows per second leaves `paused_s` out) and in the record (the
+`correct_pause` host span, the `[replay]` line). Without the key every tick
+the window held is followed and the state compared is the run's last."""
 
 from __future__ import annotations
 
@@ -14,24 +24,72 @@ import time
 import numpy as np
 
 from benchmark import program
+from benchmark.check import PERM_LEAVES
 from benchmark.feed import make_sine_feed, sample_streams, seed_key
 
 
-class GroupFeed:
-    """Random-access seeded chunks of one group's streams (lane = group)."""
+#: field f of a group draws from lane (group << 24) + f * _FIELD_LANE: above
+#: every (group, chunk) lane of field 0, which is the one-field feed's own
+_FIELD_LANE = 1 << 48
 
-    def __init__(self, seed: int, group: int, G: int, T: int):
-        self.seed, self.lane, self.G, self.T = seed, group << 24, G, T
-        _, _, self.phase = make_sine_feed(G, 1, seed_key(seed, self.lane))
+
+class GroupFeed:
+    """Random-access seeded chunks of one group's streams: [T, G] for a
+    one-field model, [T, G, n_fields] otherwise — each field a signal of its
+    own (own lane, own per-stream phase), a pure function of seed, group,
+    chunk and field."""
+
+    def __init__(self, seed: int, group: int, G: int, T: int,
+                 n_fields: int = 1):
+        self.seed, self.G, self.T = seed, G, T
+        self.lanes = [(group << 24) + f * _FIELD_LANE for f in range(n_fields)]
+        self.phases = [make_sine_feed(G, 1, seed_key(seed, lane))[2]
+                       for lane in self.lanes]
 
     def values(self, c: int) -> np.ndarray:
-        return make_sine_feed(self.G, self.T,
-                              seed_key(self.seed, self.lane + 1 + c),
-                              t0=c * self.T, phase=self.phase)[0]
+        fields = [make_sine_feed(self.G, self.T,
+                                 seed_key(self.seed, lane + 1 + c),
+                                 t0=c * self.T, phase=phase)[0]
+                  for lane, phase in zip(self.lanes, self.phases)]
+        return fields[0] if len(fields) == 1 else np.stack(fields, axis=-1)
 
     def ts(self, c: int) -> np.ndarray:
         t_idx = c * self.T + np.arange(self.T)[:, None]
         return (1_700_000_000 + t_idx + np.zeros((1, self.G))).astype(np.int64)
+
+
+def trace_budget_s(traffic: dict, seconds: float, elapsed: float,
+                   chunks: int) -> float:
+    """How much of the window's end a `--trace 1` run profiles: the mix's
+    `trace_window_s`, or — where the mix states `trace_max_chunks` — the
+    time that many chunks take at the rate the window has shown so far, if
+    that is less. The reader parses ~2,900 device events a chunk in Python,
+    and a trace of a thousand chunks takes it longer than a run may last."""
+    budget = min(seconds, traffic["trace_window_s"])
+    cap = traffic.get("trace_max_chunks")
+    if cap and chunks:
+        budget = min(budget, cap * elapsed / chunks)
+    return budget
+
+
+def slowest_interval(spans: list) -> dict:
+    """Where a window lost time, from its host spans: the longest stretch
+    between two consecutive chunks' collects against the median one, and how
+    much of it the host spent waiting for the device. A host that stopped
+    (the device ran dry: the waits after it are short) and a device that ran
+    slow (a long wait) both cost `metrics_per_s` their length; this tells
+    them apart in the run's own `[replay]` line."""
+    waits = [(t0, d) for name, t0, d in spans if name == "collect_wait"]
+    if len(waits) < 3:
+        return {}
+    ends = np.array([t0 + d for t0, d in waits])
+    gaps = np.diff(ends)
+    k = int(gaps.argmax())
+    pause = sum(d for name, t0, d in spans if name == "correct_pause"
+                and ends[k] <= t0 <= ends[k + 1])
+    return {"slowest_interval_s": float(gaps[k] - pause),
+            "median_interval_s": float(np.median(gaps)),
+            "slowest_interval_wait_s": float(waits[k + 1][1])}
 
 
 def run(ctx) -> dict:
@@ -42,15 +100,23 @@ def run(ctx) -> dict:
         raise ValueError("the replay kind is depth-2, learning on")
     NG, G = layout["groups"], layout["group_size"]
     seed, seconds = ctx.seed, ctx.seconds
+    # chunks of each sampled stream that `correct` follows (None: all of them)
+    follow = ctx.config.get("correct_ticks")
+    if follow is not None:
+        if follow <= 0 or follow % T:
+            raise ValueError(f"correct_ticks {follow} is not a whole multiple "
+                             f"of the traffic's chunk_ticks {T}")
+        follow //= T
 
     with ctx.span("state"):
         cfg = program.model_config(ctx.config, control=ctx.control)
         groups = program.build_groups(cfg, NG, G, seed)
-    feeds = [GroupFeed(seed, g, G, T) for g in range(NG)]
+    feeds = [GroupFeed(seed, g, G, T, cfg.n_fields) for g in range(NG)]
     # which of each group's streams `correct` follows
     picks = sample_streams(seed, NG * G, ctx.config["correct_sample_streams"])
     slots = {g: picks[picks // G == g] % G for g in range(NG)}
     served: dict[int, list] = {g: [] for g in range(NG)}  # raw[:, slots] per chunk
+    state_at: dict[int, list] = {}  # group -> its sampled slots' rows at tick N
 
     def sequence(i: int) -> tuple[int, int]:
         """Window chunk i -> (group, that group's chunk index). Group 0's
@@ -87,19 +153,36 @@ def run(ctx) -> dict:
         spans.append(("collect_wait", t0, t1 - t0))
         spans.append(("collect_host", t1, t2 - t1))
 
-    trace_from = seconds - min(seconds, traffic["trace_window_s"])
+    def read_state(g: int) -> list:
+        return [program.state_rows(groups[g], int(slot), PERM_LEAVES)
+                for slot in slots[g]]
+
     trace_sync = None
     ctx.compiles.start()
     ctx.setup_done()
     t_first = time.perf_counter()
-    pending, i = None, 0
+    pending, i, paused_s = None, 0, 0.0
     while True:
-        elapsed = time.perf_counter() - t_first
+        elapsed = time.perf_counter() - t_first - paused_s
         if elapsed >= seconds:
             break
-        if ctx.trace and trace_sync is None and elapsed >= trace_from:
+        if ctx.trace and trace_sync is None and seconds - elapsed <= \
+                trace_budget_s(traffic, seconds, elapsed, i):
             trace_sync = ctx.profiler_start()
         g, c = sequence(i)
+        if c == follow and len(slots[g]) and g not in state_at:
+            # group g is at tick N once what is in flight has landed: its
+            # state is read now, off the clock, before chunk N/T moves it on
+            if pending is not None:
+                collect(pending)
+                pending = None
+            t0 = time.perf_counter()
+            ctx.compiles.stop()  # the rows' slices are no part of the window
+            state_at[g] = read_state(g)
+            ctx.compiles.start()
+            dt = time.perf_counter() - t0
+            paused_s += dt
+            spans.append(("correct_pause", t0, dt))
         v = chunks.get((g, c))
         if v is None:
             v = feeds[g].values(c)
@@ -121,35 +204,49 @@ def run(ctx) -> dict:
         ctx.profiler_stop(trace_sync, t_last)
     n_chunks = i
     rows = n_chunks * T * G
+    stepping_s = t_last - t_first - paused_s
+    slowest = slowest_interval(spans)
     ctx.say(f"[replay] {n_chunks} chunks of {T} ticks x {G} streams over "
             f"{NG} groups ({n_chunks / NG:.2f} rounds) in "
-            f"{t_last - t_first:.3f}s; chunks generated inside the window "
-            f"{generated_in_window}; compiles inside the window {compiles}")
+            f"{stepping_s:.3f}s of stepping"
+            + (f" + {paused_s:.3f}s paused, off the clock, to read "
+               f"{len(state_at)} sampled group(s)' state at tick {follow * T}"
+               if follow else "")
+            + f"; chunks generated inside the window {generated_in_window}; "
+            f"compiles inside the window {compiles}"
+            + ("; slowest chunk-to-chunk interval {slowest_interval_s:.4f}s "
+               "(median {median_interval_s:.4f}s), {slowest_interval_wait_s:.4f}s "
+               "of it waiting for the device".format(**slowest)
+               if slowest else ""))
 
     # ---- after the window: what `correct` compares ----
     sample = []
     for g in range(NG):
         if not len(slots[g]) or not served[g]:
             continue  # no sampled stream here, or a window too short to reach it
-        raw = np.concatenate(served[g])  # [ticks, len(slots[g])]
-        n_c = len(served[g])
+        # a group the window left short of tick N is followed as far as it
+        # got, against the state it ended in (read_state now IS that tick's)
+        n_c = len(served[g]) if follow is None else min(follow, len(served[g]))
+        raw = np.concatenate(served[g][:n_c])  # [ticks, len(slots[g])]
         vals = np.concatenate([feeds[g].values(c) for c in range(n_c)])
         ts = np.concatenate([feeds[g].ts(c) for c in range(n_c)])
+        rows_at = state_at[g] if g in state_at else read_state(g)
         for j, slot in enumerate(slots[g]):
             sample.append({
                 "stream": g * G + int(slot), "seed": seed + g,
                 "ts": ts[:, slot], "values": vals[:, slot], "raw": raw[:, j],
-                **program.state_rows(groups[g], int(slot),
-                                     ("perm", "syn_perm"))})
+                **rows_at[j]})
     stepped = sum(1 for g in range(NG) if served[g])
     return {
-        "end_to_end": {"metrics_per_s": rows / (t_last - t_first)},
+        "end_to_end": {"metrics_per_s": rows / stepping_s},
         "attempted": rows, "failed": 0,
-        "window": (t_first, t_last), "rows_scored": rows,
+        "window": (t_first, t_last), "paused_s": paused_s, **slowest,
+        "rows_scored": rows,
         "streams": NG * G, "groups": NG, "groups_stepped": stepped,
         "chunk_ticks": T, "n_chunks": n_chunks,
         "host_s_per_chunk": host_s,
         "host_spans": spans, "compiles_in_window": compiles,
         "sample": sample, "tm_overflow": program.overflow_total(groups),
+        "tm_capacity": program.capacity_total(groups),
         "rows_misrouted": 0,
     }

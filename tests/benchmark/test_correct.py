@@ -5,16 +5,12 @@ holds (the control's readings at the cells' own size are in PERF.md)."""
 import numpy as np
 import pytest
 
-from tests.benchmark.tiny import make_root, run
+from tests.benchmark.tiny import failed_numbers, make_root, run
 
 
 @pytest.fixture(scope="module")
 def root(tmp_path_factory):
     return make_root(tmp_path_factory.mktemp("bench"))
-
-
-def failed_numbers(result) -> set:
-    return {n["name"] for n in result["compared"] if not n["ok"]}
 
 
 @pytest.mark.parametrize("workload,seconds", [("tiny-replay", 1.0),

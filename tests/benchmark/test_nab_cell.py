@@ -14,6 +14,7 @@ import pytest
 
 from benchmark import kernel_bytes_dense as kbd
 from benchmark.registry import Registry
+from tests.benchmark.tiny import failed_numbers
 from tests.benchmark.tiny_nab import CELL, CONFIG, REPO, make_root, run
 
 CONFIGS = os.path.join(REPO, "benchmark", "configs")
@@ -28,10 +29,6 @@ def nab_config() -> dict:
 @pytest.fixture(scope="module")
 def root(tmp_path_factory):
     return make_root(tmp_path_factory.mktemp("bench_nab"))
-
-
-def failed_numbers(result) -> set:
-    return {n["name"] for n in result["compared"] if not n["ok"]}
 
 
 # ---- the twin through benchmark.run ----
@@ -118,8 +115,13 @@ def test_config_file_is_the_preset_with_nothing_overridden():
     assert cfg["model"] == nab_preset(0.0, 100.0).to_dict()
     assert cfg["control"]["model_overrides"] == {"sp": {"perm_bits": 16},
                                                  "tm": {"perm_bits": 16}}
+    # the cluster configurations' guarantees, `scores` and `state` restated
+    # for the ticks this configuration has `correct` follow
     with open(os.path.join(CONFIGS, "cluster-256.json")) as f:
-        assert cfg["guarantees"] == json.load(f)["guarantees"]
+        cluster = json.load(f)["guarantees"]
+    assert set(cfg["guarantees"]) == set(cluster)
+    assert {k for k in cluster if cfg["guarantees"][k] != cluster[k]} == \
+        {"scores", "state"}
     assert 0 < cfg["precision"]["perm_tolerance"] < 0.5 / 65535  # under half a u16 quantum
 
 
@@ -222,12 +224,13 @@ def test_new_readers_read_nothing_where_there_is_nothing():
         assert reader.read(bare, definition) is None, name
     definition, reader = reg.layer_metric("tm_full_cells.nab")
     assert reader.read({"sample": []}, definition) is None
+    assert reader.read({"tm_capacity": {}}, definition) is None
 
 
 def test_full_cells_counter_and_its_reader():
     from rtap_tpu.service.registry import segment_capacity
 
-    C, K, S, M = 3, 4, 2, 5
+    C, K, S = 3, 4, 2
     in_use = np.zeros((2, C, K, S), bool)
     in_use[0, 1] = True          # a column whose every cell is full
     in_use[1, 2, 3] = True       # one more full cell
@@ -236,12 +239,22 @@ def test_full_cells_counter_and_its_reader():
         "full_cells": K + 1, "full_columns": 1, "max_segments_on_a_cell": S}
     assert segment_capacity(np.zeros((1, C, K, S), bool)) == {
         "full_cells": 0, "full_columns": 0, "max_segments_on_a_cell": 0}
-    # the reader derives slots in use from the sampled permanence rows
-    perm = np.where(in_use[..., None], np.float32(0.3),
-                    np.float32(0.0)) * np.ones(M, np.float32)
+    # the replay kind sums every group's count into the record; the reader
+    # reads that, never the sampled rows (which are a mid-run tick's)
+    class Group:
+        def __init__(self, rows):
+            self.rows = rows
+
+        def capacity_stats(self):
+            return segment_capacity(self.rows)
+
+    from benchmark import program
+    total = program.capacity_total([Group(in_use[:1]), Group(in_use[1:])])
+    assert total == {"full_cells": K + 1, "full_columns": 1,
+                     "max_segments_on_a_cell": S}
+    assert program.capacity_total([object()]) == {}
     definition, reader = Registry().layer_metric("tm_full_cells.nab")
-    record = {"sample": [{"syn_perm": perm[0]}, {"syn_perm": perm[1]}]}
-    assert reader.read(record, definition) == K + 1
+    assert reader.read({"tm_capacity": total, "sample": []}, definition) == K + 1
 
 
 def test_full_cells_reads_zero_after_a_short_run(root):

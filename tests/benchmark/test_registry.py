@@ -7,6 +7,7 @@ import os
 
 import pytest
 
+from benchmark import kernel_bytes_dense, roofline
 from benchmark.registry import NotFound, Registry
 from tests.benchmark.tiny import make_root, run
 
@@ -30,9 +31,11 @@ def test_committed_manifest_resolves_every_name():
     for w in reg.manifest["workloads"]:
         cell = reg.cell(w["name"])
         assert callable(cell["kind"].run)
+        # sized by its family's byte table: sparse pools, or dense ones
+        model = cell["config"]["model"]
+        table = roofline if model["sp"]["sparse_pool"] else kernel_bytes_dense
         assert cell["config"]["layout"]["streams"] * \
-            __import__("benchmark.roofline").roofline.state_bytes_per_stream(
-                cell["config"]["model"]) >= 4.0 * 2 ** 30
+            table.state_bytes_per_stream(model) >= 4.0 * 2 ** 30
         assert {m["name"] for m in reg.metrics(w["name"], "end_to_end")} >= \
             {"setup_s", "peak_bytes_per_stream"}
         layer = reg.metrics(w["name"], "per_layer")

@@ -393,14 +393,15 @@ def test_the_new_metric_files_resolve_and_name_their_cells():
         assert callable(reader.read)
         assert (definition["scope"], definition["module"]) == \
             (scope, "jit_chunk_step")
-        assert listed[name]["workloads"] == replay
+        # a later cell may be appended to a metric's list, never put before
+        assert listed[name]["workloads"][:2] == replay
         assert (listed[name]["source"], listed[name]["layer"]) == \
             ("device_trace", "kernels")
     for name, (phase, per) in PHASES.items():
         definition, reader = reg.layer_metric(name)
         assert (definition["phase"], definition["per"]) == (phase, per)
-        assert listed[name]["workloads"] == (
-            replay if name.endswith(".replay") else ["cluster-256-live"])
+        head = replay if name.endswith(".replay") else ["cluster-256-live"]
+        assert listed[name]["workloads"][:len(head)] == head
         assert (listed[name]["source"], listed[name]["layer"]) == \
             ("program_span", "stream groups")
     assert len(SCOPE_MS) + len(ROOFLINES) + len(PHASES) == 18

@@ -53,11 +53,17 @@ def make_root(tmp_path, groups: int = 2, group_size: int = 8) -> str:
          "traffic": "live-tiny-unheld", "chips": 1, "why": "t"}]
     for m in bm["end_to_end"] + bm["per_layer"]:
         if "workloads" in m:
+            # a cell the rig has no twin of keeps its metrics out of the way
             m["workloads"] = sorted({t for w in m["workloads"]
-                                     for t in rename[w]})
+                                     for t in rename.get(w, ())})
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bm, f)
     return root
+
+
+def failed_numbers(result) -> set:
+    """Names of the compared numbers that a run's result line failed."""
+    return {n["name"] for n in result["compared"] if not n["ok"]}
 
 
 def run(root: str, workload: str, seed: int, seconds: float, **kw):

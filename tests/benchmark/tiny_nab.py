@@ -24,7 +24,8 @@ TINY_MODEL = {
 }
 
 
-def make_root(tmp_path, streams: int = 3) -> str:
+def make_root(tmp_path, streams: int = 3, **keys) -> str:
+    """`keys` are further keys of the configuration file (`correct_ticks`)."""
     root = str(tmp_path / "checkout")
     shutil.copytree(os.path.join(REPO, "benchmark"),
                     os.path.join(root, "benchmark"))
@@ -32,9 +33,10 @@ def make_root(tmp_path, streams: int = 3) -> str:
     path = os.path.join(root, "benchmark", "configs", CONFIG + ".json")
     with open(path) as f:
         cfg = json.load(f)
-    for section, keys in TINY_MODEL.items():
-        cfg["model"][section].update(keys)
+    for section, sizes in TINY_MODEL.items():
+        cfg["model"][section].update(sizes)
     cfg["layout"].update(groups=1, group_size=streams, streams=streams)
+    cfg.update(keys)
     with open(path, "w") as f:
         json.dump(cfg, f)
     return root
