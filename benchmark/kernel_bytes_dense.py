@@ -3,11 +3,12 @@
 benchmark/roofline.py and benchmark/kernel_bytes.py cover the sparse-pool
 single-field family and raise for anything else; this is the same account for
 the family `nab-2048` belongs to: a dense SP pool (bool potential mask +
-permanences over every input), one value field plus the date encoder's bits,
-permanences in the configured domain. Leaf by leaf from the configuration's
-sizes and nothing else; the state leaves sum to what
-rtap_tpu/models/state.py:init_state allocates, to the byte (281,628,693 B a
-stream at nab_preset; tests/benchmark/test_nab_cell.py).
+permanences over every input), F = `n_fields` >= 1 uniform RDSE value fields
+fused into one SDR plus the date encoder's bits, permanences in the
+configured domain. Leaf by leaf from the configuration's sizes and nothing
+else; the state leaves sum to what rtap_tpu/models/state.py:init_state
+allocates, to the byte (281,628,693 B a stream at nab_preset, 760,871 at
+node_preset(3); tests/benchmark/test_roofline.py).
 
 Each kernel — named by its `rtap.*` scope — reads and writes the listed
 leaves once per stream-tick, so no kernel can take less time than those bytes
@@ -48,17 +49,24 @@ _PERM_BYTES = {0: 4, 8: 1, 16: 2}
 
 def leaf_bytes(model: dict) -> dict[str, int]:
     """Bytes per stream of every state leaf and hand-over vector of a
-    dense-pool, single-value-field HTM model (a configuration's `model`
-    group); the date encoder's bits are part of the input."""
+    dense-pool HTM model of F uniform RDSE value fields (a configuration's
+    `model` group); the date encoder's bits are part of the input. Only the
+    encoder's three leaves and what follows the input's width know F: the TM
+    does not know how many fields fed the SP."""
     sp, tm, rdse, date = model["sp"], model["tm"], model["rdse"], model["date"]
-    if (sp["sparse_pool"] or model["n_fields"] != 1
+    F = model["n_fields"]
+    if (sp["sparse_pool"] or F < 1
             or model["composite"] is not None or model["scalar"] is not None
             or model["classifier"]["enabled"]):
-        raise ValueError("these bytes cover the dense-pool single-field RDSE "
-                         "family only (benchmark/kernel_bytes.py has the "
-                         "sparse-pool one)")
+        raise ValueError(
+            "these bytes cover the dense-pool family of n_fields >= 1 uniform "
+            "RDSE fields only: not a sparse pool (benchmark/kernel_bytes.py "
+            "has that one), not a composite encoder (its delta fields carry "
+            "an enc_prev leaf), not a scalar encoder, not an enabled "
+            "classifier")
     C = sp["columns"]
-    n_in = rdse["size"] + date["time_of_day_size"] + date["weekend_width"]
+    # = ModelConfig.input_size
+    n_in = F * rdse["size"] + date["time_of_day_size"] + date["weekend_width"]
     K, S, M = (tm["cells_per_column"], tm["max_segments_per_cell"],
                tm["max_synapses_per_segment"])
     return {
@@ -72,7 +80,7 @@ def leaf_bytes(model: dict) -> dict[str, int]:
         "seg_pot": C * K * S * 2,
         "prev_active": C * K, "prev_winner": C * K,
         "sp_iter": 4, "tm_iter": 4, "tm_overflow": 4,
-        "enc_offset": 4, "enc_bound": 1, "enc_resolution": 4,
+        "enc_offset": 4 * F, "enc_bound": F, "enc_resolution": 4 * F,
         # bool SDR, i32 overlap per column, bool active columns / cells
         "sdr": n_in, "overlap": C * 4, "active_cols": C, "active_cells": C * K,
     }

@@ -26,7 +26,7 @@ import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 
-from benchmark import check, program, trace_reduce  # noqa: E402
+from benchmark import check, program, scoped_trace, trace_reduce  # noqa: E402
 from benchmark.registry import REPO, Registry  # noqa: E402
 
 
@@ -80,7 +80,8 @@ class RunContext:
 
 
 def reduce_trace(ctx: RunContext, record: dict) -> dict:
-    planes = trace_reduce.load_xplane(ctx.trace_dir)
+    # the one reading of the run's trace; the scope readers find it here
+    planes = record["scoped_planes"] = scoped_trace.load(ctx.trace_dir)
     sync, t_end = ctx.device_trace["sync_perf"], ctx.device_trace["t_end"]
     off = trace_reduce.sync_offset_ns(planes, sync)
 

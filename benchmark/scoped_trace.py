@@ -15,10 +15,11 @@ surface metadata stats, so `load` walks the protobuf wire format itself; the
 few message and field numbers it needs are tsl/profiler/protobuf/xplane.proto's.
 The host plane's annotations keep their keyword arguments as event stats.
 
-`load` gives a plain event list in the style of trace_reduce.load_xplane
-({plane: {line: [[name, start_ns, dur_ns, ...], ...]}}), so `by_scope` and
-`phase_ms` can be checked without a chip on a reduced recording
-(benchmark/fixtures/trace_v5e_scoped.json)."""
+`load` gives a plain event list ({plane: {line: [[name, start_ns, dur_ns,
+...], ...]}}) — the one reading of a run's trace: trace_reduce.reduce takes
+busy, idle and the `breakdown` from it, `by_scope` and `phase_ms` the
+program's names — so all three can be checked without a chip on a reduced
+recording (benchmark/fixtures/trace_v5e_scoped.json)."""
 
 from __future__ import annotations
 
@@ -28,10 +29,8 @@ import re
 import struct
 
 from benchmark.registry import REPO
-from benchmark.trace_reduce import DEVICE_PLANE, SYNC_NAME, _self_times
-
-SCOPE = re.compile(r"rtap\.[a-z_]+(?:\.[a-z_]+)*")
-UNSCOPED = "unscoped"
+from benchmark.trace_reduce import (
+    DEVICE_PLANE, SYNC_NAME, UNSCOPED, _self_times, scope_of)
 
 
 class NoScopes(ValueError):
@@ -216,14 +215,6 @@ def load(log_dir: str) -> dict:
 
 
 # ---- the reductions ----
-
-def scope_of(op_name: str) -> str:
-    """The innermost `rtap.` scope in an op's name (under vmap/scan/cond JAX
-    wraps name-stack entries: `vmap(rtap.encode)`, `while/body/...`), or
-    "unscoped"."""
-    found = SCOPE.findall(op_name or "")
-    return found[-1] if found else UNSCOPED
-
 
 def traced_window(planes: dict, window_s: float) -> tuple[float, float]:
     """The benchmark's traced window on the trace's timeline: from its sync

@@ -1,12 +1,12 @@
 """From a profiler trace to numbers: device busy/idle, per-op time, the
 program executions, and idle gaps attributed to what the host was doing.
 
-Two steps, so the arithmetic can be checked without a chip: `load_xplane`
-turns the profiler's .xplane.pb into a plain event list
-({plane: {line: [[name, start_ns, dur_ns], ...]}}; the host plane's
-`bench_*` annotations gathered under the line `annotations`), and `reduce` works on
-that list alone (tests/benchmark/test_trace_reduce.py runs it on a reduced
-recording of a real trace, benchmark/fixtures/trace_v5e_chunk_step.json).
+Two steps, so the arithmetic can be checked without a chip:
+benchmark/scoped_trace.py:load turns the profiler's .xplane.pb into a plain
+event list ({plane: {line: [[name, start_ns, dur_ns, ...], ...]}}; an `XLA
+Ops` event carries its `op_name` fourth), and `reduce` works on that list
+alone (tests/benchmark/test_trace_reduce.py runs it on reduced recordings of
+real traces, benchmark/fixtures/trace_v5e_*.json).
 
 What a TPU trace looks like (JAX 0.9, TPU v5 lite): plane `/device:TPU:<n>`
 has the lines `XLA Modules` (one event per program execution, named
@@ -17,53 +17,39 @@ timeline in nanoseconds."""
 
 from __future__ import annotations
 
-import glob
-import os
 import re
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 SYNC_NAME = "bench_sync"
 
 
-def load_xplane(log_dir: str) -> dict:
-    """The newest trace under a `jax.profiler.start_trace(log_dir)` directory
-    -> event list (device planes' module and op lines, the host's python
-    line)."""
-    import jax
-
-    paths = sorted(glob.glob(os.path.join(
-        log_dir, "plugins", "profile", "*", "*.xplane.pb")))
-    if not paths:
-        raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
-    data = jax.profiler.ProfileData.from_file(paths[-1])
-    planes: dict = {}
-    for plane in data.planes:
-        device = DEVICE_PLANE.match(plane.name)
-        if not device and plane.name != "/host:CPU":
-            continue
-        for line in plane.lines:
-            if device and line.name in ("XLA Modules", "XLA Ops"):
-                planes.setdefault(plane.name, {})[line.name] = [
-                    [e.name, int(e.start_ns), int(e.duration_ns)]
-                    for e in line.events]
-            elif not device:
-                # host threads are named after the process; only the
-                # benchmark's own annotations are wanted from them
-                planes.setdefault(plane.name, {}).setdefault(
-                    "annotations", []).extend(
-                    [e.name, int(e.start_ns), int(e.duration_ns)]
-                    for e in line.events if e.name.startswith("bench_"))
-    return planes
+SCOPE = re.compile(r"rtap\.[a-z_]+(?:\.[a-z_]+)*")
+UNSCOPED = "unscoped"
 
 
-def op_label(hlo_text: str) -> str:
-    """`%fusion.2 = pred[1024,256]{1,0:T(8,128)} fusion(...)` ->
-    `fusion.2:pred[1024,256]`: the op's own name and result type, without
-    layout or operands, short enough for a ledger line."""
-    m = re.match(r"%?([^\s=]+)\s*=\s*\(?([A-Za-z0-9_]+\[[^\]]*\])?", hlo_text)
+def scope_of(op_name: str) -> str:
+    """The innermost `rtap.` scope in an op's name (under vmap/scan/cond JAX
+    wraps name-stack entries: `vmap(rtap.encode)`, `while/body/...`), or
+    "unscoped"."""
+    found = SCOPE.findall(op_name or "")
+    return found[-1] if found else UNSCOPED
+
+
+def op_label(hlo_text: str, op_name: str = "") -> str:
+    """`%fusion.194 = s32[1024,256,192]{...} fusion(...)` named
+    `.../rtap.tm.learn/select_n` -> `rtap.tm.learn/fusion:s32[1024,256,192]`:
+    the scope that owns the op (`-` for none), its kind and result type,
+    without XLA's numbering (which changes with every compile), layout or
+    operands; short enough for a ledger line. Equal work under one scope
+    shares a label, and a label means the same at parent and change."""
+    scope = scope_of(op_name)
+    scope = "-" if scope == UNSCOPED else scope
+    m = re.match(r"%?([^\s=]+?)(?:\.\d+)?\s*=\s*\(?([A-Za-z0-9_]+\[[^\]]*\])?",
+                 hlo_text)
     if not m:
-        return hlo_text[:64]
-    return (m.group(1) + (":" + m.group(2) if m.group(2) else ""))[:64]
+        return f"{scope}/{hlo_text}"[:64]
+    return (f"{scope}/{m.group(1)}"
+            + (":" + m.group(2) if m.group(2) else ""))[:64]
 
 
 def _union(intervals: list[tuple[int, int]]) -> list[tuple[int, int]]:
@@ -77,9 +63,9 @@ def _union(intervals: list[tuple[int, int]]) -> list[tuple[int, int]]:
 
 
 def _self_times(events: list) -> list[tuple[str, int]]:
-    """(name, self ns) per event of one line: its duration minus the events
-    nested inside it (a `while` op spans its whole loop body; the time
-    belongs to the ops of the body)."""
+    """(name, self ns) per [name, start_ns, dur_ns] event of one line: its
+    duration minus the events nested inside it (a `while` op spans its whole
+    loop body; the time belongs to the ops of the body)."""
     out: list[list] = []
     stack: list[tuple[int, int]] = []  # (end_ns, index into out)
     for name, start, dur in sorted(events, key=lambda e: (e[1], -e[2])):
@@ -109,21 +95,26 @@ def reduce(planes: dict, window_ns: tuple[int, int],
     if not devices:
         raise ValueError("the trace holds no /device:TPU:<n> plane")
     per_device, ops, modules = [], {}, {}
+    labels: dict[tuple, str] = {}  # (hlo text, op_name) -> op_label
     first_busy: list[tuple[int, int]] = []
     for d in devices:
         lines = planes[d]
         op_events = lines.get("XLA Ops") or lines.get("XLA Modules") or []
-        clipped = [(max(s, w0), min(s + dur, w1)) for _n, s, dur in op_events
-                   if s + dur > w0 and s < w1]
+        clipped = [(max(e[1], w0), min(e[1] + e[2], w1)) for e in op_events
+                   if e[1] + e[2] > w0 and e[1] < w1]
         busy = _union(clipped)
         per_device.append(sum(b - a for a, b in busy) / 1e9)
         if d == devices[0]:
             first_busy = busy
-        for name, self_ns in _self_times(
-                [e for e in lines.get("XLA Ops", [])
+        # an op event is [hlo text, start, dur] or, from scoped_trace.load,
+        # [hlo text, start, dur, op_name]
+        for key, self_ns in _self_times(
+                [[(e[0], e[3] if len(e) > 3 else ""), e[1], e[2]]
+                 for e in lines.get("XLA Ops", [])
                  if e[1] + e[2] > w0 and e[1] < w1]):
-            label = op_label(name)
-            ops[label] = ops.get(label, 0.0) + self_ns / 1e9
+            if key not in labels:
+                labels[key] = op_label(*key)
+            ops[labels[key]] = ops.get(labels[key], 0.0) + self_ns / 1e9
         for name, s, dur in lines.get("XLA Modules", []):
             if s >= w0 and s + dur <= w1:  # whole executions only
                 m = modules.setdefault(re.sub(r"\(\d+\)$", "", name),
@@ -187,7 +178,7 @@ def sync_offset_ns(planes: dict, sync_perf_s: float) -> int:
     """Trace-timeline nanoseconds minus perf_counter nanoseconds, from the
     `bench_sync` annotation dropped right after the profiler started (its
     start stands for the perf_counter reading `sync_perf_s`)."""
-    for name, start, _dur in planes.get("/host:CPU", {}).get("annotations", []):
+    for name, start, *_ in planes.get("/host:CPU", {}).get("annotations", []):
         if name == SYNC_NAME:
             return int(start - sync_perf_s * 1e9)
     raise ValueError("the trace holds no bench_sync annotation; host spans "

@@ -1,10 +1,14 @@
 """Traffic kind `replay`: the fleet's history at full rate.
 
 Seeded chunks of `chunk_ticks` ticks go round-robin through EVERY resident
-group with learning on, depth-2 pipelined (chunk i+1 is dispatched before
-chunk i is collected), until `--seconds` have passed; every dispatched chunk
-is collected before anything is counted. Rows per second is taken over the
-time from the first dispatch to the last collect of those whole chunks.
+group with learning on, each group `pipeline_depth` deep (its chunk i+1 is
+dispatched before its chunk i is collected) and the groups' chunks queued
+behind one another on the device, up to the mix's `dispatch_ahead_chunks`:
+a host that stands still for less than that queue takes leaves the device
+fed. When `--seconds` have passed nothing more is sent; every dispatched
+chunk is collected before anything is counted. Rows per second is taken
+over the time from the first dispatch to the last collect of those whole
+chunks.
 
 A configuration that states `correct_ticks` N (a whole multiple of
 `chunk_ticks`) has its sampled streams compared over their first N ticks,
@@ -20,6 +24,7 @@ from __future__ import annotations
 
 import math
 import time
+from collections import deque
 
 import numpy as np
 
@@ -58,17 +63,32 @@ class GroupFeed:
         return (1_700_000_000 + t_idx + np.zeros((1, self.G))).astype(np.int64)
 
 
+def in_flight_limit(traffic: dict, n_groups: int) -> int:
+    """How many dispatched chunks the window holds at the most: each group
+    `pipeline_depth` deep, over every resident group, and no more than the
+    mix's `dispatch_ahead_chunks` (a mix without the key: two, one chunk
+    ahead of the one waited for)."""
+    depth = traffic["pipeline_depth"]
+    return max(depth, min(depth * n_groups,
+                          traffic.get("dispatch_ahead_chunks", depth)))
+
+
 def trace_budget_s(traffic: dict, seconds: float, elapsed: float,
-                   chunks: int) -> float:
-    """How much of the window's end a `--trace 1` run profiles: the mix's
-    `trace_window_s`, or — where the mix states `trace_max_chunks` — the
-    time that many chunks take at the rate the window has shown so far, if
-    that is less. The reader parses ~2,900 device events a chunk in Python,
-    and a trace of a thousand chunks takes it longer than a run may last."""
+                   chunks: int, in_flight: int = 0) -> float:
+    """How long before the window's close a `--trace 1` run starts the
+    profiler: the mix's `trace_window_s`, or — where the mix states
+    `trace_max_chunks` — the time that many chunks take at the rate the
+    window has shown so far (`chunks` done in `elapsed`), if that is less;
+    less the time the `in_flight` chunks take, which run inside the traced
+    window too (it ends at the last collect). The reader parses ~2,900
+    device events a chunk in Python, and a trace of a thousand chunks takes
+    it longer than a run may last."""
     budget = min(seconds, traffic["trace_window_s"])
-    cap = traffic.get("trace_max_chunks")
-    if cap and chunks:
-        budget = min(budget, cap * elapsed / chunks)
+    if chunks:
+        cap = traffic.get("trace_max_chunks")
+        if cap:
+            budget = min(budget, cap * elapsed / chunks)
+        budget -= in_flight * elapsed / chunks
     return budget
 
 
@@ -78,27 +98,36 @@ def slowest_interval(spans: list) -> dict:
     much of it the host spent waiting for the device. A host that stopped
     (the device ran dry: the waits after it are short) and a device that ran
     slow (a long wait) both cost `metrics_per_s` their length; this tells
-    them apart in the run's own `[replay]` line."""
+    them apart in the run's own `[replay]` line, with how many intervals ran
+    over three medians and what they cost together beyond a median each."""
     waits = [(t0, d) for name, t0, d in spans if name == "collect_wait"]
     if len(waits) < 3:
         return {}
     ends = np.array([t0 + d for t0, d in waits])
     gaps = np.diff(ends)
+    for name, t0, d in spans:
+        if name == "correct_pause":  # off the clock, here too
+            k = int(np.searchsorted(ends, t0, side="right")) - 1
+            if 0 <= k < len(gaps):
+                gaps[k] -= d
     k = int(gaps.argmax())
-    pause = sum(d for name, t0, d in spans if name == "correct_pause"
-                and ends[k] <= t0 <= ends[k + 1])
-    return {"slowest_interval_s": float(gaps[k] - pause),
-            "median_interval_s": float(np.median(gaps)),
-            "slowest_interval_wait_s": float(waits[k + 1][1])}
+    median = float(np.median(gaps))
+    long = gaps[gaps > 3 * median]
+    return {"slowest_interval_s": float(gaps[k]),
+            "median_interval_s": median,
+            "slowest_interval_wait_s": float(waits[k + 1][1]),
+            "long_intervals": int(long.size),
+            "long_intervals_lost_s": float((long - median).sum())}
 
 
 def run(ctx) -> dict:
     """One run of a replay cell (see benchmark/run.py for `ctx`)."""
     traffic, layout = ctx.traffic, ctx.config["layout"]
-    T, depth = traffic["chunk_ticks"], traffic["pipeline_depth"]
-    if depth != 2 or not traffic["learn"]:
+    T = traffic["chunk_ticks"]
+    if traffic["pipeline_depth"] != 2 or not traffic["learn"]:
         raise ValueError("the replay kind is depth-2, learning on")
     NG, G = layout["groups"], layout["group_size"]
+    limit = in_flight_limit(traffic, NG)
     seed, seconds = ctx.seed, ctx.seconds
     # chunks of each sampled stream that `correct` follows (None: all of them)
     follow = ctx.config.get("correct_ticks")
@@ -141,8 +170,10 @@ def run(ctx) -> dict:
     host_s: list[float] = []  # per chunk: dispatch + collect outside the wait
     spans: list[tuple[str, float, float]] = []  # (name, t0, dur) of the window
 
-    def collect(pending) -> None:
-        g, h, t_disp = pending
+    pending: deque = deque()  # (group, handle, dispatch time), oldest first
+
+    def collect() -> None:
+        g, h, t_disp = pending.popleft()
         t0 = time.perf_counter()
         program.wait_device(h)
         t1 = time.perf_counter()
@@ -161,21 +192,21 @@ def run(ctx) -> dict:
     ctx.compiles.start()
     ctx.setup_done()
     t_first = time.perf_counter()
-    pending, i, paused_s = None, 0, 0.0
+    i, paused_s = 0, 0.0
     while True:
         elapsed = time.perf_counter() - t_first - paused_s
         if elapsed >= seconds:
-            break
+            break  # time is up: nothing more is sent
         if ctx.trace and trace_sync is None and seconds - elapsed <= \
-                trace_budget_s(traffic, seconds, elapsed, i):
+                trace_budget_s(traffic, seconds, elapsed, i - len(pending),
+                               len(pending)):
             trace_sync = ctx.profiler_start()
         g, c = sequence(i)
         if c == follow and len(slots[g]) and g not in state_at:
             # group g is at tick N once what is in flight has landed: its
             # state is read now, off the clock, before chunk N/T moves it on
-            if pending is not None:
-                collect(pending)
-                pending = None
+            while pending:
+                collect()
             t0 = time.perf_counter()
             ctx.compiles.stop()  # the rows' slices are no part of the window
             state_at[g] = read_state(g)
@@ -192,12 +223,12 @@ def run(ctx) -> dict:
         h = groups[g].dispatch_chunk(v, ts, learn=True)
         t1 = time.perf_counter()
         spans.append(("dispatch", t0, t1 - t0))
-        if pending is not None:
-            collect(pending)
-        pending = (g, h, t1 - t0)
+        pending.append((g, h, t1 - t0))
         i += 1
-    if pending is not None:
-        collect(pending)
+        while len(pending) >= limit:
+            collect()
+    while pending:  # all that was sent counts, over all the time it took
+        collect()
     t_last = time.perf_counter()
     compiles = ctx.compiles.stop()
     if trace_sync is not None:
@@ -207,8 +238,8 @@ def run(ctx) -> dict:
     stepping_s = t_last - t_first - paused_s
     slowest = slowest_interval(spans)
     ctx.say(f"[replay] {n_chunks} chunks of {T} ticks x {G} streams over "
-            f"{NG} groups ({n_chunks / NG:.2f} rounds) in "
-            f"{stepping_s:.3f}s of stepping"
+            f"{NG} groups ({n_chunks / NG:.2f} rounds), at most {limit} "
+            f"dispatched at a time, in {stepping_s:.3f}s of stepping"
             + (f" + {paused_s:.3f}s paused, off the clock, to read "
                f"{len(state_at)} sampled group(s)' state at tick {follow * T}"
                if follow else "")
@@ -216,7 +247,9 @@ def run(ctx) -> dict:
             f"compiles inside the window {compiles}"
             + ("; slowest chunk-to-chunk interval {slowest_interval_s:.4f}s "
                "(median {median_interval_s:.4f}s), {slowest_interval_wait_s:.4f}s "
-               "of it waiting for the device".format(**slowest)
+               "of it waiting for the device; {long_intervals} interval(s) "
+               "over three medians cost {long_intervals_lost_s:.4f}s"
+               .format(**slowest)
                if slowest else ""))
 
     # ---- after the window: what `correct` compares ----

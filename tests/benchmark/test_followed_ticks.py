@@ -16,8 +16,8 @@ import pytest
 from benchmark import check, program
 from benchmark.reference.model import ReferenceStream
 from benchmark.registry import Registry
-from benchmark.traffic_kinds.replay import (GroupFeed, slowest_interval,
-                                            trace_budget_s)
+from benchmark.traffic_kinds.replay import (GroupFeed, in_flight_limit,
+                                            slowest_interval, trace_budget_s)
 from tests.benchmark import tiny_nab, tiny_node
 from tests.benchmark.tiny import failed_numbers
 
@@ -69,17 +69,28 @@ def test_three_fields_are_three_signals_and_repeat_per_seed():
         v, GroupFeed(SEED, 0, 8, 8, n_fields=3).values(2))
 
 
-@pytest.mark.parametrize("chunk_s,budget", [
-    (0.011, 256 * 0.011),  # cluster-32-replay: two rounds of its 128 groups
-    (0.0815, 10.0),        # cluster-256-replay: 123 chunks fit the 10 s
-    (1.09, 10.0)])         # nab-2048-replay: 9 chunks
-def test_a_traced_window_holds_no_more_chunks_than_the_mix_states(chunk_s,
-                                                                  budget):
-    traffic = Registry().cell("cluster-32-replay")["traffic"]
+@pytest.mark.parametrize("cell,chunk_s,limit,traced_s", [
+    # two rounds of its 128 groups, 23 chunks of them in flight at the start
+    ("cluster-32-replay", 0.011, 24, 256 * 0.011),
+    ("cluster-256-replay", 0.0815, 24, 10.0),  # 123 chunks fit the 10 s
+    ("nab-2048-replay", 1.09, 2, 10.0)])       # 9 chunks
+def test_a_traced_window_holds_no_more_chunks_than_the_mix_states(
+        cell, chunk_s, limit, traced_s):
+    c = Registry().cell(cell)
+    traffic = c["traffic"]
     assert (traffic["trace_window_s"], traffic["trace_max_chunks"]) == (10.0, 256)
+    # a group is pipeline_depth deep, all groups together dispatch_ahead_chunks
+    assert in_flight_limit(traffic, c["config"]["layout"]["groups"]) == limit
+    assert in_flight_limit({"pipeline_depth": 2}, 128) == 2
     elapsed = 40.0
-    assert trace_budget_s(traffic, 50.0, elapsed, round(elapsed / chunk_s)) \
-        == pytest.approx(budget, rel=1e-3)
+    done = round(elapsed / chunk_s)
+    # the profiler starts this long before the close; what is in flight then
+    # runs inside the traced window as well, which ends at the last collect
+    budget = trace_budget_s(traffic, 50.0, elapsed, done, limit - 1)
+    assert budget + (limit - 1) * chunk_s == pytest.approx(traced_s, rel=1e-3)
+    assert budget > 1.0
+    assert trace_budget_s(traffic, 50.0, elapsed, done) \
+        == pytest.approx(traced_s, rel=1e-3)
     # a window shorter than the mix's is traced whole, from its first chunk
     assert trace_budget_s(traffic, 1.5, 0.0, 0) == 1.5
     del traffic["trace_max_chunks"]
@@ -94,10 +105,12 @@ def test_the_slowest_interval_tells_a_stopped_host_from_a_slow_device():
              ("correct_pause", 5.1, 0.5), ("collect_wait", 7.5, 0.5)]
     assert slowest_interval(spans) == pytest.approx(
         {"slowest_interval_s": 3.0 - 0.5, "median_interval_s": 1.5,
-         "slowest_interval_wait_s": 0.5})
+         "slowest_interval_wait_s": 0.5, "long_intervals": 0,
+         "long_intervals_lost_s": 0.0})
     assert slowest_interval(spans[:4]) == pytest.approx(
         {"slowest_interval_s": 2.0, "median_interval_s": 1.0,
-         "slowest_interval_wait_s": 0.0})
+         "slowest_interval_wait_s": 0.0, "long_intervals": 0,
+         "long_intervals_lost_s": 0.0})
     assert slowest_interval(spans[:2]) == {}
 
 

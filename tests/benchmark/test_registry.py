@@ -26,8 +26,9 @@ def _edit_manifest(root, fn):
         json.dump(bm, f)
 
 
-def test_committed_manifest_resolves_every_name():
-    reg = Registry()
+def manifest_resolves_every_name(reg: Registry) -> None:
+    """What every cell of a manifest has to satisfy (the committed one here;
+    test_room_for_fields.py holds a copy with a further cell to the same)."""
     for w in reg.manifest["workloads"]:
         cell = reg.cell(w["name"])
         assert callable(cell["kind"].run)
@@ -47,6 +48,10 @@ def test_committed_manifest_resolves_every_name():
                 == (m["layer"], m["moves"], m["unit"])
     for c in reg.manifest["configs"]:
         assert os.path.isfile(os.path.join(reg.root, c["file"]))
+
+
+def test_committed_manifest_resolves_every_name():
+    manifest_resolves_every_name(Registry())
 
 
 def test_new_files_are_found_by_name(root):

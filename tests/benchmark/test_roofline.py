@@ -5,6 +5,7 @@ import os
 
 import pytest
 
+from benchmark import kernel_bytes_dense as kbd
 from benchmark.roofline import peaks, state_bytes_per_stream, step_floor_seconds
 
 CONFIGS = os.path.join(os.path.dirname(__file__), "..", "..", "benchmark",
@@ -50,3 +51,67 @@ def test_floor_and_peaks():
         peaks("TPU v9 imaginary")
     with pytest.raises(KeyError):
         peaks("_source")
+
+
+# ---- the dense-pool family: F uniform RDSE fields (kernel_bytes_dense) ----
+
+def _preset(name):
+    from rtap_tpu import config
+
+    return {"dense_cluster": config.dense_cluster_preset,
+            "node3": lambda: config.node_preset(3),
+            "node5": lambda: config.node_preset(5),
+            "nab": lambda: config.nab_preset(0.0, 100.0),
+            "cluster": config.cluster_preset,
+            "composite": config.composite_preset}[name]()
+
+
+@pytest.mark.parametrize("name,nbytes", [
+    ("dense_cluster", 564_245), ("node3", 760_871), ("node5", 957_497),
+    ("nab", 281_628_693)])
+def test_dense_state_bytes_equal_the_programs_own_count(name, nbytes):
+    from rtap_tpu.models.state import state_nbytes
+
+    cfg = _preset(name)
+    assert kbd.state_bytes_per_stream(cfg.to_dict()) == nbytes \
+        == state_nbytes(cfg)["total"]
+
+
+@pytest.mark.parametrize("name", ["dense_cluster", "node3", "node5"])
+def test_dense_leaves_equal_init_state_leaf_by_leaf(name):
+    import numpy as np
+
+    from rtap_tpu.models.state import init_state
+
+    cfg = _preset(name)
+    state = init_state(cfg, 0, include_fwd=False)
+    leaves = kbd.leaf_bytes(cfg.to_dict())
+    assert set(kbd.STATE_LEAVES) == set(state)
+    for k in kbd.STATE_LEAVES:
+        assert leaves[k] == np.asarray(state[k]).nbytes, k
+    assert leaves["sdr"] == cfg.input_size
+
+
+@pytest.mark.parametrize("scope,nbytes", [
+    ("rtap.sp.overlap", 296_320), ("rtap.sp.learn", 497_288),
+    ("rtap.tm.learn", 880_896), ("rtap.tm.dendrite", 493_568)])
+def test_dense_kernel_bytes_of_three_fields(scope, nbytes):
+    model = _preset("node3").to_dict()
+    assert kbd.kernel_bytes_per_stream(scope, model) == nbytes
+    # the TM does not know how many fields fed the SP; the SP's input does
+    one = _preset("dense_cluster").to_dict()
+    same = scope.startswith("rtap.tm.")
+    assert (kbd.kernel_bytes_per_stream(scope, one) == nbytes) == same
+    assert kbd.kernel_floor_seconds(scope, model, 1024, "TPU v5 lite") == \
+        pytest.approx(nbytes * 1024 / 819e9)
+
+
+@pytest.mark.parametrize("name,change", [
+    ("cluster", {}), ("composite", {}),
+    ("node3", {"n_fields": 0}), ("node3", {"classifier": {"enabled": True}}),
+    ("node3", {"scalar": {"any": "scalar encoder"}})])
+def test_dense_byte_table_refuses_what_it_does_not_count(name, change):
+    model = {**_preset(name).to_dict(), **change}
+    with pytest.raises(ValueError, match="n_fields >= 1 uniform RDSE fields "
+                                         "only: not a sparse pool"):
+        kbd.leaf_bytes(model)

@@ -384,8 +384,7 @@ def test_readers_read_nothing_where_there_is_nothing(recorded):
         assert reader.read(bare, definition) is None, name
 
 
-def test_the_new_metric_files_resolve_and_name_their_cells():
-    reg = Registry()
+def metric_files_resolve_and_name_their_cells(reg: Registry) -> None:
     listed = {m["name"]: m for m in reg.manifest["per_layer"]}
     replay = ["cluster-256-replay", "cluster-32-replay"]
     for name, scope in {**SCOPE_MS, **ROOFLINES}.items():
@@ -405,3 +404,7 @@ def test_the_new_metric_files_resolve_and_name_their_cells():
         assert (listed[name]["source"], listed[name]["layer"]) == \
             ("program_span", "stream groups")
     assert len(SCOPE_MS) + len(ROOFLINES) + len(PHASES) == 18
+
+
+def test_the_new_metric_files_resolve_and_name_their_cells():
+    metric_files_resolve_and_name_their_cells(Registry())

@@ -1,29 +1,38 @@
 """The trace -> busy/idle/per-op/gap reduction, on a hand-made event list
-(exact arithmetic) and on a reduced recording of a real TPU v5 lite trace."""
+(exact arithmetic) and on reduced recordings of real TPU v5 lite traces."""
 
 import json
 import os
+import re
 
 import pytest
 
-from benchmark.trace_reduce import op_label, reduce, sync_offset_ns
+from benchmark.trace_reduce import (
+    _self_times, op_label, reduce, sync_offset_ns)
 
-FIXTURE = os.path.join(os.path.dirname(__file__), "..", "..", "benchmark",
-                       "fixtures", "trace_v5e_chunk_step.json")
+FIXTURES = os.path.join(os.path.dirname(__file__), "..", "..", "benchmark",
+                        "fixtures")
+FIXTURE = os.path.join(FIXTURES, "trace_v5e_chunk_step.json")
+SCOPED = os.path.join(FIXTURES, "trace_v5e_scoped.json")
+LEARN = "jit(chunk_step)/while/body/closed_call/vmap(jit(tm_step))/rtap.tm.learn/"
 
 
 def hand_made():
     # one device: a program of 400 ns holding a `while` (100..400) whose
-    # body is two fusions, then idle, then a second program
+    # body is two fusions, then idle, then a second program whose fusion XLA
+    # numbered otherwise; op events with and without an op_name
     return {"/device:TPU:0": {
         "XLA Modules": [["jit_step(123)", 0, 400], ["jit_step(123)", 700, 200],
                         ["jit_other(9)", 950, 50]],
         "XLA Ops": [["%copy.1 = f32[8]{0} copy(%p)", 0, 100],
-                    ["%while.4 = (s32[]) while(%t)", 100, 300],
-                    ["%fusion.7 = pred[4,2]{1,0} fusion(%a)", 100, 200],
-                    ["%fusion.8 = s32[16]{0} fusion(%b)", 300, 90],
-                    ["%fusion.7 = pred[4,2]{1,0} fusion(%a)", 700, 200]]},
-        "/host:CPU": {"annotations": [["bench_sync", 40, 10]]}}
+                    ["%while.4 = (s32[]) while(%t)", 100, 300, "jit(step)/while"],
+                    ["%fusion.7 = pred[4,2]{1,0} fusion(%a)", 100, 200,
+                     LEARN + "select_n"],
+                    ["%fusion.8 = s32[16]{0} fusion(%b)", 300, 90,
+                     LEARN + "rtap.tm.learn.rows/scatter"],
+                    ["%fusion.31 = pred[4,2]{1,0} fusion(%a)", 700, 200,
+                     LEARN + "select_n"]]},
+        "/host:CPU": {"annotations": [["bench_sync", 40, 10, {}]]}}
 
 
 def test_busy_idle_ops_modules_and_gaps_exact():
@@ -34,11 +43,13 @@ def test_busy_idle_ops_modules_and_gaps_exact():
     # events and so is not busy time
     assert r["busy_s"] == pytest.approx(600e-9)
     ops = dict(r["device_ops"])
-    assert ops["fusion.7:pred[4,2]"] == pytest.approx(400e-9)
-    assert ops["copy.1:f32[8]"] == pytest.approx(100e-9)
-    assert ops["fusion.8:s32[16]"] == pytest.approx(90e-9)
+    # equal work under one scope adds up, whatever XLA numbered it
+    assert ops["rtap.tm.learn/fusion:pred[4,2]"] == pytest.approx(400e-9)
+    assert ops["-/copy:f32[8]"] == pytest.approx(100e-9)
+    assert ops["rtap.tm.learn.rows/fusion:s32[16]"] == pytest.approx(90e-9)
     # the while's own time is what its body leaves: 300 - 200 - 90
-    assert ops["while.4:s32[]"] == pytest.approx(10e-9)
+    assert ops["-/while:s32[]"] == pytest.approx(10e-9)
+    assert len(ops) == 4
     assert r["modules"]["jit_step"] == {"count": 2,
                                         "seconds": pytest.approx(600e-9)}
     assert r["modules"]["jit_other"]["count"] == 1
@@ -65,11 +76,27 @@ def test_no_device_plane_is_an_error():
         sync_offset_ns({"/host:CPU": {"annotations": []}}, 1.0)
 
 
-def test_op_label():
-    assert op_label("%fusion.2 = pred[1024,256]{1,0:T(8,128)} fusion(%x)") \
-        == "fusion.2:pred[1024,256]"
-    assert op_label("%copy-start.44 = (pred[8]{0}, pred[8]{0}) copy-start(%y)") \
-        == "copy-start.44:pred[8]"
+@pytest.mark.parametrize("hlo,op_name,label", [
+    ("%fusion.194 = s32[1024,256,192]{2,1,0:T(8,128)} fusion(%x)",
+     LEARN + "select_n", "rtap.tm.learn/fusion:s32[1024,256,192]"),
+    ("%fusion.2 = pred[1024,256]{1,0:T(8,128)} fusion(%x)", "",
+     "-/fusion:pred[1024,256]"),
+    ("%copy-start.44 = (pred[8]{0}, pred[8]{0}) copy-start(%y)",
+     "jit(chunk_step)/while/body/copy", "-/copy-start:pred[8]"),
+    # the innermost scope owns the op; a name without a number keeps itself
+    ("%reduce-window = f32[8]{0} reduce-window(%y)",
+     LEARN + "rtap.tm.learn.rows/vmap(rtap.encode)/cumsum",
+     "rtap.encode/reduce-window:f32[8]"),
+    ("not hlo at all", LEARN + "x", "rtap.tm.learn/not hlo at all"),
+])
+def test_op_label(hlo, op_name, label):
+    assert op_label(hlo, op_name) == label
+
+
+def test_op_label_fits_a_ledger_line():
+    long = op_label("%fusion.9 = s32[" + ",".join(["1024"] * 20) + "]{0} fusion(%x)",
+                    LEARN + "select_n")
+    assert len(long) == 64 and long.startswith("rtap.tm.learn/fusion:s32[1024,")
 
 
 def test_recorded_v5e_trace():
@@ -91,7 +118,32 @@ def test_recorded_v5e_trace():
         pytest.approx(0.2011, abs=0.0005)
     # the device is busy for all but the host's turn-around between programs
     assert 0.98 < r["busy_s"] / r["window_s"] < 1.0
-    assert r["device_ops"][0][0].startswith("while.4") or \
-        r["device_ops"][0][0].startswith("fusion.211")
+    # a recording from before the scopes: every label is unscoped
+    assert all(label.startswith("-/") for label, _s in r["device_ops"])
+    assert r["device_ops"][0][0].split(":")[0] in ("-/while", "-/fusion")
     assert sum(s for _n, s in r["idle_gaps"]) == \
         pytest.approx(r["window_s"] - r["busy_s"], rel=1e-6)
+
+
+def test_recorded_scoped_trace_labels_name_the_scope():
+    """The breakdown of a recording that carries the program's scopes
+    (cluster-256-replay, PR 25): every label is `<scope or ->/<kind>:<type>`
+    with no XLA number, equal work adds up, and no time is lost by it."""
+    with open(SCOPED) as f:
+        planes = json.load(f)["cluster-256-replay"]["planes"]
+    ops = planes["/device:TPU:0"]["XLA Ops"]
+    w = (min(e[1] for e in ops), max(e[1] + e[2] for e in ops))
+    r = reduce(planes, w, top=10_000)
+    labels = dict(r["device_ops"])
+    pattern = re.compile(r"^(-|rtap\.[a-z_.]+)/[A-Za-z_-]+(:[a-z0-9]+\[[0-9,]*\]?)?")
+    for label in labels:
+        assert pattern.match(label) and len(label) <= 64, label
+        assert not re.search(r"/[^:]*\.\d+(:|$)", label), label
+    assert any(label.startswith("rtap.tm.learn/") for label in labels)
+    assert any(label.startswith("-/") for label in labels)
+    # grouping only adds: fewer labels than distinct ops, the same seconds
+    assert len(labels) < len({e[0] for e in ops})
+    assert sum(labels.values()) == pytest.approx(
+        sum(ns for _n, ns in _self_times([e[:3] for e in ops])) / 1e9)
+    top = reduce(planes, w)["device_ops"]
+    assert len(top) == 10 and top == r["device_ops"][:10]
