@@ -17,9 +17,12 @@ same semantics down by an order of magnitude):
 1. **Packed-column membership.** "Is this synapse's presynaptic cell active?"
    Active cells can only live in active columns (<= col_cap of them, = SP's
    k winners), so the active set is (column ids [Ac], per-column K-bit cell
-   masks [Ac]) instead of a flat cell-id list. Membership is an
-   [..., Ac] compare + mask-select + bit probe — 8-32x fewer VPU ops than the
-   flat cell-id compare at preset sizes, and no serialized gather.
+   masks [Ac]) instead of a flat cell-id list. Membership is a static chain
+   of Ac compare + selects that picks the column's mask, then a bit probe —
+   8-32x fewer VPU ops than the flat cell-id compare at preset sizes, no
+   serialized gather, and element-wise from the pool to the bit, so it
+   fuses into the one pass of whatever sweep asks (the punish sweep, the
+   dendrite sweep; docs/KERNELS.md, "TM membership").
 2. **Column-compact learning workspace.** Every learning segment lives in an
    active column, so the learning pass gathers the <= col_cap active columns
    into a [Ac, K, S, M] workspace (with one-hot MXU matmuls at narrow pool
@@ -89,7 +92,8 @@ def _tpu_paths() -> bool:
 #: one-hot matmul moves beat indexed row moves 1.55x (SCALING.md round 4); at
 #: 16,384 lanes (nab_preset, a 64 KiB row, G = 17) the matmul moves' f32
 #: copies of both pools do not fit the chip (RESOURCE_EXHAUSTED at compile,
-#: 15.76 of 15.75 GB), and with indexed moves aos steps a tick in 158.2 ms
+#: 15.76 of 15.75 GB; 18.6 GB of arguments and temporaries by the compiler's
+#: own account since ISSUE 36), and with indexed moves aos steps a tick in 158.2 ms
 #: against flat's 202.2 (131.7 against 154.6 at `learn_cap` 128; my chip
 #: runs, PR 27; PERF.md s6). The line is the geometric middle of the two
 #: points, 85x apart, rounded to a power of two; nothing between them has
@@ -206,11 +210,27 @@ def _presyn_active_packed(
     presyn: jnp.ndarray, col_ids: jnp.ndarray, col_masks: jnp.ndarray, K: int
 ) -> jnp.ndarray:
     """Is each synapse's presynaptic cell in the packed active set? -> bool,
-    presyn's shape. `presyn` [..., M] i32 (-1 = empty, never matches)."""
-    c_pre = presyn // K  # -1 -> -1 (floor), never equals a valid col id
-    k_pre = presyn % K  # python modulo: -1 -> K-1, masked by presyn >= 0
-    msk = jnp.where(c_pre[..., None] == col_ids, col_masks, 0).sum(-1)
-    return (presyn >= 0) & (((msk >> k_pre) & 1) > 0)
+    presyn's shape. `presyn` [..., M] i16/i32 (-1 = empty, never matches).
+
+    Element-wise from end to end, so XLA fuses it into the caller's one pass
+    over the pool: the column's mask is picked by a static chain of selects
+    over the Ac entries (ops/sp_tpu.py:_sdr_at_members' form) — `col_ids`
+    are distinct and their fills (C) equal no column, so at most one entry
+    matches and the chain's value is the one a sum over a [..., Ac] grid
+    would give, without the reduce that would stand between the pool and
+    the caller's own reduce. The cell id splits by shift and mask where K
+    is a power of two (every preset: 8 or 32): the arithmetic shift takes
+    -1 to -1 and the mask to K-1, as floor division and Python's modulo do.
+    """
+    p = presyn.astype(jnp.int32)
+    if K & (K - 1) == 0:
+        c_pre, k_pre = p >> (K.bit_length() - 1), p & (K - 1)
+    else:
+        c_pre, k_pre = p // K, p % K  # -1 -> (-1, K-1), masked by p >= 0
+    msk = jnp.int32(0)
+    for a in range(col_ids.shape[0]):
+        msk = jnp.where(c_pre == col_ids[a], col_masks[a], msk)
+    return (p >= 0) & (((msk >> k_pre) & 1) > 0)
 
 
 def _winner_id_list(winner_ck: jnp.ndarray, Ac: int) -> jnp.ndarray:
@@ -667,24 +687,29 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
             else:
                 hit_pool = hit_cols.reshape(C, *([1] * (len(pool_shape) - 1)))
                 hit_seg = hit_cols.reshape(C, *([1] * (len(seg_shape) - 1)))
-                # presyn + perm pools restored in ONE [C, Ac] x [Ac, 2*KSM] pass
+                # presyn + perm pools restored in ONE [2*KSM, Ac] x [Ac, C] pass,
+                # each pool turned to [C, K*S*M] after its slice: the columns
+                # stay the product's minor dim, as the scan's carry holds the
+                # pools. Formed [C, 2*KSM], its layout ran through the fused
+                # sweep that consumes it to the carry and cost four pool
+                # copies a tick at 256 columns (ISSUE 36; docs/KERNELS.md)
                 KSM = K * S * M
-                pools = jax.lax.dot(
-                    col_oh.T,
+                pools_t = jax.lax.dot(
                     jnp.concatenate(
                         [
                             ws_presyn_r.reshape(Ac, -1).astype(jnp.float32),
                             ws_perm_r.reshape(Ac, -1),
                         ],
                         axis=1,
-                    ),
+                    ).T,
+                    col_oh,
                     precision=_HI,
-                )  # [C, 2*KSM]
-                pool_presyn = jnp.round(pools[:, :KSM]).astype(presyn_dt).reshape(*pool_shape)
-                pool_perm_f = pools[:, KSM:]
+                )  # [2*KSM, C]
+                pool_presyn = jnp.round(pools_t[:KSM]).astype(presyn_dt).T.reshape(*pool_shape)
+                pool_perm_f = pools_t[KSM:]
                 if dom.bits:
                     pool_perm_f = jnp.round(pool_perm_f)  # exact already; belt+braces
-                pool_perm = pool_perm_f.astype(p_dt).reshape(*pool_shape)
+                pool_perm = pool_perm_f.astype(p_dt).T.reshape(*pool_shape)
                 pool_last = jnp.where(
                     col_oh_b[:, :, None], ws_last.reshape(Ac, 1, -1), 0
                 ).sum(0).reshape(*seg_shape)

@@ -119,6 +119,29 @@ def test_cluster_step_holds_no_scatter_at_the_cells_batch(v5e, preset, program):
     assert " scatter(" not in compiled.as_text()
 
 
+@pytest.mark.parametrize("preset", ["cluster", "scaled32"])
+def test_cluster_chunk_step_sweeps_the_pool_in_one_fusion(v5e, preset):
+    """What the chip's compiler makes of the element-wise membership test
+    (ISSUE 36): the punish sweep, synapse death and the dendrite sweep are
+    ONE fusion that takes the pools and gives them back beside the two
+    count operands — no op of the program has a 32-bit pool-shaped result
+    (the parent wrote the decoded ids and each sweep's mask grid to HBM as
+    `s32[G,C,192]`, four fusions a tick), and exactly one fusion's result
+    holds both pools."""
+    from rtap_tpu.ops.step import chunk_step
+
+    cfg = cluster_preset() if preset == "cluster" else scaled_cluster_preset(32)
+    text = chunk_step.lower(*_step_args(cfg, v5e, T=2), cfg,
+                            learn=True).compile().as_text()
+    pool = rf"\[{G},{cfg.sp.columns},192\]"
+    results = re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = (.+?) fusion\(", text, re.M)
+    assert len(results) > 50  # a whole step's fusions were read
+    assert not [r for r in results if re.search("s32" + pool, r)]
+    both = [r for r in results
+            if re.search("s16" + pool, r) and re.search("u16" + pool, r)]
+    assert len(both) == 1 and both[0].count("pred[") == 2, both
+
+
 def test_nab_width_step_scatters_whole_rows_only(v5e):
     """At the NAB width the lowered step keeps its scatters — the learning
     workspace's rows moved by index — and each of them moves a window (a
@@ -142,8 +165,9 @@ def test_nab_width_chunk_step_fits_a_v5e_only_in_the_wide_row_forms(v5e, forms, 
     at the benchmark cell's batch of 17 streams. In the form the shape rule
     picks (tm_tpu.wide_rows: indexed row moves, [C, K, S, M] pools) the
     program fits the chip; in the narrow-row form the cluster presets run —
-    the line moved over this shape, here — the chip's compiler refuses it for
-    memory: the reason the line exists."""
+    the line moved over this shape, here — it does not: the chip's compiler
+    refuses it for memory, or passes it at more bytes than the chip has. The
+    reason the line exists."""
     from rtap_tpu.ops.step import chunk_step
 
     cfg = nab_preset(0.0, 100.0)
@@ -164,8 +188,16 @@ def test_nab_width_chunk_step_fits_a_v5e_only_in_the_wide_row_forms(v5e, forms, 
     jax.clear_caches()  # the form is read at trace time
     try:
         assert not tm_tpu.wide_rows(cfg.tm)
-        with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|[Oo]ut of memory"):
-            chunk_step.lower(*args, cfg, learn=True).compile()
+        # until ISSUE 36 the compiler refused this outright (15.76 of 15.75
+        # GB); with the membership test's full-pool temporaries gone it may
+        # pass the program, and then says itself that the program needs more
+        # than the chip holds (18.6 GB of arguments and temporaries)
+        try:
+            mem = chunk_step.lower(*args, cfg, learn=True).compile().memory_analysis()
+        except Exception as e:  # noqa: BLE001 — the compiler's own error type
+            assert re.search("RESOURCE_EXHAUSTED|[Oo]ut of memory", str(e)), e
+        else:
+            assert mem.argument_size_in_bytes + mem.temp_size_in_bytes > 16 * 10 ** 9
     finally:
         jax.clear_caches()
 

@@ -52,6 +52,27 @@ def test_compact_ids_matches_nonzero(force_tpu_paths):
             np.testing.assert_array_equal(got, want, err_msg=f"n={n} size={size} d={density}")
 
 
+def _lowered_text(cfg, program: str) -> str:
+    """StableHLO of `ops/step.py`'s `program` (learning on) at a batch of 4,
+    traced afresh in whatever formulations are forced."""
+    import jax
+    import jax.numpy as jnp
+
+    import rtap_tpu.ops.step as step
+    from rtap_tpu.models.state import init_state
+
+    G, lead = 4, (2,) if program == "chunk_step" else ()
+    state = {k: jax.ShapeDtypeStruct((G, *np.shape(v)), np.asarray(v).dtype)
+             for k, v in init_state(cfg, 0).items()}
+    vals = jax.ShapeDtypeStruct((*lead, G, cfg.n_fields), jnp.float32)
+    ts = jax.ShapeDtypeStruct((*lead, G), jnp.int32)
+    jax.clear_caches()  # a program traced with the backend's forms must not serve
+    try:
+        return getattr(step, program).lower(state, vals, ts, cfg, learn=True).as_text()
+    finally:
+        jax.clear_caches()
+
+
 @pytest.mark.parametrize("program", ["chunk_step", "group_step"])
 @pytest.mark.parametrize("preset", ["cluster", "scaled32"])
 def test_cluster_step_lowers_with_no_scatter_and_no_gather(force_tpu_paths, preset, program):
@@ -60,24 +81,41 @@ def test_cluster_step_lowers_with_no_scatter_and_no_gather(force_tpu_paths, pres
     step of both cluster presets lowers to no `stablehlo.scatter` and no
     `stablehlo.gather` — an index list becomes a mask by compare (ISSUE 31;
     the gathers went with ISSUEs 26 and 28)."""
-    import jax
-    import jax.numpy as jnp
-
-    import rtap_tpu.ops.step as step
     from rtap_tpu.config import cluster_preset, scaled_cluster_preset
-    from rtap_tpu.models.state import init_state
 
     cfg = cluster_preset() if preset == "cluster" else scaled_cluster_preset(32)
-    G, lead = 4, (2,) if program == "chunk_step" else ()
-    state = {k: jax.ShapeDtypeStruct((G, *np.shape(v)), np.asarray(v).dtype)
-             for k, v in init_state(cfg, 0).items()}
-    vals = jax.ShapeDtypeStruct((*lead, G, cfg.n_fields), jnp.float32)
-    ts = jax.ShapeDtypeStruct((*lead, G), jnp.int32)
-    jax.clear_caches()  # a program traced with the backend's forms must not serve
-    try:
-        text = getattr(step, program).lower(state, vals, ts, cfg, learn=True).as_text()
-    finally:
-        jax.clear_caches()
+    text = _lowered_text(cfg, program)
     assert "stablehlo.scatter" not in text
     assert "stablehlo.gather" not in text
     assert text.count("stablehlo.compare") > 50  # a whole step was lowered
+
+
+@pytest.mark.parametrize("program", ["chunk_step", "group_step"])
+@pytest.mark.parametrize("preset", ["cluster", "scaled32", "wide"])
+def test_step_lowers_membership_with_no_candidate_axis_and_no_division(force_tpu_paths, preset, program):
+    """The TM's membership test is element-wise over the pool (ISSUE 36): a
+    static chain of selects over the Ac packed columns and a shift/mask
+    decode, so nothing stands between the pool and the reduce over its
+    synapse lanes. Held by the lowering, at both cluster presets and at a
+    wide-row shape of a tiny size: no tensor carries a trailing Ac axis
+    behind the pool's dims ([C, K*S*M, Ac] flat, [C, K, S, M, Ac] wide — the
+    parent held 23 at `cluster_preset`), and no `stablehlo.divide` or
+    `stablehlo.remainder` works on a pool-shaped operand (6 and 9 there).
+    The mechanism engages always or never; this says which."""
+    import re
+
+    from rtap_tpu.config import cluster_preset, scaled_cluster_preset
+    from tests.parity.test_tm_forms import form_cfg
+
+    cfg = {"cluster": cluster_preset, "scaled32": lambda: scaled_cluster_preset(32),
+           "wide": lambda: form_cfg("wide", 16)}[preset]()
+    tm = cfg.tm
+    K, S, M = tm.cells_per_column, tm.max_segments_per_cell, tm.max_synapses_per_segment
+    assert tm_tpu.wide_rows(tm) == (preset == "wide")
+    pool = f"{cfg.sp.columns}x{K}x{S}x{M}" if preset == "wide" else f"{cfg.sp.columns}x{K * S * M}"
+    text = _lowered_text(cfg, program)
+    assert f"x{pool}x" in text  # the pools are in the program, in this spelling
+    assert not re.findall(rf"tensor<(?:\d+x)*{pool}x{tm.col_cap}x\w+>", text)
+    on_pool = [ln for ln in text.splitlines()
+               if ("stablehlo.divide" in ln or "stablehlo.remainder" in ln) and f"x{pool}x" in ln]
+    assert not on_pool, on_pool[:3]
