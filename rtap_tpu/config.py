@@ -827,9 +827,24 @@ def node_preset(n_metrics: int = 3, perm_bits: int = 16) -> ModelConfig:
     config needs its own occupancy/quality study — until then it keeps
     the measured dense geometry, and only the SP pool tables grow with
     input_size.
+
+    `learn_cap` is the structural bound, 320 (below), not the cluster
+    preset's 64.
     """
     base = dense_cluster_preset(perm_bits=perm_bits)
-    return dataclasses.replace(base, n_fields=n_metrics)
+    # learn_cap = every segment that can learn in one tick: at most col_cap
+    # columns are active, each with K x S segments, so at 10 x 8 x 4 = 320
+    # `tm_overflow` cannot count a truncated burst (as at 32 columns, where
+    # 3 x 8 x 2 = 48 <= 64). A matured node model's bursts pass the cluster
+    # preset's 64 (first at tick 912 of a replayed node, PERF.md s6), so a
+    # run that only steps faster would turn the cell's `correct` false
+    # through the preset. The cap only truncates: where no burst passes 64
+    # the step's results are bit-equal. Its cost is the [L, Ac*K*S]
+    # compaction and growth's [L, R, W] grid at L = 320.
+    tm = dataclasses.replace(
+        base.tm, learn_cap=base.tm.col_cap * base.tm.cells_per_column
+        * base.tm.max_segments_per_cell)
+    return dataclasses.replace(base, n_fields=n_metrics, tm=tm)
 
 
 def composite_preset(perm_bits: int = 16, value_resolution: float = 0.5,

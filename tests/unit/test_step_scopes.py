@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from rtap_tpu.config import scaled_cluster_preset
+from rtap_tpu.config import node_preset, scaled_cluster_preset
 from rtap_tpu.models.state import init_state
 from rtap_tpu.ops import tm_tpu
 from rtap_tpu.ops.step import chunk_step, fused_step, group_step, replicate_state
@@ -31,10 +31,13 @@ def _group_state(cfg):
 
 
 @pytest.mark.parametrize("learn", [True, False])
-def test_compiled_chunk_step_carries_the_scopes(learn):
-    cfg = scaled_cluster_preset(32)
+@pytest.mark.parametrize("cfg", [scaled_cluster_preset(32), node_preset(3)],
+                         ids=["cluster32", "node3"])
+def test_compiled_chunk_step_carries_the_scopes(cfg, learn):
+    # sparse pools and one field; the dense SP branch under a fused
+    # three-field encoder (the `node-3` deployment's program)
     hlo = chunk_step.lower(
-        _group_state(cfg), jnp.zeros((T, G, 1), jnp.float32),
+        _group_state(cfg), jnp.zeros((T, G, cfg.n_fields), jnp.float32),
         jnp.zeros((T, G), jnp.int32), cfg, learn=learn).compile().as_text()
     # under vmap JAX wraps the outermost entry: `vmap(rtap.encode)/...`,
     # but `vmap(jit(sp_step))/rtap.sp.overlap/...`
