@@ -49,7 +49,7 @@ from rtap_tpu.ops.tm_tpu import tm_step
 #:   rtap.tm.learn       reinforce/punish/grow                      (tm_tpu.tm_step)
 #:   rtap.tm.learn.rows  the workspace's rows moved by index: the form
 #:                       wide pool rows take (tm_tpu.wide_rows); absent
-#:                       where one-hot matmuls move them              (tm_tpu.tm_step)
+#:                       where one-hot moves carry them               (tm_tpu.tm_step)
 #:   rtap.tm.dendrite    dendrite activity for t+1                  (tm_tpu.tm_step)
 #:   rtap.reduce.health, rtap.reduce.predict, rtap.classifier
 #:                       the optional reducers / classifier         (_tick, _step_impl)

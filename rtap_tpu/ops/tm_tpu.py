@@ -25,10 +25,11 @@ same semantics down by an order of magnitude):
    dendrite sweep; docs/KERNELS.md, "TM membership").
 2. **Column-compact learning workspace.** Every learning segment lives in an
    active column, so the learning pass gathers the <= col_cap active columns
-   into a [Ac, K, S, M] workspace (with one-hot MXU matmuls at narrow pool
-   rows, by index at wide ones — below), does the compact reinforce/grow
-   pass there (selecting <= learn_cap segments with a cheap top_k over
-   Ac*K*S instead of C*K*S), and scatters the workspace back.
+   into a [Ac, K, S, M] workspace (by one-hot moves over the whole pool at
+   narrow pool rows — a compare-select reduce or an MXU matmul — by index
+   at wide ones; below), does the compact reinforce/grow pass there
+   (selecting <= learn_cap segments with a cheap top_k over Ac*K*S instead
+   of C*K*S), and scatters the workspace back.
 
 Step outline: dense column categorization (predicted / burst-matching /
 burst-new) -> workspace learning (alloc, reinforce, grow toward previous
@@ -49,6 +50,13 @@ block — each output element touches only its own operand columns), the
 dendrite conn/pot counts share one block-diagonal reduction, and
 tick-invariant operands (the reduction matrix) hoist out of the chunk scan
 via :func:`tm_invariants`.
+
+Within the narrow form the first stage — the gather of the active columns'
+pool rows — has a second exact form, picked the same way in one place
+(:func:`gather_by_select`, which says why): a compare-select reduce over the
+columns in the pools' own types where a pool row fills whole 128-lane tiles
+(384 lanes: the node presets), the one-hot matmul where it does not (192
+lanes: the cluster presets).
 
 Capacity bounds (col_cap active columns, learn_cap learning segments per
 step) are static-shape requirements of XLA; overflow beyond the bounds is
@@ -101,13 +109,46 @@ def _tpu_paths() -> bool:
 WIDE_ROW_LANES = 2048
 
 
+def _row_lanes(cfg: TMConfig) -> int:
+    """Synapse lanes of one pool row: a column's K*S*M slots."""
+    return (cfg.cells_per_column * cfg.max_segments_per_cell
+            * cfg.max_synapses_per_segment)
+
+
 def wide_rows(cfg: TMConfig) -> bool:
     """Does this shape take the wide-row form (indexed workspace moves,
-    [C, K, S, M] pools) rather than the narrow-row one (one-hot matmul moves,
-    flat [C, K*S*M] pools)? The one place the step's form is decided; both
-    forms are bit-identical to the oracle (tests/parity/test_tm_forms.py)."""
-    return (cfg.cells_per_column * cfg.max_segments_per_cell
-            * cfg.max_synapses_per_segment) >= WIDE_ROW_LANES
+    [C, K, S, M] pools) rather than the narrow-row one (one-hot moves over
+    the whole pool, flat [C, K*S*M] pools)? The one place the step's form is
+    decided; both forms are bit-identical to the oracle
+    (tests/parity/test_tm_forms.py)."""
+    return _row_lanes(cfg) >= WIDE_ROW_LANES
+
+
+#: Lanes of one vector register (and of one tile of the chip's memory layouts).
+LANE_TILE = 128
+
+
+def gather_by_select(cfg: TMConfig) -> bool:
+    """Within the narrow-row form: are the learning workspace's rows gathered
+    by a compare-select reduce in the pools' own types (True) or by the
+    one-hot MXU matmul over f32 casts of the pools (False)? The one place it
+    is decided, from the static shape: a pool row of whole 128-lane tiles
+    takes the select.
+
+    The matmul wants its contracting dimension — the columns — minor in its
+    operand, and its operand is the pools as the scan carries them, so it
+    holds every [C, K*S*M] leaf of the carry columns-minor. Where a row does
+    not fill its last tile (192 lanes: the cluster presets) the whole loop
+    body prefers that layout too (lanes-minor would pad 192 to 256), the
+    matmul costs no copy, and it is the faster gather: 7.43 against 7.76 ms a
+    group-tick at 256 columns, 1.234 against 1.247 at 32 (chip runs, PR 38).
+    Where rows are whole tiles (384 lanes: `node_preset`) everything else
+    runs lanes-minor and that one operand made the compiler re-lay seven
+    pool-sized leaves inside the scan body every tick — the TM's two pools in
+    and out, the dense SP's `perm` in and out and its `potential` mask in:
+    21.60 against 15.67 ms a group-tick (same runs). Both forms are
+    bit-identical to the oracle (tests/parity/test_tm_forms.py)."""
+    return not wide_rows(cfg) and _row_lanes(cfg) % LANE_TILE == 0
 
 
 # TM state keys the narrow-row form runs flat: key -> how many trailing dims
@@ -399,6 +440,29 @@ def _gather_rows_i32(x: jnp.ndarray, oh_b: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(oh_b[:, :, None], x[None, :, :], 0).sum(1)
 
 
+def _gather_rows_or(xs: tuple, oh_b: jnp.ndarray) -> tuple:
+    """One-hot row gather of equal-shaped tensors in their OWN types, all in
+    one pass: oh_b [R, C] bool one-hot rows, each x [C, F] -> each [R, F].
+
+    A select of the hit row against zeros, OR-reduced over C as ONE variadic
+    reduce: exact in any type (at most one term of an output element is not
+    zero; an f32 permanence rides as its bit pattern), a row of zeros where
+    `oh_b`'s row hits nothing (the fills — what the one-hot matmul gives
+    there), and one fusion whose [R, C, F] selects never reach HBM. On a v5e
+    the OR costs 2/3 of a 16-bit unsigned `max`, and two pools in one reduce
+    73 % of two reduces (chip runs, PR 38; docs/KERNELS.md, "The workspace
+    gather and the carry's layout")."""
+    bits = tuple(jax.lax.bitcast_convert_type(x, jnp.uint32) if x.dtype == jnp.float32 else x
+                 for x in xs)
+    picked = tuple(jnp.where(oh_b[:, :, None], b[None, :, :], jnp.zeros((), b.dtype))
+                   for b in bits)
+    out = jax.lax.reduce(
+        picked, tuple(jnp.zeros((), b.dtype) for b in bits),
+        lambda p, q: tuple(a | b for a, b in zip(p, q)), (1,))
+    return tuple(jax.lax.bitcast_convert_type(o, x.dtype) if o.dtype != x.dtype else o
+                 for o, x in zip(out, xs))
+
+
 # rtap: twin[TMOracle] — the oracle TM is stateful (TMOracle.compute)
 @partial(jax.jit, static_argnames=("cfg", "learn"))
 def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = True,
@@ -544,24 +608,40 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
                         learn_mask.reshape(C, -1)[idx_c] & (col_ids < C)[:, None]
                     ).reshape(Ac, K, S)
             else:
-                # ONE one-hot MXU pass gathers presyn + perm + seg_pot together
-                # (fused-region consolidation: each output element of the
-                # concatenated matmul touches only its own operand block, so
-                # the values are bitwise those of the three separate gathers;
-                # seg_pot <= M << 2^24 and cell ids < 2^24 are f32-exact)
-                KSM = K * S * M
-                cat = jnp.concatenate(
-                    [
-                        presyn.reshape(C, -1).astype(jnp.float32),
-                        syn_perm.reshape(C, -1).astype(jnp.float32),
-                        state["seg_pot"].reshape(C, -1).astype(jnp.float32),
-                    ],
-                    axis=1,
-                )  # [C, 2*KSM + K*S]
-                g = _gather_rows_f32(cat, col_oh)  # [Ac, 2*KSM + K*S]
-                ws_presyn = jnp.round(g[:, :KSM]).astype(jnp.int32)  # [Ac, K*S*M]
-                ws_perm = g[:, KSM:2 * KSM]  # [Ac, K*S*M]
-                ws_pot = jnp.round(g[:, 2 * KSM:]).astype(jnp.int32).reshape(Ac, K, S)
+                if gather_by_select(cfg):
+                    # the rows picked by compare-select in the pools' own
+                    # types, both pools in one OR-reduce over C: no operand
+                    # wants the columns minor, so the scan's carry keeps every
+                    # [C, K*S*M] leaf lanes-minor like the rest of the loop
+                    # body (docs/KERNELS.md, "The workspace gather and the
+                    # carry's layout"); the casts are of [Ac, K*S*M], not of
+                    # the pools
+                    ws_presyn, ws_perm = _gather_rows_or((presyn, syn_perm), col_oh_b)
+                    ws_presyn = ws_presyn.astype(jnp.int32)
+                    ws_perm = ws_perm.astype(jnp.float32)
+                    ws_pot = _gather_rows_i32(
+                        state["seg_pot"].reshape(C, -1).astype(jnp.int32), col_oh_b)
+                else:
+                    # ONE one-hot MXU pass gathers presyn + perm + seg_pot
+                    # together (fused-region consolidation: each output element
+                    # of the concatenated matmul touches only its own operand
+                    # block, so the values are bitwise those of the three
+                    # separate gathers; seg_pot <= M << 2^24 and cell ids <
+                    # 2^24 are f32-exact)
+                    KSM = K * S * M
+                    cat = jnp.concatenate(
+                        [
+                            presyn.reshape(C, -1).astype(jnp.float32),
+                            syn_perm.reshape(C, -1).astype(jnp.float32),
+                            state["seg_pot"].reshape(C, -1).astype(jnp.float32),
+                        ],
+                        axis=1,
+                    )  # [C, 2*KSM + K*S]
+                    g = _gather_rows_f32(cat, col_oh)  # [Ac, 2*KSM + K*S]
+                    ws_presyn = jnp.round(g[:, :KSM]).astype(jnp.int32)  # [Ac, K*S*M]
+                    ws_perm = g[:, KSM:2 * KSM]  # [Ac, K*S*M]
+                    ws_pot = jnp.round(g[:, 2 * KSM:]).astype(jnp.int32)
+                ws_pot = ws_pot.reshape(Ac, K, S)
                 # seg_last carries unbounded iteration stamps (> 2^24 possible):
                 # it keeps the exact integer gather
                 ws_last = _gather_rows_i32(seg_last.reshape(C, -1), col_oh_b).reshape(Ac, K, S)

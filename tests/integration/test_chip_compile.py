@@ -25,7 +25,7 @@ import numpy as np
 import pytest
 
 import rtap_tpu.ops.tm_tpu as tm_tpu
-from rtap_tpu.config import cluster_preset, nab_preset, scaled_cluster_preset
+from rtap_tpu.config import cluster_preset, nab_preset, node_preset, scaled_cluster_preset
 from rtap_tpu.models.state import init_state
 
 G = 128
@@ -140,6 +140,52 @@ def test_cluster_chunk_step_sweeps_the_pool_in_one_fusion(v5e, preset):
     both = [r for r in results
             if re.search("s16" + pool, r) and re.search("u16" + pool, r)]
     assert len(both) == 1 and both[0].count("pred[") == 2, both
+
+
+def _scan_body(text: str) -> str:
+    """The computation the program's `while` (the scan over the chunk's
+    ticks) names as its body — the entry computation's own loop, not the
+    small ones inside sorts and cumulative sums."""
+    entry = text[text.index("\nENTRY "):]
+    bodies = re.findall(r" while\(.*?body=%?([\w.\-]+)", entry)
+    assert bodies, "the compiled chunk program holds no while loop"
+    found = []
+    for name in bodies:
+        start = re.search(rf"^%?{re.escape(name)} \(", text, re.M).start()
+        found.append(text[start:text.index("\n}\n", start)])
+    return max(found, key=len)
+
+
+@pytest.mark.parametrize("preset", ["cluster", "scaled32", "node3"])
+def test_chunk_step_relays_no_pool_inside_the_scan(v5e, preset):
+    """The scan's carry and its loop body agree on the layout of every
+    `[G, C, K*S*M]` leaf: the `while` body holds no `copy` with a 16-bit
+    pool-shaped result. Where a pool row fills whole 128-lane tiles
+    (`node_preset(3)`: 384 lanes) the workspace gather's one-hot matmul was
+    the one consumer that wanted the columns minor, and held the carry of
+    all four such leaves — the dense SP's `perm` among them — in a layout
+    the rest of the body re-laid every tick: eight pool-shaped copies, six
+    of them 16-bit, 5.3 ms of a 21.6 ms group-tick (ISSUE 38; PERF.md §6).
+    There the gather is a compare-select reduce in the pools' own types
+    (`tm_tpu.gather_by_select`), so no f32 pool exists in the body either;
+    at 192 lanes the matmul stays and the body shares its layout."""
+    from rtap_tpu.ops.step import chunk_step
+
+    cfg = {"cluster": cluster_preset, "scaled32": lambda: scaled_cluster_preset(32),
+           "node3": lambda: node_preset(3)}[preset]()
+    tm = cfg.tm
+    lanes = tm.cells_per_column * tm.max_segments_per_cell * tm.max_synapses_per_segment
+    assert tm_tpu.gather_by_select(tm) == (preset == "node3")
+    body = _scan_body(chunk_step.lower(*_step_args(cfg, v5e, T=2), cfg,
+                                       learn=True).compile().as_text())
+    results = re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = (.+?) ([\w\-]+)\(", body, re.M)
+    assert len(results) > 200  # a whole tick's instructions were read
+    pool = rf"\[{G},{cfg.sp.columns},{lanes}\]"
+    assert any(re.match("[su]16" + pool, ty) for ty, _ in results)  # the pattern bites
+    assert not [ty for ty, op in results
+                if op == "copy" and re.match("[su]16" + pool, ty)]
+    if preset == "node3":
+        assert not [ty for ty, _ in results if re.search("f32" + pool, ty)]
 
 
 def test_nab_width_step_scatters_whole_rows_only(v5e):

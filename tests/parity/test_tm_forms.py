@@ -1,10 +1,14 @@
 """The TM step has two forms and `tm_tpu.wide_rows(cfg)` picks one from the
 static shape: below `WIDE_ROW_LANES` synapse lanes a pool row, one-hot matmul
 moves over flat pools; at or above it, indexed moves over [C, K, S, M] pools.
-Both are held to the numpy oracle here, end to end (encode -> SP -> TM -> raw
-score), bit for bit, in every permanence domain, under both `_compact_ids`
-formulations (`FORCE_TPU_PATHS`: the one the chip runs and the one the CPU
-backend picks), learning and inferring.
+Within the narrow-row form `tm_tpu.gather_by_select(cfg)` picks the workspace
+gather the same way: a compare-select reduce in the pools' own types where a
+row fills whole 128-lane tiles (128, 384, 512 lanes here), the one-hot matmul
+where it does not (192). All are held to the numpy oracle here, end to end
+(encode -> SP -> TM -> raw score), bit for bit, in every permanence domain,
+under both `_compact_ids` formulations (`FORCE_TPU_PATHS`: the one the chip
+runs and the one the CPU backend picks), learning and inferring, and through
+the stream-group programs (`group_step`, `chunk_step`).
 
 Nothing but the shape selects a form: no environment variable, no setter
 (the last two tests)."""
@@ -36,8 +40,12 @@ STATE_KEYS = ("perm", "boost", "overlap_duty", "active_duty", "presyn", "syn_per
 def form_cfg(rows: str, perm_bits: int) -> ModelConfig:
     """narrow: 256 columns x 8 cells x 4 segments x 16 synapses = 512 lanes a
     row; wide: 64 columns (small, so the oracle stays fast) x 8 x 8 x 32 =
-    2,048 lanes, the first width on the wide side of the line."""
-    columns, k, S, M = {"narrow": (256, 10, 4, 16), "wide": (64, 6, 8, 32)}[rows]
+    2,048 lanes, the first width on the wide side of the line; lanes<n>:
+    narrow rows of n lanes at 64 columns — 128 (one tile), 192 (the cluster
+    presets': a tile and a half), 384 (the node presets': three tiles)."""
+    columns, k, S, M = {"narrow": (256, 10, 4, 16), "wide": (64, 6, 8, 32),
+                        "lanes128": (64, 6, 2, 8), "lanes192": (64, 6, 2, 12),
+                        "lanes384": (64, 6, 4, 12)}[rows]
     return ModelConfig(
         rdse=RDSEConfig(size=128, active_bits=11, resolution=0.7),
         date=DateConfig(time_of_day_width=7, time_of_day_size=18, weekend_width=3),
@@ -46,6 +54,10 @@ def form_cfg(rows: str, perm_bits: int) -> ModelConfig:
                     max_segments_per_cell=S, max_synapses_per_segment=M,
                     new_synapse_count=8, learn_cap=48, perm_bits=perm_bits),
     )
+
+
+#: the shapes of `form_cfg` whose workspace gather is the compare-select reduce
+SELECT_ROWS = ("narrow", "lanes128", "lanes384")
 
 
 @pytest.fixture(scope="module", params=[True, False], ids=["tpu_paths", "cpu_paths"])
@@ -61,10 +73,11 @@ def tpu_paths(request):
 @exact_only
 @pytest.mark.parametrize("learn", ["learning", "inferring"])
 @pytest.mark.parametrize("perm_bits", [0, 16, 8])
-@pytest.mark.parametrize("rows", ["narrow", "wide"])
+@pytest.mark.parametrize("rows", ["narrow", "wide", "lanes128", "lanes192", "lanes384"])
 def test_form_equals_the_oracle_end_to_end(tpu_paths, rows, perm_bits, learn):
     cfg = form_cfg(rows, perm_bits)
     assert tm_tpu.wide_rows(cfg.tm) == (rows == "wide")
+    assert tm_tpu.gather_by_select(cfg.tm) == (rows in SELECT_ROWS)
     cpu = HTMModel(cfg, seed=17, backend="cpu")
     dev = HTMModel(cfg, seed=17, backend="tpu")
     n = 160
@@ -81,6 +94,50 @@ def test_form_equals_the_oracle_end_to_end(tpu_paths, rows, perm_bits, learn):
         np.testing.assert_array_equal(np.asarray(state[k]), np.asarray(cpu.state[k]), err_msg=k)
     assert int(state["tm_overflow"]) == 0
     assert (np.asarray(state["presyn"]) >= 0).sum() > 100  # it really learned
+
+
+@exact_only
+@pytest.mark.parametrize("program", ["group_step", "chunk_step"])
+@pytest.mark.parametrize("rows", ["lanes128", "lanes192", "lanes384"])
+def test_gather_equals_the_oracle_through_the_group_programs(rows, program):
+    """Both gathers under the programs the service runs — vmapped over a
+    group's streams, and inside the chunk's scan: three streams of one group
+    against three oracles, raw scores tick by tick and every leaf after."""
+    from rtap_tpu.models.htm_model import oracle_record_step
+    from rtap_tpu.models.oracle.temporal_memory import TMOracle
+    from rtap_tpu.ops.step import chunk_step, group_step, replicate_state
+
+    cfg = form_cfg(rows, 16)
+    assert tm_tpu.gather_by_select(cfg.tm) == (rows in SELECT_ROWS)
+    G, T, n = 3, 8, 96
+    gstate = jax.device_put(replicate_state(init_state(cfg, seed=5), G))
+    oracles = []
+    for _ in range(G):
+        st = init_state(cfg, seed=5)
+        oracles.append((st, TMOracle(st, cfg.tm)))
+    vals = np.stack([make_values(n, 1, seed=31 + g)[:, 0] for g in range(G)], 1)
+    ts = 1_700_000_000 + 300 * np.arange(n, dtype=np.int32)
+    raws = []
+    if program == "group_step":
+        for i in range(n):
+            gstate, raw = group_step(gstate, vals[i][:, None], np.full(G, ts[i]), cfg)
+            raws.append(np.asarray(raw))
+    else:
+        for c in range(0, n, T):
+            gstate, raw = chunk_step(gstate, vals[c:c + T][:, :, None],
+                                     np.repeat(ts[c:c + T, None], G, 1), cfg)
+            raws.extend(np.asarray(raw))
+    for i in range(n):
+        for g, (st, tm) in enumerate(oracles):
+            want = oracle_record_step(cfg, st, tm, vals[i, g:g + 1], int(ts[i]), True)
+            assert float(want) == float(raws[i][g]), f"tick {i} stream {g}"
+    dev = jax.device_get(gstate)
+    for k in STATE_KEYS:
+        for g, (st, _) in enumerate(oracles):
+            np.testing.assert_array_equal(np.asarray(dev[k][g]), np.asarray(st[k]),
+                                          err_msg=f"{k} stream {g}")
+    assert int(np.asarray(dev["tm_overflow"]).sum()) == 0
+    assert (np.asarray(dev["presyn"]) >= 0).sum() > 100 * G  # they really learned
 
 
 _LOWER = """

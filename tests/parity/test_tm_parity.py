@@ -130,21 +130,72 @@ def test_tm_parity_random_stream_with_eviction(rows):
 
 
 @pytest.mark.quick
-@pytest.mark.parametrize("S,M,wide", [(2, 6, False), (16, 32, True)],
-                         ids=["narrow", "wide"])
-def test_tm_parity_explicit_layouts(S, M, wide):
+@pytest.mark.parametrize("S,M,wide,select", [
+    (2, 6, False, False), (16, 32, True, False),
+    (4, 8, False, True), (4, 12, False, False), (8, 12, False, True),
+], ids=["narrow", "wide", "lanes128", "lanes192", "lanes384"])
+def test_tm_parity_explicit_layouts(S, M, wide, select):
     """Full state parity in BOTH forms where the shape itself picks the form
     (the other tests move the line under one shape): 48 lanes a row, and
-    2,048."""
+    2,048 — and, within the narrow form, under both workspace gathers where
+    the shape picks the gather: the compare-select reduce at rows of whole
+    128-lane tiles (128, 384), the one-hot matmul at 192 (and at 48)."""
     C, cfg = 32, TMConfig(
         cells_per_column=4, activation_threshold=2, min_threshold=1,
         max_segments_per_cell=S, max_synapses_per_segment=M,
         new_synapse_count=4, learn_cap=32,
     )
     assert tm_tpu.wide_rows(cfg) == wide
+    assert tm_tpu.gather_by_select(cfg) == select
     rng = np.random.default_rng(29)
     seq = [_pattern(rng, C, 4) for _ in range(60)]
     _run_parity(C, cfg, seq)
+
+
+@pytest.mark.parametrize("perm", ["u16", "u8", "f32"])
+@pytest.mark.parametrize("ids", ["i16", "i32"])
+@pytest.mark.parametrize("hits", ["sparse", "full_cap", "all_fills"])
+def test_gather_rows_or_picks_the_rows_exactly(hits, ids, perm):
+    """The gather alone: `_gather_rows_or` against `pool[col_ids]`, a row of
+    zeros where `col_ids` holds its fill C (the row the one-hot matmul gave
+    there), the pools in their own types and together in one call — cell ids
+    up to the type's largest beside the -1 empties, permanences over the
+    whole range of their domain (quanta >= 2^15 set a 16-bit sign bit; f32
+    rides as its bit pattern, -0.0 kept) — and equal to the f32 one-hot
+    matmul's rows wherever that is exact."""
+    C, F, Ac = 24, 40, 6
+    rng = np.random.default_rng(43)
+    id_dt = {"i16": np.int16, "i32": np.int32}[ids]
+    top = np.iinfo(id_dt).max
+    presyn = rng.integers(-1, 200, (C, F)).astype(id_dt)
+    presyn[rng.random((C, F)) < 0.3] = -1
+    presyn[rng.random((C, F)) < 0.1] = top
+    presyn[3, :4] = [top, top - 1, -1, 0]
+    if perm == "f32":
+        syn_perm = rng.random((C, F)).astype(np.float32)
+        syn_perm[3, :3] = [-0.0, 1.0, np.float32(1e-38)]
+    else:
+        p_dt = {"u16": np.uint16, "u8": np.uint8}[perm]
+        syn_perm = rng.integers(0, np.iinfo(p_dt).max + 1, (C, F)).astype(p_dt)
+        syn_perm[3, :3] = [0, np.iinfo(p_dt).max, np.iinfo(p_dt).max // 2 + 1]
+    n_hit = {"sparse": 3, "full_cap": Ac, "all_fills": 0}[hits]
+    col_ids = np.full(Ac, C, np.int32)
+    col_ids[:n_hit] = np.sort(rng.choice(C, n_hit, replace=False))
+    if n_hit:
+        col_ids[0] = 3  # the row of edge values; ascending order is not needed
+    oh_b = jnp.asarray(col_ids[:, None] == np.arange(C)[None, :])
+    got_pre, got_perm = jax.jit(tm_tpu._gather_rows_or)(
+        (jnp.asarray(presyn), jnp.asarray(syn_perm)), oh_b)
+    valid = col_ids < C
+    for got, pool in ((got_pre, presyn), (got_perm, syn_perm)):
+        got = np.asarray(got)
+        assert got.dtype == pool.dtype and got.shape == (Ac, F)
+        want = np.where(valid[:, None], pool[np.minimum(col_ids, C - 1)], 0).astype(pool.dtype)
+        np.testing.assert_array_equal(got.view(f"u{pool.itemsize}"), want.view(f"u{pool.itemsize}"))
+    if ids == "i16":  # ids < 2^24: the matmul the other narrow rows keep is exact too
+        mm = tm_tpu._gather_rows_f32(jnp.asarray(presyn, jnp.float32), oh_b.astype(jnp.float32))
+        np.testing.assert_array_equal(np.round(np.asarray(mm)).astype(np.int32),
+                                      np.asarray(got_pre, np.int32))
 
 
 def test_tm_parity_punishment_path(rows):
