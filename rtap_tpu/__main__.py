@@ -1285,7 +1285,11 @@ def main(argv: list[str] | None = None) -> int:
                         "ui.perfetto.dev; docs/POSTMORTEM.md). Tracing is "
                         "a bounded in-memory ring, near-zero overhead — "
                         "also served live at GET /trace?last=N with "
-                        "--obs-port")
+                        "--obs-port. Spans: tick, source, membership, "
+                        "dispatch, collect, emit, checkpoint, per-group "
+                        "dispatch/collect, aot_warm, gc — the ring names "
+                        "of the rtap.* vocabulary (obs/trace.py:SPANS; "
+                        "docs/TELEMETRY.md), keyed by tick")
     p.add_argument("--trace-ring", type=int, default=65536,
                    help="span-ring capacity in records PER WRITER THREAD "
                         "(~33 B each); older records are overwritten and "
@@ -1431,10 +1435,17 @@ def main(argv: list[str] | None = None) -> int:
                    help="wrap the serve window in jax.profiler.trace "
                         "writing the XLA device trace (.xplane.pb) to this "
                         "directory: device ops named by the step's "
-                        "`rtap.*` scopes, and the stream groups' "
-                        "`rtap.group.*` phases on its host plane. Pairs "
-                        "with --trace-out (the loop's own spans, Chrome "
-                        "JSON on perf_counter): the device trace's "
+                        "`rtap.*` scopes and, on its host plane, the "
+                        "loop's ticks and phases (`rtap.loop.*`), the "
+                        "stream groups' chunk phases (`rtap.group.*`), "
+                        "the ingest handlers (`rtap.ingest.*`) and "
+                        "garbage collections (`rtap.host.gc`), joined "
+                        "by their arguments `tick`, `group` (first "
+                        "stream id), `seq` (obs/trace.py:SPANS; "
+                        "docs/TELEMETRY.md). Pairs "
+                        "with --trace-out (the same spans in the loop's "
+                        "ring, Chrome JSON on perf_counter): the device "
+                        "trace's "
                         "`rtap.sync` annotation starts at the host "
                         "trace's `profiler_sync` instant "
                         "(otherData.profiler_sync_perf), which puts the "

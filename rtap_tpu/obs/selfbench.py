@@ -107,15 +107,19 @@ def measure_trace(n: int = 50_000, cadence_s: float = 1.0,
     measured anyway for the record)."""
     from rtap_tpu.obs.flight import FlightRecorder
     from rtap_tpu.obs.metrics import TelemetryRegistry
-    from rtap_tpu.obs.trace import TraceRecorder
+    from rtap_tpu.obs.trace import TraceRecorder, span
 
     tr = TraceRecorder(capacity=4096)
     t0 = time.perf_counter()
     # warm the shard + name intern out of the measurement (first-op cost)
     tr.add_span("dispatch", 0, t0, 0.001, group=3)
     tr.add_instant("missed_tick", 0, {"elapsed_s": 1.2})
-    span_s = _time_op(lambda: tr.add_span("dispatch", 1, t0, 0.001, group=3),
-                      n)
+    # what the loop pays for one span: the seam (obs/trace.py:span) with
+    # the ring attached and no profiler running — its two clock readings,
+    # the ring append, JAX's flag check where JAX is loaded
+    span_s = _time_op(
+        lambda: span("rtap.loop.group.dispatch", tr, tick=1, group="s0",
+                     seq=1, track=3).begin().end(), n)
     n_inst = max(1, n // 10)
     inst_s = _time_op(
         lambda: tr.add_instant("missed_tick", 1, {"elapsed_s": 1.2}), n_inst)

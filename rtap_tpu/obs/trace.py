@@ -33,18 +33,76 @@ Span names come from a small vocabulary (the six loop phases, "tick",
 event kinds); the intern table is bounded at ``max_names`` and overflow
 maps to ``"<other>"`` so a pathological caller cannot grow host memory
 through the name channel.
+
+**The seam.** Every host span of the served path — the loop's phases and
+per-group children, the stream groups' chunk phases, the ingest handlers,
+the AOT warm-up, garbage collections — is written through :class:`span`:
+one clock reading at each end serves this recorder's ring (where the
+caller hands one) and a ``jax.profiler.TraceAnnotation`` named from
+:data:`SPANS` (where a JAX profiler trace is running: ``serve
+--jax-trace``, the benchmark's ``--trace 1``), so the loop's ticks, the
+groups' phases and the device's ops lie on one clock in one file, joined
+by ``tick`` / ``group`` / ``seq``. There is no second span system.
 """
 
 from __future__ import annotations
 
+import gc
 import json
 import os
+import sys
 import threading
 import time
+import weakref
 
 import numpy as np
 
-__all__ = ["TraceRecorder", "REC_DTYPE"]
+__all__ = ["TraceRecorder", "REC_DTYPE", "SPANS", "span",
+           "install_gc_hook", "uninstall_gc_hook"]
+
+#: The host-span vocabulary — every name :class:`span` writes into a JAX
+#: profiler trace, as ``ops/step.py:SCOPES`` is for the device's ops.
+#: Identifiers (annotation arguments): ``tick`` = the loop's tick index,
+#: ``group`` = the group's FIRST STREAM ID, ``seq`` = the chunk handle's
+#: sequence number; so tick -> (group, seq) -> the chunk's four phases ->
+#: its execution on the device is one chain. The benchmark's readers match
+#: these names (benchmark/layer_metrics/*.json); docs/TELEMETRY.md
+#: "Per-tick tracing" lists them for operators.
+SPANS = (
+    # service/loop.py:live_loop — one tick and its phases (ring: the last
+    # component, "tick" / "source" / ...; `sleep` is annotation-only)
+    "rtap.loop.tick",
+    "rtap.loop.source",
+    "rtap.loop.membership",
+    "rtap.loop.dispatch",
+    "rtap.loop.collect",
+    "rtap.loop.emit",
+    "rtap.loop.checkpoint",
+    "rtap.loop.sleep",
+    # ... and its per-group children (ring: "dispatch" / "collect" on the
+    # group's own track)
+    "rtap.loop.group.dispatch",
+    "rtap.loop.group.collect",
+    # service/registry.py:StreamGroup — the chunk path's four phases
+    "rtap.group.stage",
+    "rtap.group.enqueue",
+    "rtap.group.fetch",
+    "rtap.group.likelihood",
+    # service/sources.py:TcpJsonlSource — a handler's locked parse of one
+    # recv batch (`bytes`, `wait_us`), the loop's locked copy-and-drain
+    "rtap.ingest.feed",
+    "rtap.ingest.snapshot",
+    # service/aot.py:prewarm — one per program executed (`program`)
+    "rtap.aot.warm",
+    # one per garbage collection of this process (`generation`, `collected`)
+    "rtap.host.gc",
+)
+
+#: the name a span takes in a TraceRecorder ring (the names
+#: benchmark/traffic_kinds/live.py and the Chrome export's readers know)
+_RING_NAME = {name: name.rpartition(".")[2] for name in SPANS}
+_RING_NAME["rtap.aot.warm"] = "aot_warm"
+_RING_NAME["rtap.host.gc"] = "gc"
 
 #: one trace record: interned name id, kind (0 span / 1 instant), tick
 #: correlation id, start offset vs the recorder epoch (perf_counter
@@ -61,16 +119,21 @@ REC_DTYPE = np.dtype([
 _KIND_SPAN = 0
 _KIND_INSTANT = 1
 
+#: every TraceRecorder alive: a garbage collection is a loop-track span in
+#: each of them (weak: a recorder dies with its owner)
+_RECORDERS: "weakref.WeakSet[TraceRecorder]" = weakref.WeakSet()
+
 
 class _Shard:
     """One writer thread's private ring (no cross-thread writes)."""
 
-    __slots__ = ("recs", "aux", "n")
+    __slots__ = ("recs", "aux", "n", "tick")
 
     def __init__(self, capacity: int):
         self.recs = np.zeros(capacity, REC_DTYPE)
         self.aux: list = [None] * capacity  # instant payloads (json str)
         self.n = 0  # total appended; ring index = n % capacity
+        self.tick = -1  # of the newest record (TraceRecorder.latest_tick)
 
 
 class TraceRecorder:
@@ -104,6 +167,7 @@ class TraceRecorder:
         self._names: dict[str, int] = {"<other>": 0}
         self._names_rev: list[str] = ["<other>"]
         self._names_lock = threading.Lock()
+        _RECORDERS.add(self)
 
     # ------------------------------------------------------------ write --
     def _shard(self) -> _Shard:
@@ -128,15 +192,19 @@ class TraceRecorder:
         return nid
 
     def add_span(self, name: str, tick: int, t0: float, dur: float,
-                 group: int = -1) -> None:
+                 group: int = -1, args_json: str | None = None) -> None:
         """Record one completed span. `t0` is a ``time.perf_counter()``
         reading (the caller already holds one from its own phase
-        accounting — re-reading the clock here would double the cost)."""
+        accounting — re-reading the clock here would double the cost).
+        `args_json` (a JSON object, already serialized) rides into the
+        Chrome export's ``args``, truncated like an instant's payload."""
         shard = self._shard()
         i = shard.n % self.capacity
         shard.recs[i] = (self._name_id(name), _KIND_SPAN, tick,
                          t0 - self.epoch_perf, dur, group)
-        shard.aux[i] = None
+        shard.aux[i] = None if args_json is None \
+            else args_json[: self.max_arg_bytes]
+        shard.tick = tick
         shard.n += 1
 
     def add_instant(self, name: str, tick: int, fields: dict | None = None,
@@ -159,6 +227,7 @@ class TraceRecorder:
             except (TypeError, ValueError):
                 aux = repr(fields)[: self.max_arg_bytes]
         shard.aux[i] = aux
+        shard.tick = tick
         shard.n += 1
 
     def profiler_sync(self, t: float) -> None:
@@ -181,6 +250,13 @@ class TraceRecorder:
             except RuntimeError:  # dict resize under a brand-new writer
                 continue
         return list(dict(self._shards).values())
+
+    def latest_tick(self) -> int:
+        """The newest tick any writer thread has recorded under (-1 before
+        the first): what a record made outside the loop — a garbage
+        collection — is filed under, so tick windows keep or drop it with
+        the tick it fell in."""
+        return max((s.tick for s in self._shard_list()), default=-1)
 
     @property
     def total(self) -> int:
@@ -309,3 +385,142 @@ class TraceRecorder:
                 "dropped_records": self.dropped,
             },
         }
+
+
+# ------------------------------------------------------------- the seam --
+_trace_me = None  # jax.profiler.TraceAnnotation, once JAX is loaded
+
+
+def _profiling():
+    """``jax.profiler.TraceAnnotation`` while a JAX profiler trace is
+    running, else None. JAX is used only where something else has already
+    imported it: the cpu-oracle serve path never loads it, and has no
+    profiler to write into."""
+    global _trace_me
+    if _trace_me is None:
+        # getattr twice: another thread may be half-way through `import jax`
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _trace_me = getattr(profiler, "TraceAnnotation", None)
+        if _trace_me is None:
+            return None
+    return _trace_me if _trace_me.is_enabled() else None  # JAX's flag, ~20 ns
+
+
+class span:
+    """One host span of the served path, named from :data:`SPANS`.
+
+    A context manager; the loop, whose phases are stretches between clock
+    readings it already books (not lexical blocks), uses the pair
+    ``sp = span(...).begin()`` / ``t1 = sp.end()`` and reads ``sp.t0``.
+    ``perf_counter`` is read once at each end; that reading goes to
+    `recorder`'s ring (when one is given: ring name = the vocabulary
+    name's last component, ring track = `track`, the group INDEX) and
+    brackets the ``TraceAnnotation`` opened when a JAX profiler trace is
+    running (arguments: `tick`, `group`, `seq` where given — ``group`` is
+    the group's first stream id, what ``rtap.group.*`` carry — and
+    `counts`). With neither it is a None check and JAX's flag check.
+    """
+
+    __slots__ = ("name", "recorder", "tick", "group", "seq", "track",
+                 "counts", "t0", "_ann")
+
+    def __init__(self, name: str, recorder: "TraceRecorder | None" = None,
+                 tick: int = -1, group=-1, seq: int = -1, track: int = -1,
+                 **counts):
+        self.name, self.recorder, self.tick = name, recorder, tick
+        self.group, self.seq, self.track = group, seq, track
+        self.counts = counts
+
+    def begin(self) -> "span":
+        if _gc_hook is None:
+            install_gc_hook()  # once a process, on the seam's first use
+        annotation = _profiling()
+        if annotation is None:
+            self._ann = None
+        else:
+            args = self.counts
+            if self.tick != -1:
+                args["tick"] = self.tick
+            if self.group != -1:
+                args["group"] = self.group
+            if self.seq != -1:
+                args["seq"] = self.seq
+            self._ann = annotation(self.name, **args)
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def end(self, dur: float | None = None, record: bool = True,
+            **counts) -> float:
+        """Close the span -> the ``perf_counter`` reading that ended it.
+        `dur` overrides the ring's duration (a phase that books less than
+        its stretch: nested drains own their own spans), `record=False`
+        skips the ring; `counts` known only now (a lock wait, a collected
+        count) join the annotation's arguments."""
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            if counts:
+                self._ann.set_metadata(**counts)
+            self._ann.__exit__(None, None, None)
+        if self.recorder is not None and record:
+            self.recorder.add_span(
+                _RING_NAME[self.name], self.tick, self.t0,
+                t1 - self.t0 if dur is None else dur, self.track)
+        return t1
+
+    __enter__ = begin
+
+    def __exit__(self, *exc) -> None:
+        self.end()
+
+
+# --------------------------------------------------- garbage collections --
+_gc_hook = None  # None: never installed; True: installed; False: removed
+_gc_open = None  # (t0, annotation) of the collection in progress
+_gc_lock = threading.Lock()
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: one ``rtap.host.gc`` span per collection — an
+    annotation under a running profiler, a loop-track ``gc`` span in every
+    TraceRecorder alive. Collections do not nest (the collector is not
+    re-entrant), so one open slot serves every thread."""
+    global _gc_open
+    if phase == "start":
+        ann = _profiling()
+        if ann is not None:
+            ann = ann("rtap.host.gc", generation=info["generation"])
+            ann.__enter__()
+        _gc_open = (time.perf_counter(), ann)
+    elif _gc_open is not None:
+        t1 = time.perf_counter()
+        (t0, ann), _gc_open = _gc_open, None
+        if ann is not None:
+            ann.set_metadata(collected=info["collected"])
+            ann.__exit__(None, None, None)
+        for rec in list(_RECORDERS):
+            rec.add_span(
+                "gc", rec.latest_tick(), t0, t1 - t0,
+                args_json='{"generation": %d, "collected": %d}' % (
+                    info["generation"], info["collected"]))
+
+
+def install_gc_hook() -> None:
+    """Install the process's one garbage-collection hook (idempotent; the
+    seam does it on first use, so every process that writes a span — the
+    replay path, which has no TraceRecorder, too — names its own
+    collections)."""
+    global _gc_hook
+    with _gc_lock:
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+        _gc_hook = True
+
+
+def uninstall_gc_hook() -> None:
+    """Remove the hook; it stays off until :func:`install_gc_hook`."""
+    global _gc_hook, _gc_open
+    with _gc_lock:
+        if _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)
+        _gc_hook, _gc_open = False, None

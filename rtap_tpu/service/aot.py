@@ -36,6 +36,7 @@ compiles after tick 0" via the jit cache sizes themselves.
 from __future__ import annotations
 
 from rtap_tpu.obs import get_registry
+from rtap_tpu.obs.trace import span
 
 
 def knowable_programs(groups, micro_chunk: int, learn: bool,
@@ -60,8 +61,15 @@ def knowable_programs(groups, micro_chunk: int, learn: bool,
 
 
 def prewarm(groups, micro_chunk: int, learn: bool, degradation=None,
-            include_claim: bool = False, seed: int = 0) -> set[tuple]:
+            include_claim: bool = False, seed: int = 0,
+            trace=None) -> set[tuple]:
     """Compile-and-execute every knowable program on throwaway state.
+
+    Each program is one `rtap.aot.warm` span (obs/trace.py:SPANS) from its
+    call to its result on the device — trace, compile or cache load,
+    execution — recorded in `trace` (live_loop's TraceRecorder, loop track,
+    name ``aot_warm``): no profiler runs this early, so the ring is how the
+    launcher's time is read from inside.
 
     Returns the warmed key set ((m, config, learn) — live_loop seeds its
     single-flight `warmed` set with it so its own bookkeeping agrees).
@@ -76,8 +84,8 @@ def prewarm(groups, micro_chunk: int, learn: bool, degradation=None,
                      and getattr(g, "mesh", None) is None]
     if not device_groups:
         return set()
+    import jax
     import jax.numpy as jnp
-    import numpy as np
 
     from rtap_tpu.models.state import init_state
     from rtap_tpu.ops.step import (
@@ -113,7 +121,7 @@ def prewarm(groups, micro_chunk: int, learn: bool, degradation=None,
                   for g in device_groups if g.cfg == cfg), default=0)
         for cfg in by_cfg
     }
-    for cfg, mls in by_cfg.items():
+    for ci, (cfg, mls) in enumerate(by_cfg.items()):
         G = next(g.G for g in device_groups if g.cfg == cfg)
         pk = predict_by_cfg[cfg]
         # one scratch state per config, threaded through every program
@@ -124,9 +132,12 @@ def prewarm(groups, micro_chunk: int, learn: bool, degradation=None,
         for m, lf in sorted(mls):
             vals = jnp.full((m, G, cfg.n_fields), jnp.nan, jnp.float32)
             ts = jnp.zeros((m, G), jnp.int32)
-            scratch, _ = chunk_step(scratch, vals, ts, cfg, learn=lf,
-                                    health=health_by_cfg[cfg],
-                                    predict=bool(pk))
+            with span("rtap.aot.warm", trace,
+                      program=f"chunk_step.cfg{ci}.T{m}.learn{int(lf)}"):
+                scratch, out = chunk_step(scratch, vals, ts, cfg, learn=lf,
+                                          health=health_by_cfg[cfg],
+                                          predict=bool(pk))
+                jax.block_until_ready(out)
             counter.inc()
             warmed.add((m, cfg, lf))
         if include_claim:
@@ -134,8 +145,10 @@ def prewarm(groups, micro_chunk: int, learn: bool, degradation=None,
             # set_state_row): the slot index is traced, so ONE execution
             # covers every future claim
             fresh = init_state(cfg, seed, predict_horizon=pk)
-            scratch = set_state_row(
-                scratch, {k: fresh[k] for k in scratch}, 0)
+            with span("rtap.aot.warm", trace,
+                      program=f"set_state_row.cfg{ci}"):
+                scratch = jax.block_until_ready(set_state_row(
+                    scratch, {k: fresh[k] for k in scratch}, 0))
             counter.inc()
         del scratch
     return warmed

@@ -18,6 +18,7 @@ import numpy as np
 from rtap_tpu.config import ModelConfig
 from rtap_tpu.data.synthetic import LabeledStream
 from rtap_tpu.obs import TickWatchdog, get_registry
+from rtap_tpu.obs.trace import span
 from rtap_tpu.service.alerts import AlertWriter, ThroughputCounter
 from rtap_tpu.service.registry import (
     PAD_PREFIX,
@@ -89,7 +90,6 @@ def replay_streams(
     checkpoint_dir: str | None = None,
     checkpoint_every: int = 0,
     debounce: int = 1,
-    trace=None,
 ) -> ReplayResult:
     """Replay equal-length streams through grouped models at full speed.
 
@@ -199,15 +199,9 @@ def replay_streams(
         gv[:, :live] = values[:, lo : lo + live]
         gt[:, :live] = ts[:, lo : lo + live]
 
-        def collect(span, handle):
-            t0, t1 = span
-            tc0 = time.perf_counter() if trace is not None else 0.0
+        def collect(bounds, handle):
+            t0, t1 = bounds
             r, ll, al = grp.collect_chunk(handle)
-            if trace is not None:
-                # chunk-granularity spans (replay has no cadence): the
-                # correlation tick is the chunk's first tick
-                trace.add_span("replay_collect", t0, tc0,
-                               time.perf_counter() - tc0, group=gi)
             raw[t0:t1, lo : lo + live] = r[:, :live]
             loglik[t0:t1, lo : lo + live] = ll[:, :live]
             alerts[t0:t1, lo : lo + live] = al[:, :live]
@@ -232,11 +226,7 @@ def replay_streams(
         chunks_done = 0
         for t0 in range(grp.ticks, T, chunk_ticks):
             t1 = min(t0 + chunk_ticks, T)
-            td0 = time.perf_counter() if trace is not None else 0.0
             handle = grp.dispatch_chunk(gv[t0:t1], gt[t0:t1], learn=learn)
-            if trace is not None:
-                trace.add_span("replay_dispatch", t0, td0,
-                               time.perf_counter() - td0, group=gi)
             pending.append(((t0, t1), handle))
             if len(pending) >= 2:
                 collect(*pending.popleft())
@@ -467,8 +457,11 @@ def live_loop(
     The membership and checkpoint spans are positioned at their block
     start with the BOOKED duration (the same drain-exclusion arithmetic
     the phase histograms use), so their on-screen width matches the
-    attributed cost, not the raw wall interval. None = zero hot-path
-    cost.
+    attributed cost, not the raw wall interval. Every span goes through
+    the one seam obs/trace.py:span, whose clock readings are the loop's
+    own phase accounting: the same reading lands in the ring and brackets
+    an `rtap.loop.*` annotation when a JAX profiler trace is running
+    (serve --jax-trace), `trace` or no `trace`. None = no ring.
 
     `flight` (an obs.FlightRecorder) keeps a bounded black-box ring of
     the last N ticks (latency, per-phase deltas, per-group scored
@@ -1035,7 +1028,11 @@ def live_loop(
         Quarantine itself happens after the join, in the loop thread —
         AlertWriter emission is single-threaded by contract."""
         gi, grp, h = item
-        tg0 = time.perf_counter() if trace is not None else 0.0
+        # per-group child span on the group's own track — runs in a pool
+        # thread; the recorder's shards are per-thread. `group` / `seq` are
+        # what the chunk's own `rtap.group.*` phases carry.
+        sp = span("rtap.loop.group.collect", trace, tick=cur_tick,
+                  group=grp.stream_ids[0], seq=h["seq"], track=gi).begin()
         try:
             if chaos is not None:
                 chaos.on_collect(gi, cur_tick)
@@ -1043,11 +1040,7 @@ def live_loop(
         except Exception as e:  # noqa: BLE001 — any fault isolates the group
             return gi, None, e
         finally:
-            if trace is not None:
-                # per-group child span on the group's own track — runs in
-                # a pool thread; the recorder's shards are per-thread
-                trace.add_span("collect", cur_tick, tg0,
-                               time.perf_counter() - tg0, group=gi)
+            sp.end()
 
     def _collect_tick(ts_rows, value_rows, handles, rmaps, idx=None):
         # collects in parallel (each blocks on its group's device fetch —
@@ -1058,17 +1051,16 @@ def live_loop(
         # are skipped; a collect fault quarantines its group here and the
         # rest of the tick proceeds untouched.
         sel = range(len(groups)) if idx is None else idx
-        t0 = time.perf_counter()
+        sp = span("rtap.loop.collect", trace, tick=cur_tick).begin()
         pairs = [(gi, groups[gi], h) for gi, h in zip(sel, handles)
                  if gi not in quarantined and h is not None]
         if pool is None:
             outs = [_try_collect(p) for p in pairs]
         else:
             outs = list(pool.map(_try_collect, pairs))
-        t1 = time.perf_counter()
-        phase_s["collect"] += t1 - t0
-        if trace is not None:
-            trace.add_span("collect", cur_tick, t0, t1 - t0)
+        t1 = sp.end()
+        phase_s["collect"] += t1 - sp.t0
+        sp = span("rtap.loop.emit", trace, tick=cur_tick).begin()
         results: dict = {}
         for gi, res, exc in outs:
             if exc is not None:
@@ -1132,10 +1124,7 @@ def live_loop(
             writer.flush_sink()
             journal.append_cursor(journal_base + cur_tick,
                                   writer.sink_offset())
-        t2 = time.perf_counter()
-        phase_s["emit"] += t2 - t1
-        if trace is not None:
-            trace.add_span("emit", cur_tick, t1, t2 - t1)
+        phase_s["emit"] += sp.end() - t1
 
     aot_programs = 0
     if aot_warmup:
@@ -1148,7 +1137,7 @@ def live_loop(
         prewarmed = prewarm(
             groups, micro_chunk, learn, degradation=degradation,
             include_claim=auto_register or any(
-                g.free_slot_count() for g in groups))
+                g.free_slot_count() for g in groups), trace=trace)
         aot_programs = len(prewarmed)
     else:
         prewarmed = set()
@@ -1377,17 +1366,19 @@ def live_loop(
         """Dispatch one group's chunk, capturing the fault: a raising
         dispatch (device error, wedged RPC surfacing, injected chaos)
         must isolate THAT group, not unwind the tick."""
-        tg0 = time.perf_counter() if trace is not None else 0.0
+        sp = span("rtap.loop.group.dispatch", trace, tick=cur_tick,
+                  group=grp.stream_ids[0], track=gi).begin()
+        seq = -1  # the handle's, once there is one
         try:
             if chaos is not None:
                 chaos.on_dispatch(gi, cur_tick)
-            return grp.dispatch_chunk(v, t, learn=learn_flag), None
+            handle = grp.dispatch_chunk(v, t, learn=learn_flag)
+            seq = handle["seq"]
+            return handle, None
         except Exception as e:  # noqa: BLE001 — any fault isolates the group
             return None, e
         finally:
-            if trace is not None:
-                trace.add_span("dispatch", cur_tick, tg0,
-                               time.perf_counter() - tg0, group=gi)
+            sp.end(seq=seq)
 
     def _dispatch_all(value_rows, ts_rows, rmaps, idx=None, learn_flag=None):
         """Dispatch every non-quarantined group in `idx`; returns handles
@@ -1521,13 +1512,10 @@ def live_loop(
         # time (level 1 thins, level >= 2 freezes); it never adds it
         lrn = learn and (degradation is None
                          or degradation.learn_allowed(cur_tick))
-        now = time.perf_counter()
+        sp = span("rtap.loop.dispatch", trace, tick=cur_tick).begin()
         handles = _dispatch_all(vrows, tsrows, routing, class_idx[c],
                                 learn_flag=lrn)
-        t1 = time.perf_counter()
-        phase_s["dispatch"] += t1 - now
-        if trace is not None:
-            trace.add_span("dispatch", cur_tick, now, t1 - now)
+        phase_s["dispatch"] += sp.end() - sp.t0
         in_flights[c].append((tsrows, vrows, handles, routing, class_idx[c]))
         while len(in_flights[c]) >= pipeline_depth:
             _collect_tick(*in_flights[c].popleft())
@@ -1573,8 +1561,12 @@ def live_loop(
             cur_tick = k
             if chaos is not None:
                 chaos.set_tick(k)
-            t_start = time.perf_counter()
-            t_phase = t_start
+            # the seam's clock readings are the loop's own: one reading
+            # serves the phase accounting, the ring and the annotation
+            sp_tick = span("rtap.loop.tick", trace, tick=k).begin()
+            sp_phase = span("rtap.loop.membership", trace, tick=k).begin()
+            t_start = sp_tick.t0
+            t_phase = sp_phase.t0
             scored_tick0 = list(group_scored) if flight is not None else None
             phase_tick0 = dict(phase_s)  # per-tick deltas feed the per-
             # phase histograms at tick end (cumulative sums stay the
@@ -1764,16 +1756,16 @@ def live_loop(
                         if not quarantined:
                             journal.compact(min(
                                 (g.ticks for g in groups), default=0))
-            now = time.perf_counter()
-            _mem_booked = (now - t_phase) - (
+            _mem_booked = (time.perf_counter() - t_phase) - (
                 phase_s["collect"] + phase_s["emit"] + phase_s["dispatch"]
                 - ce_tick0)
             phase_s["membership"] += _mem_booked
-            if trace is not None and _mem_booked > 1e-6:
-                # positioned at the block start with the BOOKED duration
-                # (drains inside the block already own their own spans)
-                trace.add_span("membership", k, t_phase,
-                               max(0.0, _mem_booked))
+            # in the ring: positioned at the block start with the BOOKED
+            # duration (drains inside the block already own their own spans)
+            sp_phase.end(dur=max(0.0, _mem_booked),
+                         record=_mem_booked > 1e-6)
+            sp_phase = span("rtap.loop.source", trace, tick=k).begin()
+            now = sp_phase.t0
             tick_frames = None  # raw binary ingest frames (journal path)
             try:
                 values, ts = source(k)
@@ -1804,10 +1796,7 @@ def live_loop(
                     # the fallback NaN tick below must journal as the
                     # full-width NaN row it actually scored
                     tick_frames = source.take_tick_frames()
-            _src_t1 = time.perf_counter()
-            phase_s["source"] += _src_t1 - now
-            if trace is not None:
-                trace.add_span("source", k, now, _src_t1 - now)
+            phase_s["source"] += sp_phase.end() - now
             # the poll-done wall instant anchors the tick's ingest-lag
             # measurement (source ts -> loop); perf_counter has no epoch
             lat_poll_wall = time.time() if latency is not None else 0.0
@@ -1905,7 +1894,9 @@ def live_loop(
                 # degrade the cadence to lcm(M, checkpoint_every)
                 if ck_breaker.allow():
                     ck_quarantine_announced = False
-                    now = time.perf_counter()
+                    sp_phase = span("rtap.loop.checkpoint", trace,
+                                    tick=k).begin()
+                    now = sp_phase.t0
                     ce0 = (phase_s["collect"] + phase_s["emit"]
                            + phase_s["dispatch"])
                     ck0 = phase_s["checkpoint"]
@@ -1924,9 +1915,7 @@ def live_loop(
                     phase_s["checkpoint"] += (time.perf_counter() - now) - (
                         phase_s["collect"] + phase_s["emit"]
                         + phase_s["dispatch"] - ce0)
-                    if trace is not None:
-                        trace.add_span("checkpoint", k, now,
-                                       max(0.0, phase_s["checkpoint"] - ck0))
+                    sp_phase.end(dur=max(0.0, phase_s["checkpoint"] - ck0))
                     watchdog.observe_checkpoint(
                         k, phase_s["checkpoint"] - ck0)
                     if failed:
@@ -1966,7 +1955,7 @@ def live_loop(
                             consecutive_failures=
                             ck_breaker.consecutive_failures,
                             cooldown_s=ck_breaker.cooldown_s)
-            elapsed = time.perf_counter() - t_start
+            elapsed = sp_tick.end() - t_start
             latencies[k] = elapsed
             obs_ticks.inc()
             obs_last_tick_wall.set(time.time())
@@ -1974,7 +1963,6 @@ def live_loop(
             for p in _PHASES:
                 obs_phase[p].observe(phase_s[p] - phase_tick0[p])
             if trace is not None:
-                trace.add_span("tick", k, t_start, elapsed)
                 obs_trace_records.set(trace.total)
                 obs_trace_dropped.set(trace.dropped)
             missed_this = watchdog.observe_tick(k, elapsed)
@@ -2029,10 +2017,13 @@ def live_loop(
             # the tick period silently past the cadence.
             budget = max(0.0, eff_cadence - (time.perf_counter() - t_start))
             if not missed_this and k + 1 < n_ticks:
-                if stop_event is not None:
-                    stop_event.wait(budget)  # a shutdown signal ends the sleep
-                else:
-                    time.sleep(budget)
+                # annotation only: in a profiler trace the device's idle
+                # time between ticks has a name; the ring keeps ticks
+                with span("rtap.loop.sleep", tick=k):
+                    if stop_event is not None:
+                        stop_event.wait(budget)  # a shutdown signal ends it
+                    else:
+                        time.sleep(budget)
         for c in range(n_classes):
             if chunk_bufs[c]:
                 # early stop mid-chunk: score what was ingested

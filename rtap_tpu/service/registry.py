@@ -14,12 +14,12 @@ CPU default, TPU opt-in per group (BASELINE.json north star).
 
 from __future__ import annotations
 
-import contextlib
 from dataclasses import dataclass, field
 
 import numpy as np
 
 from rtap_tpu.config import ModelConfig
+from rtap_tpu.obs.trace import span
 from rtap_tpu.service.likelihood_batch import BatchAnomalyLikelihood
 
 
@@ -398,23 +398,6 @@ class StreamGroup:
             pred = None if pred is None else pred[0]
         return raw, pred
 
-    def _phase(self, name: str, seq: int):
-        """One phase of the chunk path as a `jax.profiler.TraceAnnotation`
-        on the host plane of a running JAX profiler trace (`serve
-        --jax-trace`, the benchmark's `--trace 1`): `rtap.group.stage`
-        (host arrays -> device), `rtap.group.enqueue` (the step program up
-        to the handle), `rtap.group.fetch` (the blocking device -> host
-        read), `rtap.group.likelihood` (host likelihood + debounce). `group`
-        (the first stream id) and `seq` (the handle's) tie one chunk's four
-        spans together. With no trace running it is a flag check; the
-        cpu-oracle backend, which never loads JAX, records nothing."""
-        if self.backend != "tpu":
-            return contextlib.nullcontext()
-        import jax
-
-        return jax.profiler.TraceAnnotation(
-            name, group=self.stream_ids[0], seq=seq)
-
     def dispatch_chunk(self, values: np.ndarray, ts: np.ndarray, learn: bool = True) -> dict:
         """Enqueue T ticks on the device WITHOUT blocking on the result.
 
@@ -428,8 +411,17 @@ class StreamGroup:
         On the CPU backend there is no async device; the chunk is computed
         here and the handle carries the finished scores.
         """
+        # the chunk path's four phases are host spans of the served path's one
+        # seam (obs/trace.py:SPANS): `rtap.group.stage` (host arrays ->
+        # device), `.enqueue` (the step program up to the handle), `.fetch`
+        # (the blocking device -> host read: in a live tick mostly the wait
+        # behind the other groups' programs), `.likelihood` (host likelihood
+        # + debounce). `group` (the first stream id) and `seq` (the handle's)
+        # tie one chunk's four spans to each other, to the loop's per-group
+        # spans and to the chunk's execution on the device.
+        gid = self.stream_ids[0]
         seq = self._seq + 1  # the handle's, so one chunk's phases share it
-        with self._phase("rtap.group.stage", seq):
+        with span("rtap.group.stage", group=gid, seq=seq):
             values = np.asarray(values, np.float32)
             if values.ndim == 2:
                 values = values[..., None]
@@ -438,7 +430,7 @@ class StreamGroup:
                 dev_values = self._put(values, axis=1)
                 dev_ts = self._put(ts.astype(np.int32), axis=1)
         if self.backend == "tpu":
-            with self._phase("rtap.group.enqueue", seq):
+            with span("rtap.group.enqueue", group=gid, seq=seq):
                 if self.mesh is not None:
                     from rtap_tpu.ops.step import sharded_chunk_step
 
@@ -503,7 +495,8 @@ class StreamGroup:
                 f"collect_chunk out of order: handle seq {handle['seq']}, "
                 f"expected {self._collected + 1} (likelihood state is sequential)"
             )
-        with self._phase("rtap.group.fetch", handle["seq"]):
+        gid, seq = self.stream_ids[0], handle["seq"]
+        with span("rtap.group.fetch", group=gid, seq=seq):
             if handle["device"]:
                 # the blocking fetch can surface a device error — only a chunk
                 # whose scores actually materialized counts as collected
@@ -523,7 +516,7 @@ class StreamGroup:
         T = handle["T"]
         self.last_predictions = pred
         self.ticks += T
-        with self._phase("rtap.group.likelihood", handle["seq"]):
+        with span("rtap.group.likelihood", group=gid, seq=seq):
             loglik = np.empty((T, self.G))
             alerts = np.empty((T, self.G), bool)
             for i in range(T):

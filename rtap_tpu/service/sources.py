@@ -30,6 +30,7 @@ import urllib.request
 import numpy as np
 
 from rtap_tpu.obs import get_registry
+from rtap_tpu.obs.trace import span
 
 __all__ = ["HttpPollSource", "TcpJsonlSource", "BinaryBatchSource",
            "send_jsonl"]
@@ -237,6 +238,10 @@ class TcpJsonlSource:
 
         class Handler(socketserver.StreamRequestHandler):
             def handle(self):
+                # one `rtap.ingest.feed` span per recv batch, on either
+                # parse path (obs/trace.py:SPANS): the layer's busy time
+                # and its wait for the lock (`wait_us`), beside the count
+                # it has (records_parsed)
                 if outer._nstate is not None:
                     conn = outer._nstate.new_conn()
                     try:
@@ -244,54 +249,31 @@ class TcpJsonlSource:
                             data = self.connection.recv(65536)
                             if not data:
                                 break
+                            sp = span("rtap.ingest.feed",
+                                      bytes=len(data)).begin()
                             with outer._lock:
+                                locked = time.perf_counter()
                                 conn.feed(data)
+                            sp.end(wait_us=int((locked - sp.t0) * 1e6))
                         with outer._lock:
                             conn.flush()  # unterminated final line, like rfile
                     finally:
                         conn.close()
                     return
-                for line in self.rfile:
-                    try:
-                        rec = json.loads(line)
-                        sid = rec["id"]
-                        # index resolved under the SAME lock as the write:
-                        # set_ids swaps (_index, _latest) together, and an
-                        # index from the old mapping must never address the
-                        # new array (it would misroute the sample). Effect
-                        # ORDER is pinned by the native-parity fuzz: the
-                        # unknown check precedes value conversion (bad value
-                        # on an unknown id = unknown, not parse error), and
-                        # the value write precedes ts conversion (bad ts
-                        # counts a parse error but KEEPS the value) — the C
-                        # parser implements the same order.
-                        with outer._lock:
-                            i = outer._index.get(sid)
-                            if i is None:
-                                outer._py_unknown_ids += 1
-                                if outer._track_unknown and \
-                                        isinstance(sid, str) and \
-                                        len(outer._unknown_seen) < \
-                                        outer.MAX_UNKNOWN_TRACKED:
-                                    outer._unknown_seen.add(sid)
-                                continue
-                            outer._latest[i] = np.float32(rec["value"])
-                            outer._latest_ts = max(outer._latest_ts,
-                                                   int(rec.get("ts", 0)))
-                            # success is counted AFTER the ts conversion:
-                            # a bad ts keeps the value but counts as a
-                            # parse error, not a parsed record — the
-                            # order the C parser implements (pinned by
-                            # the native-parity fuzz)
-                            outer._py_records += 1
-                    except Exception:
-                        # under the lock like every other tally: handler
-                        # threads are one-per-connection, and an
-                        # unguarded += across N malformed producers
-                        # loses increments (read-modify-write race the
-                        # analyzer's race pass flags)
-                        with outer._lock:
-                            outer._py_parse_errors += 1
+                # the Python fallback: lines split on "\n" as rfile's are,
+                # the unterminated final line fed at EOF
+                tail = b""
+                while True:
+                    data = self.connection.recv(65536)
+                    batch = (tail + data).split(b"\n")
+                    tail = batch.pop()
+                    if not data and tail:
+                        batch, tail = [tail], b""
+                    if batch:
+                        sp = span("rtap.ingest.feed", bytes=len(data)).begin()
+                        sp.end(wait_us=int(outer._feed_lines(batch) * 1e6))
+                    if not data:
+                        break
 
         class Server(socketserver.ThreadingTCPServer):
             allow_reuse_address = True
@@ -302,6 +284,55 @@ class TcpJsonlSource:
         self._thread = threading.Thread(target=self._server.serve_forever,
                                         name="rtap-sources-accept",
                                         daemon=True)
+
+    def _feed_lines(self, lines: list[bytes]) -> float:
+        """The Python parse path over one batch of complete lines -> the
+        seconds it waited for the lock."""
+        waited = 0.0
+        for line in lines:
+            try:
+                rec = json.loads(line)
+                sid = rec["id"]
+                # index resolved under the SAME lock as the write:
+                # set_ids swaps (_index, _latest) together, and an
+                # index from the old mapping must never address the
+                # new array (it would misroute the sample). Effect
+                # ORDER is pinned by the native-parity fuzz: the
+                # unknown check precedes value conversion (bad value
+                # on an unknown id = unknown, not parse error), and
+                # the value write precedes ts conversion (bad ts
+                # counts a parse error but KEEPS the value) — the C
+                # parser implements the same order.
+                t_ask = time.perf_counter()
+                with self._lock:
+                    waited += time.perf_counter() - t_ask
+                    i = self._index.get(sid)
+                    if i is None:
+                        self._py_unknown_ids += 1
+                        if self._track_unknown and \
+                                isinstance(sid, str) and \
+                                len(self._unknown_seen) < \
+                                self.MAX_UNKNOWN_TRACKED:
+                            self._unknown_seen.add(sid)
+                        continue
+                    self._latest[i] = np.float32(rec["value"])
+                    self._latest_ts = max(self._latest_ts,
+                                          int(rec.get("ts", 0)))
+                    # success is counted AFTER the ts conversion:
+                    # a bad ts keeps the value but counts as a
+                    # parse error, not a parsed record — the
+                    # order the C parser implements (pinned by
+                    # the native-parity fuzz)
+                    self._py_records += 1
+            except Exception:
+                # under the lock like every other tally: handler
+                # threads are one-per-connection, and an
+                # unguarded += across N malformed producers
+                # loses increments (read-modify-write race the
+                # analyzer's race pass flags)
+                with self._lock:
+                    self._py_parse_errors += 1
+        return waited
 
     def start(self) -> "TcpJsonlSource":
         self._thread.start()
@@ -382,12 +413,18 @@ class TcpJsonlSource:
         producer that stops pushing yields missing samples (NaN) rather than
         its stale last value being re-scored forever — a silent outage must
         surface as missing data, not as a suspiciously flat healthy metric."""
+        # `rtap.ingest.snapshot` ends at the snapshot instant as the program
+        # itself knows it (the loop's `source` span also holds whatever
+        # wraps this call)
+        sp = span("rtap.ingest.snapshot", tick=tick).begin()
         with self._lock:
+            locked = time.perf_counter()
             values = self._latest.copy()
             self._latest[:] = np.nan
             if self._nstate is not None:
                 self._latest_ts = max(self._latest_ts, int(self._nstate.ts_buf[0]))
             ts = self._latest_ts or int(time.time())
+        sp.end(wait_us=int((locked - sp.t0) * 1e6))
         # once-per-tick delta sync of THIS instance's ingest tallies into
         # the process-global registry counters (outside the lock: reads +
         # obs-cell increments only). Per-instance deltas, never a raise-

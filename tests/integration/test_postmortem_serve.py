@@ -126,7 +126,9 @@ def test_chaos_quarantine_autodumps_valid_bundle_and_trace_route(tmp_path):
         small = json.loads(urllib.request.urlopen(
             f"http://{host}:{port}/trace?last=1", timeout=10).read())
         ticks = {e["args"]["tick"] for e in _spans(small["traceEvents"])}
-        assert ticks == {N_TICKS - 1}
+        # (unticked spans — a garbage collection before the loop's first
+        # record, an AOT warm-up program — are kept whatever the window)
+        assert ticks - {-1} == {N_TICKS - 1}
         # on-demand postmortem over HTTP (fresh reason, not throttled)
         pm = json.loads(urllib.request.urlopen(
             f"http://{host}:{port}/postmortem", timeout=10).read())

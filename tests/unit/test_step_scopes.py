@@ -4,7 +4,6 @@ around the stages of the fused step (in every XLA op's `op_name`) and
 path. The names are spelled out here on purpose — a benchmark reader matches
 them in recorded traces, so a rename has to fail a test."""
 
-import contextlib
 import glob
 import re
 
@@ -15,6 +14,7 @@ import pytest
 
 from rtap_tpu.config import node_preset, scaled_cluster_preset
 from rtap_tpu.models.state import init_state
+from rtap_tpu.obs.trace import span
 from rtap_tpu.ops import tm_tpu
 from rtap_tpu.ops.step import chunk_step, fused_step, group_step, replicate_state
 from rtap_tpu.service.registry import StreamGroup
@@ -112,8 +112,12 @@ def test_chunk_path_phases_land_in_a_profiler_trace(tmp_path):
     assert {a["group"] for a in seen.values()} == {"a0"}
 
 
-def test_no_trace_no_record_and_the_oracle_backend_stays_off_jax():
-    # the cpu-oracle backend's phases are a plain no-op context
+def test_no_trace_no_record_and_the_phases_go_through_the_seam():
+    # the chunk path's phases are spans of obs/trace.py's seam (which the
+    # cpu-oracle backend uses too, and which never imports JAX:
+    # tests/unit/test_span_seam.py); with no profiler running one opens no
+    # annotation
     group = StreamGroup(scaled_cluster_preset(32), ["a0"], backend="cpu")
-    assert isinstance(group._phase("rtap.group.stage", 1),
-                      contextlib.nullcontext)
+    assert not hasattr(group, "_phase")
+    with span("rtap.group.stage", group="a0", seq=1) as sp:
+        assert sp._ann is None

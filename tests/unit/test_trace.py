@@ -137,27 +137,20 @@ def test_concurrent_writers_have_private_shards():
 
 
 @pytest.mark.quick
-def test_replay_streams_records_chunk_spans():
-    """replay_streams is instrumented too (ISSUE 4 tentpole): every chunk
-    dispatch/collect lands as a per-group span keyed by the chunk's first
-    tick."""
-    from rtap_tpu.config import cluster_preset
-    from rtap_tpu.data.synthetic import SyntheticStreamConfig, generate_cluster
-    from rtap_tpu.service.loop import replay_streams
-
-    streams = generate_cluster(
-        1, cfg=SyntheticStreamConfig(length=16, cadence_s=1.0,
-                                     n_anomalies=0), seed=0)
-    tr = TraceRecorder(capacity=256)
-    res = replay_streams(streams, cluster_preset(), backend="tpu",
-                         chunk_ticks=8, trace=tr)
-    assert res.raw.shape[0] == 16
-    recs = tr.records()
-    disp = [r for r in recs if r["name"] == "replay_dispatch"]
-    coll = [r for r in recs if r["name"] == "replay_collect"]
-    assert len(disp) == 2 and len(coll) == 2  # 16 ticks / 8 per chunk
-    assert sorted(r["tick"] for r in disp) == [0, 8]
-    assert all(r["group"] == 0 for r in disp + coll)
+def test_span_args_ride_into_the_chrome_export():
+    # a span may carry a serialized JSON object (the seam's garbage-
+    # collection spans do): bounded like an instant's payload
+    tr = TraceRecorder(capacity=8, max_arg_bytes=32)
+    t = time.perf_counter()
+    tr.add_span("gc", -1, t, 0.001, args_json='{"generation": 2}')
+    tr.add_span("gc", -1, t + 1, 0.001, args_json='{"x": "' + "y" * 64 + '"}')
+    tr.add_span("tick", 0, t + 2, 0.5)
+    a, b, c = tr.records()
+    assert a["args_json"] == '{"generation": 2}' and len(b["args_json"]) == 32
+    assert "args_json" not in c
+    ev = [e for e in tr.chrome_trace()["traceEvents"] if e.get("ph") == "X"]
+    assert ev[0]["args"] == {"tick": -1, "generation": 2}
+    assert ev[1]["args"]["tick"] == -1 and "info" in ev[1]["args"]  # cut
 
 
 @pytest.mark.quick
