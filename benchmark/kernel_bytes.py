@@ -4,8 +4,26 @@ Leaf by leaf from the configuration's sizes, like roofline.py's
 `state_bytes_per_stream` (the state leaves below sum to it, to the byte):
 each kernel — named by its `rtap.*` scope — reads and writes the listed
 leaves once per stream-tick and no kernel can take less time than those bytes
-at the chip's peak HBM rate. All four are memory-bound (integer compares and
-adds over the pools, no matrix unit in the floor), so the bytes bound them."""
+at the chip's peak HBM rate. All are memory-bound (integer compares and adds
+over the pools, no matrix unit in the floor), so the bytes bound them.
+
+The temporal memory has ONE entry, `rtap.tm`, for all of `rtap.tm.*`
+(activate, learn, learn.rows, dendrite): which of those scopes a pool sweep's
+time is filed under follows the compiler's choice of fusion root (since PR 36
+restore, punish, synapse death and the dendrite membership test are one
+fusion under `rtap.tm.dendrite`), so bytes split by scope cannot be kept
+right; their sum can. The entry is a floor: what NO implementation of a TM
+tick can avoid, each leaf at most once in each direction. Every synapse is
+tested against this tick's active cells, so both pools are read; the segment
+and cell vectors are rewritten whole every tick. The pools are NOT counted as
+written: learning changes at most `learn_cap` segments' rows and the punished
+segments' permanences, so a program that writes only those is possible, and a
+floor that charged the whole pools' write would read up to 200 % for it. The
+per-scope entries this one replaces (`rtap.tm.learn`: pools read and written
+whole; `rtap.tm.dendrite`: pools read once more) asked for three reads and
+one write of the pools a tick and read 109.06 % for `rtap.tm.learn` at the
+NAB width the moment PR 40 took the layout copies out of that scope (ledger,
+PR 40: 11.386 ms of floor over 8.419 + 2.022 ms)."""
 
 from __future__ import annotations
 
@@ -19,20 +37,20 @@ STATE_LEAVES = (
     "enc_offset", "enc_bound", "enc_resolution")
 
 #: scope -> (leaves read, leaves written). `sdr`, `overlap`, `active_cols`
-#: and `active_cells` are the vectors the stages hand each other.
+#: and `active_cells` are the vectors the stages hand each other. A scope's
+#: sub-scopes are part of it (`rtap.tm` is every `rtap.tm.*`).
 KERNELS = {
     "rtap.sp.overlap": (("members", "perm", "sdr"), ("overlap",)),
     "rtap.sp.learn": (
         ("members", "perm", "sdr", "overlap", "active_cols", "overlap_duty",
          "active_duty", "sp_iter"),
         ("perm", "overlap_duty", "active_duty", "sp_iter")),
-    "rtap.tm.learn": (
+    "rtap.tm": (
         ("presyn", "syn_perm", "seg_last", "seg_pot", "matching_seg",
-         "prev_active", "prev_winner", "active_cols"),
-        ("presyn", "syn_perm", "seg_last")),
-    "rtap.tm.dendrite": (
-        ("presyn", "syn_perm", "seg_last", "active_cells"),
-        ("active_seg", "matching_seg", "seg_pot", "seg_last")),
+         "active_seg", "prev_active", "prev_winner", "active_cols",
+         "active_cells"),
+        ("active_seg", "matching_seg", "seg_pot", "seg_last", "prev_active",
+         "prev_winner")),
 }
 
 

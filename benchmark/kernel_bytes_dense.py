@@ -13,8 +13,8 @@ node_preset(3); tests/benchmark/test_roofline.py).
 Each kernel — named by its `rtap.*` scope — reads and writes the listed
 leaves once per stream-tick, so no kernel can take less time than those bytes
 at the chip's peak HBM rate. All are memory-bound: the dense overlap reads
-3.7 MB of permanences for a 2048 x 454 matvec (1.9 MFLOP); the TM kernels are
-integer compares and adds over the pools."""
+3.7 MB of permanences for a 2048 x 454 matvec (1.9 MFLOP); the TM is integer
+compares and adds over the pools."""
 
 from __future__ import annotations
 
@@ -30,18 +30,18 @@ STATE_LEAVES = (
 
 #: scope -> (leaves read, leaves written); `sdr`, `overlap`, `active_cols`
 #: and `active_cells` are the vectors the stages hand each other. A scope's
-#: sub-scopes (`rtap.tm.learn.rows`) are part of it. The TM does not know
-#: which SP pool feeds it: its two kernels are kernel_bytes.py's, leaf for
-#: leaf; the SP's read the potential mask where the sparse family reads
-#: member indices.
+#: sub-scopes are part of it (`rtap.tm` is every `rtap.tm.*`). The TM does
+#: not know which SP pool feeds it: its one entry, the floor of the whole
+#: temporal memory, is kernel_bytes.py's, leaf for leaf (its docstring says
+#: why the pools' write is not in it); the SP's read the potential mask where
+#: the sparse family reads member indices.
 KERNELS = {
     "rtap.sp.overlap": (("potential", "perm", "sdr"), ("overlap",)),
     "rtap.sp.learn": (
         ("potential", "perm", "sdr", "overlap", "active_cols", "overlap_duty",
          "active_duty", "sp_iter"),
         ("perm", "overlap_duty", "active_duty", "sp_iter")),
-    "rtap.tm.learn": _SPARSE_KERNELS["rtap.tm.learn"],
-    "rtap.tm.dendrite": _SPARSE_KERNELS["rtap.tm.dendrite"],
+    "rtap.tm": _SPARSE_KERNELS["rtap.tm"],
 }
 
 _PERM_BYTES = {0: 4, 8: 1, 16: 2}

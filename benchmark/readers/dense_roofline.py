@@ -2,8 +2,8 @@
 (benchmark/kernel_bytes_dense.py has the bytes), selected by `what`:
 
     kernel   the floor of the kernel under `scope` over the device time of
-             that scope AND its sub-scopes (`rtap.tm.learn.rows` is part of
-             `rtap.tm.learn`) per group-tick
+             that scope AND its sub-scopes (`rtap.tm` is every `rtap.tm.*`:
+             activate, learn, learn.rows, dendrite) per group-tick
     step     the floor of the whole tick (state read once, written once)
              over the sum of every scope's device time per group-tick —
              the step's true time (PERF.md s7, question 11)
@@ -13,7 +13,7 @@ no scope, or a trace with no whole execution, gives nothing to read."""
 
 from benchmark.kernel_bytes_dense import (
     kernel_floor_seconds, step_floor_seconds)
-from benchmark.scoped_trace import scope_table
+from benchmark.scoped_trace import scope_table, scope_with_subscopes_ms
 
 
 def read(record: dict, definition: dict):
@@ -27,8 +27,7 @@ def read(record: dict, definition: dict):
         floor = step_floor_seconds(model, group_size, record["device_kind"])
     elif definition["what"] == "kernel":
         scope = definition["scope"]
-        ms = sum(v for k, v in table.items()
-                 if k == scope or k.startswith(scope + "."))
+        ms = scope_with_subscopes_ms(table, scope)
         floor = kernel_floor_seconds(scope, model, group_size,
                                      record["device_kind"])
     else:

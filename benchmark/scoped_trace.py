@@ -326,3 +326,12 @@ def scope_table(record: dict, module: str) -> dict | None:
         except NoScopes:
             cache[module] = None
     return cache[module]
+
+
+def scope_with_subscopes_ms(table: dict, scope: str) -> float:
+    """Device ms per group-tick of `scope` and every scope beneath it
+    (`rtap.tm.learn.rows` is part of `rtap.tm.learn`, and both of `rtap.tm`):
+    a share of a floor divides by all the time its bytes were moved in,
+    whichever of those names a fusion's root filed it under."""
+    return sum(ms for name, ms in table.items()
+               if name == scope or name.startswith(scope + "."))

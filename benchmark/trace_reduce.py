@@ -129,12 +129,22 @@ def reduce(planes: dict, window_ns: tuple[int, int],
         cursor = max(cursor, b)
     if cursor < w1:
         gaps.append((cursor, w1))
-    spans = sorted(((e - s, name, s, e) for name, s, e in host_spans_ns
-                    if e > s), key=lambda x: x[0])
+    # shortest covering span first (ties: the caller's order). The gaps are
+    # disjoint and ascending, so the spans that can overlap one are kept by
+    # a sweep: a gap is held against those alone, not against every span
+    # (gaps x spans is minutes on a window of 250 programs)
+    spans = sorted(((s, e - s, i, name, e)
+                    for i, (name, s, e) in enumerate(host_spans_ns) if e > s))
     by_span: dict[str, float] = {}
+    overlapping: list[tuple] = []
+    nxt_span = 0
     for ga, gb in gaps:
+        while nxt_span < len(spans) and spans[nxt_span][0] < gb:
+            overlapping.append(spans[nxt_span])
+            nxt_span += 1
+        overlapping = [sp for sp in overlapping if sp[4] > ga]
         left = [(ga, gb)]
-        for _len, name, s, e in spans:
+        for s, _len, _i, name, e in sorted(overlapping, key=lambda sp: sp[1:3]):
             nxt = []
             for a, b in left:
                 lo, hi = max(a, s), min(b, e)

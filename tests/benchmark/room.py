@@ -29,10 +29,18 @@ DENSE_CELL = "nab-2048-replay"
 ROOFLINES = {
     "sp_overlap_roofline.fields": ("kernel", "rtap.sp.overlap"),
     "sp_learn_roofline.fields": ("kernel", "rtap.sp.learn"),
-    "tm_learn_roofline.fields": ("kernel", "rtap.tm.learn"),
-    "tm_dendrite_roofline.fields": ("kernel", "rtap.tm.dendrite"),
+    "tm_roofline.fields": ("kernel", "rtap.tm"),
     "step_roofline.fields": ("step", None),
 }
+
+
+def shape_free_lists(manifest: dict) -> list[dict]:
+    """The per-layer metrics one name serves across the shape range — the
+    accepted replay cells' own, which already hold the dense family's first
+    cell: a further cell of that family joins these lists."""
+    return [m for m in manifest["per_layer"]
+            if m.get("workloads", [])[:2] == REPLAY_HEAD
+            and DENSE_CELL in m["workloads"]]
 
 
 def make_root(tmp_path, groups: int = 6, group_size: int = 1024, **keys) -> str:
@@ -87,12 +95,8 @@ def make_root(tmp_path, groups: int = 6, group_size: int = 1024, **keys) -> str:
     for m in bm["end_to_end"]:
         if m["name"] == "metrics_per_s":
             m["workloads"].append(CELL)
-    # the shape-free scope and phase metrics: the accepted replay cells'
-    # own, which already hold the dense family's first cell
-    for m in bm["per_layer"]:
-        listed = m.get("workloads", [])
-        if listed[:2] == REPLAY_HEAD and DENSE_CELL in listed:
-            listed.append(CELL)
+    for m in shape_free_lists(bm):
+        m["workloads"].append(CELL)
     bm["per_layer"] += [
         {"name": name, "unit": "%", "better": "higher",
          "source": "device_trace", "layer": "kernels",

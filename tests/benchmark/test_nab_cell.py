@@ -21,6 +21,25 @@ CONFIGS = os.path.join(REPO, "benchmark", "configs")
 SEED = 4_270_000_001  # beyond 2**31, like the driver's
 
 
+#: one name for one measurement across the shape range: what the step's
+#: scopes and a group's phases took, and the outside-timed twins (PERF.md s3).
+#: A later PR's shape-free metric joins these by listing the cell; none of
+#: these may drop it.
+SHAPE_FREE = {
+    "encode_ms.replay", "sp_overlap_ms.replay", "sp_inhibit_ms.replay",
+    "sp_learn_ms.replay", "tm_activate_ms.replay", "tm_learn_ms.replay",
+    "tm_dendrite_ms.replay", "unscoped_ms.replay",
+    "group_stage_ms.replay", "group_enqueue_ms.replay",
+    "group_fetch_ms.replay", "group_likelihood_ms.replay",
+    "warm_compile_s", "group_host_ms.replay", "step_device_ms.replay",
+    "device_idle_share.replay"}
+#: the cell's own: the dense byte table's shares, the wide rows' sub-scope,
+#: the capacity counter
+NAB_METRICS = {"tm_learn_rows_ms.nab", "step_roofline.nab",
+               "sp_overlap_roofline.nab", "tm_roofline.nab",
+               "tm_full_cells.nab"}
+
+
 def nab_config() -> dict:
     with open(os.path.join(CONFIGS, CONFIG + ".json")) as f:
         return json.load(f)
@@ -89,7 +108,6 @@ def cell_resolves_and_fills_a_quarter_of_the_chip(reg: Registry) -> None:
     assert {m["name"] for m in reg.metrics(CELL, "end_to_end")} == \
         {"metrics_per_s", "setup_s", "peak_bytes_per_stream"}
     layer = reg.metrics(CELL, "per_layer")
-    assert len(layer) == 22
     for m in layer:
         definition, reader = reg.layer_metric(m["name"])
         assert callable(reader.read)
@@ -100,11 +118,15 @@ def cell_resolves_and_fills_a_quarter_of_the_chip(reg: Registry) -> None:
     # are the accepted cells' own, with this cell on their lists after the
     # accepted heads (a later cell may follow it: tests/benchmark/room.py)
     shared = [m for m in layer if not m["name"].endswith(".nab")]
-    assert len(shared) == 16 and all(
+    assert {m["name"] for m in shared} >= SHAPE_FREE and all(
         CELL in m["workloads"] and m["workloads"][:2] ==
         ["cluster-256-replay", "cluster-32-replay"] for m in shared)
     new = [m for m in layer if m["name"].endswith(".nab")]
-    assert len(new) == 6 and all(m["workloads"] == [CELL] for m in new)
+    assert {m["name"] for m in new} >= NAB_METRICS and all(
+        m["workloads"] == [CELL] for m in new)
+    assert not [m for m in reg.manifest["per_layer"]
+                if m["name"].startswith(("tm_learn_roofline.",
+                                         "tm_dendrite_roofline."))]
     (entry,) = [c for c in reg.manifest["configs"] if c["name"] == CONFIG]
     assert entry["reduced"] == cfg["reduced"] and len(entry["source"]) <= 200
 
@@ -210,13 +232,14 @@ def test_new_readers_on_a_hand_made_trace():
     assert read("tm_dendrite_ms.replay") == pytest.approx(1500 / 2 / 1e6)
     assert read("unscoped_ms.replay") == pytest.approx(400 / 2 / 1e6)
     assert read("encode_ms.replay") == 0.0
-    # a kernel's share counts its sub-scopes' time; the step's, every scope's
-    floor = kbd.kernel_floor_seconds("rtap.tm.learn", model, 17, "TPU v5 lite")
-    assert read("tm_learn_roofline.nab") == pytest.approx(
-        100 * floor / (2000 / 2 / 1e9))
-    floor = kbd.kernel_floor_seconds("rtap.tm.dendrite", model, 17, "TPU v5 lite")
-    assert read("tm_dendrite_roofline.nab") == pytest.approx(
-        100 * floor / (1500 / 2 / 1e9))
+    # a kernel's share counts its sub-scopes' time — the TM's, every
+    # `rtap.tm.*` scope's (learn, its rows, dendrite); the step's, every scope's
+    floor = kbd.kernel_floor_seconds("rtap.tm", model, 17, "TPU v5 lite")
+    assert read("tm_roofline.nab") == pytest.approx(
+        100 * floor / ((1600 + 400 + 1500) / 2 / 1e9))
+    floor = kbd.kernel_floor_seconds("rtap.sp.overlap", model, 17, "TPU v5 lite")
+    assert read("sp_overlap_roofline.nab") == pytest.approx(
+        100 * floor / (100 / 2 / 1e9))
     assert read("step_roofline.nab") == pytest.approx(
         100 * kbd.step_floor_seconds(model, 17, "TPU v5 lite") / (4000 / 2 / 1e9))
 
@@ -226,7 +249,7 @@ def test_new_readers_read_nothing_where_there_is_nothing():
     bare = hand_made_record()
     for ev in bare["scoped_planes"]["/device:TPU:0"]["XLA Ops"]:
         ev[3] = ev[3].replace("rtap.", "")  # a program before the scopes
-    for name in ("step_roofline.nab", "tm_learn_roofline.nab",
+    for name in ("step_roofline.nab", "tm_roofline.nab",
                  "tm_learn_rows_ms.nab", "tm_learn_ms.replay"):
         definition, reader = reg.layer_metric(name)
         assert reader.read({"trace": None}, definition) is None

@@ -94,13 +94,13 @@ def test_dense_leaves_equal_init_state_leaf_by_leaf(name):
 
 @pytest.mark.parametrize("scope,nbytes", [
     ("rtap.sp.overlap", 296_320), ("rtap.sp.learn", 497_288),
-    ("rtap.tm.learn", 880_896), ("rtap.tm.dendrite", 493_568)])
+    ("rtap.tm", 534_784)])
 def test_dense_kernel_bytes_of_three_fields(scope, nbytes):
     model = _preset("node3").to_dict()
     assert kbd.kernel_bytes_per_stream(scope, model) == nbytes
     # the TM does not know how many fields fed the SP; the SP's input does
     one = _preset("dense_cluster").to_dict()
-    same = scope.startswith("rtap.tm.")
+    same = scope == "rtap.tm"
     assert (kbd.kernel_bytes_per_stream(scope, one) == nbytes) == same
     assert kbd.kernel_floor_seconds(scope, model, 1024, "TPU v5 lite") == \
         pytest.approx(nbytes * 1024 / 819e9)

@@ -68,7 +68,9 @@ def test_the_cell_comes_as_new_files_and_appended_entries_only(root):
             new = {**new, "workloads": new["workloads"][:-1]}
             joined += 1
         assert new == old
-    assert joined == 1 + 16  # metrics_per_s and the sixteen shape-free lists
+    # metrics_per_s and every shape-free list: the accepted replay cells'
+    # own that hold the dense family's first cell
+    assert joined == 1 + len(room.shape_free_lists(had))
 
 
 def test_the_copy_passes_the_manifests_own_tests(root):
@@ -81,7 +83,9 @@ def test_the_copy_passes_the_manifests_own_tests(root):
     assert cfg["layout"]["streams"] == 6 * 1024 and "live_cadence_s" not in cfg
     assert kbd.state_bytes_per_stream(cfg["model"]) == 760_871
     layer = reg.metrics(room.CELL, "per_layer")
-    assert len(layer) == 16 + len(room.ROOFLINES)
+    assert {m["name"] for m in layer} == \
+        {m["name"] for m in room.shape_free_lists(reg.manifest)} \
+        | set(room.ROOFLINES)
     assert {m["name"] for m in reg.metrics(room.CELL, "end_to_end")} == \
         {"metrics_per_s", "setup_s", "peak_bytes_per_stream"}
 
@@ -111,14 +115,13 @@ def test_the_new_rooflines_read_through_the_dense_reader(root):
                  for s in kbd.KERNELS}
     assert floors_ms == pytest.approx(
         {"rtap.sp.overlap": 0.3705, "rtap.sp.learn": 0.6218,
-         "rtap.tm.learn": 1.1014, "rtap.tm.dendrite": 0.6171}, abs=5e-5)
+         "rtap.tm": 0.6686}, abs=5e-5)
     step_ms = kbd.step_floor_seconds(model, 1024, "TPU v5 lite") * 1e3
     assert step_ms == pytest.approx(1.9026, abs=5e-5)
     for name, scope, ns in (
             ("sp_overlap_roofline.fields", "rtap.sp.overlap", 300),
             ("sp_learn_roofline.fields", "rtap.sp.learn", 500),
-            ("tm_learn_roofline.fields", "rtap.tm.learn", 1400 + 200),
-            ("tm_dendrite_roofline.fields", "rtap.tm.dendrite", 1200)):
+            ("tm_roofline.fields", "rtap.tm", 1400 + 200 + 1200)):
         assert read(name) == pytest.approx(
             100 * floors_ms[scope] / (ns / 2 / 1e6)), name
     assert read("step_roofline.fields") == pytest.approx(

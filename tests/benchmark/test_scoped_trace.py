@@ -30,8 +30,7 @@ SCOPE_MS = {"encode_ms.replay": "rtap.encode",
             "unscoped_ms.replay": "unscoped"}
 ROOFLINES = {"sp_overlap_roofline.replay": "rtap.sp.overlap",
              "sp_learn_roofline.replay": "rtap.sp.learn",
-             "tm_learn_roofline.replay": "rtap.tm.learn",
-             "tm_dendrite_roofline.replay": "rtap.tm.dendrite"}
+             "tm_roofline.replay": "rtap.tm"}
 PHASES = {"group_stage_ms.replay": ("rtap.group.stage", "chunk"),
           "group_enqueue_ms.replay": ("rtap.group.enqueue", "chunk"),
           "group_fetch_ms.replay": ("rtap.group.fetch", "chunk"),
@@ -270,8 +269,8 @@ def test_kernel_bytes_from_shapes(name):
         assert set(read) | set(written) <= set(leaves), scope
         assert kernel_bytes_per_stream(scope, m) < \
             2 * state_bytes_per_stream(m)  # no kernel moves the whole state twice
-    assert kernel_floor_seconds("rtap.tm.learn", m, 1024, "TPU v5 lite") == \
-        pytest.approx(kernel_bytes_per_stream("rtap.tm.learn", m) * 1024 / 819e9)
+    assert kernel_floor_seconds("rtap.tm", m, 1024, "TPU v5 lite") == \
+        pytest.approx(kernel_bytes_per_stream("rtap.tm", m) * 1024 / 819e9)
     with pytest.raises(KeyError, match="no byte count"):
         kernel_bytes_per_stream("rtap.encode", m)
 
@@ -392,8 +391,11 @@ def metric_files_resolve_and_name_their_cells(reg: Registry) -> None:
         assert callable(reader.read)
         assert (definition["scope"], definition["module"]) == \
             (scope, "jit_chunk_step")
-        # a later cell may be appended to a metric's list, never put before
-        assert listed[name]["workloads"][:2] == replay
+        # a later cell may be appended to a metric's list, never put before;
+        # the SP overlap's share is read where HBM bounds the scope only (at
+        # 32 columns the scan's carry holds its pools on chip: PERF.md s7)
+        head = replay[:1] if name == "sp_overlap_roofline.replay" else replay
+        assert listed[name]["workloads"][:2] == head
         assert (listed[name]["source"], listed[name]["layer"]) == \
             ("device_trace", "kernels")
     for name, (phase, per) in PHASES.items():
@@ -403,7 +405,7 @@ def metric_files_resolve_and_name_their_cells(reg: Registry) -> None:
         assert listed[name]["workloads"][:len(head)] == head
         assert (listed[name]["source"], listed[name]["layer"]) == \
             ("program_span", "stream groups")
-    assert len(SCOPE_MS) + len(ROOFLINES) + len(PHASES) == 18
+    assert len(SCOPE_MS) + len(ROOFLINES) + len(PHASES) == 17
 
 
 def test_the_new_metric_files_resolve_and_name_their_cells():

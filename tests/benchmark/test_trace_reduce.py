@@ -69,6 +69,68 @@ def test_window_clips_and_uncovered_gap_is_unattributed():
     assert r["modules"] == {}
 
 
+def _gaps_by_every_span(busy, window, spans):
+    """The attribution as first written: every gap held against every span,
+    shortest first (ties: the caller's order)."""
+    gaps, cursor = [], window[0]
+    for a, b in sorted(busy):
+        if a > cursor:
+            gaps.append((cursor, a))
+        cursor = max(cursor, b)
+    if cursor < window[1]:
+        gaps.append((cursor, window[1]))
+    out = {}
+    for gap in gaps:
+        left = [gap]
+        for name, s, e in sorted((sp for sp in spans if sp[2] > sp[1]),
+                                 key=lambda sp: sp[2] - sp[1]):
+            cut = []
+            for a, b in left:
+                lo, hi = max(a, s), min(b, e)
+                if hi > lo:
+                    out[name] = out.get(name, 0) + (hi - lo)
+                    cut += [(a, lo)] * (a < lo) + [(hi, b)] * (hi < b)
+                else:
+                    cut.append((a, b))
+            left = cut
+        if left:
+            out["unattributed"] = out.get("unattributed", 0) + \
+                sum(b - a for a, b in left)
+    return out
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_the_gap_sweep_attributes_as_every_gap_against_every_span(seed):
+    # a long window of many small gaps and many spans — nested, tied in
+    # length, empty, before and past the window: the sweep keeps only the
+    # spans that can overlap a gap and must give what the full product gives
+    import random
+    rnd = random.Random(seed)
+    busy, t = [], 0
+    for _ in range(300):
+        t += rnd.randint(1, 40)
+        d = rnd.randint(5, 60)
+        busy.append((t, t + d))
+        t += d
+    window = (busy[3][0] + 1, t - 7)
+    names = ["dispatch", "collect_wait", "collect_host", "feed", "tick"]
+    spans = [("tick", -50, t // 3), ("tick", t // 2, t + 90)]
+    for _ in range(400):
+        s = rnd.randint(-200, t + 100)
+        spans.append((rnd.choice(names), s,
+                      s + rnd.choice([0, 3, 3, 17, 17, 120, 250])))
+    planes = {"/device:TPU:0": {
+        "XLA Ops": [["%fusion.1 = f32[8]{0} fusion(%a)", a, b - a]
+                    for a, b in busy]}}
+    r = reduce(planes, window, spans, top=99)
+    clipped = [(max(a, window[0]), min(b, window[1])) for a, b in busy
+               if b > window[0] and a < window[1]]
+    want = _gaps_by_every_span(clipped, window, spans)
+    assert dict(r["idle_gaps"]) == {
+        k: pytest.approx(v * 1e-9, rel=1e-12) for k, v in want.items()}
+    assert len(want) > 3 and "unattributed" in want
+
+
 def test_no_device_plane_is_an_error():
     with pytest.raises(ValueError, match="no /device:TPU"):
         reduce({"/host:CPU": {"annotations": []}}, (0, 10))
