@@ -202,10 +202,13 @@ def _scan_chunk(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: Mod
     Used identically by the single-device and shard_map entry points, so the
     two can never diverge semantically.
 
-    The kernel-layout adapters sit OUTSIDE the scan: at narrow pool rows
-    (tm_tpu.wide_rows) the carry holds flat pools for all T ticks and the
-    public [C,K,S,M] layout is restored once per chunk (shape-only reshapes — checkpoints,
-    oracle parity, and the service API never see kernel layout). Likewise
+    The kernel-layout adapters sit OUTSIDE the scan: the carry holds the
+    pools in the kernel's layout for all T ticks (tm_tpu.wide_rows: flat at
+    narrow pool rows, a reshape; [C, M, K*S] at wide ones, a transpose a
+    pool each way — which a chunk of one tick could not win back, so it
+    keeps the public layout, tm_tpu.public_in_kernel) and the public
+    [C,K,S,M] layout is restored once per chunk — checkpoints, oracle
+    parity, and the service API never see kernel layout. Likewise
     the tick-invariant kernel operands (the flat layout's per-segment
     reduction matrix) are built ONCE here and closed over by the body, so
     they are hoisted out of the scan by construction and stay HBM-resident
@@ -219,11 +222,12 @@ def _scan_chunk(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: Mod
         return _tick(s, v, t, cfg, learn, inv, health=health,
                      predict=predict)
 
+    T = values.shape[0]
     with jax.named_scope("rtap.layout"):
-        state = to_kernel_layout(state, cfg.tm)
+        state = to_kernel_layout(state, cfg.tm, T)
     state, out = jax.lax.scan(body, state, (values, ts_unix))
     with jax.named_scope("rtap.layout"):
-        return from_kernel_layout(state, cfg.tm), out
+        return from_kernel_layout(state, cfg.tm, T), out
 
 
 # rtap: twin[oracle_record_step] — time-scanned form of the oracle chain

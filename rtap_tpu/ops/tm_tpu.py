@@ -5,7 +5,8 @@ One rule for every kernel of the step: index lists become masks by compare
 `.at[ids].set`, and small tables are read through such a grid too, never by
 `table[ids]`, because an element-wise gather or scatter costs 5-10 ns an
 element on a v5e (PRs 26, 28, 31; PERF.md s6). Only whole pool rows move by
-index (the wide-row form, `rtap.tm.learn.rows`).
+index (the wide-row form, `rtap.tm.learn.rows`), out of and into pools laid
+out so that such a row is one contiguous block.
 
 The reference's TM is Cells4.cpp/TemporalMemory.cpp over the Connections
 pointer graph (SURVEY.md C4/C5). TPU-native re-design (SURVEY.md §7 hard part
@@ -25,7 +26,7 @@ same semantics down by an order of magnitude):
    dendrite sweep; docs/KERNELS.md, "TM membership").
 2. **Column-compact learning workspace.** Every learning segment lives in an
    active column, so the learning pass gathers the <= col_cap active columns
-   into a [Ac, K, S, M] workspace (by one-hot moves over the whole pool at
+   into a [Ac, K*S*M] workspace (by one-hot moves over the whole pool at
    narrow pool rows — a compare-select reduce or an MXU matmul — by index
    at wide ones; below), does the compact reinforce/grow pass there
    (selecting <= learn_cap segments with a cheap top_k over Ac*K*S instead
@@ -42,9 +43,18 @@ The step has two forms and the static shape picks between them in one place
 (:func:`wide_rows`): below WIDE_ROW_LANES synapse lanes a pool row, one-hot
 MXU matmuls move the workspace's rows and the pools run flat ([C, K*S*M],
 per-segment reductions as a block-diagonal matmul); at or above it, the rows
-move by index and the pools keep [C, K, S, M]. Nothing outside this module
-knows there is a choice. In the narrow form the workspace path is
-region-consolidated: presyn + perm (+ seg_pot) ride ONE one-hot MXU pass per
+move by index and, in a scan over ticks, the pools run [C, M, K*S] — the
+segments on the lanes, a segment's synapses down the sublanes, so a column's
+row is contiguous for the moves AND the full-pool sweep has whole tiles on
+both minor dims; one layout serves both and no pool changes layout inside
+the scan (docs/KERNELS.md, "Why [C, M, K*S]"). A one-tick program has no
+later tick to win the two transposes a pool back on and runs the same form
+on the public [C, K, S, M] (:func:`public_in_kernel`). The kernel layouts are
+entered and left once a program (:func:`to_kernel_layout`,
+:func:`from_kernel_layout`; ops/step.py calls them under `rtap.layout`), and
+nothing outside this module knows there is a choice. In the narrow form the
+workspace path is region-consolidated:
+presyn + perm (+ seg_pot) ride ONE one-hot MXU pass per
 gather/scatter stage instead of one pass per tensor (bitwise identical per
 block — each output element touches only its own operand columns), the
 dendrite conn/pot counts share one block-diagonal reduction, and
@@ -101,11 +111,13 @@ def _tpu_paths() -> bool:
 #: 16,384 lanes (nab_preset, a 64 KiB row, G = 17) the matmul moves' f32
 #: copies of both pools do not fit the chip (RESOURCE_EXHAUSTED at compile,
 #: 15.76 of 15.75 GB; 18.6 GB of arguments and temporaries by the compiler's
-#: own account since ISSUE 36), and with indexed moves aos steps a tick in 158.2 ms
-#: against flat's 202.2 (131.7 against 154.6 at `learn_cap` 128; my chip
-#: runs, PR 27; PERF.md s6). The line is the geometric middle of the two
-#: points, 85x apart, rounded to a power of two; nothing between them has
-#: been measured.
+#: own account since ISSUE 36), and the indexed moves over [C, M, K*S] pools
+#: step a group-tick of 17 streams in 50.53 ms (75.96 with the public
+#: [C, K, S, M] layout in the kernel, which the chip holds columns-minor and
+#: re-laid four times a tick around the moves; chip runs, PRs 40 and 42;
+#: PERF.md s6).
+#: The line is the geometric middle of the two points, 85x apart, rounded to
+#: a power of two; nothing between them has been measured.
 WIDE_ROW_LANES = 2048
 
 
@@ -117,10 +129,15 @@ def _row_lanes(cfg: TMConfig) -> int:
 
 def wide_rows(cfg: TMConfig) -> bool:
     """Does this shape take the wide-row form (indexed workspace moves,
-    [C, K, S, M] pools) rather than the narrow-row one (one-hot moves over
-    the whole pool, flat [C, K*S*M] pools)? The one place the step's form is
-    decided; both forms are bit-identical to the oracle
-    (tests/parity/test_tm_forms.py)."""
+    [C, M, K*S] pools in the kernel) rather than the narrow-row one (one-hot
+    moves over the whole pool, flat [C, K*S*M] pools)? The one place the
+    step's form is decided; both forms are bit-identical to the oracle
+    (tests/parity/test_tm_forms.py).
+
+    What the wide layout assumes, for speed and never for correctness: K*S
+    (the lanes) a multiple of 128 and M (the sublanes) a multiple of 8, as
+    at the NAB width (512 and 32). Another wide shape pads its tiles — the
+    sweep and the moves touch the padding too — and computes the same."""
     return _row_lanes(cfg) >= WIDE_ROW_LANES
 
 
@@ -151,38 +168,70 @@ def gather_by_select(cfg: TMConfig) -> bool:
     return not wide_rows(cfg) and _row_lanes(cfg) % LANE_TILE == 0
 
 
-# TM state keys the narrow-row form runs flat: key -> how many trailing dims
-# collapse into one (pools: K,S,M -> K*S*M; segment tensors: K,S -> K*S).
-_FLAT_KEYS = {
+# TM state keys that change shape in the kernel: key -> how many trailing dims
+# the public layout spends on them (pools: K,S,M; segment tensors: K,S).
+_KERNEL_KEYS = {
     "presyn": 3, "syn_perm": 3,
     "seg_last": 2, "active_seg": 2, "matching_seg": 2, "seg_pot": 2,
 }
 
 
-def to_kernel_layout(state: dict, cfg: TMConfig) -> dict:
-    """Public state layout -> kernel layout (no-op at wide rows). Shape
-    change only — values are untouched, so checkpoints, the oracle, and the
-    parity harness all keep the public [C, K, S, M] layout. The layout
-    follows `cfg`'s shape (`wide_rows`), as `tm_step(cfg)` reads it."""
-    if wide_rows(cfg):
+def public_in_kernel(cfg: TMConfig, ticks: int) -> bool:
+    """Does a program of `ticks` ticks run its wide-row step on the public
+    [C, K, S, M] layout as it stands? Only the one-tick programs do (the
+    served path: `group_step`, `fused_step`, `chunk_step` at T = 1). The
+    chip holds that layout columns-minor and re-lays both pools around the
+    indexed row moves, four pool copies a tick; entering and leaving
+    [C, M, K*S] costs a one-tick program the same four, and there both
+    layouts of both pools are live together: 96.9 ms a group-tick of 17
+    NAB-width streams for 75.7, 13.7 GB of program for 7.7 (chip run and
+    compile, PR 40; on the public layout the one-tick program is the
+    earlier one byte for byte, 75.69 ms, PR 42). A scan of two ticks or
+    more wins them back on every tick but the first (50.53 ms at T = 8)."""
+    return wide_rows(cfg) and ticks == 1
+
+
+def to_kernel_layout(state: dict, cfg: TMConfig, ticks: int = 1) -> dict:
+    """Public state layout -> the layout `tm_step(cfg)` runs `ticks` ticks
+    on before `from_kernel_layout` restores it. The values are untouched, so
+    checkpoints, the oracle, and the parity harness all keep the public
+    [C, K, S, M] layout. At narrow rows a reshape: [C, K*S*M] pools, [C, K*S]
+    segment tensors. At wide rows (`wide_rows`) the same segment tensors and
+    the pools with their synapse axis turned inward, [C, M, K*S] — one
+    transpose a pool, entering a program, where the narrow form pays nothing
+    — unless the program runs one tick only (`public_in_kernel`): then
+    nothing changes shape."""
+    if public_in_kernel(cfg, ticks):
         return state
+    wide = wide_rows(cfg)
+    M = cfg.max_synapses_per_segment
     out = dict(state)
-    for k, nd in _FLAT_KEYS.items():
+    for k, nd in _KERNEL_KEYS.items():
         x = out[k]
-        out[k] = x.reshape(*x.shape[: x.ndim - nd], -1)
+        lead = x.shape[: x.ndim - nd]
+        if wide and nd == 3:
+            out[k] = x.reshape(*lead, -1, M).swapaxes(-1, -2)
+        else:
+            out[k] = x.reshape(*lead, -1)
     return out
 
 
-def from_kernel_layout(state: dict, cfg: TMConfig) -> dict:
-    """Kernel layout -> public state layout (no-op at wide rows)."""
-    if wide_rows(cfg):
+def from_kernel_layout(state: dict, cfg: TMConfig, ticks: int = 1) -> dict:
+    """Kernel layout -> public state layout (`to_kernel_layout`'s inverse,
+    at the same `ticks`)."""
+    if public_in_kernel(cfg, ticks):
         return state
+    wide = wide_rows(cfg)
     K, S, M = cfg.cells_per_column, cfg.max_segments_per_cell, cfg.max_synapses_per_segment
-    tails = {3: (K, S, M), 2: (K, S)}
     out = dict(state)
-    for k, nd in _FLAT_KEYS.items():
+    for k, nd in _KERNEL_KEYS.items():
         x = out[k]
-        out[k] = x.reshape(*x.shape[:-1], *tails[nd])
+        if nd == 2:
+            out[k] = x.reshape(*x.shape[:-1], K, S)
+        elif wide:
+            out[k] = x.swapaxes(-1, -2).reshape(*x.shape[:-2], K, S, M)
+        else:
+            out[k] = x.reshape(*x.shape[:-1], K, S, M)
     return out
 
 
@@ -202,8 +251,8 @@ def tm_invariants(cfg: TMConfig) -> dict | None:  # rtap: allow[twin-parity] —
     caller scanning over ticks (ops/step.py:_scan_chunk) can hoist them
     out of the scan body explicitly — they stay HBM-resident across the
     whole T-tick chunk instead of rematerializing as per-iteration
-    constants. None at wide rows ([C, K, S, M] pools reduce on the trailing
-    dim directly)."""
+    constants. None at wide rows ([C, M, K*S] pools sum their M axis
+    directly)."""
     if wide_rows(cfg):
         return None
     K, S, M = cfg.cells_per_column, cfg.max_segments_per_cell, cfg.max_synapses_per_segment
@@ -476,24 +525,25 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
     in-trace constants (single-dispatch callers).
     """
     wide = wide_rows(cfg)
-    if wide:
-        C, K, S, M = state["presyn"].shape
-    else:
-        K, S, M = cfg.cells_per_column, cfg.max_segments_per_cell, cfg.max_synapses_per_segment
-        if state["presyn"].ndim != 2:
-            raise ValueError(
-                "narrow pool rows: tm_step expects kernel-layout state "
-                "([C, K*S*M] pools — ops/step.py applies to_kernel_layout); "
-                f"got presyn shape {state['presyn'].shape}"
-            )
-        C = state["presyn"].shape[0]
+    K, S, M = cfg.cells_per_column, cfg.max_segments_per_cell, cfg.max_synapses_per_segment
+    C = state["presyn"].shape[0]
+    # a one-tick program's wide-row state comes as it stands (public_in_kernel)
+    m_minor = wide and state["presyn"].shape == (C, K, S, M)
+    pool_shape = (C, K, S, M) if m_minor else (C, M, K * S) if wide else (C, K * S * M)
+    seg_shape = (C, K, S) if m_minor else (C, K * S)
+    if state["presyn"].shape != pool_shape or state["seg_last"].shape != seg_shape:
+        raise ValueError(
+            f"{'wide' if wide else 'narrow'} pool rows: tm_step expects "
+            f"kernel-layout state ({'[C, M, K*S] or public' if wide else '[C, K*S*M]'} "
+            "pools — ops/step.py applies to_kernel_layout); "
+            f"got presyn shape {state['presyn'].shape}, "
+            f"seg_last shape {state['seg_last'].shape}"
+        )
+    m_ax = -1 if m_minor else -2  # the wide pools' synapse axis
     N = C * K
     L, Ac = cfg.learn_cap, cfg.col_cap
     if K > 32:
         raise ValueError("cells_per_column > 32 unsupported (packed cell masks)")
-
-    pool_shape = (C, K, S, M) if wide else (C, K * S * M)
-    seg_shape = (C, K, S) if wide else (C, K * S)
 
     def _red():
         if inv is not None:
@@ -503,9 +553,10 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
     def seg_sum(x):
         """Per-segment count over synapse lanes -> i32 [*seg_shape]. Flat
         pools reduce via the block-diagonal 0/1 MXU matmul (counts <= M <<
-        2^24: f32-exact) instead of a minor-dim sum the tiler pads."""
+        2^24: f32-exact) instead of a minor-dim sum the tiler pads; wide
+        pools sum their M axis."""
         if wide:
-            return x.sum(-1)
+            return x.sum(m_ax)
         return jnp.round(
             jax.lax.dot(x.astype(jnp.float32), _red(), precision=_HI)
         ).astype(jnp.int32)
@@ -516,7 +567,7 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
         instead of two (fused-region consolidation; bitwise identical per
         block — each output element touches only its own operand rows)."""
         if wide:
-            return a.sum(-1), b.sum(-1)
+            return a.sum(m_ax), b.sum(m_ax)
         both = jnp.round(
             jax.lax.dot(
                 jnp.concatenate([a, b], 0).astype(jnp.float32), _red(),
@@ -527,7 +578,9 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
 
     def seg_expand(x):
         """Broadcast a per-segment value onto its synapse lanes."""
-        return x[..., None] if wide else jnp.repeat(x, M, axis=-1)
+        if wide:
+            return x[..., None] if m_minor else x[:, None, :]
+        return jnp.repeat(x, M, axis=-1)
 
     # Permanence-domain constants (models/perm.py). The learning workspace
     # computes on integer-VALUED f32 in quantized domains (quanta <= 65535
@@ -549,8 +602,9 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
 
     # the scope names are the step's vocabulary (ops/step.py SCOPES)
     with jax.named_scope("rtap.tm.activate"):
-        # 4-D views of the SMALL segment tensors for the categorization logic
-        # (32 KB each — cheap to repack; the MB-scale pools never leave flat)
+        # [C, K, S] views of the SMALL segment tensors for the categorization
+        # logic (32 KB each — cheap to repack; the MB-scale pools never leave
+        # the kernel's layout)
         active_seg4 = state["active_seg"].reshape(C, K, S)
         matching_seg4 = state["matching_seg"].reshape(C, K, S)
         seg_pot4 = state["seg_pot"].reshape(C, K, S)
@@ -597,11 +651,22 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
             if wide:
                 # move only the <= Ac touched rows; fill slots (id C) clamp to a
                 # junk copy of row C-1 that is masked out of learning (ws_learn /
-                # ws_alloc are False there) and dropped at scatter-back
+                # ws_alloc are False there) and dropped at scatter-back. A
+                # [C, M, K*S] pool is indexed on its leading axis as it stands
+                # — a row is one contiguous [M, K*S] block — and what turns to
+                # the [K*S, M] order the compaction wants is the workspace,
+                # not the pool
                 idx_c = jnp.clip(col_ids, 0, C - 1)
+
+                def take_rows(pool, dt):
+                    """[Ac, K*S*M] rows of a pool, a row in the public order."""
+                    if m_minor:
+                        return pool.reshape(C, -1)[idx_c].astype(dt)
+                    return pool[idx_c].astype(dt).swapaxes(-1, -2).reshape(Ac, -1)
+
                 with jax.named_scope("rtap.tm.learn.rows"):
-                    ws_presyn = presyn.reshape(C, -1)[idx_c].astype(jnp.int32)
-                    ws_perm = syn_perm.reshape(C, -1)[idx_c].astype(jnp.float32)
+                    ws_presyn = take_rows(presyn, jnp.int32)
+                    ws_perm = take_rows(syn_perm, jnp.float32)
                     ws_last = seg_last.reshape(C, -1)[idx_c].reshape(Ac, K, S)
                     ws_pot = state["seg_pot"].reshape(C, -1)[idx_c].astype(jnp.int32).reshape(Ac, K, S)
                     ws_learn = (
@@ -742,28 +807,21 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
             # --- scatter the workspace back to the pools ---
             if wide:
                 # only the <= Ac touched rows are written; fill ids (C) drop
+                def put_rows(x, ws):
+                    """The workspace's [Ac] rows into `x` where it lies."""
+                    if x.ndim == 3 and not m_minor:  # a [C, M, K*S] pool
+                        flat, rows = x, ws.reshape(Ac, K * S, M).swapaxes(-1, -2)
+                    else:
+                        flat, rows = x.reshape(C, -1), ws.reshape(Ac, -1)
+                    return flat.at[col_ids].set(rows.astype(x.dtype), mode="drop").reshape(x.shape)
+
                 with jax.named_scope("rtap.tm.learn.rows"):
-                    presyn = (
-                        presyn.reshape(C, -1)
-                        .at[col_ids]
-                        .set(ws_presyn_r.reshape(Ac, -1).astype(presyn_dt), mode="drop")
-                        .reshape(*pool_shape)
-                    )
+                    presyn = put_rows(presyn, ws_presyn_r)
                     ws_perm_w = ws_perm_r.reshape(Ac, -1)
                     if dom.bits:
                         ws_perm_w = jnp.round(ws_perm_w)  # exact already; belt+braces
-                    syn_perm = (
-                        syn_perm.reshape(C, -1)
-                        .at[col_ids]
-                        .set(ws_perm_w.astype(p_dt), mode="drop")
-                        .reshape(*pool_shape)
-                    )
-                    seg_last = (
-                        seg_last.reshape(C, -1)
-                        .at[col_ids]
-                        .set(ws_last.reshape(Ac, -1), mode="drop")
-                        .reshape(*seg_shape)
-                    )
+                    syn_perm = put_rows(syn_perm, ws_perm_w)
+                    seg_last = put_rows(seg_last, ws_last)
             else:
                 hit_pool = hit_cols.reshape(C, *([1] * (len(pool_shape) - 1)))
                 hit_seg = hit_cols.reshape(C, *([1] * (len(seg_shape) - 1)))

@@ -188,6 +188,54 @@ def test_chunk_step_relays_no_pool_inside_the_scan(v5e, preset):
         assert not [ty for ty, _ in results if re.search("f32" + pool, ty)]
 
 
+@pytest.fixture(scope="module")
+def nab_chunk(v5e):
+    """`chunk_step` at the published NAB width and the benchmark cell's batch
+    (17 streams), T = 2, learning on, as the chip's compiler makes it — one
+    compile (~20 s) for the tests that read it."""
+    from rtap_tpu.ops.step import chunk_step
+
+    cfg = nab_preset(0.0, 100.0)
+    assert tm_tpu.wide_rows(cfg.tm) and not tm_tpu.wide_rows(cluster_preset().tm)
+    return chunk_step.lower(*_step_args(cfg, v5e, T=2, g=17), cfg,
+                            learn=True).compile()
+
+
+def test_nab_chunk_step_relays_no_pool_inside_the_scan(nab_chunk):
+    """At wide rows the kernel holds the pools `[C, M, K*S]`, so a column's
+    row — what the learning workspace's indexed moves take and put back — is
+    one contiguous 64 KiB block, and the fused sweep runs in the same layout
+    (K*S = 512 on the lanes, M = 32 on the sublanes: whole tiles both): the
+    `while` body holds no `copy` and no `transpose` with a pool-sized result.
+    With the public `[C, K, S, M]` layout in the kernel it held four a tick
+    (`{s32,f32}[17,2048,16384]`, rows contiguous for the moves and back to
+    columns-minor for the sweep: 27 of a 76 ms group-tick, ISSUE 40); they
+    stand in ENTRY now, once a program (`rtap.layout`)."""
+    text = nab_chunk.as_text()
+    cfg = nab_preset(0.0, 100.0).tm
+    slots = cfg.cells_per_column * cfg.max_segments_per_cell * cfg.max_synapses_per_segment
+
+    def pool_sized(ty):
+        """Does the result type `ty` name an array of 17 x 2048 x 16,384?"""
+        for dims in re.findall(r"\[(17,2048,[\d,]+)\]", ty):
+            if np.prod([int(d) for d in dims.split(",")[2:]]) == slots:
+                return True
+        return False
+
+    results = re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = (.+?) ([\w\-]+)\(", _scan_body(text), re.M)
+    assert len(results) > 200  # a whole tick's instructions were read
+    assert any(pool_sized(ty) for ty, _ in results)  # the pattern bites
+    assert not [(ty, op) for ty, op in results
+                if op in ("copy", "transpose") and pool_sized(ty)]
+    # the moves update the pools where they lie
+    assert [ty for ty, op in results if op == "fusion" and "scatter" not in ty
+            and re.match(r"[sf]32\[17,2048,32,512\]\{3,2,1,0", ty)]
+    # and the layout changes are the adapters', outside the loop
+    entry = text[text.index("\nENTRY "):]
+    moved = re.findall(r"= ([sf]32\[17,2048,[\d,]+\]\S*) copy\(", entry)
+    assert len([ty for ty in moved if pool_sized(ty)]) == 4, moved
+
+
 def test_nab_width_step_scatters_whole_rows_only(v5e):
     """At the NAB width the lowered step keeps its scatters — the learning
     workspace's rows moved by index — and each of them moves a window (a
@@ -205,31 +253,49 @@ def test_nab_width_step_scatters_whole_rows_only(v5e):
         assert re.search(r"update_window_dims = \[\d", d), d
 
 
-@pytest.mark.parametrize("forms", ["by_shape", "narrow_forced"])
-def test_nab_width_chunk_step_fits_a_v5e_only_in_the_wide_row_forms(v5e, forms, monkeypatch):
+@pytest.mark.parametrize("forms", ["by_shape", "by_shape_one_tick", "narrow_forced"])
+def test_nab_width_chunk_step_fits_a_v5e_only_in_the_wide_row_forms(v5e, nab_chunk, forms, monkeypatch):
     """The published NAB width (2048 x 32 x 16 x 32: 16,384-lane pool rows)
     at the benchmark cell's batch of 17 streams. In the form the shape rule
-    picks (tm_tpu.wide_rows: indexed row moves, [C, K, S, M] pools) the
-    program fits the chip; in the narrow-row form the cluster presets run —
-    the line moved over this shape, here — it does not: the chip's compiler
-    refuses it for memory, or passes it at more bytes than the chip has. The
-    reason the line exists."""
+    picks (tm_tpu.wide_rows: indexed row moves; [C, M, K*S] pools in a scan
+    over ticks, the public layout in a one-tick program) the program fits
+    the chip; in the narrow-row form the cluster
+    presets run — the line moved over this shape, here — it does not: the
+    chip's compiler refuses it for memory, or passes it at more bytes than
+    the chip has. The reason the line exists."""
     from rtap_tpu.ops.step import chunk_step
 
     cfg = nab_preset(0.0, 100.0)
-    assert tm_tpu.wide_rows(cfg.tm) and not tm_tpu.wide_rows(cluster_preset().tm)
-    args = _step_args(cfg, v5e, T=2, g=17)
     if forms == "by_shape":
-        compiled = chunk_step.lower(*args, cfg, learn=True).compile()
-        mem = compiled.memory_analysis()
-        assert "tpu_custom_call" not in compiled.as_text()
+        mem = nab_chunk.memory_analysis()
+        assert "tpu_custom_call" not in nab_chunk.as_text()
         assert mem.argument_size_in_bytes >= 17 * 281_628_693
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14 * 2 ** 30
-        # growth's [L, R, W] rank-match grid (1,280 x 20 x 1,280 a stream:
-        # 2.2 GB if it were a buffer) fuses into its reduce: the temporaries
-        # stay under the 5,111,318,528 B the gather form took (ISSUE 28)
-        assert mem.temp_size_in_bytes <= 5_111_318_528
+        # the carry's two kernel-layout pools (2 x 2.28 GB) stand beside the
+        # donated public-layout arguments, which the outputs alias, for the
+        # whole scan: 4,777,026,560 B of temporaries where the program that
+        # re-laid the pools every tick took 2,744,192,512 (ISSUE 40) — what
+        # a state that crosses chunks in the kernel's layout would win back.
+        # Growth's [L, R, W] rank-match grid (1,280 x 20 x 1,280 a stream:
+        # 2.2 GB if it were a buffer) still fuses into its reduce (ISSUE 28)
+        assert mem.temp_size_in_bytes <= 4_777_026_560
         return
+    if forms == "by_shape_one_tick":
+        # the served tick's program (`StreamGroup` calls chunk_step at T = 1
+        # in the live loop): with no later tick to win the adapters' four
+        # transposes back on, and both layouts of both pools live together
+        # if it took them (13.7 GB, 8.9 of them temporaries; 96.9 ms a tick
+        # for 75.7 on the chip, ISSUE 40), it keeps the public layout in the
+        # kernel (tm_tpu.public_in_kernel) and the 2,934,913,024 B of
+        # temporaries it had before: 7.72 GB, about 34 streams a chip
+        compiled = chunk_step.lower(*_step_args(cfg, v5e, T=1, g=17), cfg,
+                                    learn=True).compile()
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes <= 2_934_913_024
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 7.73 * 10 ** 9
+        assert not re.findall(r"\[17,2048,32,512\]", compiled.as_text())
+        return
+    args = _step_args(cfg, v5e, T=2, g=17)
     monkeypatch.setattr(tm_tpu, "WIDE_ROW_LANES", 1 << 30)
     jax.clear_caches()  # the form is read at trace time
     try:

@@ -1,6 +1,9 @@
 """The TM step has two forms and `tm_tpu.wide_rows(cfg)` picks one from the
 static shape: below `WIDE_ROW_LANES` synapse lanes a pool row, one-hot matmul
-moves over flat pools; at or above it, indexed moves over [C, K, S, M] pools.
+moves over flat pools; at or above it, indexed moves over pools a scan over
+ticks holds [C, M, K*S] (a column's row contiguous) and a one-tick program
+leaves in the public [C, K, S, M] (`to_kernel_layout` / `from_kernel_layout`
+decide, `tm_tpu.public_in_kernel`).
 Within the narrow-row form `tm_tpu.gather_by_select(cfg)` picks the workspace
 gather the same way: a compare-select reduce in the pools' own types where a
 row fills whole 128-lane tiles (128, 384, 512 lanes here), the one-hot matmul
@@ -98,11 +101,13 @@ def test_form_equals_the_oracle_end_to_end(tpu_paths, rows, perm_bits, learn):
 
 @exact_only
 @pytest.mark.parametrize("program", ["group_step", "chunk_step"])
-@pytest.mark.parametrize("rows", ["lanes128", "lanes192", "lanes384"])
+@pytest.mark.parametrize("rows", ["lanes128", "lanes192", "lanes384", "wide"])
 def test_gather_equals_the_oracle_through_the_group_programs(rows, program):
-    """Both gathers under the programs the service runs — vmapped over a
-    group's streams, and inside the chunk's scan: three streams of one group
-    against three oracles, raw scores tick by tick and every leaf after."""
+    """Both narrow-row gathers, and the wide form's indexed one, under the
+    programs the service runs — vmapped over a group's streams (at wide rows
+    on the public layout), and inside the chunk's scan (there on [C, M, K*S]
+    pools): three streams of one group against three oracles, raw scores
+    tick by tick and every leaf after."""
     from rtap_tpu.models.htm_model import oracle_record_step
     from rtap_tpu.models.oracle.temporal_memory import TMOracle
     from rtap_tpu.ops.step import chunk_step, group_step, replicate_state
@@ -138,6 +143,83 @@ def test_gather_equals_the_oracle_through_the_group_programs(rows, program):
                                           err_msg=f"{k} stream {g}")
     assert int(np.asarray(dev["tm_overflow"]).sum()) == 0
     assert (np.asarray(dev["presyn"]) >= 0).sum() > 100 * G  # they really learned
+
+
+@pytest.mark.parametrize("ticks", [1, 8], ids=["one_tick", "a_scan"])
+@pytest.mark.parametrize("lead", ["one_stream", "a_group"])
+@pytest.mark.parametrize("rows", ["wide", "lanes192", "lanes384"])
+def test_layout_adapters_round_trip_leaf_for_leaf(rows, lead, ticks):
+    """`from_kernel_layout(to_kernel_layout(s)) == s`, every leaf's values,
+    shape and type, on a learned state (so the pools hold distinct values
+    wherever a transposition could misplace one) — one stream's state, as
+    `fused_step` hands it over, and a group's with its leading G axis, as
+    `group_step` / `chunk_step` do. In between, the pools have the kernel's
+    shape: [C, K*S*M] at narrow rows; at wide ones [C, M, K*S] for a scan
+    over ticks and the public shape, untouched, for a one-tick program."""
+    from rtap_tpu.ops.step import replicate_state
+
+    cfg = form_cfg(rows, 16)
+    model = HTMModel(cfg, seed=23, backend="cpu")
+    vals = make_values(120, 1, seed=3)
+    for i in range(120):
+        model.run(1_700_000_000 + 300 * i, float(vals[i, 0]))
+    state = {k: np.asarray(v) for k, v in model.state.items()}
+    assert (state["presyn"] >= 0).sum() > 100
+    # distinct values in every slot, so a misplaced one cannot hide
+    state["presyn"] = np.arange(state["presyn"].size, dtype=state["presyn"].dtype
+                                ).reshape(state["presyn"].shape)
+    if lead == "a_group":
+        state = replicate_state(state, 3)
+        state["presyn"][1] += 1
+    tm = cfg.tm
+    K, S, M = tm.cells_per_column, tm.max_segments_per_cell, tm.max_synapses_per_segment
+    C = cfg.sp.columns
+    assert tm_tpu.public_in_kernel(tm, ticks) == (rows == "wide" and ticks == 1)
+    kernel = tm_tpu.to_kernel_layout(state, tm, ticks)
+    g = (3,) if lead == "a_group" else ()
+    if tm_tpu.public_in_kernel(tm, ticks):
+        assert kernel is state
+    else:
+        want = (C, M, K * S) if rows == "wide" else (C, K * S * M)
+        assert kernel["presyn"].shape == kernel["syn_perm"].shape == g + want
+        assert kernel["seg_last"].shape == kernel["seg_pot"].shape == g + (C, K * S)
+        # the kernel's slot [c, m, k*S + s] is the public one [c, k, s, m]
+        pub = state["presyn"].reshape(*g, C, K * S, M)
+        if rows == "wide":
+            np.testing.assert_array_equal(kernel["presyn"][..., 5, 7, 9], pub[..., 5, 9, 7])
+    back = tm_tpu.from_kernel_layout(kernel, tm, ticks)
+    assert back.keys() == state.keys()
+    for k, v in state.items():
+        assert back[k].shape == v.shape and back[k].dtype == v.dtype, k
+        np.testing.assert_array_equal(np.asarray(back[k]), v, err_msg=k)
+
+
+@pytest.mark.parametrize("rows", ["wide", "narrow"])
+def test_tm_step_refuses_a_state_in_another_forms_layout(rows):
+    """Either form runs on the layouts its adapters hand it and says so when
+    handed another, instead of computing on misread axes: the narrow form
+    refuses the public [C, K, S, M] pools; the wide one takes them (a
+    one-tick program's) and [C, M, K*S] (a scan's), and refuses flat pools
+    and a state half turned."""
+    from tests.parity.test_tm_parity import TM_KEYS
+
+    cfg = form_cfg(rows, 16)
+    st = init_state(cfg, 3)
+    public = {k: np.asarray(st[k]) for k in TM_KEYS}
+    active = np.zeros(cfg.sp.columns, bool)
+    turned = tm_tpu.to_kernel_layout(public, cfg.tm, 8)
+    if rows == "narrow":
+        wrong = [public]
+    else:
+        flat = {**turned, **{k: public[k].reshape(cfg.sp.columns, -1)
+                             for k in ("presyn", "syn_perm")}}
+        wrong = [flat, {**turned, "seg_last": public["seg_last"]},
+                 {**public, "seg_last": turned["seg_last"]}]
+    for state in wrong:
+        with pytest.raises(ValueError, match=rf"{rows} pool rows.*kernel-layout"):
+            tm_tpu.tm_step(state, active, cfg.tm, learn=True)
+    for ticks in (1, 8):
+        tm_tpu.tm_step(tm_tpu.to_kernel_layout(public, cfg.tm, ticks), active, cfg.tm, learn=True)
 
 
 _LOWER = """

@@ -54,25 +54,36 @@ def _assert_state_equal(host, dev, step):
         )
 
 
-@pytest.fixture(params=["narrow", "wide"])
+#: how many ticks the layout adapters are told the program runs: 1 keeps the
+#: public layout in the wide-row kernel (the served one-tick programs), more
+#: turns the pools [C, M, K*S] (a chunk's scan). The `rows` fixture sets it.
+_TICKS = 1
+
+
+@pytest.fixture(params=["narrow", "wide", "wide_one_tick"])
 def rows(request, monkeypatch):
     """Every scenario in both forms of the step at ITS OWN shape (the pools
     must fill for the eviction branches, which a shape wide by itself never
     does here): the line between the forms is moved under the shape, and the
-    caches cleared because the form is read at trace time."""
+    caches cleared because the form is read at trace time. The wide form in
+    both layouts it runs on: [C, M, K*S] pools as a chunk's scan holds them
+    ("wide"), the public layout as a one-tick program does."""
     monkeypatch.setattr(tm_tpu, "WIDE_ROW_LANES",
-                        1 if request.param == "wide" else 1 << 30)
+                        1 << 30 if request.param == "narrow" else 1)
+    monkeypatch.setattr(
+        "tests.parity.test_tm_parity._TICKS", 2 if request.param == "wide" else 1)
     jax.clear_caches()
     yield request.param
     jax.clear_caches()
 
 
-def _run_parity(C, cfg, sequences, learn=True, host=None):
+def _run_parity(C, cfg, sequences, learn=True, host=None, ticks=None):
+    ticks = _TICKS if ticks is None else ticks
     host = _init_tm_state(C, cfg) if host is None else host
     # the public [C, K, S, M] layout crosses the boundary via the same
     # reshape adapters ops/step.py uses
     dev = to_kernel_layout(
-        {k: jnp.asarray(v) for k, v in copy.deepcopy(host).items()}, cfg)
+        {k: jnp.asarray(v) for k, v in copy.deepcopy(host).items()}, cfg, ticks)
     oracle = TMOracle(host, cfg)
     for step, cols in enumerate(sequences):
         active = np.zeros(C, bool)
@@ -80,7 +91,7 @@ def _run_parity(C, cfg, sequences, learn=True, host=None):
         raw_host = oracle.compute(active, learn=learn)
         dev, raw_dev = tm_step(dev, jnp.asarray(active), cfg, learn=learn)
         assert abs(raw_host - float(raw_dev)) < 1e-6, f"raw score step {step}"
-        _assert_state_equal(host, from_kernel_layout(dev, cfg), step)
+        _assert_state_equal(host, from_kernel_layout(dev, cfg, ticks), step)
 
 
 def _pattern(rng, C, n_active):
@@ -129,15 +140,46 @@ def test_tm_parity_random_stream_with_eviction(rows):
     _run_parity(C, cfg, seq)
 
 
+@pytest.mark.parametrize("slots", ["every_slot_a_column", "last_column_beside_fills",
+                                   "some_ticks_all_fills"])
+def test_tm_parity_workspace_slots_at_their_edges(rows, compact_paths, slots):
+    """The learning workspace's `col_cap` slots, in both forms and under both
+    `_compact_ids`: a burst that fills every slot (no fill id anywhere); fewer
+    active columns than slots with column C-1 among them — at wide rows a fill
+    slot (id C) is gathered from a clamped index, a junk copy of row C-1, and
+    the scatter back must drop it (`mode="drop"`), not lay it over the real
+    row C-1's learning; and ticks with no active column at all, every slot a
+    fill."""
+    C, cap = 32, 6
+    cfg = TMConfig(
+        cells_per_column=4, activation_threshold=2, min_threshold=1,
+        max_segments_per_cell=2, max_synapses_per_segment=6,
+        new_synapse_count=4, learn_cap=cap * 4 * 2, col_cap=cap,
+    )
+    rng = np.random.default_rng(47)
+    if slots == "every_slot_a_column":
+        pats = [np.append(_pattern(rng, C - 1, cap - 1), C - 1) if i % 2
+                else _pattern(rng, C, cap) for i in range(5)]
+        seq = pats * 8 + [_pattern(rng, C, cap) for _ in range(40)]
+    else:
+        pats = [np.append(_pattern(rng, C - 1, 3), C - 1) for _ in range(4)]
+        seq = pats * 8 + [np.append(_pattern(rng, C - 1, 2), C - 1) for _ in range(40)]
+        if slots == "some_ticks_all_fills":
+            seq = [p if i % 5 else np.array([], int) for i, p in enumerate(seq)]
+    _run_parity(C, cfg, seq)
+
+
 @pytest.mark.quick
-@pytest.mark.parametrize("S,M,wide,select", [
-    (2, 6, False, False), (16, 32, True, False),
-    (4, 8, False, True), (4, 12, False, False), (8, 12, False, True),
-], ids=["narrow", "wide", "lanes128", "lanes192", "lanes384"])
-def test_tm_parity_explicit_layouts(S, M, wide, select):
+@pytest.mark.parametrize("S,M,wide,select,ticks", [
+    (2, 6, False, False, 1), (16, 32, True, False, 2), (16, 32, True, False, 1),
+    (4, 8, False, True, 1), (4, 12, False, False, 1), (8, 12, False, True, 1),
+], ids=["narrow", "wide", "wide_one_tick", "lanes128", "lanes192", "lanes384"])
+def test_tm_parity_explicit_layouts(S, M, wide, select, ticks):
     """Full state parity in BOTH forms where the shape itself picks the form
     (the other tests move the line under one shape): 48 lanes a row, and
-    2,048 — and, within the narrow form, under both workspace gathers where
+    2,048 — there in both layouts the wide form runs on, [C, M, K*S] as a
+    chunk's scan holds the pools and the public one of a one-tick program —
+    and, within the narrow form, under both workspace gathers where
     the shape picks the gather: the compare-select reduce at rows of whole
     128-lane tiles (128, 384), the one-hot matmul at 192 (and at 48)."""
     C, cfg = 32, TMConfig(
@@ -149,7 +191,7 @@ def test_tm_parity_explicit_layouts(S, M, wide, select):
     assert tm_tpu.gather_by_select(cfg) == select
     rng = np.random.default_rng(29)
     seq = [_pattern(rng, C, 4) for _ in range(60)]
-    _run_parity(C, cfg, seq)
+    _run_parity(C, cfg, seq, ticks=ticks)
 
 
 @pytest.mark.parametrize("perm", ["u16", "u8", "f32"])
@@ -343,30 +385,39 @@ def test_best_matching_mask_is_the_oracles_choice(case, rows):
     np.testing.assert_array_equal(np.asarray(learn_mask), want)
 
 
-def test_forms_agree_when_learning_overflows(compact_paths, monkeypatch):
+@pytest.mark.parametrize("cut", ["learn_cap", "col_cap"])
+def test_forms_agree_when_learning_overflows(compact_paths, monkeypatch, cut):
     """Past `learn_cap` the oracle (which has no cap) is no yardstick, but
     the two forms still are for each other: the first `learn_cap` learning
-    segments learn and are stamped, the rest wait. The wide form names the
-    stamped rows by a compare against the largest compacted id, the narrow
-    one by the compacted ids' one-hot rows; same rows, same state."""
+    segments learn and are stamped, the rest wait. The wide form (in either
+    layout of its pools) names the stamped rows by a compare against the
+    largest compacted id, the narrow
+    one by the compacted ids' one-hot rows; same rows, same state. Past
+    `col_cap` likewise: the first `col_cap` active columns enter the
+    workspace — by index at wide rows, every slot a column and none a fill,
+    by one-hot rows at narrow ones — and the rest learn nothing."""
     C, cfg = 32, TMConfig(
         cells_per_column=4, activation_threshold=2, min_threshold=1,
         max_segments_per_cell=2, max_synapses_per_segment=6,
-        new_synapse_count=4, learn_cap=3, col_cap=8,
+        new_synapse_count=4, **({"learn_cap": 3, "col_cap": 8} if cut == "learn_cap"
+                                else {"learn_cap": 32, "col_cap": 4}),
     )
     finals = {}
-    for form, lanes in (("wide", 1), ("narrow", 1 << 30)):
+    for form, lanes, ticks in (("wide", 1, 2), ("wide_one_tick", 1, 1),
+                               ("narrow", 1 << 30, 1)):
         monkeypatch.setattr(tm_tpu, "WIDE_ROW_LANES", lanes)
         jax.clear_caches()
         dev = to_kernel_layout(
-            {k: jnp.asarray(v) for k, v in _init_tm_state(C, cfg).items()}, cfg)
+            {k: jnp.asarray(v) for k, v in _init_tm_state(C, cfg).items()}, cfg, ticks)
         rng = np.random.default_rng(5)
         for _ in range(40):
             active = np.zeros(C, bool)
             active[_pattern(rng, C, 6)] = True
             dev, _ = tm_step(dev, jnp.asarray(active), cfg, learn=True)
-        finals[form] = jax.device_get(from_kernel_layout(dev, cfg))
+        finals[form] = jax.device_get(from_kernel_layout(dev, cfg, ticks))
     jax.clear_caches()
     assert int(finals["wide"]["tm_overflow"]) > 0  # the cap really cut
-    for key in TM_KEYS:
-        np.testing.assert_array_equal(finals["wide"][key], finals["narrow"][key], err_msg=key)
+    for form in ("wide", "wide_one_tick"):
+        for key in TM_KEYS:
+            np.testing.assert_array_equal(finals[form][key], finals["narrow"][key],
+                                          err_msg=f"{form} {key}")

@@ -98,7 +98,8 @@ def test_step_lowers_membership_with_no_candidate_axis_and_no_division(force_tpu
     decode, so nothing stands between the pool and the reduce over its
     synapse lanes. Held by the lowering, at both cluster presets and at a
     wide-row shape of a tiny size: no tensor carries a trailing Ac axis
-    behind the pool's dims ([C, K*S*M, Ac] flat, [C, K, S, M, Ac] wide — the
+    behind the pool's dims ([C, K*S*M, Ac] flat; at wide rows [C, M, K*S, Ac]
+    in a chunk's scan and [C, K, S, M, Ac] in a one-tick program — the
     parent held 23 at `cluster_preset`), and no `stablehlo.divide` or
     `stablehlo.remainder` works on a pool-shaped operand (6 and 9 there).
     The mechanism engages always or never; this says which."""
@@ -112,7 +113,9 @@ def test_step_lowers_membership_with_no_candidate_axis_and_no_division(force_tpu
     tm = cfg.tm
     K, S, M = tm.cells_per_column, tm.max_segments_per_cell, tm.max_synapses_per_segment
     assert tm_tpu.wide_rows(tm) == (preset == "wide")
-    pool = f"{cfg.sp.columns}x{K}x{S}x{M}" if preset == "wide" else f"{cfg.sp.columns}x{K * S * M}"
+    pool = (f"{cfg.sp.columns}x{K * S * M}" if preset != "wide" else
+            f"{cfg.sp.columns}x{M}x{K * S}" if program == "chunk_step" else
+            f"{cfg.sp.columns}x{K}x{S}x{M}")
     text = _lowered_text(cfg, program)
     assert f"x{pool}x" in text  # the pools are in the program, in this spelling
     assert not re.findall(rf"tensor<(?:\d+x)*{pool}x{tm.col_cap}x\w+>", text)

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from rtap_tpu.config import node_preset, scaled_cluster_preset
+from rtap_tpu.config import cluster_preset, node_preset, scaled_cluster_preset
 from rtap_tpu.models.state import init_state
 from rtap_tpu.obs.trace import span
 from rtap_tpu.ops import tm_tpu
@@ -65,6 +65,59 @@ def test_layout_adapters_are_scoped(entry):
             _group_state(cfg), jnp.zeros((T, G, 1), jnp.float32),
             jnp.zeros((T, G), jnp.int32), cfg, learn=False)
     assert "rtap.layout/reshape" in low.as_text(debug_info=True)
+
+
+def _ops_under(text: str, op: str, scope: str) -> list[str]:
+    """The lines of a lowering (`as_text(debug_info=True)`) that hold the
+    StableHLO op `op` and whose name stack — resolved through the `#loc`
+    table at the end of the text — passes through `scope`."""
+    locs = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, re.M))
+    found = []
+    for line in text.splitlines():
+        ref = re.search(r"loc\((#loc\d+)\)\s*$", line)
+        if ref and f"stablehlo.{op}" in line and scope in locs.get(ref.group(1), ""):
+            found.append(line)
+    return found
+
+
+@pytest.mark.parametrize("program", ["chunk_step", "chunk_step_one_tick", "group_step"])
+@pytest.mark.parametrize("shape", ["cluster", "cluster32", "node3", "wide"])
+def test_only_a_wide_row_scan_pays_a_transpose_at_the_layout_boundary(shape, program):
+    """At narrow pool rows the kernel layout is a reshape of the public one
+    and the adapters move no data: the three narrow-row presets the
+    benchmark's cells run lower to no `stablehlo.transpose` under
+    `rtap.layout` (ISSUE 40 left their programs the parent's, byte for
+    byte). At wide rows a scan over ticks takes the pools as [C, M, K*S]:
+    two transposes in, two out, once a program — and none anywhere else in
+    the step, so no pool turns inside the scan. A one-tick program
+    (`group_step`, `chunk_step` at T = 1: the served path) has no later tick
+    to win them back on and keeps the public layout in the kernel: no
+    transpose, and no op at all under `rtap.layout`."""
+    from tests.parity.test_tm_forms import form_cfg
+
+    cfg = {"cluster": cluster_preset, "cluster32": lambda: scaled_cluster_preset(32),
+           "node3": lambda: node_preset(3), "wide": lambda: form_cfg("wide", 16)}[shape]()
+    assert tm_tpu.wide_rows(cfg.tm) == (shape == "wide")
+    lead = {"chunk_step": (T,), "chunk_step_one_tick": (1,), "group_step": ()}[program]
+    text = (group_step if program == "group_step" else chunk_step).lower(
+        _group_state(cfg), jnp.zeros((*lead, G, cfg.n_fields), jnp.float32),
+        jnp.zeros((*lead, G), jnp.int32), cfg, learn=True).as_text(debug_info=True)
+    turned = _ops_under(text, "transpose", "rtap.layout")
+    if shape == "wide" and program != "chunk_step":
+        assert "rtap.layout" not in text
+        assert _ops_under(text, "reshape", "rtap.tm.learn.rows")  # the resolver bites
+        return
+    assert _ops_under(text, "reshape", "rtap.layout")  # the resolver bites
+    tm = cfg.tm
+    pool = (f"{cfg.sp.columns}x{tm.max_synapses_per_segment}x"
+            f"{tm.cells_per_column * tm.max_segments_per_cell}x")
+    if shape == "wide":
+        assert len(turned) == 4 and sum(f"-> tensor<{G}x{pool}" in ln for ln in turned) == 2
+        in_step = [ln for ln in _ops_under(text, "transpose", "rtap.tm.")
+                   if f"x{pool}" in ln]
+        assert not in_step, in_step[:2]
+    else:
+        assert not turned
 
 
 def test_optional_reducers_are_scoped():
