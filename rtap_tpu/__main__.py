@@ -330,6 +330,12 @@ def _cmd_serve(args: argparse.Namespace) -> int:
         from rtap_tpu.config import categorical_preset
 
         cfg = categorical_preset()
+    elif args.preset == "node":
+        from rtap_tpu.config import node_preset
+
+        # one model a node over --fields metrics (cpu, mem, net): a wire
+        # record is {"id", "values": [..F..], "ts"}, docs/INGEST.md
+        cfg = node_preset(args.fields)
     else:
         cfg = _sized_cluster(args)
     cfg = _apply_cadence(cfg, args)
@@ -492,10 +498,16 @@ def _cmd_serve(args: argparse.Namespace) -> int:
                   file=sys.stderr)
         source, close = bsrc, bsrc.close
     else:
+        # the record's width is the model's, never the first record's: a
+        # multi-field model (node, composite) takes {"id", "values", "ts"}
         tcp = TcpJsonlSource(ids, port=args.port,
                              track_unknown=args.auto_register,
-                             native=native).start()
+                             native=native, n_fields=cfg.n_fields).start()
         host, port = tcp.address
+        if cfg.n_fields > 1:
+            print(f"serve: a JSONL record carries \"values\": a list of "
+                  f"{cfg.n_fields} (null = a missing metric)",
+                  file=sys.stderr)
         print(f"serve: listening for JSONL records on {host}:{port}", file=sys.stderr)
         source, close = tcp, tcp.close
     # telemetry exposition (rtap_tpu.obs): a localhost /metrics endpoint for
@@ -987,14 +999,23 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--ticks", type=int, default=60)
     p.add_argument("--cadence", type=float, default=1.0)
     p.add_argument("--preset", choices=("cluster", "nab", "composite",
-                                        "categorical"), default="cluster",
+                                        "categorical", "node"),
+                   default="cluster",
                    help="model family: cluster (scalar RDSE, the "
                         "default), nab (NAB-scale), composite (the "
                         "ISSUE 9 multi-field encoder preset — each wire "
                         "record is [value, delta, event-class] fused "
-                        "with the hour-of-day ring into one SDR), or "
+                        "with the hour-of-day ring into one SDR), "
                         "categorical (single event-class/log-template "
-                        "field; docs/WORKLOADS.md encoder family)")
+                        "field; docs/WORKLOADS.md encoder family), or "
+                        "node (one model a node over --fields metrics — "
+                        "cpu, mem, net — fused into one SDR; a stream id "
+                        "is a node and a JSONL record carries "
+                        "\"values\": [..F..], null for a missing metric)")
+    p.add_argument("--fields", type=int, default=None, metavar="F",
+                   help="with --preset node: metrics a node's model fuses "
+                        "(node_preset(F); default 3). The JSONL listener "
+                        "takes exactly F values a record")
     p.add_argument("--backend", default="tpu")
     p.add_argument("--group-size", type=int, default=1024,
                    help="streams per device group; len(streams) above this "
@@ -1566,6 +1587,28 @@ def main(argv: list[str] | None = None) -> int:
               "(the NAB family scales via scaled_nab_preset; the "
               "composite/categorical presets fix their field geometry)",
               file=sys.stderr)
+        return 2
+    if getattr(args, "fields", None) is not None and \
+            getattr(args, "preset", "cluster") != "node":
+        print("serve: --fields sizes the node preset only (add --preset "
+              "node; the other presets fix their field count)",
+              file=sys.stderr)
+        return 2
+    if getattr(args, "preset", "cluster") == "node":
+        if args.fields is None:
+            args.fields = 3
+        if not 1 <= args.fields <= 64:
+            print("serve: --fields must be 1..64 (the native parser's "
+                  "widest record)", file=sys.stderr)
+            return 2
+    if getattr(args, "preset", "cluster") in ("node", "composite") and (
+            getattr(args, "http", None)
+            or getattr(args, "ingest_port", None) is not None
+            or getattr(args, "ingest_shm", None)):
+        print(f"serve: --preset {args.preset} scores a record of several "
+              "fields a model, which only the TCP JSONL listener carries "
+              "(\"values\": [..]); the HTTP poll and the RB1 binary batch "
+              "path hold one scalar an id", file=sys.stderr)
         return 2
     if (getattr(args, "correlate_window", None) is not None
             or getattr(args, "correlate_min_streams", None) is not None) \

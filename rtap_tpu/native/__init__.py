@@ -88,10 +88,11 @@ def load() -> ctypes.CDLL:
         lib.rtap_parser_feed.restype = ctypes.c_int
         lib.rtap_parser_feed.argtypes = [
             ctypes.c_void_p, ctypes.c_char_p, ctypes.c_long, f32p, f64p, f64p,
-            u8p, f64p, ctypes.c_long]
+            u8p, f64p, ctypes.c_long, ctypes.c_int32, f64p]
         lib.rtap_parser_flush.restype = None
         lib.rtap_parser_flush.argtypes = [
-            ctypes.c_void_p, f32p, f64p, f64p, u8p, f64p, ctypes.c_long]
+            ctypes.c_void_p, f32p, f64p, f64p, u8p, f64p, ctypes.c_long,
+            ctypes.c_int32, f64p]
         _lib = lib
         return _lib
 
@@ -100,9 +101,13 @@ class NativeJsonlState:
     """Listener-wide native parse state: the id hash table plus the shared
     output buffers the C code writes into.
 
-    ``latest`` is the caller's float32 [G] array — feed() updates it in
+    ``latest`` is the caller's float32 table — [G] for scalar records
+    (``{"id", "value", "ts"}``), [G, F] for vector records of F fields a
+    model (``{"id", "values": [..F..], "ts"}``, ``null`` = that field's
+    NaN): F is the table's, never a record's. feed() updates it in
     place (the caller must never reallocate it). ``counters`` is
-    [parsed, parse_errors, unknown_ids]; ``ts_buf[0]`` is the running ts
+    [parsed, parse_errors, unknown_ids]; ``value_counters`` is [values
+    written non-null, values null]; ``ts_buf[0]`` is the running ts
     maximum. One :class:`ConnParser` per connection carries that
     connection's partial-line remainder; the caller serializes feed()
     calls across connections with its own lock.
@@ -111,11 +116,27 @@ class NativeJsonlState:
     #: unknown-name capture buffer ("id\n" entries; full = drop, Python
     #: dedups and the id re-surfaces next tick)
     UNKNOWN_BUF_BYTES = 1 << 16
+    #: widest vector record (jsonl_parser.c MAX_FIELDS: the row converts on
+    #: the stack before any of it is written)
+    MAX_FIELDS = 64
+
+    @classmethod
+    def _fields_of(cls, stream_ids: list[str], latest: np.ndarray) -> int:
+        """The table's field count, after checking it is one the C side
+        can write: float32, C-contiguous, [n] or [n, F <= MAX_FIELDS]."""
+        if latest.dtype != np.float32 or not latest.flags.c_contiguous:
+            raise ValueError("latest must be a C-contiguous float32 array")
+        n_fields = latest.shape[1] if latest.ndim == 2 else 1
+        if latest.ndim not in (1, 2) or len(latest) != len(stream_ids) \
+                or not 1 <= n_fields <= cls.MAX_FIELDS:
+            raise ValueError(
+                f"latest must be [{len(stream_ids)}] or [{len(stream_ids)}, "
+                f"1..{cls.MAX_FIELDS}], got {latest.shape}")
+        return n_fields
 
     def __init__(self, stream_ids: list[str], latest: np.ndarray,
                  track_unknown: bool = False):
-        if latest.dtype != np.float32 or not latest.flags.c_contiguous:
-            raise ValueError("latest must be a C-contiguous float32 array")
+        self.n_fields = self._fields_of(stream_ids, latest)
         self._lib = load()
         ids = [sid.encode() for sid in stream_ids]
         blob = b"".join(ids)
@@ -126,6 +147,7 @@ class NativeJsonlState:
         self.latest = latest
         self.ts_buf = np.zeros(1, np.int64)
         self.counters = np.zeros(3, np.int64)
+        self.value_counters = np.zeros(2, np.int64)
         self.unk_buf = np.zeros(self.UNKNOWN_BUF_BYTES, np.uint8)
         # cap 0 disables capture in C (no memcpy on the hot locked path
         # when nothing will ever drain the buffer)
@@ -140,14 +162,13 @@ class NativeJsonlState:
         The caller must hold the listener lock that serializes feed() —
         every per-connection parser observes the new table on its next
         line via the shared indirection; partial-line state survives."""
-        if latest.dtype != np.float32 or not latest.flags.c_contiguous:
-            raise ValueError("latest must be a C-contiguous float32 array")
+        n_fields = self._fields_of(stream_ids, latest)
         ids = [sid.encode() for sid in stream_ids]
         blob = b"".join(ids)
         lens = (ctypes.c_int32 * len(ids))(*[len(b) for b in ids])
         if self._lib.rtap_parser_set_table(self._owner, blob, lens, len(ids)):
             raise MemoryError("rtap_parser_set_table failed")
-        self.latest = latest
+        self.latest, self.n_fields = latest, n_fields
 
     def drain_unknown_names(self) -> list[str]:
         """Pop captured unknown-id names (caller holds the listener lock).
@@ -191,12 +212,14 @@ class ConnParser:
         st = self._state
         st._lib.rtap_parser_feed(self._h, data, len(data),
                                  st.latest, st.ts_buf, st.counters,
-                                 st.unk_buf, st.unk_cur, st.unk_cap)
+                                 st.unk_buf, st.unk_cur, st.unk_cap,
+                                 st.n_fields, st.value_counters)
 
     def flush(self) -> None:
         st = self._state
         st._lib.rtap_parser_flush(self._h, st.latest, st.ts_buf, st.counters,
-                                  st.unk_buf, st.unk_cur, st.unk_cap)
+                                  st.unk_buf, st.unk_cur, st.unk_cap,
+                                  st.n_fields, st.value_counters)
 
     def close(self) -> None:
         if self._h:

@@ -21,6 +21,15 @@
  * fields we need) are accepted here but rejected by the Python fallback —
  * the parity tests pin both parsers on the realistic record space.
  *
+ * Vector records (n_fields > 1: one model a node over F metrics): the
+ * listener's table is [n_ids, n_fields] and a line is {"id", "values":
+ * [v0, .., vF-1], "ts"} — the array is parsed in place into a stack row
+ * (no allocation a record), `null` is that field's missing sample (NaN in
+ * that field only), and a list of another length, a non-list, a nested
+ * element or a missing "values" key is a parse error that writes nothing.
+ * At n_fields == 1 "values" is an extra field like any other and the
+ * record is {"id", "value", "ts"}, to the byte what it always was.
+ *
  * Concurrency: one Parser per connection (it owns that connection's
  * partial-line remainder); the output arrays are shared and the caller
  * serializes feed() calls with its own lock (one lock per chunk, not per
@@ -37,6 +46,9 @@
 #define COUNTER_PARSED 0
 #define COUNTER_PARSE_ERRORS 1
 #define COUNTER_UNKNOWN_IDS 2
+#define MAX_FIELDS 64           /* widest vector record (stack row)      */
+#define VALUES_PARSED 0         /* vcounters: values written, nulls apart */
+#define VALUES_NULL 1
 
 /* ------------------------------------------------------------------ hash */
 
@@ -211,6 +223,7 @@ typedef struct {
     const char *id;   long id_len;   int has_id;
     const char *val;  long val_len;  int has_val;  int val_quoted;
     const char *ts;   long ts_len;   int has_ts;   int ts_quoted;
+    const char *vals; const char *vals_end; int has_vals;  /* 1 = array */
 } Fields;
 
 /* Scan one line's top-level "key": value pairs. Returns 0 on schema
@@ -280,6 +293,11 @@ static int scan_line(const char *s, const char *end, Fields *f) {
             f->val = vs; f->val_len = vlen; f->has_val = 1; f->val_quoted = quoted;
         } else if (klen == 2 && memcmp(kstart, "ts", 2) == 0) {
             f->ts = vs; f->ts_len = vlen; f->has_ts = 1; f->ts_quoted = quoted;
+        } else if (klen == 6 && memcmp(kstart, "values", 6) == 0) {
+            /* 1 = a list (parsed in place by values_to_row); 2 = any
+             * other type (isinstance(vals, list) fails -> parse_error) */
+            f->vals = vstart; f->vals_end = vend;
+            f->has_vals = (*vstart == '[') ? 1 : 2;
         }
 
         s = skip_ws(vend, end);
@@ -313,6 +331,56 @@ static int token_to_double(const char *s, long n, double *out) {
     return 0;
 }
 
+/* A vector record's "values" list, [s, end) from '[' to just past its
+ * ']', into row[0..n): each element as the scalar path converts a value
+ * (numbers, quoted numbers, true/false), an unquoted null -> NaN counted
+ * in *nulls, a nested list/object -> error (the Python path raises on
+ * them). Anything but exactly n elements is an error; nothing is written
+ * anywhere but `row`. Returns 0 ok. */
+static int values_to_row(const char *s, const char *end, int32_t n,
+                         float *row, int32_t *nulls) {
+    const char *stop = end - 1;           /* the closing bracket */
+    const char *q = skip_ws(s + 1, stop);
+    int32_t k = 0;
+    *nulls = 0;
+    if (q == stop) return -1;             /* []: no record has 0 fields */
+    for (;;) {
+        const char *tok;
+        long len;
+        int quoted = 0;
+        if (q >= stop) return -1;         /* "[1, 2, ]" */
+        if (*q == '"') {
+            const char *past = skip_string(q, stop);
+            if (!past) return -1;
+            tok = q + 1; len = (past - 1) - tok; quoted = 1;
+            q = past;
+        } else if (*q == '[' || *q == '{') {
+            return -1;
+        } else {
+            tok = q;
+            while (q < stop && *q != ',' && *q != ' ' && *q != '\t' &&
+                   *q != '\r')
+                q++;
+            len = q - tok;
+            if (len == 0) return -1;      /* "[1,,2]" */
+        }
+        if (k >= n) return -1;            /* longer than the model's F */
+        double v;
+        if (!quoted && len == 4 && memcmp(tok, "null", 4) == 0) {
+            v = NAN;
+            (*nulls)++;
+        } else if (token_to_double(tok, len, &v) != 0) {
+            return -1;
+        }
+        row[k++] = (float)v;
+        q = skip_ws(q, stop);
+        if (q == stop) break;
+        if (*q != ',') return -1;
+        q = skip_ws(q + 1, stop);
+    }
+    return k == n ? 0 : -1;
+}
+
 /* Quoted ts goes through Python's int(str), which accepts ONLY an
  * optionally-signed decimal integer with surrounding whitespace —
  * int("101.9") and int("1e3") raise. Mirror that exactly. */
@@ -342,7 +410,8 @@ static int quoted_ts_to_int(const char *s, long n, int64_t *out) {
  * known id with unconvertible value -> parse_errors. */
 static void process_line(Parser *p, const char *s, const char *end,
                          float *latest, int64_t *ts_max, int64_t *counters,
-                         char *unk_buf, int64_t *unk_cur, long unk_cap) {
+                         char *unk_buf, int64_t *unk_cur, long unk_cap,
+                         int32_t n_fields, int64_t *vcounters) {
     /* blank lines: Python json.loads("") raises -> parse_error; but a
      * bare "\n" between records is produced by no real producer — treat
      * whitespace-only lines as Python does (error) for parity. */
@@ -386,17 +455,38 @@ static void process_line(Parser *p, const char *s, const char *end,
         }
         return;
     }
-    double v;
-    if (f.has_val && !f.val_quoted && f.val_len == 4
-            && memcmp(f.val, "null", 4) == 0) {
-        v = NAN;  /* np.float32(None) is nan, not an error */
-    } else if (!f.has_val || token_to_double(f.val, f.val_len, &v) != 0) {
-        counters[COUNTER_PARSE_ERRORS]++;   /* rec["value"]/np.float32 raised */
-        return;
+    if (n_fields > 1) {
+        /* a vector record: all F values convert before any is written (a
+         * short, long or unconvertible list writes nothing), then the row
+         * lands whole — a node's F values are never split over two ticks */
+        float row[MAX_FIELDS];
+        int32_t nulls;
+        if (f.has_vals != 1 || values_to_row(f.vals, f.vals_end, n_fields,
+                                             row, &nulls) != 0) {
+            counters[COUNTER_PARSE_ERRORS]++;  /* rec["values"] / its length /
+                                                  an element raised */
+            return;
+        }
+        memcpy(latest + (size_t)idx * (size_t)n_fields, row,
+               (size_t)n_fields * sizeof(float));
+        vcounters[VALUES_PARSED] += n_fields - nulls;
+        vcounters[VALUES_NULL] += nulls;
+    } else {
+        double v;
+        int is_null = 0;
+        if (f.has_val && !f.val_quoted && f.val_len == 4
+                && memcmp(f.val, "null", 4) == 0) {
+            v = NAN;  /* np.float32(None) is nan, not an error */
+            is_null = 1;
+        } else if (!f.has_val || token_to_double(f.val, f.val_len, &v) != 0) {
+            counters[COUNTER_PARSE_ERRORS]++;   /* rec["value"]/np.float32 raised */
+            return;
+        }
+        /* Python assigns latest[i] and THEN converts ts; a bad ts therefore
+         * still applies the value (and counts as a parse error). Mirror it. */
+        latest[idx] = (float)v;
+        vcounters[is_null ? VALUES_NULL : VALUES_PARSED]++;
     }
-    /* Python assigns latest[i] and THEN converts ts; a bad ts therefore
-     * still applies the value (and counts as a parse error). Mirror it. */
-    latest[idx] = (float)v;
     if (f.has_ts) {
         int64_t tsv;
         if (f.ts_quoted) {
@@ -421,7 +511,7 @@ static void process_line(Parser *p, const char *s, const char *end,
  * without a trailing newline — process the remainder the same way. */
 void rtap_parser_flush(Parser *p, float *latest, int64_t *ts_max,
                        int64_t *counters, char *unk_buf, int64_t *unk_cur,
-                       long unk_cap) {
+                       long unk_cap, int32_t n_fields, int64_t *vcounters) {
     if (p->rem_overflow) {
         counters[COUNTER_PARSE_ERRORS]++;
         p->rem_overflow = 0;
@@ -430,18 +520,22 @@ void rtap_parser_flush(Parser *p, float *latest, int64_t *ts_max,
     }
     if (p->rem_len > 0) {
         process_line(p, p->rem, p->rem + p->rem_len, latest, ts_max,
-                     counters, unk_buf, unk_cur, unk_cap);
+                     counters, unk_buf, unk_cur, unk_cap, n_fields, vcounters);
         p->rem_len = 0;
     }
 }
 
 /* Feed one recv() chunk. Complete lines are processed; a trailing partial
- * line is kept in the parser for the next chunk. Returns 0, or -1 on
+ * line is kept in the parser for the next chunk. `latest` is the caller's
+ * [n_ids] table at n_fields == 1 and [n_ids, n_fields] otherwise (1 <=
+ * n_fields <= MAX_FIELDS, checked by the caller); `vcounters` is [values
+ * written non-null, values null]. Returns 0, or -1 on
  * internal error (never raises mid-stream; malformed data only bumps
  * counters). */
 int rtap_parser_feed(Parser *p, const char *buf, long n,
                      float *latest, int64_t *ts_max, int64_t *counters,
-                     char *unk_buf, int64_t *unk_cur, long unk_cap) {
+                     char *unk_buf, int64_t *unk_cur, long unk_cap,
+                     int32_t n_fields, int64_t *vcounters) {
     long i = 0;
     while (i < n) {
         const char *nl = (const char *)memchr(buf + i, '\n', (size_t)(n - i));
@@ -470,14 +564,16 @@ int rtap_parser_feed(Parser *p, const char *buf, long n,
                 memcpy(p->rem + p->rem_len, buf + i, (size_t)tail);
                 p->rem_len += tail;
                 process_line(p, p->rem, p->rem + p->rem_len, latest, ts_max,
-                             counters, unk_buf, unk_cur, unk_cap);
+                             counters, unk_buf, unk_cur, unk_cap, n_fields,
+                             vcounters);
                 p->rem_len = 0;
             }
         } else if (line_end > i) {   /* skip empty lines like rfile iteration? no:
                                         a lone "\n" yields the line "\n" in Python,
                                         whose json.loads fails -> parse_error */
             process_line(p, buf + i, buf + line_end, latest, ts_max,
-                         counters, unk_buf, unk_cur, unk_cap);
+                         counters, unk_buf, unk_cur, unk_cap, n_fields,
+                         vcounters);
         } else {
             counters[COUNTER_PARSE_ERRORS]++;   /* empty line between \n\n */
         }

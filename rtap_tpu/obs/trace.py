@@ -89,7 +89,9 @@ SPANS = (
     "rtap.group.fetch",
     "rtap.group.likelihood",
     # service/sources.py:TcpJsonlSource — a handler's locked parse of one
-    # recv batch (`bytes`, `wait_us`), the loop's locked copy-and-drain
+    # recv batch (`bytes`, `wait_us`, `values` = values it wrote into the
+    # table, `nulls` = values that came as null, counted apart), the loop's
+    # locked copy-and-drain (`tick`, `wait_us`, `fields` = values a record)
     "rtap.ingest.feed",
     "rtap.ingest.snapshot",
     # service/aot.py:prewarm — one per program executed (`program`)

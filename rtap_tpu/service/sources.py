@@ -15,7 +15,10 @@ Two transports:
   Prometheus-exporter-style shape the reference scrapes).
 - :class:`TcpJsonlSource` — push. A background listener accepts JSONL
   records ``{"id": ..., "value": ..., "ts": ...}`` from any number of
-  producers; each tick drains the latest value per stream.
+  producers; each tick drains the latest value per stream. For a model
+  of F > 1 fields (``n_fields``: one model a node over its cpu/mem/net)
+  a record is ``{"id": ..., "values": [v0, .., vF-1], "ts": ...}``, a
+  metric the collector missed is ``null``, and a tick drains ``[G, F]``.
 """
 
 from __future__ import annotations
@@ -166,9 +169,31 @@ class HttpPollSource:
         self._known = set(self.stream_ids)
 
 
+def _field_f32(v) -> np.float32:
+    """One element of a vector record's ``values`` list: ``null`` is that
+    field's missing sample, a nested list or object no value at all
+    (np.float32 would make an array of it)."""
+    if v is None:
+        return np.float32(np.nan)
+    if isinstance(v, (list, dict)):
+        raise TypeError("a field's value is a number, not a container")
+    return np.float32(v)
+
+
 class TcpJsonlSource:
     """Push transport: listens on a TCP port for newline-delimited JSON
     records and keeps the latest value per stream; each tick snapshots them.
+
+    `n_fields` is the model's (``ModelConfig.n_fields``), never taken from
+    a record. At 1 the table is ``[n_streams]`` and a record carries one
+    ``"value"``. At F > 1 the table is ``[n_streams, F]`` and a record
+    carries ``"values"``: a list of exactly F elements, ``null`` for a
+    missing metric (NaN in that field only); the row is written whole, so
+    a node's F values are scored in one tick and never split over two. A
+    list of another length, a ``"value"`` where ``"values"`` is due (or
+    the reverse) is a parse error and writes nothing. Both parsers keep
+    one effect order: unknown id before value conversion, values before
+    ``ts``, success counted last.
 
     Start/stop with a context manager (or .start()/.close()). The listener
     thread is a daemon; record parse errors are counted, never raised (a
@@ -180,14 +205,22 @@ class TcpJsonlSource:
     MAX_UNKNOWN_TRACKED = 4096
 
     def __init__(self, stream_ids: list[str], host: str = "127.0.0.1", port: int = 0,
-                 native: bool | None = None, track_unknown: bool = False):
+                 native: bool | None = None, track_unknown: bool = False,
+                 n_fields: int = 1):
+        if n_fields < 1:
+            raise ValueError(f"n_fields must be >= 1, got {n_fields}")
         self.stream_ids = list(stream_ids)
+        self.n_fields = int(n_fields)
         self._index = {sid: i for i, sid in enumerate(self.stream_ids)}
-        self._latest = np.full(len(self.stream_ids), np.nan, np.float32)
+        self._latest = self._empty_table(len(self.stream_ids))
         self._latest_ts = 0
         self._lock = threading.Lock()
         self._py_parse_errors = 0
         self._py_unknown_ids = 0
+        # values written non-null / values that came as ``null`` on the
+        # wire, as the C parser's value_counters count them
+        self._py_values = 0
+        self._py_values_null = 0
         self._py_records = 0  # successes on the Python fallback path —
         # counted like the C parser's COUNTER_PARSED so records_parsed
         # (and rtap_obs_ingest_records_total) agree across parser
@@ -205,7 +238,9 @@ class TcpJsonlSource:
         # state for per-record cheapness; _obs_synced remembers how much of
         # this instance's tally already landed in the global counters
         obs = get_registry()
-        self._obs_synced = {"pe": 0, "uk": 0, "rec": 0}
+        self._obs_synced = {"parse_errors": 0, "unknown_ids": 0,
+                            "records_parsed": 0, "values_parsed": 0,
+                            "values_null": 0}
         self._obs_parse_errors = obs.counter(
             "rtap_obs_ingest_parse_errors_total",
             "malformed JSONL records dropped by the TCP listener")
@@ -217,6 +252,14 @@ class TcpJsonlSource:
             "rtap_obs_ingest_records_total",
             "successfully parsed ingest records (JSONL records and "
             "binary batch rows, both parser backends)")
+        self._obs_values = obs.counter(
+            "rtap_obs_ingest_values_total",
+            "metric values the TCP listener wrote into its table (one a "
+            "scalar record, up to F a vector record; nulls apart)")
+        self._obs_values_null = obs.counter(
+            "rtap_obs_ingest_values_null_total",
+            "values that arrived as null on the wire: a metric the "
+            "collector missed, scored as that field's missing sample")
         # Native C parse path (rtap_tpu/native/jsonl_parser.c): the whole
         # recv-chunk drain in one locked C call instead of per-record
         # json.loads + dict lookup + lock — the host core feeding 100k
@@ -253,8 +296,11 @@ class TcpJsonlSource:
                                       bytes=len(data)).begin()
                             with outer._lock:
                                 locked = time.perf_counter()
+                                v0, z0 = outer._nstate.value_counters
                                 conn.feed(data)
-                            sp.end(wait_us=int((locked - sp.t0) * 1e6))
+                                v1, z1 = outer._nstate.value_counters
+                            sp.end(wait_us=int((locked - sp.t0) * 1e6),
+                                   values=int(v1 - v0), nulls=int(z1 - z0))
                         with outer._lock:
                             conn.flush()  # unterminated final line, like rfile
                     finally:
@@ -271,7 +317,9 @@ class TcpJsonlSource:
                         batch, tail = [tail], b""
                     if batch:
                         sp = span("rtap.ingest.feed", bytes=len(data)).begin()
-                        sp.end(wait_us=int(outer._feed_lines(batch) * 1e6))
+                        waited, values, nulls = outer._feed_lines(batch)
+                        sp.end(wait_us=int(waited * 1e6), values=values,
+                               nulls=nulls)
                     if not data:
                         break
 
@@ -285,10 +333,18 @@ class TcpJsonlSource:
                                         name="rtap-sources-accept",
                                         daemon=True)
 
-    def _feed_lines(self, lines: list[bytes]) -> float:
-        """The Python parse path over one batch of complete lines -> the
-        seconds it waited for the lock."""
+    def _empty_table(self, n: int) -> np.ndarray:
+        """The latest-value table of `n` streams, all missing: ``[n]`` at
+        one field (the scalar path's own object), ``[n, n_fields]`` else."""
+        shape = (n,) if self.n_fields == 1 else (n, self.n_fields)
+        return np.full(shape, np.nan, np.float32)
+
+    def _feed_lines(self, lines: list[bytes]) -> tuple[float, int, int]:
+        """The Python parse path over one batch of complete lines -> (the
+        seconds it waited for the lock, values written non-null, null)."""
         waited = 0.0
+        F = self.n_fields
+        wrote = wrote_null = 0  # this batch's share of the two tallies
         for line in lines:
             try:
                 rec = json.loads(line)
@@ -315,7 +371,24 @@ class TcpJsonlSource:
                                 self.MAX_UNKNOWN_TRACKED:
                             self._unknown_seen.add(sid)
                         continue
-                    self._latest[i] = np.float32(rec["value"])
+                    if F == 1:
+                        v = rec["value"]
+                        self._latest[i] = np.float32(v)
+                        nulls = int(v is None)
+                    else:
+                        # the whole row converts before any of it is
+                        # written: a short, long or unconvertible list
+                        # writes nothing
+                        vals = rec["values"]
+                        if not isinstance(vals, list) or len(vals) != F:
+                            raise ValueError(
+                                f"'values' must be a list of {F}")
+                        self._latest[i] = [_field_f32(v) for v in vals]
+                        nulls = sum(v is None for v in vals)
+                    self._py_values += F - nulls
+                    self._py_values_null += nulls
+                    wrote += F - nulls
+                    wrote_null += nulls
                     self._latest_ts = max(self._latest_ts,
                                           int(rec.get("ts", 0)))
                     # success is counted AFTER the ts conversion:
@@ -332,7 +405,7 @@ class TcpJsonlSource:
                 # analyzer's race pass flags)
                 with self._lock:
                     self._py_parse_errors += 1
-        return waited
+        return waited, wrote, wrote_null
 
     def start(self) -> "TcpJsonlSource":
         self._thread.start()
@@ -368,6 +441,24 @@ class TcpJsonlSource:
         return self._py_records + n
 
     @property
+    def values_parsed(self) -> int:
+        """Values written into the table, nulls apart (one a scalar record,
+        F less its nulls a vector record) — both parser backends. Counted
+        at the write, which precedes the ``ts`` conversion: a record whose
+        ``ts`` is bad keeps its values and counts them."""
+        n = int(self._nstate.value_counters[0]) \
+            if self._nstate is not None else 0
+        return self._py_values + n
+
+    @property
+    def values_null(self) -> int:
+        """Values that arrived as ``null``: a metric the collector missed,
+        that field's missing sample."""
+        n = int(self._nstate.value_counters[1]) \
+            if self._nstate is not None else 0
+        return self._py_values_null + n
+
+    @property
     def native_active(self) -> bool:
         return self._nstate is not None
 
@@ -397,7 +488,7 @@ class TcpJsonlSource:
         feed(), so per-connection parsers keep their partial-line state
         and observe the new table on their next line."""
         with self._lock:
-            latest = np.full(len(stream_ids), np.nan, np.float32)
+            latest = self._empty_table(len(stream_ids))  # field axis carried
             for j, sid in enumerate(stream_ids):
                 i = self._index.get(sid)
                 if i is not None:
@@ -409,7 +500,8 @@ class TcpJsonlSource:
             self._latest = latest
 
     def __call__(self, tick: int) -> tuple[np.ndarray, int]:
-        """Snapshot AND DRAIN: values reset to NaN after each tick, so a
+        """-> (values ``[G]``, or ``[G, n_fields]`` for vector records, ts).
+        Snapshot AND DRAIN: values reset to NaN after each tick, so a
         producer that stops pushing yields missing samples (NaN) rather than
         its stale last value being re-scored forever — a silent outage must
         surface as missing data, not as a suspiciously flat healthy metric."""
@@ -424,7 +516,7 @@ class TcpJsonlSource:
             if self._nstate is not None:
                 self._latest_ts = max(self._latest_ts, int(self._nstate.ts_buf[0]))
             ts = self._latest_ts or int(time.time())
-        sp.end(wait_us=int((locked - sp.t0) * 1e6))
+        sp.end(wait_us=int((locked - sp.t0) * 1e6), fields=self.n_fields)
         # once-per-tick delta sync of THIS instance's ingest tallies into
         # the process-global registry counters (outside the lock: reads +
         # obs-cell increments only). Per-instance deltas, never a raise-
@@ -435,15 +527,14 @@ class TcpJsonlSource:
         # Each tally is read ONCE into a local — the handler thread keeps
         # bumping it, and an inc/store pair reading twice would drop any
         # increments landing between the reads.
-        pe = self.parse_errors
-        self._obs_parse_errors.inc(max(0, pe - self._obs_synced["pe"]))
-        self._obs_synced["pe"] = pe
-        uk = self.unknown_ids
-        self._obs_unknown_ids.inc(max(0, uk - self._obs_synced["uk"]))
-        self._obs_synced["uk"] = uk
-        n = self.records_parsed
-        self._obs_records.inc(max(0, n - self._obs_synced["rec"]))
-        self._obs_synced["rec"] = n
+        for tally, counter in (("parse_errors", self._obs_parse_errors),
+                               ("unknown_ids", self._obs_unknown_ids),
+                               ("records_parsed", self._obs_records),
+                               ("values_parsed", self._obs_values),
+                               ("values_null", self._obs_values_null)):
+            n = getattr(self, tally)
+            counter.inc(max(0, n - self._obs_synced[tally]))
+            self._obs_synced[tally] = n
         return values, ts
 
 
@@ -453,11 +544,24 @@ class TcpJsonlSource:
 _SEND_BATCH = 512
 
 
+def _wire_record(record: dict) -> dict:
+    """A record as it goes on the wire: a vector record's ``values`` may be
+    any sequence of numbers (a numpy row); a missing metric — ``None`` or
+    NaN — is sent as ``null``. A scalar record goes as it is."""
+    if "values" not in record:
+        return record
+    values = [v.item() if isinstance(v, np.generic) else v
+              for v in record["values"]]
+    return {**record, "values": [
+        None if isinstance(v, float) and v != v else v for v in values]}
+
+
 def send_jsonl(address: tuple[str, int], records: list[dict],
                retry=None) -> int:
     """Producer-side helper (tests, demos, soak feeders): push records to
-    a :class:`TcpJsonlSource` listener. Returns the count actually handed
-    to the kernel.
+    a :class:`TcpJsonlSource` listener — scalar ``{"id", "value", "ts"}``
+    or vector ``{"id", "values": [..], "ts"}`` ones (:func:`_wire_record`).
+    Returns the count actually handed to the kernel.
 
     A listener restart mid-soak used to surface here as a raised
     ``ConnectionRefusedError`` that killed the producer; now the
@@ -474,7 +578,7 @@ def send_jsonl(address: tuple[str, int], records: list[dict],
         retry = Retry(attempts=4, base_delay_s=0.05, max_delay_s=0.5,
                       op="send_jsonl")
     payloads = [
-        "".join(json.dumps(r) + "\n"
+        "".join(json.dumps(_wire_record(r)) + "\n"
                 for r in records[i:i + _SEND_BATCH]).encode()
         for i in range(0, len(records), _SEND_BATCH)
     ]

@@ -263,3 +263,90 @@ def test_serve_fleet_flag_usage_errors():
                 "--fleet-join", ":9999", "--fleet-push-interval", "0")
     assert p.returncode == 2
     assert "must be > 0" in p.stderr
+
+
+def test_serve_node_preset_flag_gates():
+    """--fields sizes the node preset only, within what the native parser's
+    stack row holds; a multi-field model is served by the TCP JSONL listener
+    alone (the HTTP poll and the RB1 batch path hold one scalar an id).
+    Usage errors, before the backend comes up."""
+    p = run_cli("serve", "--streams", "a", "--fields", "3")
+    assert p.returncode == 2 and "--preset" in p.stderr
+    p = run_cli("serve", "--streams", "a", "--preset", "node",
+                "--fields", "65")
+    assert p.returncode == 2 and "1..64" in p.stderr
+    for flags in (("--http", "http://127.0.0.1:1/m"), ("--ingest-port", "0")):
+        p = run_cli("serve", "--streams", "a", "--preset", "node", *flags)
+        assert p.returncode == 2 and "TCP JSONL" in p.stderr
+
+
+def test_serve_node_preset_scores_vector_records(tmp_path):
+    """`serve --preset node --fields 3` builds node_preset(3) and its
+    listener takes three values a record — F from the model, never from a
+    record: a scalar record is a parse error there, a `null` field a
+    missing metric of a record that is still scored."""
+    import re
+
+    from rtap_tpu.config import node_preset
+
+    alerts = tmp_path / "alerts.jsonl"
+    ck = tmp_path / "ck"
+    proc = subprocess.Popen(
+        [sys.executable, "-m", "rtap_tpu", "serve", "--streams", "n0,n1",
+         "--preset", "node", "--fields", "3", "--ticks", "5",
+         "--cadence", "0.2", "--backend", "cpu", "--port", "0",
+         "--alerts", str(alerts), "--checkpoint-dir", str(ck),
+         "--checkpoint-every", "5"],
+        cwd=REPO, env=ENV, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+        text=True)
+    port, lines = None, []
+
+    def drain():
+        nonlocal port
+        for line in proc.stderr:
+            lines.append(line)
+            m = re.search(r"listening for JSONL records on \S+?:(\d+)", line)
+            if m:
+                port = int(m.group(1))
+
+    threading.Thread(target=drain, daemon=True).start()
+    deadline = time.time() + 120
+    while port is None and time.time() < deadline and proc.poll() is None:
+        time.sleep(0.05)
+    assert port, (proc.poll(), "".join(lines)[-2000:])
+    assert any('"values": a list of 3' in ln for ln in lines)
+    stop = threading.Event()
+
+    def produce():
+        from rtap_tpu.service.sources import send_jsonl
+
+        k = 0
+        while not stop.is_set():
+            try:
+                send_jsonl(("127.0.0.1", port), [
+                    {"id": "n0", "values": [40 + k, None, 5.5]},
+                    {"id": "n1", "values": [60 - k, 30.0, 7.0]},
+                    {"id": "n1", "value": 1.0}])  # scalar: a parse error
+            except OSError:
+                pass
+            k += 1
+            time.sleep(0.1)
+
+    threading.Thread(target=produce, daemon=True).start()
+    out, _ = proc.communicate(timeout=300)
+    stop.set()
+    assert proc.returncode == 0, "".join(lines)[-2000:]
+    stats = json.loads(out.strip().splitlines()[-1])
+    assert stats["ticks"] == 5 and stats["scored"] == 10
+    # the checkpoint's model config is the preset's, nothing overridden
+    found = [os.path.join(d, f) for d, _s, fs in os.walk(ck) for f in fs
+             if f.endswith(".json")]
+    configs = []
+    for path in found:
+        with open(path) as f:
+            meta = json.load(f)
+        cfg = meta.get("config") or meta.get("model_config")
+        if isinstance(cfg, dict) and "n_fields" in cfg:
+            configs.append(cfg)
+    assert configs and all(c == node_preset(3).to_dict() for c in configs), \
+        found

@@ -447,3 +447,247 @@ def test_tcp_source_close_joins_accept_thread():
     src = TcpJsonlSource(["s0"], port=0).start()
     src.close()
     assert not src._thread.is_alive()
+
+
+# ------------------------------------------- vector records (ISSUE 43) --
+# a model of F > 1 fields (node_preset: cpu, mem, net of one node) takes
+# {"id", "values": [..F..], "ts"}; `null` is that field's missing sample
+
+
+def _vstate(ids=("a", "b", "c"), n_fields=3):
+    latest = np.full((len(ids), n_fields), np.nan, np.float32)
+    return NativeJsonlState(list(ids), latest), latest
+
+
+@needs_native
+def test_vector_line_lands_whole_with_null_in_its_field_only():
+    st, latest = _vstate()
+    c = st.new_conn()
+    c.feed(b'{"id": "a", "values": [1.5, null, "3"], "ts": 7}\n')
+    assert latest[0, 0] == np.float32(1.5) and latest[0, 2] == 3.0
+    assert np.isnan(latest[0, 1]) and np.isnan(latest[1:]).all()
+    assert list(st.counters) == [1, 0, 0] and st.ts_buf[0] == 7
+    assert list(st.value_counters) == [2, 1]
+    # a later record overwrites the row whole, field by field
+    c.feed(b'{"ts":8,"values":[ true ,2e1,-4 ] , "id":"a"}\n')
+    assert latest[0].tolist() == [1.0, 20.0, -4.0] and st.ts_buf[0] == 8
+    assert list(st.value_counters) == [5, 1]
+    c.close()
+
+
+@needs_native
+@pytest.mark.parametrize("line", [
+    b'{"id": "b", "values": [1, 2], "ts": 5}',          # shorter than F
+    b'{"id": "b", "values": [1, 2, 3, 4], "ts": 5}',    # longer
+    b'{"id": "b", "values": [], "ts": 5}',
+    b'{"id": "b", "value": 1.0, "ts": 5}',             # `value` where
+    b'{"id": "b", "values": 1.0, "ts": 5}',            # `values` is due
+    b'{"id": "b", "values": "1,2,3", "ts": 5}',
+    b'{"id": "b", "values": {"cpu": 1}, "ts": 5}',
+    b'{"id": "b", "values": [1, [2], 3], "ts": 5}',     # nested element
+    b'{"id": "b", "values": [1, 2, 3,], "ts": 5}',      # trailing comma
+    b'{"id": "b", "values": [1,, 3], "ts": 5}',
+    b'{"id": "b", "values": [1, "x", 3], "ts": 5}',     # unconvertible
+    b'{"id": "b", "values": [1, "null", 3], "ts": 5}',  # quoted null
+    b'{"id": "b", "values": [1, 2, 0x3], "ts": 5}',
+], ids=lambda b: b.decode()[11:40])
+def test_vector_parse_error_writes_nothing(line):
+    st, latest = _vstate()
+    c = st.new_conn()
+    c.feed(b'{"id": "b", "values": [7, 8, 9], "ts": 3}\n' + line + b"\n")
+    assert latest[1].tolist() == [7.0, 8.0, 9.0]  # no field of it moved
+    assert list(st.counters) == [1, 1, 0] and st.ts_buf[0] == 3
+    assert list(st.value_counters) == [3, 0]
+    c.close()
+
+
+@needs_native
+def test_vector_effect_order_is_the_scalar_paths():
+    st, latest = _vstate()
+    c = st.new_conn()
+    # unknown id BEFORE value conversion: a bad list on an unknown id is
+    # unknown, not a parse error
+    c.feed(b'{"id": "ghost", "values": [1]}\n')
+    assert list(st.counters) == [0, 0, 1]
+    # values BEFORE ts: a bad ts keeps the row and its value counts, but
+    # the record is a parse error, never a parsed success
+    c.feed(b'{"id": "c", "values": [4, null, 6], "ts": "xx"}\n')
+    assert latest[2, 0] == 4.0 and np.isnan(latest[2, 1]) \
+        and latest[2, 2] == 6.0
+    assert list(st.counters) == [0, 1, 1]
+    assert list(st.value_counters) == [2, 1] and st.ts_buf[0] == 0
+    c.close()
+
+
+@needs_native
+def test_scalar_table_ignores_values_and_counts_its_nulls():
+    """At F = 1 `values` is an extra field like any other and the record is
+    {"id", "value", "ts"}; the value counters move there too."""
+    st, latest = _state()
+    c = st.new_conn()
+    c.feed(b'{"id": "a", "values": [1.0], "ts": 5}\n')  # no `value`
+    assert list(st.counters) == [0, 1, 0] and np.isnan(latest).all()
+    c.feed(b'{"id": "a", "value": 2.5, "values": [9, 9, 9]}\n'
+           b'{"id": "a", "value": null}\n')
+    assert list(st.counters) == [2, 1, 0] and np.isnan(latest[2])
+    assert list(st.value_counters) == [1, 1]
+    c.close()
+
+
+@needs_native
+def test_native_state_refuses_a_table_it_cannot_write():
+    ids = ["a", "b"]
+    for bad in (np.zeros((2, 65), np.float32), np.zeros((3, 2), np.float32),
+                np.zeros((2, 2, 2), np.float32), np.zeros((2, 0), np.float32),
+                np.zeros((2, 3), np.float64),
+                np.zeros((3, 2), np.float32).T):
+        with pytest.raises(ValueError):
+            NativeJsonlState(ids, bad)
+    with pytest.raises(ValueError):
+        TcpJsonlSource(ids, n_fields=0)
+
+
+def test_scalar_source_is_the_parents_shape():
+    """F = 1 keeps the [n_streams] table (the parent's object): what
+    `cluster-256-live` runs is the scalar path, not a [n, 1] one."""
+    for native in (None, False):
+        src = TcpJsonlSource(["a", "b"], native=native)
+        try:
+            assert src.n_fields == 1 and src._latest.shape == (2,)
+            values, _ts = src(0)
+            assert values.shape == (2,) and values.dtype == np.float32
+            src.set_ids(["b", "c", "a"])
+            assert src._latest.shape == (3,)
+        finally:
+            src._server.server_close()
+
+
+def _fuzz_vector_records(seed: int, ids: list[str], n_fields: int,
+                         n: int) -> list[bytes]:
+    """Randomized vector lines: good rows, `null`s, wrong lengths, `value`
+    and `values` mixed up or both present, nested and unconvertible
+    elements, bad ts, unknown ids, shuffled keys, malformed tails."""
+    rng = np.random.default_rng(seed)
+    out = []
+    for _ in range(n):
+        sid = ids[int(rng.integers(0, len(ids)))] \
+            if rng.random() < 0.85 else "ghost"
+        k = n_fields if rng.random() < 0.8 else int(rng.integers(0, 6))
+        elems = [str(rng.choice([
+            str(float(rng.normal(50, 20))), "null", "null",
+            str(int(rng.integers(-100, 100))), '"7.5"', "true", "1e3",
+            '"nope"', "[1]", '{"x": 1}', '"null"',
+        ], p=[0.72, 0.08, 0.04, 0.04, 0.02, 0.02, 0.02, 0.02, 0.015, 0.01,
+              0.015])) for _ in range(k)]
+        sep_in = ", " if rng.random() < 0.7 else rng.choice([",", " , "])
+        values = "[" + sep_in.join(elems) + "]"
+        if rng.random() < 0.03:
+            # (no trailing comma here: json.loads refuses the whole line,
+            # the C scanner only the list — they differ on an unknown id,
+            # a divergence from strict JSON the module's header documents)
+            values = rng.choice(["3.5", '"1,2,3"', "null", "[[1, 2, 3]]"])
+        ts = rng.choice([str(int(rng.integers(1, 10**9))), '"123"', '"9.5"',
+                         "55.7", "null"], p=[0.8, 0.05, 0.05, 0.05, 0.05])
+        fields = [f'"id": "{sid}"', f'"ts": {ts}',
+                  '"extra": {"nested": [1, "x"]}']
+        r = rng.random()
+        if r < 0.88:
+            fields.append(f'"values": {values}')
+        elif r < 0.94:  # a scalar record where a vector one is due
+            fields.append(f'"value": {float(rng.normal()):.3f}')
+        else:  # both keys: `values` decides
+            fields += [f'"values": {values}', '"value": 1.25']
+        rng.shuffle(fields)
+        line = "{" + (", " if rng.random() < 0.8 else ",").join(fields) + "}"
+        if rng.random() < 0.05:
+            line = line[: int(rng.integers(1, len(line)))]  # malformed tail
+        out.append(line.encode())
+    return out
+
+
+@needs_native
+@pytest.mark.parametrize("seed,n_fields", [(1, 3), (2, 3), (3, 5), (4, 2)])
+def test_socket_parity_fuzz_vector_records(seed, n_fields):
+    """(2a) The Python `_feed_lines` path is the plain parser: over a
+    seeded fuzz of vector lines, split across `recv`s at awkward sizes,
+    the C parser gives the same table, the same ts and the same counters
+    — records, parse errors, unknown ids, values and nulls."""
+    ids = [f"n{i}" for i in range(6)]
+    lines = _fuzz_vector_records(seed, ids, n_fields, 500)
+    payload = b"\n".join(lines) + b"\n"
+    sentinel = json.dumps({"id": ids[0], "values": [31337.0] * n_fields}
+                          ).encode() + b"\n"
+    results = []
+    for native in (True, False):
+        src = TcpJsonlSource(ids, native=native, n_fields=n_fields)
+        with src:
+            assert src.native_active == native
+            assert src._latest.shape == (len(ids), n_fields)
+            with socket.create_connection(src.address, timeout=5.0) as s:
+                data = payload + sentinel
+                for off in range(0, len(data), 251):  # lines split mid-record
+                    s.sendall(data[off:off + 251])
+            deadline = time.time() + 20
+            while time.time() < deadline:
+                with src._lock:
+                    if (src._latest[0] == np.float32(31337.0)).all():
+                        break
+                time.sleep(0.01)
+            values, ts = src(0)
+            drained, _ = src(1)
+        assert values.shape == (len(ids), n_fields)
+        assert np.isnan(drained).all()  # snapshot AND drain, every field
+        results.append((values, ts, src.parse_errors, src.unknown_ids,
+                        src.records_parsed, src.values_parsed,
+                        src.values_null))
+    (v_n, *tally_n), (v_p, *tally_p) = results
+    assert np.array_equal(v_n, v_p, equal_nan=True)
+    assert tally_n == tally_p
+    _ts, pe, unk, rec, vals, nulls = tally_n
+    assert pe > 0 and unk > 0 and rec > 0 and nulls > 0
+    # a sound record writes F values, nulls among them; a record with a bad
+    # ts wrote its values too, so the count is at least the records'
+    assert vals + nulls >= rec * n_fields
+
+
+@needs_native
+@pytest.mark.parametrize("native", [True, False], ids=["native", "python"])
+def test_vector_set_ids_carries_rows_by_id(native):
+    """set_ids on the [n, F] table: retained nodes keep this slot's row BY
+    ID, new ones start all-missing, on both parse paths — and a partial
+    line held by a connection completes against the new table."""
+    src = TcpJsonlSource(["a", "b"], native=native, n_fields=3,
+                         track_unknown=True).start()
+    try:
+        with socket.create_connection(src.address, timeout=5.0) as s:
+            s.sendall(b'{"id": "a", "values": [7, null, 9]}\n'
+                      b'{"id": "c", "values": [1, 2')
+            deadline = time.time() + 5
+            while time.time() < deadline and src.records_parsed < 1:
+                time.sleep(0.01)
+            src.set_ids(["c", "a"])
+            assert src._latest.shape == (2, 3)
+            s.sendall(b", 3]}\n")
+        deadline = time.time() + 5
+        while time.time() < deadline and src.records_parsed < 2:
+            time.sleep(0.01)
+        assert src.drain_unknown() == []  # c was registered before its line
+        values, _ = src(0)
+        assert values[0].tolist() == [1.0, 2.0, 3.0]
+        assert values[1][0] == 7.0 and np.isnan(values[1][1]) \
+            and values[1][2] == 9.0
+    finally:
+        src.close()
+
+
+def test_send_jsonl_sends_vector_records_with_null_for_a_missing_metric():
+    with TcpJsonlSource(["a"], native=False, n_fields=3) as src:
+        row = np.array([1.5, np.nan, 3.0], np.float32)
+        assert send_jsonl(src.address, [{"id": "a", "values": row,
+                                         "ts": 9}]) == 1
+        deadline = time.time() + 5
+        while time.time() < deadline and src.records_parsed < 1:
+            time.sleep(0.01)
+        values, ts = src(0)
+    assert ts == 9 and np.array_equal(values[0], row, equal_nan=True)
+    assert (src.values_parsed, src.values_null) == (2, 1)  # NaN went as null

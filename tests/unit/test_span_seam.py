@@ -239,15 +239,22 @@ def test_a_dead_recorder_takes_no_gc_spans():
     assert len(seam._RECORDERS) == n - 1
 
 
+@pytest.mark.parametrize("n_fields", [1, 3], ids=["scalar", "vector"])
 @pytest.mark.parametrize("native", [None, False], ids=["auto", "python"])
-def test_ingest_feed_bytes_sum_to_what_was_sent(native, tmp_path):
+def test_ingest_feed_bytes_and_values_sum_to_what_was_sent(native, n_fields,
+                                                           tmp_path):
+    """`rtap.ingest.feed` says what each batch did — `bytes` taken, `values`
+    written, `nulls` among them counted apart — and `rtap.ingest.snapshot`
+    the record's width; at three fields every record's middle one is null."""
     from rtap_tpu.service.sources import TcpJsonlSource
 
     ids = [f"s{i:03d}" for i in range(50)]
-    payload = b"".join(
-        b'{"id": "%s", "value": %d.5, "ts": 1700000000}\n' % (sid.encode(), i)
-        for i, sid in enumerate(ids)) * 40
-    with TcpJsonlSource(ids, native=native) as src:
+    record = b'{"id": "%s", "value": %d.5, "ts": 1700000000}\n' \
+        if n_fields == 1 else \
+        b'{"id": "%s", "values": [%d.5, null, 7], "ts": 1700000000}\n'
+    payload = b"".join(record % (sid.encode(), i)
+                       for i, sid in enumerate(ids)) * 40
+    with TcpJsonlSource(ids, native=native, n_fields=n_fields) as src:
         with Profile(tmp_path) as prof:
             with socket.create_connection(src.address) as conn:
                 conn.sendall(payload)
@@ -256,12 +263,18 @@ def test_ingest_feed_bytes_sum_to_what_was_sent(native, tmp_path):
                 time.sleep(0.01)
             values, _ts = src(3)
         assert src.records_parsed == 50 * 40 and src.parse_errors == 0
-    assert np.array_equal(values, np.arange(50, dtype=np.float32) + 0.5)
+    first = values if n_fields == 1 else values[:, 0]
+    assert np.array_equal(first, np.arange(50, dtype=np.float32) + 0.5)
     feeds = prof.named("rtap.ingest.feed")
     assert sum(f[3]["bytes"] for f in feeds) == len(payload)
     assert all(f[3]["wait_us"] >= 0 for f in feeds)
+    nulls = 0 if n_fields == 1 else 50 * 40
+    assert sum(f[3]["nulls"] for f in feeds) == nulls == src.values_null
+    assert sum(f[3]["values"] for f in feeds) == src.values_parsed == \
+        50 * 40 * n_fields - nulls
     (snap,) = prof.named("rtap.ingest.snapshot")
     assert snap[3]["tick"] == 3 and snap[3]["wait_us"] >= 0
+    assert snap[3]["fields"] == n_fields
 
 
 def test_python_fallback_feeds_an_unterminated_final_line():
