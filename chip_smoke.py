@@ -191,19 +191,20 @@ def mesh_phase(sizes: dict) -> int:
                 ok = False
         return ok
 
-    checks["shards_placed"] = placement(meshed.state)
+    # `resident`: the leaves as the devices hold them (ops/resident.py)
+    checks["shards_placed"] = placement(meshed.resident)
     _, _, raw_mesh, mw = _run_chunks(meshed, G, T)
     # donation must keep every leaf where it was put
-    checks["shards_stay_placed"] = placement(meshed.state)
+    checks["shards_stay_placed"] = placement(meshed.resident)
     say(f"[mesh] cluster_preset G={G} over {sorted(str(d) for d in mesh.devices.flat)}: "
-        f"{len(meshed.state)} state leaves x 4 shards of {G // 4} rows; "
+        f"{len(meshed.resident)} state leaves x 4 shards of {G // 4} rows; "
         f"first chunk {mw[0]:.1f}s, second {mw[1]:.2f}s")
 
     state_ranks = tuple(sorted((k, max(np.ndim(v), 1))
-                               for k, v in meshed.state.items()))
+                               for k, v in meshed.resident.items()))
     v, ts, _ = _feed(G, T, 0)
     txt = _sharded_chunk_fn(cfg, mesh, True, state_ranks).lower(
-        meshed.state, put_sharded(v[..., None], mesh, 1),
+        meshed.resident, put_sharded(v[..., None], mesh, 1),
         put_sharded(ts.astype(np.int32), mesh, 1)).compile().as_text()
     found = [c for c in ("all-reduce", "all-gather", "collective-permute",
                          "all-to-all", "reduce-scatter") if c in txt]

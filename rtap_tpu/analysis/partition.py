@@ -59,9 +59,9 @@ RULES = {
 _CONSUMER_SCOPE = ("rtap_tpu/service/", "rtap_tpu/resilience/",
                    "rtap_tpu/obs/", "rtap_tpu/correlate/")
 
-#: receivers treated as "the state tree" at consumer sites: grp.state,
+#: receivers treated as "the state tree" at consumer sites: grp.state / grp.resident,
 #: a local st/state/model binding, or the oracle's per-stream _states
-_STATE_RECEIVERS = frozenset({"state", "st", "model", "_states"})
+_STATE_RECEIVERS = frozenset({"state", "resident", "st", "model", "_states"})
 
 _CHECKPOINT_FILE = "rtap_tpu/service/checkpoint.py"
 _LOOP_FILE = "rtap_tpu/service/loop.py"

@@ -98,6 +98,10 @@ SPANS = (
     "rtap.aot.warm",
     # one per garbage collection of this process (`generation`, `collected`)
     "rtap.host.gc",
+    # ops/resident.py — one per conversion of state leaves between the public
+    # layout and the form the device holds them in (`leaves`, `bytes`): set-up,
+    # a slot claimed, a checkpoint, a row read; none in a chunk or a live tick
+    "rtap.state.relayout",
 )
 
 #: the name a span takes in a TraceRecorder ring (the names

@@ -179,7 +179,7 @@ def health_reduce(state: dict, raw, values, cfg: ModelConfig) -> dict:
 def health_reduce_host(state: dict, raw: np.ndarray, values: np.ndarray,
                        cfg: ModelConfig) -> dict:
     """Numpy twin of :func:`health_reduce` on PUBLIC-layout group state
-    ([G, C, K, S, M] pools — what ``grp.state`` holds between chunks).
+    ([G, C, K, S, M] pools — what ``grp.state`` reads as between chunks).
     Same schema, same semantics; the parity test pins the two against
     each other and the CPU-oracle backend emits health through it."""
     tm = cfg.tm

@@ -8,15 +8,21 @@ exactly the one BASELINE.json prescribes: values/timestamps in, raw scores
 out; anomaly likelihood stays on host (models/oracle/likelihood.py,
 service/likelihood_batch.py).
 
-Three entry points:
+Entry points:
 
 - :func:`fused_step` — single stream, used by `HTMModel(backend="tpu")`.
 - :func:`group_step` — vmapped over a leading stream-group axis G: one
   dispatch scores G streams in lockstep (SURVEY.md §2.3 "DP over streams").
+- :func:`chunk_step` — `group_step`'s body scanned over T ticks: the program
+  the stream groups call, in replay (T = 8) and in the live loop (T = 1).
 - :class:`TpuStepRunner` — stateful convenience wrapper holding device state.
 
-All three are bit-identical to the CPU oracle per step
-(tests/parity/test_e2e_parity.py).
+All are bit-identical to the CPU oracle per step
+(tests/parity/test_e2e_parity.py), and each hands the state back in the form
+it arrived in (:func:`_enter_kernel`): the public [C, K, S, M] layout for
+the harnesses that build their own trees, the kernel's form for the owners
+that keep theirs on the device between programs (ops/resident.py) — for
+those no pool changes layout at a program's boundary.
 """
 
 from __future__ import annotations
@@ -53,7 +59,9 @@ from rtap_tpu.ops.tm_tpu import tm_step
 #:   rtap.tm.dendrite    dendrite activity for t+1                  (tm_tpu.tm_step)
 #:   rtap.reduce.health, rtap.reduce.predict, rtap.classifier
 #:                       the optional reducers / classifier         (_tick, _step_impl)
-#:   rtap.layout         to/from_kernel_layout, once per program   (fused_step, group_step, _scan_chunk)
+#:   rtap.layout         to/from_kernel_layout of a PUBLIC-layout argument,
+#:                       once per program; absent where the state arrives
+#:                       in the kernel's form (a stream group's)    (_enter_kernel)
 SCOPES = (
     "rtap.encode",
     "rtap.sp.overlap", "rtap.sp.inhibit", "rtap.sp.learn",
@@ -101,18 +109,44 @@ def _step_impl(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: Mode
     return state, raw
 
 
+def _enter_kernel(state: dict, cfg: ModelConfig, ticks: int = 1):
+    """A program's state argument -> (the tree `tm_step` runs `ticks` ticks
+    on, the function that hands the stepped tree back in the form the
+    argument came in). Told apart by the leaves' shapes at trace time
+    (tm_tpu.kernel_resident), never by a setting:
+
+    - the kernel's form (what a `StreamGroup` or a `TpuStepRunner` holds on
+      the device between programs, ops/resident.py) passes both ways
+      untouched — the program's parameters and results ARE the scan's
+      carry, and no pool is copied at its boundary;
+    - the public [C, K, S, M] layout (the parity harness, the oracle's
+      twin, scripts that build their own trees) goes through
+      `to_kernel_layout` / `from_kernel_layout` under `rtap.layout`, once a
+      program each way.
+    """
+    from rtap_tpu.ops.tm_tpu import from_kernel_layout, kernel_resident, to_kernel_layout
+
+    if kernel_resident(state):
+        return state, lambda stepped: stepped
+    with jax.named_scope("rtap.layout"):
+        entered = to_kernel_layout(state, cfg.tm, ticks)
+
+    def leave(stepped: dict) -> dict:
+        with jax.named_scope("rtap.layout"):
+            return from_kernel_layout(stepped, cfg.tm, ticks)
+
+    return entered, leave
+
+
 # rtap: twin[oracle_record_step] — the oracle chains bind/encode/SP/TM
 # per record (models/htm_model.py); parity: tests/parity/test_e2e_parity.py
 @partial(jax.jit, static_argnames=("cfg", "learn"))
 def fused_step(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: ModelConfig, learn: bool = True):
-    """Single-stream fused step (see :func:`_step_impl`)."""
-    from rtap_tpu.ops.tm_tpu import from_kernel_layout, to_kernel_layout
-
-    with jax.named_scope("rtap.layout"):
-        state = to_kernel_layout(state, cfg.tm)
+    """Single-stream fused step (see :func:`_step_impl`). The state comes
+    back in the form it arrived in (:func:`_enter_kernel`)."""
+    state, leave = _enter_kernel(state, cfg)
     state, out = _step_impl(state, values, ts_unix, cfg, learn)
-    with jax.named_scope("rtap.layout"):
-        return from_kernel_layout(state, cfg.tm), out
+    return leave(state), out
 
 
 def _tick(s: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: ModelConfig, learn: bool,
@@ -186,14 +220,10 @@ def group_step(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: Mode
     `predict=True` the predictive-horizon leaf wraps outermost — see
     :func:`_tick` / ops/health_tpu.py / ops/predict_tpu.py.
     """
-    from rtap_tpu.ops.tm_tpu import from_kernel_layout, to_kernel_layout
-
-    with jax.named_scope("rtap.layout"):
-        state = to_kernel_layout(state, cfg.tm)
+    state, leave = _enter_kernel(state, cfg)
     state, out = _tick(state, values, ts_unix, cfg, learn,
                        health=health, predict=predict)
-    with jax.named_scope("rtap.layout"):
-        return from_kernel_layout(state, cfg.tm), out
+    return leave(state), out
 
 
 def _scan_chunk(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: ModelConfig, learn: bool,
@@ -202,18 +232,22 @@ def _scan_chunk(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: Mod
     Used identically by the single-device and shard_map entry points, so the
     two can never diverge semantically.
 
-    The kernel-layout adapters sit OUTSIDE the scan: the carry holds the
-    pools in the kernel's layout for all T ticks (tm_tpu.wide_rows: flat at
-    narrow pool rows, a reshape; [C, M, K*S] at wide ones, a transpose a
-    pool each way — which a chunk of one tick could not win back, so it
-    keeps the public layout, tm_tpu.public_in_kernel) and the public
-    [C,K,S,M] layout is restored once per chunk — checkpoints, oracle
-    parity, and the service API never see kernel layout. Likewise
+    The carry holds the pools in the kernel's layout for all T ticks
+    (tm_tpu.wide_rows: flat at narrow pool rows, [C, M, K*S] at wide ones).
+    A stream group hands its state over in that form and takes it back so
+    (ops/resident.py): the program's parameters are the carry. A tree in
+    the public [C,K,S,M] layout — the parity harness's, the oracle twin's —
+    is adapted OUTSIDE the scan, once per chunk each way (a reshape at
+    narrow rows; at wide ones a transpose a pool, which a chunk of one tick
+    could not win back, so it keeps the public layout in the kernel,
+    tm_tpu.public_in_kernel); checkpoints and the oracle never see the
+    kernel layout, and readers of a group's state see it through
+    `StreamGroup.state`, public again. Likewise
     the tick-invariant kernel operands (the flat layout's per-segment
     reduction matrix) are built ONCE here and closed over by the body, so
     they are hoisted out of the scan by construction and stay HBM-resident
     across the whole T-tick chunk."""
-    from rtap_tpu.ops.tm_tpu import from_kernel_layout, tm_invariants, to_kernel_layout
+    from rtap_tpu.ops.tm_tpu import tm_invariants
 
     inv = tm_invariants(cfg.tm)
 
@@ -222,12 +256,9 @@ def _scan_chunk(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: Mod
         return _tick(s, v, t, cfg, learn, inv, health=health,
                      predict=predict)
 
-    T = values.shape[0]
-    with jax.named_scope("rtap.layout"):
-        state = to_kernel_layout(state, cfg.tm, T)
+    state, leave = _enter_kernel(state, cfg, values.shape[0])
     state, out = jax.lax.scan(body, state, (values, ts_unix))
-    with jax.named_scope("rtap.layout"):
-        return from_kernel_layout(state, cfg.tm, T), out
+    return leave(state), out
 
 
 # rtap: twin[oracle_record_step] — time-scanned form of the oracle chain
@@ -240,8 +271,10 @@ def chunk_step(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: Mode
     `values` is [T, G, n_fields] f32, `ts_unix` [T, G] i32 ->
     (state, raw [T, G] f32). This is the replay/bench fast path (SURVEY.md §7
     hard part 3: amortize per-tick dispatch latency by batching ticks when
-    replaying faster than real time); the live 1s-cadence service uses
-    :func:`group_step` per tick instead. With `health=True` (static) the
+    replaying faster than real time) and, at T = 1 (or the micro-chunk's
+    length), the live service's too: `StreamGroup.dispatch_chunk` is what
+    `live_loop` calls, and :func:`group_step` serves `StreamGroup.tick`
+    alone. With `health=True` (static) the
     out leaf becomes (out, health_leaf) and every health-leaf array gains
     the leading T axis — one ~200 B record per tick, scanned alongside the
     scores (ops/health_tpu.py). With `predict=True` the predictive-horizon
@@ -331,7 +364,10 @@ def set_state_row(state: dict, fresh: dict, slot: int) -> dict:  # rtap: allow[t
     """Overwrite ONE stream's row of grouped [G, ...] state with a fresh
     single-stream state (dynamic slot claim — registry.claim_slot). The
     slot index is a traced argument so claiming different slots reuses one
-    compiled program; the group buffer is donated (no [G, ...] copy)."""
+    compiled program; the group buffer is donated (no [G, ...] copy).
+    `fresh` comes in the group's form: for a group that holds the kernel's,
+    the caller re-lays `init_state`'s public row on the host first
+    (ops/resident.py:host_resident), one row's worth."""
     return _set_row_jit(state, {k: jnp.asarray(v) for k, v in fresh.items()},
                         jnp.asarray(slot, jnp.int32))
 
@@ -341,18 +377,29 @@ class TpuStepRunner:
 
     Used by `HTMModel(backend="tpu")` — the single-stream convenience path.
     High-throughput multi-stream execution goes through service/registry.py
-    stream groups and :func:`group_step` instead.
+    stream groups and :func:`chunk_step` instead. Like a group, the runner
+    keeps the state in the kernel's form on the device (`resident`) and
+    `state` reads as the public tree (ops/resident.py).
     """
 
     def __init__(self, cfg: ModelConfig, state: dict):
+        from rtap_tpu.ops.resident import host_resident
+
         self.cfg = cfg
-        self.state = jax.device_put(state)
+        self.relayouts = 0
+        self.resident = jax.device_put(host_resident(state, cfg.tm, self))
+
+    @property
+    def state(self):
+        from rtap_tpu.ops.resident import PublicState
+
+        return PublicState(self)
 
     def step(self, values: np.ndarray, ts_unix: int, learn: bool = True):
         """-> raw score (float), or (raw, prediction, prob) floats when the
         SDR classifier is enabled (static per config)."""
         v = jnp.asarray(np.atleast_1d(values), jnp.float32)
-        self.state, out = fused_step(self.state, v, jnp.int32(ts_unix), self.cfg, learn)
+        self.resident, out = fused_step(self.resident, v, jnp.int32(ts_unix), self.cfg, learn)
         if self.cfg.classifier.enabled:
             return float(out[0]), float(out[1]), float(out[2])
         return float(out)

@@ -47,12 +47,17 @@ move by index and, in a scan over ticks, the pools run [C, M, K*S] — the
 segments on the lanes, a segment's synapses down the sublanes, so a column's
 row is contiguous for the moves AND the full-pool sweep has whole tiles on
 both minor dims; one layout serves both and no pool changes layout inside
-the scan (docs/KERNELS.md, "Why [C, M, K*S]"). A one-tick program has no
-later tick to win the two transposes a pool back on and runs the same form
-on the public [C, K, S, M] (:func:`public_in_kernel`). The kernel layouts are
-entered and left once a program (:func:`to_kernel_layout`,
-:func:`from_kernel_layout`; ops/step.py calls them under `rtap.layout`), and
-nothing outside this module knows there is a choice. In the narrow form the
+the scan (docs/KERNELS.md, "Why [C, M, K*S]"). The owners of a state — a
+stream group, a step runner — keep it on the device in the kernel's form
+between programs (:func:`resident_form`; ops/resident.py), so their programs
+enter and leave no layout. A tree handed over in the public [C, K, S, M]
+layout enters and leaves the kernel's once a program
+(:func:`to_kernel_layout`, :func:`from_kernel_layout`; ops/step.py calls
+them under `rtap.layout`) — except a one-tick program at wide rows, which
+has no later tick to win the two transposes a pool back on and runs the same
+form on the public layout as it stands (:func:`public_in_kernel`). The form
+is a function of the shape, and nothing outside this module and
+ops/resident.py knows there is a choice. In the narrow form the
 workspace path is region-consolidated:
 presyn + perm (+ seg_pot) ride ONE one-hot MXU pass per
 gather/scatter stage instead of one pass per tensor (bitwise identical per
@@ -177,9 +182,10 @@ _KERNEL_KEYS = {
 
 
 def public_in_kernel(cfg: TMConfig, ticks: int) -> bool:
-    """Does a program of `ticks` ticks run its wide-row step on the public
-    [C, K, S, M] layout as it stands? Only the one-tick programs do (the
-    served path: `group_step`, `fused_step`, `chunk_step` at T = 1). The
+    """Does a program of `ticks` ticks that is HANDED the public
+    [C, K, S, M] layout run its wide-row step on it as it stands? Only the
+    one-tick programs do (`group_step`, `fused_step`, `chunk_step` at T = 1;
+    a state that arrives resident is [C, M, K*S] already and runs so). The
     chip holds that layout columns-minor and re-lays both pools around the
     indexed row moves, four pool copies a tick; entering and leaving
     [C, M, K*S] costs a one-tick program the same four, and there both
@@ -191,48 +197,74 @@ def public_in_kernel(cfg: TMConfig, ticks: int) -> bool:
     return wide_rows(cfg) and ticks == 1
 
 
+def kernel_resident(state: dict) -> bool:
+    """Does this tree hold the six `_KERNEL_KEYS` leaves in the kernel's form
+    already (the form a stream group keeps on the device between programs)?
+    Read off the leaves' shapes alone, with any leading axes: a segment
+    tensor is [C, K*S] there — the rank of `prev_active` [C, K] — and
+    [C, K, S], one more, in the public layout."""
+    return np.ndim(state["seg_last"]) == np.ndim(state["prev_active"])
+
+
+def resident_leaf(key: str, x, cfg: TMConfig):
+    """One `_KERNEL_KEYS` leaf, public layout -> the kernel's form; leading
+    axes pass through, the values are untouched. The leaf may be numpy's (a
+    host-side view: what a checkpoint read or a fresh stream's row goes
+    through before it reaches the device), the device's, or traced."""
+    nd = _KERNEL_KEYS[key]
+    lead = x.shape[: x.ndim - nd]
+    if nd == 3 and wide_rows(cfg):
+        return x.reshape(*lead, -1, cfg.max_synapses_per_segment).swapaxes(-1, -2)
+    return x.reshape(*lead, -1)
+
+
+def public_leaf(key: str, x, cfg: TMConfig):
+    """`resident_leaf`'s inverse."""
+    K, S, M = cfg.cells_per_column, cfg.max_segments_per_cell, cfg.max_synapses_per_segment
+    if _KERNEL_KEYS[key] == 2:
+        return x.reshape(*x.shape[:-1], K, S)
+    if wide_rows(cfg):
+        return x.swapaxes(-1, -2).reshape(*x.shape[:-2], K, S, M)
+    return x.reshape(*x.shape[:-1], K, S, M)
+
+
+def resident_form(state: dict, cfg: TMConfig) -> dict:
+    """Public state layout -> the form the device holds between programs and
+    `tm_step(cfg)` runs on, a function of `cfg` alone. At narrow rows a
+    reshape: [C, K*S*M] pools, [C, K*S] segment tensors. At wide rows
+    (`wide_rows`) the same segment tensors and the pools with their synapse
+    axis turned inward, [C, M, K*S]."""
+    return {**state, **{k: resident_leaf(k, state[k], cfg) for k in _KERNEL_KEYS}}
+
+
+def public_form(state: dict, cfg: TMConfig) -> dict:
+    """`resident_form`'s inverse: the public [C, K, S, M] pools and
+    [C, K, S] segment tensors — what checkpoints, the oracle and the parity
+    harness keep."""
+    return {**state, **{k: public_leaf(k, state[k], cfg) for k in _KERNEL_KEYS}}
+
+
 def to_kernel_layout(state: dict, cfg: TMConfig, ticks: int = 1) -> dict:
-    """Public state layout -> the layout `tm_step(cfg)` runs `ticks` ticks
-    on before `from_kernel_layout` restores it. The values are untouched, so
-    checkpoints, the oracle, and the parity harness all keep the public
-    [C, K, S, M] layout. At narrow rows a reshape: [C, K*S*M] pools, [C, K*S]
-    segment tensors. At wide rows (`wide_rows`) the same segment tensors and
-    the pools with their synapse axis turned inward, [C, M, K*S] — one
-    transpose a pool, entering a program, where the narrow form pays nothing
-    — unless the program runs one tick only (`public_in_kernel`): then
-    nothing changes shape."""
-    if public_in_kernel(cfg, ticks):
+    """A program's state argument -> the layout `tm_step(cfg)` runs `ticks`
+    ticks on. A tree that arrives in the kernel's form (`kernel_resident`:
+    a stream group's, between programs) passes untouched, whatever `ticks`
+    is. A tree in the public layout takes `resident_form` — a reshape at
+    narrow rows; at wide rows one transpose a pool, entering the program,
+    which `from_kernel_layout` pays again on the way out — unless the
+    program runs one tick only (`public_in_kernel`): then nothing changes
+    shape."""
+    if kernel_resident(state) or public_in_kernel(cfg, ticks):
         return state
-    wide = wide_rows(cfg)
-    M = cfg.max_synapses_per_segment
-    out = dict(state)
-    for k, nd in _KERNEL_KEYS.items():
-        x = out[k]
-        lead = x.shape[: x.ndim - nd]
-        if wide and nd == 3:
-            out[k] = x.reshape(*lead, -1, M).swapaxes(-1, -2)
-        else:
-            out[k] = x.reshape(*lead, -1)
-    return out
+    return resident_form(state, cfg)
 
 
 def from_kernel_layout(state: dict, cfg: TMConfig, ticks: int = 1) -> dict:
-    """Kernel layout -> public state layout (`to_kernel_layout`'s inverse,
-    at the same `ticks`)."""
+    """What `to_kernel_layout` made of a public-layout tree -> the public
+    layout again, at the same `ticks`. (A tree that arrived resident is
+    handed back as it is: ops/step.py does not call this for it.)"""
     if public_in_kernel(cfg, ticks):
         return state
-    wide = wide_rows(cfg)
-    K, S, M = cfg.cells_per_column, cfg.max_segments_per_cell, cfg.max_synapses_per_segment
-    out = dict(state)
-    for k, nd in _KERNEL_KEYS.items():
-        x = out[k]
-        if nd == 2:
-            out[k] = x.reshape(*x.shape[:-1], K, S)
-        elif wide:
-            out[k] = x.swapaxes(-1, -2).reshape(*x.shape[:-2], K, S, M)
-        else:
-            out[k] = x.reshape(*x.shape[:-1], K, S, M)
-    return out
+    return public_form(state, cfg)
 
 
 @lru_cache(maxsize=None)

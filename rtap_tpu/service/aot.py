@@ -88,6 +88,7 @@ def prewarm(groups, micro_chunk: int, learn: bool, degradation=None,
     import jax.numpy as jnp
 
     from rtap_tpu.models.state import init_state
+    from rtap_tpu.ops.resident import host_resident
     from rtap_tpu.ops.step import (
         chunk_step, replicate_state_device, set_state_row,
     )
@@ -126,9 +127,11 @@ def prewarm(groups, micro_chunk: int, learn: bool, degradation=None,
         pk = predict_by_cfg[cfg]
         # one scratch state per config, threaded through every program
         # (chunk_step donates its state argument, so each call consumes
-        # the previous call's output buffers — no HBM accumulation)
-        scratch = replicate_state_device(
-            init_state(cfg, seed, predict_horizon=pk), G)
+        # the previous call's output buffers — no HBM accumulation), in the
+        # form the groups hold theirs on the device: the programs the loop
+        # will call are the ones whose state arrives resident
+        fresh = host_resident(init_state(cfg, seed, predict_horizon=pk), cfg.tm)
+        scratch = replicate_state_device(fresh, G)
         for m, lf in sorted(mls):
             vals = jnp.full((m, G, cfg.n_fields), jnp.nan, jnp.float32)
             ts = jnp.zeros((m, G), jnp.int32)
@@ -144,7 +147,6 @@ def prewarm(groups, micro_chunk: int, learn: bool, degradation=None,
             # the first-claim/realignment program (registry.claim_slot ->
             # set_state_row): the slot index is traced, so ONE execution
             # covers every future claim
-            fresh = init_state(cfg, seed, predict_horizon=pk)
             with span("rtap.aot.warm", trace,
                       program=f"set_state_row.cfg{ci}"):
                 scratch = jax.block_until_ready(set_state_row(

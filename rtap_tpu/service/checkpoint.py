@@ -60,7 +60,11 @@ def save_group(grp: StreamGroup, path: str | Path,
 
     path = Path(path).absolute()
     if grp.backend == "tpu":
-        tree = {"model": {k: np.asarray(v) for k, v in jax.device_get(grp.state).items()}}
+        from rtap_tpu.ops.resident import host_public
+
+        # the files keep the public layout: the leaves are fetched as the
+        # device holds them and re-laid on the host
+        tree = {"model": host_public(jax.device_get(grp.resident), grp.cfg.tm, grp)}
     else:
         # per-stream state dicts include classifier cls_* arrays when enabled
         # (the oracle operates on the shared state layout, like TMOracle)
@@ -220,13 +224,18 @@ def load_group(path: str | Path, mesh=None, sparsify: bool = False) -> StreamGro
     )
     if grp.backend == "tpu":
         # fwd_*: a forward index an older build may have stored
+        from rtap_tpu.ops.resident import host_resident
+
         model = {k: v for k, v in tree["model"].items() if not k.startswith("fwd_")}
+        # the files hold the public layout; the device the kernel's form,
+        # taken on the host before the put (never two pools on the chip)
+        model = host_resident(model, cfg.tm, grp)
         if mesh is not None:
             from rtap_tpu.parallel.sharding import shard_state
 
-            grp.state = shard_state(model, mesh)
+            grp.resident = shard_state(model, mesh)
         else:
-            grp.state = jax.device_put(model)
+            grp.resident = jax.device_put(model)
     else:
         for g in range(grp.G):
             saved = tree["model"][f"s{g}"]

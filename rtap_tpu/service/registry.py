@@ -152,23 +152,31 @@ class StreamGroup:
         # latest predicted values [T, G] (classifier only); kept in sync by
         # both run_chunk and tick so it can never serve stale data
         self.last_predictions: np.ndarray | None = None
+        # conversions of the state between the public layout and the form
+        # the device holds it in (ops/resident.py): set-up, a slot claimed,
+        # a checkpoint, a row read — never a dispatched chunk or a live tick
+        self.relayouts = 0
         if backend == "tpu":
             from rtap_tpu.models.state import init_state
+            from rtap_tpu.ops.resident import host_resident
 
+            # the device holds the state in the form the kernel runs on, so
+            # no program re-lays a pool on its way in or out; `self.state`
+            # reads it as the public tree
+            single = host_resident(
+                init_state(cfg, seed, predict_horizon=self.predict), cfg.tm, self)
             if mesh is not None:
                 # memory-lean: per-shard broadcast views, never the full
                 # group on host (54 GiB at the 100k-stream scale)
                 from rtap_tpu.parallel.sharding import broadcast_group_state
 
-                self.state = broadcast_group_state(init_state(cfg, seed), self.G, mesh)
+                self.resident = broadcast_group_state(single, self.G, mesh)
             else:
                 # one ~0.5 MB transfer + on-chip broadcast, never a [G, ...]
                 # host staging (208 s at the G=24k HBM frontier)
                 from rtap_tpu.ops.step import replicate_state_device
 
-                self.state = replicate_state_device(
-                    init_state(cfg, seed, predict_horizon=self.predict),
-                    self.G)
+                self.resident = replicate_state_device(single, self.G)
         else:
             from rtap_tpu.models.oracle.temporal_memory import TMOracle
             from rtap_tpu.models.state import init_state
@@ -184,6 +192,24 @@ class StreamGroup:
                 self._classifiers = [
                     SDRClassifierOracle(s, cfg.classifier) for s in self._states
                 ]
+
+    # ---- the state, read from outside ----
+    @property
+    def state(self):
+        """The group's model state as the public tree ([G, C, K, S, M]
+        pools): a view of `resident`, what the device holds (tpu backend).
+        Reading one stream's row slices the resident leaf first; assigning
+        a tree or a leaf in the public layout converts it once, there
+        (ops/resident.py)."""
+        from rtap_tpu.ops.resident import PublicState
+
+        return PublicState(self)
+
+    @state.setter
+    def state(self, tree) -> None:
+        from rtap_tpu.ops.resident import resident_tree
+
+        self.resident = resident_tree(tree, self.cfg.tm, self)
 
     # ---- dynamic membership (slots are static, streams are data) ----
     @property
@@ -208,7 +234,7 @@ class StreamGroup:
         """:func:`segment_capacity` of this group's streams, fetched from
         the state it holds (off the step's path, like ``tm_overflow``)."""
         if self.backend == "tpu":
-            seg_last = np.asarray(self.state["seg_last"])
+            seg_last = np.asarray(self.state["seg_last"])  # the public [G, C, K, S]
         else:
             seg_last = np.stack([s["seg_last"] for s in self._states])
         return segment_capacity(seg_last >= 0)
@@ -268,12 +294,15 @@ class StreamGroup:
             # gates scoring on tick >= pred_tick0 + horizon)
             fresh["pred_tick0"] = np.int32(self.ticks)
         if self.backend == "tpu":
+            from rtap_tpu.ops.resident import host_resident
             from rtap_tpu.ops.step import set_state_row
 
             # match the live tree's structure (forward-index mode carries
-            # derived fwd_* leaves that init_state also builds)
-            self.state = set_state_row(
-                self.state, {k: fresh[k] for k in self.state}, slot)
+            # derived fwd_* leaves that init_state also builds) and its
+            # form: the fresh row is re-laid on the host, one row's worth
+            fresh = host_resident(
+                {k: fresh[k] for k in self.resident}, self.cfg.tm, self)
+            self.resident = set_state_row(self.resident, fresh, slot)
         else:
             from rtap_tpu.models.oracle.temporal_memory import TMOracle
 
@@ -336,8 +365,8 @@ class StreamGroup:
             if self.mesh is not None:
                 from rtap_tpu.ops.step import sharded_chunk_step
 
-                self.state, out = sharded_chunk_step(
-                    self.state, self._put(values[None], axis=1),
+                self.resident, out = sharded_chunk_step(
+                    self.resident, self._put(values[None], axis=1),
                     self._put(ts[None].astype(np.int32), axis=1), self.cfg, self.mesh,
                     learn=learn,
                 )
@@ -345,8 +374,8 @@ class StreamGroup:
             else:
                 from rtap_tpu.ops.step import group_step
 
-                self.state, out = group_step(
-                    self.state, self._put(values), self._put(ts.astype(np.int32)), self.cfg,
+                self.resident, out = group_step(
+                    self.resident, self._put(values), self._put(ts.astype(np.int32)), self.cfg,
                     learn=learn, health=self.health,
                     predict=bool(self.predict),
                 )
@@ -434,15 +463,15 @@ class StreamGroup:
                 if self.mesh is not None:
                     from rtap_tpu.ops.step import sharded_chunk_step
 
-                    self.state, out = sharded_chunk_step(
-                        self.state, dev_values, dev_ts, self.cfg, self.mesh,
+                    self.resident, out = sharded_chunk_step(
+                        self.resident, dev_values, dev_ts, self.cfg, self.mesh,
                         learn=learn,
                     )
                 else:
                     from rtap_tpu.ops.step import chunk_step
 
-                    self.state, out = chunk_step(
-                        self.state, dev_values, dev_ts,
+                    self.resident, out = chunk_step(
+                        self.resident, dev_values, dev_ts,
                         self.cfg, learn=learn, health=self.health,
                         predict=bool(self.predict),
                     )

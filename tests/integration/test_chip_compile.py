@@ -275,7 +275,8 @@ def test_nab_width_chunk_step_fits_a_v5e_only_in_the_wide_row_forms(v5e, nab_chu
         # donated public-layout arguments, which the outputs alias, for the
         # whole scan: 4,777,026,560 B of temporaries where the program that
         # re-laid the pools every tick took 2,744,192,512 (ISSUE 40) — what
-        # a state that crosses chunks in the kernel's layout would win back.
+        # a state that arrives in the kernel's layout does not pay (a stream
+        # group's, ISSUE 44: under 1 GB, the resident-state test below).
         # Growth's [L, R, W] rank-match grid (1,280 x 20 x 1,280 a stream:
         # 2.2 GB if it were a buffer) still fuses into its reduce (ISSUE 28)
         assert mem.temp_size_in_bytes <= 4_777_026_560
@@ -328,3 +329,122 @@ def test_serve_group_step_with_reducers_compiles_for_v5e(v5e):
     state = init_state(cfg, 0, predict_horizon=8)
     per_stream = sum(np.asarray(v).nbytes for v in state.values())
     assert compiled.memory_analysis().output_size_in_bytes >= G * per_stream
+
+
+# ---- the state in the kernel's form between programs (ISSUE 44) ----
+
+def _resident_args(cfg, sharding, T, g):
+    """`_step_args` with the state's shapes in the form a `StreamGroup`
+    holds on the device (ops/resident.py)."""
+    state, vals, ts = _step_args(cfg, sharding, T=T, g=g)
+    shapes = tm_tpu.resident_form(
+        {k: np.empty(v.shape, v.dtype) for k, v in state.items()}, cfg.tm)
+    return ({k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=sharding)
+             for k, v in shapes.items()}, vals, ts)
+
+
+def _entry_moves(text: str, g: int, cfg) -> list:
+    """(result type, op) of every 16- or 32-bit `copy` / `transpose` in the
+    compiled program's ENTRY computation whose result is pool-sized:
+    `[g, C, ...]` with K*S*M elements a column, in any of the layouts."""
+    tm = cfg.tm
+    slots = tm.cells_per_column * tm.max_segments_per_cell * tm.max_synapses_per_segment
+    entry = text[text.index("\nENTRY "):]
+    found = []
+    for ty, op in re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = (\S+) (copy|transpose)\(", entry, re.M):
+        m = re.match(rf"[suf](?:16|32)\[{g},{cfg.sp.columns},([\d,]+)\]", ty)
+        if m and np.prod([int(d) for d in m.group(1).split(",")]) == slots:
+            found.append((ty, op))
+    return found
+
+
+@pytest.mark.parametrize("T", [1, 2])
+@pytest.mark.parametrize("preset", ["cluster", "scaled32", "node3", "nab"])
+def test_resident_state_crosses_the_program_boundary_with_no_pool_copy(v5e, nab_chunk, preset, T):
+    """With the state handed over in the kernel's form, the program's
+    parameters and results are the scan's carry: ENTRY holds no pool-sized
+    `copy` or `transpose` — at 192, 384 and 16,384 lanes a row, in the
+    one-tick program the live loop calls and in a scan. The chip's default
+    layout of a `[G, C, K*S*M]` argument is the one the carry wants
+    (columns-minor at 192 lanes, lanes-minor at 384), so an array that
+    simply has the kernel's shape arrives ready. At 32 columns the carry is
+    staged into on-chip memory and back (`S(1)`), a pair of copies a pool
+    each way: they stay, and are all there is. Handed the public layout the
+    same programs held 6-10 such copies at the cluster and node widths
+    (3.7 ms of a 21.2 ms one-tick program at 384 lanes on the chip) and four
+    pool transposes at the NAB width (ISSUE 44; PERF.md §6)."""
+    from rtap_tpu.ops.step import chunk_step
+
+    cfg = {"cluster": cluster_preset, "scaled32": lambda: scaled_cluster_preset(32),
+           "node3": lambda: node_preset(3),
+           "nab": lambda: nab_preset(0.0, 100.0)}[preset]()
+    g = 17 if preset == "nab" else G
+    compiled = chunk_step.lower(*_resident_args(cfg, v5e, T, g), cfg,
+                                learn=True).compile()
+    text = compiled.as_text()
+    if T == 2:  # the pattern bites: the public-layout program has them
+        public = nab_chunk if preset == "nab" else chunk_step.lower(
+            *_step_args(cfg, v5e, T=T, g=g), cfg, learn=True).compile()
+        assert _entry_moves(public.as_text(), g, cfg)
+    moves = _entry_moves(text, g, cfg)
+    if preset == "scaled32":
+        assert 0 < len(moves) <= 4 and all("S(1)" in ty or op == "copy" for ty, op in moves), moves
+        staged = [ty for ty, _ in moves if "S(1)" in ty]
+        assert len(staged) == len(moves) // 2, moves  # in staged, out plain
+    else:
+        assert not moves, moves
+    if preset == "nab" and T == 2:
+        # the carry IS the arguments: no second copy of the pools (4.78 GB
+        # of temporaries with the public layout handed over)
+        assert compiled.memory_analysis().temp_size_in_bytes < 10 ** 9
+    if preset == "nab" and T == 1:
+        # the one-tick program on [C, M, K*S] holds neither layout twice
+        assert compiled.memory_analysis().temp_size_in_bytes < 10 ** 9
+        assert re.findall(r"\[17,2048,32,512\]", text)
+
+
+@pytest.mark.parametrize("preset", ["cluster", "scaled32", "node3", "nab"])
+def test_public_layout_input_lowers_to_the_parents_program(v5e, preset):
+    """Handed the public layout, every entry point is the program it was
+    before the state could stay resident: `chunk_step` (T = 1 and 2) and
+    `group_step` lower to the text of the parent commit's bodies — the
+    adapters under `rtap.layout` around the same scan / tick, written out
+    here as that commit had them (by sha256 against the parent's own
+    checkout when the change was made: PERF.md §6, PR 44)."""
+    import rtap_tpu.ops.step as step
+    from rtap_tpu.ops.tm_tpu import from_kernel_layout, tm_invariants, to_kernel_layout
+
+    cfg = {"cluster": cluster_preset, "scaled32": lambda: scaled_cluster_preset(32),
+           "node3": lambda: node_preset(3),
+           "nab": lambda: nab_preset(0.0, 100.0)}[preset]()
+
+    def chunk_step(state, values, ts_unix):  # the parent's _scan_chunk
+        inv = tm_invariants(cfg.tm)
+
+        def body(s, inp):
+            v, t = inp
+            return step._tick(s, v, t, cfg, True, inv, health=False, predict=False)
+
+        T = values.shape[0]
+        with jax.named_scope("rtap.layout"):
+            state = to_kernel_layout(state, cfg.tm, T)
+        state, out = jax.lax.scan(body, state, (values, ts_unix))
+        with jax.named_scope("rtap.layout"):
+            return from_kernel_layout(state, cfg.tm, T), out
+
+    def group_step(state, values, ts_unix):  # the parent's group_step
+        with jax.named_scope("rtap.layout"):
+            state = to_kernel_layout(state, cfg.tm)
+        state, out = step._tick(state, values, ts_unix, cfg, True,
+                                health=False, predict=False)
+        with jax.named_scope("rtap.layout"):
+            return from_kernel_layout(state, cfg.tm), out
+
+    g = 4
+    for T in (1, 2, None):
+        args = _step_args(cfg, v5e, T=T, g=g)
+        mine = (step.group_step if T is None else step.chunk_step).lower(
+            *args, cfg, learn=True).as_text()
+        theirs = jax.jit(group_step if T is None else chunk_step,
+                         donate_argnums=(0,)).lower(*args).as_text()
+        assert mine == theirs, (preset, T)

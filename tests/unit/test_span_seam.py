@@ -69,7 +69,7 @@ def test_vocabulary():
         "rtap.group.stage", "rtap.group.enqueue", "rtap.group.fetch",
         "rtap.group.likelihood",
         "rtap.ingest.feed", "rtap.ingest.snapshot",
-        "rtap.aot.warm", "rtap.host.gc")
+        "rtap.aot.warm", "rtap.host.gc", "rtap.state.relayout")
     # the ring keeps the names benchmark/traffic_kinds/live.py reads
     ring = seam._RING_NAME
     assert [ring["rtap.loop." + n] for n in (
