@@ -8,7 +8,10 @@
                       reader benchmark/readers/<reader>.py
 
 A later PR adds any of them as new files (and one entry of BENCHMARK.json);
-a name that cannot be found fails loudly, naming the file looked for."""
+a name that cannot be found fails loudly, naming the file looked for. What a
+cell's test may assert of the manifest — entries by name, a shared list's
+accepted cells first and in their order, never a last place or a whole
+list — is written once, in tests/benchmark/manifest_rules.py."""
 
 from __future__ import annotations
 

@@ -289,7 +289,7 @@ def test_committed_nab_configuration_states_its_followed_ticks():
     assert cfg["correct_ticks"] % reg.cell(tiny_nab.CELL)["traffic"]["chunk_ticks"] == 0
     assert "first 128 ticks" in cfg["guarantees"]["scores"]
     assert "at tick 128" in cfg["guarantees"]["state"]
-    # the cluster configurations follow every tick the window held
-    for w in reg.manifest["workloads"]:
-        if w["config"] != tiny_nab.CONFIG:
-            assert "correct_ticks" not in reg.cell(w["name"])["config"]
+    # the cluster and node configurations follow every tick the window held
+    # (by name: a later configuration may state the key)
+    for name in ("cluster-256", "cluster-32", "node-3", "node-3-served"):
+        assert "correct_ticks" not in reg._json("configs", name)

@@ -13,12 +13,17 @@ import pytest
 
 from benchmark import device_clock
 from benchmark.registry import Registry
+from tests.benchmark import manifest_rules as rules
 
 HERE = os.path.dirname(__file__)
 MODULE = "jit_chunk_step"
 WINDOW = (1_000, 10_000_000)
 
-NEW = {  # metric -> (reader, cells)
+#: the cells each family's lists began with (later cells follow them)
+FIRST = {"live": ["cluster-256-live"],
+         "replay": ["cluster-256-replay", "cluster-32-replay"]}
+
+NEW = {  # metric -> (reader, cells), in the order they were added
     "ingest_snapshot_ms": ("group_phase", "live"),
     "loop_dispatch_ms": ("group_phase", "live"),
     "loop_emit_ms": ("group_phase", "live"),
@@ -356,21 +361,30 @@ def test_a_trace_of_the_parent_reads_none_of_the_new_spans(reg, monkeypatch):
         "tm_learn_ms.live", "tm_dendrite_ms.live", "unscoped_ms.live"}
 
 
-@pytest.mark.parametrize("name", sorted(NEW))
-def test_new_metric_resolves_and_is_listed_for_its_cells(reg, name):
+def metric_holds(reg: Registry, name: str) -> None:
     want_reader, cells = NEW[name]
-    definition, module = reg.layer_metric(name)
+    entry = rules.entry(reg.manifest["per_layer"], name)
+    definition = rules.agrees_with_definition(reg, entry)
     assert definition["name"] == name and definition["reader"] == want_reader
-    assert callable(module.read)
-    (entry,) = [m for m in reg.manifest["per_layer"] if m["name"] == name]
-    assert entry["layer"] == definition["layer"]
-    assert entry["moves"] == definition["moves"]
-    assert entry["unit"] == definition["unit"]
-    # (the accepted tests of `nab-2048-replay` and `node-3-replay` count
-    # those cells' metrics, so a list cannot take them without a
-    # `benchmark` PR: PERF.md s7)
-    assert entry["workloads"] == (
-        ["cluster-256-live"] if cells == "live"
-        else ["cluster-256-replay", "cluster-32-replay"])
+    # the cells the list was accepted with come first, in their order; a
+    # later cell is appended after them (tests/benchmark/manifest_rules.py)
+    rules.starts_with(entry["workloads"], FIRST[cells])
     for cell in entry["workloads"]:
         assert name in [m["name"] for m in reg.metrics(cell, "per_layer")]
+
+
+def manifest_holds(reg: Registry) -> None:
+    """What this file holds of a manifest: the committed one, and the
+    rehearsal's copy (tests/benchmark/room.py)."""
+    for name in NEW:
+        metric_holds(reg, name)
+    rules.added_in_order(reg.manifest["per_layer"], NEW)
+
+
+@pytest.mark.parametrize("name", sorted(NEW))
+def test_new_metric_resolves_and_is_listed_for_its_cells(reg, name):
+    metric_holds(reg, name)
+
+
+def test_the_new_metrics_stand_in_the_order_they_were_added(reg):
+    manifest_holds(reg)

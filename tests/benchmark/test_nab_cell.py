@@ -14,6 +14,7 @@ import pytest
 
 from benchmark import kernel_bytes_dense as kbd
 from benchmark.registry import Registry
+from tests.benchmark import manifest_rules as rules
 from tests.benchmark.tiny import failed_numbers
 from tests.benchmark.tiny_nab import CELL, CONFIG, REPO, make_root, run
 
@@ -33,11 +34,11 @@ SHAPE_FREE = {
     "group_fetch_ms.replay", "group_likelihood_ms.replay",
     "warm_compile_s", "group_host_ms.replay", "step_device_ms.replay",
     "device_idle_share.replay"}
-#: the cell's own: the dense byte table's shares, the wide rows' sub-scope,
-#: the capacity counter
-NAB_METRICS = {"tm_learn_rows_ms.nab", "step_roofline.nab",
+#: the cell's own, in the order they were added: the wide rows' sub-scope,
+#: the dense byte table's shares, the capacity counter
+NAB_METRICS = ("tm_learn_rows_ms.nab", "step_roofline.nab",
                "sp_overlap_roofline.nab", "tm_roofline.nab",
-               "tm_full_cells.nab"}
+               "tm_full_cells.nab")
 
 
 def nab_config() -> dict:
@@ -99,36 +100,46 @@ def test_a_step_that_learns_nothing_is_not_correct(root, monkeypatch):
 
 # ---- the committed files ----
 
+#: the cells the shape-free lists held when this cell joined them
+REPLAY_HEAD = ["cluster-256-replay", "cluster-32-replay"]
+HEADS = {"warm_compile_s": [*REPLAY_HEAD, "cluster-256-live"]}
+
+
 def cell_resolves_and_fills_a_quarter_of_the_chip(reg: Registry) -> None:
+    """What this cell's test holds of a manifest (tests/benchmark/
+    manifest_rules.py): the committed one, and the rehearsal's copy."""
+    rules.cell_entry(reg, CELL, CONFIG, "replay-full")
     cell = reg.cell(CELL)
     assert callable(cell["kind"].run) and cell["traffic"]["name"] == "replay-full"
     cfg = cell["config"]
     assert cfg["layout"]["streams"] * kbd.state_bytes_per_stream(cfg["model"]) \
         >= 4.0 * 2 ** 30
-    assert {m["name"] for m in reg.metrics(CELL, "end_to_end")} == \
-        {"metrics_per_s", "setup_s", "peak_bytes_per_stream"}
-    layer = reg.metrics(CELL, "per_layer")
-    for m in layer:
-        definition, reader = reg.layer_metric(m["name"])
-        assert callable(reader.read)
-        assert (definition["layer"], definition["moves"], definition["unit"]) \
-            == (m["layer"], m["moves"], m["unit"])
+    rules.reports_at_least(reg, CELL, "end_to_end",
+                           {"metrics_per_s", "setup_s", "peak_bytes_per_stream"})
+    layer = rules.reports_at_least(reg, CELL, "per_layer",
+                                   SHAPE_FREE | set(NAB_METRICS))
+    for m in layer.values():
+        rules.agrees_with_definition(reg, m)
         assert m["moves"] in ("metrics_per_s", "setup_s")
     # one name for one measurement: the shape-free scope and phase metrics
-    # are the accepted cells' own, with this cell on their lists after the
-    # accepted heads (a later cell may follow it: tests/benchmark/room.py)
-    shared = [m for m in layer if not m["name"].endswith(".nab")]
-    assert {m["name"] for m in shared} >= SHAPE_FREE and all(
-        CELL in m["workloads"] and m["workloads"][:2] ==
-        ["cluster-256-replay", "cluster-32-replay"] for m in shared)
-    new = [m for m in layer if m["name"].endswith(".nab")]
-    assert {m["name"] for m in new} >= NAB_METRICS and all(
-        m["workloads"] == [CELL] for m in new)
+    # are the accepted cells' own, with this cell on their lists right after
+    # the accepted heads (a later cell may follow it: tests/benchmark/room.py)
+    for name in SHAPE_FREE:
+        rules.listed_after(layer[name]["workloads"],
+                           HEADS.get(name, REPLAY_HEAD), CELL)
+    for name in NAB_METRICS:  # its own: it stands first on each
+        rules.listed_after(layer[name]["workloads"], [], CELL)
+    rules.added_in_order(reg.manifest["per_layer"], NAB_METRICS,
+                         after=SHAPE_FREE)
     assert not [m for m in reg.manifest["per_layer"]
                 if m["name"].startswith(("tm_learn_roofline.",
                                          "tm_dendrite_roofline."))]
-    (entry,) = [c for c in reg.manifest["configs"] if c["name"] == CONFIG]
+    entry = rules.entry(reg.manifest["configs"], CONFIG)
     assert entry["reduced"] == cfg["reduced"] and len(entry["source"]) <= 200
+    assert entry["file"] == f"benchmark/configs/{CONFIG}.json"
+
+
+manifest_holds = cell_resolves_and_fills_a_quarter_of_the_chip
 
 
 def test_committed_cell_resolves_and_fills_a_quarter_of_the_chip():

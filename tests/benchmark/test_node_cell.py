@@ -14,7 +14,9 @@ import pytest
 
 from benchmark import kernel_bytes_dense as kbd
 from benchmark.registry import REPO, Registry
-from tests.benchmark.test_nab_cell import SHAPE_FREE, hand_made_record
+from tests.benchmark import manifest_rules as rules
+from tests.benchmark.test_nab_cell import (
+    HEADS, NAB_METRICS, REPLAY_HEAD, SHAPE_FREE, hand_made_record)
 from tests.benchmark.test_room_for_fields import OPS
 from tests.benchmark.tiny import failed_numbers, run
 
@@ -92,41 +94,45 @@ def test_state_on_the_device_is_over_a_quarter_of_the_chip():
     assert share >= 0.25 and share == pytest.approx(0.3628, abs=1e-4)
 
 
-def test_manifest_lists_the_cell_where_the_issue_says():
-    reg = Registry()
-    (entry,) = [c for c in reg.manifest["configs"] if c["name"] == CONFIG]
+def manifest_holds(reg: Registry) -> None:
+    """What this cell's test holds of a manifest (tests/benchmark/
+    manifest_rules.py): the committed one, and the rehearsal's copy."""
+    entry = rules.entry(reg.manifest["configs"], CONFIG)
     cfg = node_config()
     assert entry["source"] == cfg["source"] and len(entry["source"]) <= 200
     assert entry["reduced"] == cfg["reduced"] == []
-    (cell,) = [w for w in reg.manifest["workloads"] if w["name"] == CELL]
-    assert (cell["config"], cell["traffic"], cell["chips"]) == \
-        (CONFIG, "replay-full", 1) and len(cell["why"]) <= 200
-    assert {m["name"] for m in reg.metrics(CELL, "end_to_end")} == \
-        {"metrics_per_s", "setup_s", "peak_bytes_per_stream"}
-    layer = reg.metrics(CELL, "per_layer")
-    # every shape-free scope and phase metric lists the cell, after the
-    # accepted cells (a later PR's metric may join them; none may drop it)
-    shared = [m for m in layer if not m["name"].endswith(".node")]
-    assert {m["name"] for m in shared} >= SHAPE_FREE and all(
-        m["workloads"][-1] == CELL and m["workloads"][:2] ==
-        ["cluster-256-replay", "cluster-32-replay"] for m in shared)
-    new = [m for m in layer if m["name"].endswith(".node")]
-    assert [m["name"] for m in new] == list(NODE_METRICS)
-    assert all(m["workloads"] == [CELL] for m in new)
+    assert entry["file"] == f"benchmark/configs/{CONFIG}.json"
+    rules.cell_entry(reg, CELL, CONFIG, "replay-full")
+    rules.reports_at_least(reg, CELL, "end_to_end",
+                           {"metrics_per_s", "setup_s", "peak_bytes_per_stream"})
+    shared = SHAPE_FREE | {"host_gc_ms.replay"}  # the second since ISSUE 45
+    layer = rules.reports_at_least(reg, CELL, "per_layer",
+                                   shared | set(NODE_METRICS))
+    # every shape-free scope and phase metric lists the cell right after the
+    # cells accepted before it (a later cell may follow; none may drop out)
+    for name in shared:
+        rules.listed_after(
+            layer[name]["workloads"],
+            [*HEADS.get(name, REPLAY_HEAD), "nab-2048-replay"], CELL)
+    for name in NODE_METRICS:  # its own: it stands first on each
+        rules.listed_after(layer[name]["workloads"], [], CELL)
+    rules.added_in_order(reg.manifest["per_layer"], NODE_METRICS,
+                         after=SHAPE_FREE | set(NAB_METRICS))
     # the overlap's share reads over 100 % at this shape (the mask is staged
     # on chip: PERF.md s7), so the cell reports none
-    assert not [m for m in layer if m["name"].startswith("sp_overlap_roofline")]
-    for m in layer:
-        definition, reader = reg.layer_metric(m["name"])
-        assert callable(reader.read)
-        assert (definition["layer"], definition["moves"], definition["unit"]) \
-            == (m["layer"], m["moves"], m["unit"])
+    assert not [n for n in layer if n.startswith("sp_overlap_roofline")]
+    for m in layer.values():
+        rules.agrees_with_definition(reg, m)
     for name, scope in NODE_METRICS.items():
         definition, _ = reg.layer_metric(name)
         assert definition.get("scope") == scope
         assert definition["reader"] == (
             "segment_capacity" if name == "tm_full_cells.node"
             else "dense_roofline")
+
+
+def test_manifest_lists_the_cell_where_the_issue_says():
+    manifest_holds(Registry())
 
 
 def test_the_node_metrics_read_a_hand_made_trace_and_nothing_from_none():

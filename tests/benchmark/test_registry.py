@@ -9,6 +9,7 @@ import pytest
 
 from benchmark import kernel_bytes_dense, roofline
 from benchmark.registry import NotFound, Registry
+from tests.benchmark import manifest_rules as rules
 from tests.benchmark.tiny import make_root, run
 
 
@@ -42,10 +43,7 @@ def manifest_resolves_every_name(reg: Registry) -> None:
         layer = reg.metrics(w["name"], "per_layer")
         assert layer
         for m in layer:
-            definition, reader = reg.layer_metric(m["name"])
-            assert callable(reader.read)
-            assert (definition["layer"], definition["moves"], definition["unit"]) \
-                == (m["layer"], m["moves"], m["unit"])
+            rules.agrees_with_definition(reg, m)
     for c in reg.manifest["configs"]:
         assert os.path.isfile(os.path.join(reg.root, c["file"]))
 

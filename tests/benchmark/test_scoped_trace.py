@@ -14,6 +14,7 @@ from benchmark.kernel_bytes import (KERNELS, STATE_LEAVES, kernel_bytes_per_stre
                                     kernel_floor_seconds, leaf_bytes)
 from benchmark.registry import Registry
 from benchmark.roofline import state_bytes_per_stream
+from tests.benchmark import manifest_rules as rules
 
 HERE = os.path.dirname(__file__)
 FIXTURE = os.path.join(HERE, "..", "..", "benchmark", "fixtures",
@@ -384,28 +385,32 @@ def test_readers_read_nothing_where_there_is_nothing(recorded):
 
 
 def metric_files_resolve_and_name_their_cells(reg: Registry) -> None:
-    listed = {m["name"]: m for m in reg.manifest["per_layer"]}
     replay = ["cluster-256-replay", "cluster-32-replay"]
     for name, scope in {**SCOPE_MS, **ROOFLINES}.items():
-        definition, reader = reg.layer_metric(name)
-        assert callable(reader.read)
+        listed = rules.entry(reg.manifest["per_layer"], name)
+        definition = rules.agrees_with_definition(reg, listed)
         assert (definition["scope"], definition["module"]) == \
             (scope, "jit_chunk_step")
         # a later cell may be appended to a metric's list, never put before;
         # the SP overlap's share is read where HBM bounds the scope only (at
         # 32 columns the scan's carry holds its pools on chip: PERF.md s7)
-        head = replay[:1] if name == "sp_overlap_roofline.replay" else replay
-        assert listed[name]["workloads"][:2] == head
-        assert (listed[name]["source"], listed[name]["layer"]) == \
+        rules.starts_with(listed["workloads"], replay[:1] if name ==
+                          "sp_overlap_roofline.replay" else replay)
+        assert (listed["source"], listed["layer"]) == \
             ("device_trace", "kernels")
     for name, (phase, per) in PHASES.items():
-        definition, reader = reg.layer_metric(name)
+        listed = rules.entry(reg.manifest["per_layer"], name)
+        definition = rules.agrees_with_definition(reg, listed)
         assert (definition["phase"], definition["per"]) == (phase, per)
-        head = replay if name.endswith(".replay") else ["cluster-256-live"]
-        assert listed[name]["workloads"][:len(head)] == head
-        assert (listed[name]["source"], listed[name]["layer"]) == \
+        rules.starts_with(listed["workloads"], replay if name.endswith(
+            ".replay") else ["cluster-256-live"])
+        assert (listed["source"], listed["layer"]) == \
             ("program_span", "stream groups")
+    rules.added_in_order(reg.manifest["per_layer"], SCOPE_MS)
     assert len(SCOPE_MS) + len(ROOFLINES) + len(PHASES) == 17
+
+
+manifest_holds = metric_files_resolve_and_name_their_cells
 
 
 def test_the_new_metric_files_resolve_and_name_their_cells():
