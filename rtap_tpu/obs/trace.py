@@ -77,6 +77,9 @@ SPANS = (
     "rtap.loop.dispatch",
     "rtap.loop.collect",
     "rtap.loop.emit",
+    # ... inside `emit`: the tick's alert decisions to lines in the sink,
+    # flushed (`tick`, `lines` = alert lines written; ring: "alert")
+    "rtap.loop.alert",
     "rtap.loop.checkpoint",
     "rtap.loop.sleep",
     # ... and its per-group children (ring: "dispatch" / "collect" on the
@@ -102,6 +105,12 @@ SPANS = (
     # layout and the form the device holds them in (`leaves`, `bytes`): set-up,
     # a slot claimed, a checkpoint, a row read; none in a chunk or a live tick
     "rtap.state.relayout",
+    # service/checkpoint.py — one group's checkpoint written (the fetch, the
+    # re-layout, the files, the swap) or read back onto the device (`group`
+    # = the group's first stream id, `bytes` = the state tree's; ring:
+    # "checkpoint_save" / "checkpoint_load")
+    "rtap.checkpoint.save",
+    "rtap.checkpoint.load",
 )
 
 #: the name a span takes in a TraceRecorder ring (the names
@@ -109,6 +118,8 @@ SPANS = (
 _RING_NAME = {name: name.rpartition(".")[2] for name in SPANS}
 _RING_NAME["rtap.aot.warm"] = "aot_warm"
 _RING_NAME["rtap.host.gc"] = "gc"
+_RING_NAME["rtap.checkpoint.save"] = "checkpoint_save"
+_RING_NAME["rtap.checkpoint.load"] = "checkpoint_load"
 
 #: one trace record: interned name id, kind (0 span / 1 instant), tick
 #: correlation id, start offset vs the recorder epoch (perf_counter

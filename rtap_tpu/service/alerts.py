@@ -153,6 +153,7 @@ class AlertWriter:
             self._offset += self.torn_heals
         self._fh: IO[str] | None = open(path, "a") if path else None
         self.count = 0
+        self.written = 0  # alert lines handed to the sink
         self.suppressed = 0  # resume-suppressed (already-delivered) lines
         self._suppress: set[str] = set()
         self.dropped = 0
@@ -362,6 +363,7 @@ class AlertWriter:
             # window's anchor).
             off0 = self._offset
             if self._safe_write(lines):
+                self.written += len(lines)
                 if self._correlator is not None:
                     for aid, sid, tsi, tf in folds:
                         self._correlator.observe_alert(aid, sid, tsi,

@@ -21,15 +21,27 @@ import numpy as np
 
 from rtap_tpu.config import ModelConfig
 from rtap_tpu.obs import get_registry
+from rtap_tpu.obs.trace import span
 from rtap_tpu.service.registry import StreamGroup
 
 
-# rtap: host-boundary — checkpoint save OWNS the device->host
-# materialization: it must fetch the full (possibly mesh-sharded) tree
-# to write a topology-independent checkpoint, with the pipeline drained
+def _tree_bytes(tree) -> int:
+    """Bytes of every array leaf of a (nested) state tree."""
+    if isinstance(tree, dict):
+        return sum(_tree_bytes(v) for v in tree.values())
+    return int(np.asarray(tree).nbytes)
+
+
+def _count_bytes(n: int, op: str) -> None:
+    get_registry().counter(
+        "rtap_obs_checkpoint_bytes_total",
+        "bytes of group state trees written to (save) or read from (load) "
+        "checkpoints", op=op).inc(n)
+
+
 def save_group(grp: StreamGroup, path: str | Path,
                alerts_offset: int | None = None,
-               journal_tick: int | None = None) -> None:
+               journal_tick: int | None = None, trace=None) -> None:
     """Write one group's resume state to `path` (a directory, per group).
 
     Atomic on overwrite: the tree + meta are written to a fresh temp sibling
@@ -51,7 +63,24 @@ def save_group(grp: StreamGroup, path: str | Path,
     mid-run quarantine restore REWINDS the group counter while the
     global clock keeps running — the journal replay must match rows by
     this global cursor, never by the rewindable per-group one.
+
+    One `rtap.checkpoint.save` span (obs/trace.py; into `trace`'s ring too
+    where a recorder is handed over) covers the whole save: `group`, `bytes`.
     """
+    sp = span("rtap.checkpoint.save", trace, group=grp.stream_ids[0]).begin()
+    try:
+        n = _save_group(grp, path, alerts_offset, journal_tick)
+    except BaseException:
+        sp.end(record=False)
+        raise
+    sp.end(bytes=n)
+
+
+# rtap: host-boundary — checkpoint save OWNS the device->host
+# materialization: it must fetch the full (possibly mesh-sharded) tree
+# to write a topology-independent checkpoint, with the pipeline drained
+def _save_group(grp, path, alerts_offset, journal_tick) -> int:
+    """save_group's body -> the bytes of the state tree it wrote."""
     import jax
     import orbax.checkpoint as ocp
 
@@ -138,6 +167,9 @@ def save_group(grp: StreamGroup, path: str | Path,
     obs.histogram("rtap_obs_checkpoint_save_seconds",
                   "wall seconds per group save (state fetch + orbax write + "
                   "swap)").observe(time.perf_counter() - t_save)
+    n = _tree_bytes(tree)
+    _count_bytes(n, "save")
+    return n
 
 
 def _recover_residue(path: Path) -> Path:
@@ -167,8 +199,15 @@ def _recover_residue(path: Path) -> Path:
     return path
 
 
-def load_group(path: str | Path, mesh=None, sparsify: bool = False) -> StreamGroup:
+def load_group(path: str | Path, mesh=None, sparsify: bool = False,
+               trace=None) -> StreamGroup:
     """Rebuild a StreamGroup from `path`; scoring continues bit-identically.
+
+    One `rtap.checkpoint.load` span (`group`, `bytes`; into `trace`'s ring
+    too) covers the read, the re-layout and the put. The group is built
+    WITHOUT a state of its own (`make_state=False`): beside whatever the
+    caller already holds, a load puts one group's state on the device, never
+    two.
 
     A group checkpointed while sharded over a mesh records that fact; pass
     `mesh` to re-shard on resume. Resuming a sharded checkpoint without a mesh
@@ -182,6 +221,18 @@ def load_group(path: str | Path, mesh=None, sparsify: bool = False) -> StreamGro
     BIT-IDENTICALLY to the dense run (the re-layout is lossless — see
     docs/MIGRATION.md). Already-sparse checkpoints are untouched.
     """
+    sp = span("rtap.checkpoint.load", trace).begin()
+    try:
+        grp, n = _load_group(path, mesh, sparsify)
+    except BaseException:
+        sp.end(record=False)
+        raise
+    sp.end(group=grp.stream_ids[0], bytes=n)
+    return grp
+
+
+def _load_group(path, mesh, sparsify) -> tuple[StreamGroup, int]:
+    """load_group's body -> (the group, the bytes of the state tree read)."""
     import jax
     import orbax.checkpoint as ocp
 
@@ -220,7 +271,7 @@ def load_group(path: str | Path, mesh=None, sparsify: bool = False) -> StreamGro
     grp = StreamGroup(
         cfg, meta["stream_ids"], backend=meta["backend"], threshold=meta["threshold"],
         mesh=mesh, debounce=int(meta.get("debounce", 1)),
-        predict=int(meta.get("predict", 0)),
+        predict=int(meta.get("predict", 0)), make_state=False,
     )
     if grp.backend == "tpu":
         # fwd_*: a forward index an older build may have stored
@@ -257,7 +308,9 @@ def load_group(path: str | Path, mesh=None, sparsify: bool = False) -> StreamGro
     get_registry().counter(
         "rtap_obs_checkpoint_loads_total",
         "group checkpoints restored (service/replay resume)").inc()
-    return grp
+    n = _tree_bytes(tree)
+    _count_bytes(n, "load")
+    return grp, n
 
 
 def peek_resume_ticks(checkpoint_dir: str | Path) -> int:

@@ -90,13 +90,18 @@ def replay_streams(
     checkpoint_dir: str | None = None,
     checkpoint_every: int = 0,
     debounce: int = 1,
+    seed: int = 0,
+    trace=None,
 ) -> ReplayResult:
     """Replay equal-length streams through grouped models at full speed.
 
     All streams must share a clock (same length; timestamps of stream 0 are
     used for the result). Groups are sized `group_size` (default: all streams
     in one group) and each chunk of `chunk_ticks` ticks costs one device
-    dispatch per group.
+    dispatch per group. A stream's `values` are [T], or [T, F] for a model
+    of F fields (a row a tick). `seed` is the registry's (group g's state is
+    made from ``seed + g``, as `serve` makes it); `trace` (an
+    obs.TraceRecorder) takes the checkpoint spans.
 
     Crash recovery (SURVEY.md §5 checkpoint/resume as *elastic recovery*):
     with `checkpoint_dir` + `checkpoint_every=k`, each group's full resume
@@ -119,12 +124,12 @@ def replay_streams(
     ids = [s.stream_id for s in streams]
 
     reg = StreamGroupRegistry(cfg, group_size=group_size, backend=backend,
-                              threshold=threshold, debounce=debounce)
+                              seed=seed, threshold=threshold, debounce=debounce)
     for sid in ids:
         reg.add_stream(sid)
     reg.finalize()
 
-    values = np.stack([s.values for s in streams], axis=1)  # [T, N]
+    values = np.stack([s.values for s in streams], axis=1)  # [T, N(, F)]
     ts = np.stack([s.timestamps for s in streams], axis=1).astype(np.int64)  # [T, N]
 
     raw = np.full((T, n), np.nan, np.float32)
@@ -157,7 +162,7 @@ def replay_streams(
             if os.path.isdir(ck_path):
                 from rtap_tpu.service.checkpoint import load_group, validate_resume
 
-                resumed = load_group(ck_path)
+                resumed = load_group(ck_path, trace=trace)
                 # shared resume-safety gate (stream ids + config + alerting
                 # semantics) — one implementation for replay and live serve
                 validate_resume(resumed, ck_path, grp)
@@ -244,7 +249,8 @@ def replay_streams(
                 # drained instant: flush the sink so the alert cursor in
                 # meta equals the on-disk size (exactly-once resume)
                 writer.flush_sink()
-                save_group(grp, ck_path, alerts_offset=writer.sink_offset())
+                save_group(grp, ck_path, alerts_offset=writer.sink_offset(),
+                           trace=trace)
         while pending:
             collect(*pending.popleft())
             chunks_done += 1
@@ -253,7 +259,8 @@ def replay_streams(
 
             writer.flush_sink()
             # final state, resumable past the end
-            save_group(grp, ck_path, alerts_offset=writer.sink_offset())
+            save_group(grp, ck_path, alerts_offset=writer.sink_offset(),
+                       trace=trace)
             # (frozen replay never writes — read-only like serve --freeze)
     writer.close()
     if resumed_from and not groups_with_work:
@@ -291,6 +298,110 @@ def replay_streams(
         predictions=preds,
         throughput=stats,
     )
+
+
+@dataclass
+class Resumed:
+    """What :func:`resume_registry` loaded."""
+
+    from_ticks: dict[str, int]  # "group<i>" -> the tick its checkpoint holds
+    tick_skew: int  # most - fewest ticks over the groups (a torn save set)
+    #: the lowest alert cursor the loaded checkpoints carry (None: none does):
+    #: sink bytes past it were written after the oldest of them was saved
+    alerts_offset: int | None
+
+
+def resume_registry(reg: StreamGroupRegistry, checkpoint_dir: str,
+                    allow_claimed_extras: bool = False, trace=None) -> Resumed:
+    """Load every group of a finalized registry that has a checkpoint under
+    `checkpoint_dir` (``group<i>``, service/shardpath.py) in the place of
+    the group the registry built: the resume of a restarted ``serve
+    --checkpoint-dir``. :func:`live_loop` calls it; a caller that needs the
+    resumed instances before the loop has them (to wrap them, to read their
+    alert cursor) calls it first and then hands the loop no directory.
+
+    Each loaded group passes `checkpoint.validate_resume` against the built
+    one (stream ids, config, alerting semantics; `allow_claimed_extras` as
+    there), takes its place in ``reg.groups`` and in the registry's slot
+    index, and is one `rtap.checkpoint.load` span in `trace`. A checkpoint
+    group beyond the built topology is an error, never dropped."""
+    import os
+    import re
+
+    from rtap_tpu.service.checkpoint import load_group, validate_resume
+    from rtap_tpu.service.shardpath import group_checkpoint_path
+
+    groups = reg.groups  # the live list: entries are replaced in place
+    from_ticks: dict[str, int] = {}
+    for gi, grp in enumerate(groups):
+        ck_path = group_checkpoint_path(checkpoint_dir, gi)
+        if not os.path.isdir(ck_path):
+            continue
+        resumed = load_group(ck_path, mesh=grp.mesh, trace=trace)
+        # the health flag is serve-run config, not checkpoint state:
+        # the resumed instance dispatches the same program variant
+        # the built group would have (ISSUE 6)
+        resumed.health = getattr(grp, "health", False)
+        # claimed extras resume when this run could have claimed them
+        # (auto_register) OR when it serves frozen: an elastically-
+        # learned fleet must be servable read-only from its own
+        # checkpoint (--freeze forbids NEW claims — the footgun — but
+        # not reading streams a prior learning run registered)
+        validate_resume(resumed, ck_path, grp,
+                        allow_claimed_extras=allow_claimed_extras)
+        groups[gi] = resumed  # n_live derives from the resumed ids
+        # the registry's lookup() index must observe the resumed
+        # instance too, not the stale fresh group
+        for slot in reg._slots.values():
+            if slot.group is grp:
+                slot.group = resumed
+        # streams the PRIOR run auto-registered (live in the
+        # checkpoint, pads in the built group) rejoin the
+        # registry's index so routing emits them and re-arriving
+        # records aren't re-claimed into duplicate slots
+        for si, sid in enumerate(resumed.stream_ids):
+            if not sid.startswith(PAD_PREFIX) and sid not in reg:
+                reg._slots[sid] = _RegistrySlot(resumed, si)
+                reg.version += 1
+        from_ticks[f"group{gi}"] = resumed.ticks
+    # a checkpoint group BEYOND the built topology must not be
+    # silently dropped: a run resumed with a smaller --reserve than
+    # the one that learned (e.g. register-then-freeze without
+    # repeating --reserve) would lose every stream living in the
+    # extra groups — loudly demand a matching topology instead
+    stray = sorted(
+        d for d in os.listdir(checkpoint_dir)
+        if re.fullmatch(r"group\d{4,}", d)
+        and int(d[5:]) >= len(groups)
+        and os.path.isdir(os.path.join(checkpoint_dir, d))
+    ) if os.path.isdir(checkpoint_dir) else []
+    if stray:
+        raise ValueError(
+            f"checkpoint dir {checkpoint_dir} holds {stray} beyond this "
+            f"run's {len(groups)} group(s): the prior run had more "
+            "claimable capacity. Rerun with the same --reserve/"
+            "--group-size so every checkpointed stream resumes")
+    # A crash between per-group saves leaves a torn set (groups at
+    # different ticks). Live data is NOT tick-indexed (every group
+    # scores whatever arrives now) and groups are fully independent,
+    # so a behind group merely lost a few ticks of learning — resume
+    # anyway, loudly: the skew is warned and exposed in stats.
+    # (replay_streams is different: its feed IS tick-indexed, and it
+    # resumes each group from its own recorded offset.)
+    ticks_seen = {g.ticks for g in groups}
+    if len(ticks_seen) > 1:
+        import logging
+
+        logging.getLogger(__name__).warning(
+            "live_loop: resuming a torn checkpoint set (group ticks %s "
+            "— a crash landed between per-group saves); behind groups "
+            "lost that many ticks of learning", sorted(ticks_seen))
+    cursors = [off for off in (getattr(g, "resume_alerts_offset", None)
+                               for g in groups) if off is not None]
+    return Resumed(
+        from_ticks=from_ticks,
+        tick_skew=(max(ticks_seen) - min(ticks_seen)) if from_ticks else 0,
+        alerts_offset=min(cursors) if cursors else None)
 
 
 def live_loop(
@@ -572,7 +683,8 @@ def live_loop(
     `checkpoint_dir` + `checkpoint_every=k`, every group's full resume
     state is saved atomically every k ticks (the in-flight pipeline is
     drained before each save, so nothing is in flight), and a later call with
-    the same dir resumes each group from its recorded tick — same
+    the same dir resumes each group from its recorded tick
+    (:func:`resume_registry`) — same
     validation as replay_streams (stream ids, config, alerting semantics
     must match the checkpoint; mismatches are errors, not surprises).
     Saves run inline, so a checkpoint tick may miss its cadence deadline —
@@ -617,83 +729,16 @@ def live_loop(
                 "StreamGroup caller could not observe the resumed instances)")
         groups = [group]
     resumed_from: dict[str, int] = {}
+    resume_tick_skew = 0
     if checkpoint_dir is not None:
-        import os
-
-        from rtap_tpu.service.checkpoint import load_group, validate_resume
-        from rtap_tpu.service.shardpath import group_checkpoint_path
-
-        for gi, grp in enumerate(groups):
-            ck_path = group_checkpoint_path(checkpoint_dir, gi)
-            if not os.path.isdir(ck_path):
-                continue
-            resumed = load_group(ck_path, mesh=grp.mesh)
-            # the health flag is serve-run config, not checkpoint state:
-            # the resumed instance dispatches the same program variant
-            # the built group would have (ISSUE 6)
-            resumed.health = getattr(grp, "health", False)
-            # claimed extras resume when this run could have claimed them
-            # (auto_register) OR when it serves frozen: an elastically-
-            # learned fleet must be servable read-only from its own
-            # checkpoint (--freeze forbids NEW claims — the footgun — but
-            # not reading streams a prior learning run registered)
-            validate_resume(resumed, ck_path, grp,
-                            allow_claimed_extras=auto_register or not learn)
-            groups[gi] = resumed  # n_live derives from the resumed ids
-            # the registry's lookup() index must observe the resumed
-            # instance too, not the stale fresh group
-            if isinstance(group, StreamGroupRegistry):
-                for slot in group._slots.values():
-                    if slot.group is grp:
-                        slot.group = resumed
-                # streams the PRIOR run auto-registered (live in the
-                # checkpoint, pads in the built group) rejoin the
-                # registry's index so routing emits them and re-arriving
-                # records aren't re-claimed into duplicate slots
-                for si, sid in enumerate(resumed.stream_ids):
-                    if not sid.startswith(PAD_PREFIX) and sid not in group:
-                        group._slots[sid] = _RegistrySlot(resumed, si)
-                        group.version += 1
-            resumed_from[f"group{gi}"] = resumed.ticks
-        # a checkpoint group BEYOND the built topology must not be
-        # silently dropped: a run resumed with a smaller --reserve than
-        # the one that learned (e.g. register-then-freeze without
-        # repeating --reserve) would lose every stream living in the
-        # extra groups — loudly demand a matching topology instead
-        import re as _re
-
-        stray = sorted(
-            d for d in os.listdir(checkpoint_dir)
-            if _re.fullmatch(r"group\d{4,}", d)
-            and int(d[5:]) >= len(groups)
-            and os.path.isdir(os.path.join(checkpoint_dir, d))
-        ) if os.path.isdir(checkpoint_dir) else []
-        if stray:
-            raise ValueError(
-                f"checkpoint dir {checkpoint_dir} holds {stray} beyond this "
-                f"run's {len(groups)} group(s): the prior run had more "
-                "claimable capacity. Rerun with the same --reserve/"
-                "--group-size so every checkpointed stream resumes")
-        if isinstance(group, StreamGroupRegistry) and resumed_from:
+        resumed = resume_registry(group, checkpoint_dir,
+                                  allow_claimed_extras=auto_register
+                                  or not learn, trace=trace)
+        resumed_from, resume_tick_skew = resumed.from_ticks, resumed.tick_skew
+        if resumed_from:
             # the source must accept the resumed extras' records and return
             # values in the (possibly grown) dispatch order / slot map
             _sync_source_membership(source, group)
-        # A crash between per-group saves leaves a torn set (groups at
-        # different ticks). Live data is NOT tick-indexed (every group
-        # scores whatever arrives now) and groups are fully independent,
-        # so a behind group merely lost a few ticks of learning — resume
-        # anyway, loudly: the skew is warned and exposed in stats.
-        # (replay_streams is different: its feed IS tick-indexed, and it
-        # resumes each group from its own recorded offset.)
-        ticks_seen = {g.ticks for g in groups}
-        if len(ticks_seen) > 1:
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "live_loop: resuming a torn checkpoint set (group ticks %s "
-                "— a crash landed between per-group saves); behind groups "
-                "lost that many ticks of learning", sorted(ticks_seen))
-        resume_tick_skew = (max(ticks_seen) - min(ticks_seen)) if resumed_from else 0
     reg = group if isinstance(group, StreamGroupRegistry) else None
 
     # Value/emission routing: per group, the live slot indices, their ids,
@@ -1068,6 +1113,11 @@ def live_loop(
             else:
                 results[gi] = res
         scored = 0
+        # the tick's alert decisions to lines in the sink, flushed as
+        # `alert_flush_every` has it: when this span ends, the tick's lines
+        # are as durable as the sink makes them
+        sp_alert = span("rtap.loop.alert", trace, tick=cur_tick).begin()
+        lines0 = writer.written
         for gi, _grp, _h in pairs:  # pairs preserve group order (emission
             if gi not in results:  # stays schedule-independent)
                 continue
@@ -1111,6 +1161,7 @@ def live_loop(
                 predictor.fold(gi, groups[gi].last_predict,
                                tick=groups[gi].ticks - 1,
                                ids=id_by_slot)
+        sp_alert.end(lines=writer.written - lines0)
         obs_scored.inc(scored)
         if journal is not None and pairs:
             # alert-delivery cursor: alerts through this tick have been
@@ -1611,7 +1662,8 @@ def live_loop(
                                 raise FileNotFoundError(
                                     f"no checkpoint at {ck_path} (the group "
                                     "was never saved before its fault)")
-                            restored = load_group(ck_path, mesh=old.mesh)
+                            restored = load_group(ck_path, mesh=old.mesh,
+                                                  trace=trace)
                             restored.health = getattr(old, "health", False)
                             validate_resume(
                                 restored, ck_path, old,
@@ -1749,7 +1801,7 @@ def live_loop(
                         on_failure=lambda gi, e: _on_save_failure(
                             gi, k, e),
                         alerts_offset=writer.sink_offset(),
-                        journal_tick=journal_base + ticks_run)
+                        journal_tick=journal_base + ticks_run, trace=trace)
                     if not failed_m:
                         checkpoints_saved += 1
                         last_saved = ticks_run
@@ -1911,7 +1963,7 @@ def live_loop(
                         on_failure=lambda gi, e: _on_save_failure(gi, k, e),
                         alerts_offset=writer.sink_offset(),
                         journal_tick=journal_base + ticks_run
-                        if journal is not None else None)
+                        if journal is not None else None, trace=trace)
                     phase_s["checkpoint"] += (time.perf_counter() - now) - (
                         phase_s["collect"] + phase_s["emit"]
                         + phase_s["dispatch"] - ce0)
@@ -2060,7 +2112,7 @@ def live_loop(
             on_failure=lambda gi, e: _on_save_failure(gi, ticks_run, e),
             alerts_offset=writer.sink_offset(),
             journal_tick=journal_base + ticks_run
-            if journal is not None else None)
+            if journal is not None else None, trace=trace)
         if not failed:
             checkpoints_saved += 1
             if journal is not None and not quarantined:
@@ -2163,7 +2215,7 @@ def live_loop(
 
 def _save_all(groups, checkpoint_dir: str, skip=(), chaos=None, tick: int = 0,
               on_failure=None, alerts_offset: int | None = None,
-              journal_tick: int | None = None) -> tuple[int, int]:
+              journal_tick: int | None = None, trace=None) -> tuple[int, int]:
     """One atomic per-group save per group dir (group{i:04d}).
 
     Quarantined groups (`skip`) are NOT saved: their state may be
@@ -2185,7 +2237,7 @@ def _save_all(groups, checkpoint_dir: str, skip=(), chaos=None, tick: int = 0,
                 chaos.on_checkpoint_save(gi, tick)
             save_group(grp, group_checkpoint_path(checkpoint_dir, gi),
                        alerts_offset=alerts_offset,
-                       journal_tick=journal_tick)
+                       journal_tick=journal_tick, trace=trace)
             saved += 1
         except Exception as e:  # noqa: BLE001 — contained per group
             failed += 1

@@ -90,6 +90,10 @@ class StreamGroup:
     or off (the model leaves are pure reads; the predictor leaves exist
     only when armed). Unsupported under a mesh for the same contract
     reason as health.
+
+    ``make_state=False`` (tpu backend) builds the group with no state on the
+    device: ``checkpoint.load_group`` hands it the saved one, so a load never
+    holds a seeded state beside the one it restores.
     """
 
     def __init__(
@@ -103,6 +107,7 @@ class StreamGroup:
         debounce: int = 1,
         health: bool = False,
         predict: int = 0,
+        make_state: bool = True,
     ):
         if debounce < 1:
             raise ValueError(f"debounce must be >= 1, got {debounce}")
@@ -156,7 +161,11 @@ class StreamGroup:
         # the device holds it in (ops/resident.py): set-up, a slot claimed,
         # a checkpoint, a row read — never a dispatched chunk or a live tick
         self.relayouts = 0
-        if backend == "tpu":
+        if backend == "tpu" and not make_state:
+            # checkpoint.load_group puts the saved state here: a state made
+            # from the seed first would be a second group on the device
+            self.resident = None
+        elif backend == "tpu":
             from rtap_tpu.models.state import init_state
             from rtap_tpu.ops.resident import host_resident
 

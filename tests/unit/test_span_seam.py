@@ -64,12 +64,13 @@ def test_vocabulary():
     assert SPANS == (
         "rtap.loop.tick", "rtap.loop.source", "rtap.loop.membership",
         "rtap.loop.dispatch", "rtap.loop.collect", "rtap.loop.emit",
-        "rtap.loop.checkpoint", "rtap.loop.sleep",
+        "rtap.loop.alert", "rtap.loop.checkpoint", "rtap.loop.sleep",
         "rtap.loop.group.dispatch", "rtap.loop.group.collect",
         "rtap.group.stage", "rtap.group.enqueue", "rtap.group.fetch",
         "rtap.group.likelihood",
         "rtap.ingest.feed", "rtap.ingest.snapshot",
-        "rtap.aot.warm", "rtap.host.gc", "rtap.state.relayout")
+        "rtap.aot.warm", "rtap.host.gc", "rtap.state.relayout",
+        "rtap.checkpoint.save", "rtap.checkpoint.load")
     # the ring keeps the names benchmark/traffic_kinds/live.py reads
     ring = seam._RING_NAME
     assert [ring["rtap.loop." + n] for n in (
@@ -80,6 +81,9 @@ def test_vocabulary():
     assert ring["rtap.loop.group.collect"] == "collect"
     assert ring["rtap.aot.warm"] == "aot_warm"
     assert ring["rtap.host.gc"] == "gc"
+    assert ring["rtap.loop.alert"] == "alert"
+    assert ring["rtap.checkpoint.save"] == "checkpoint_save"
+    assert ring["rtap.checkpoint.load"] == "checkpoint_load"
 
 
 def test_the_vocabulary_is_what_the_package_writes():
