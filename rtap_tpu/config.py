@@ -839,8 +839,9 @@ def node_preset(n_metrics: int = 3, perm_bits: int = 16) -> ModelConfig:
     # preset's 64 (first at tick 912 of a replayed node, PERF.md s6), so a
     # run that only steps faster would turn the cell's `correct` false
     # through the preset. The cap only truncates: where no burst passes 64
-    # the step's results are bit-equal. Its cost is the [L, Ac*K*S]
-    # compaction and growth's [L, R, W] grid at L = 320.
+    # the step's results are bit-equal. At the structural bound the step
+    # compacts nothing (ops/tm_tpu.py:compacts_learning_rows): its cost is
+    # growth's [L, R, W] grid on all 320 workspace rows.
     tm = dataclasses.replace(
         base.tm, learn_cap=base.tm.col_cap * base.tm.cells_per_column
         * base.tm.max_segments_per_cell)

@@ -30,7 +30,8 @@ same semantics down by an order of magnitude):
    narrow pool rows — a compare-select reduce or an MXU matmul — by index
    at wide ones; below), does the compact reinforce/grow pass there
    (selecting <= learn_cap segments with a cheap top_k over Ac*K*S instead
-   of C*K*S), and scatters the workspace back.
+   of C*K*S, where that cap cuts rows at all), and scatters the workspace
+   back.
 
 Step outline: dense column categorization (predicted / burst-matching /
 burst-new) -> workspace learning (alloc, reinforce, grow toward previous
@@ -72,6 +73,12 @@ pool rows — has a second exact form, picked the same way in one place
 columns in the pools' own types where a pool row fills whole 128-lane tiles
 (384 lanes: the node presets), the one-hot matmul where it does not (192
 lanes: the cluster presets).
+
+In either form the compaction of the learning segments within the workspace
+engages only where `learn_cap` cuts the workspace's rows
+(:func:`compacts_learning_rows`, the third such place): where the cap is the
+structural bound col_cap*K*S or above it (the node presets; 32 columns) the
+workspace's rows are the learning rows where they lie.
 
 Capacity bounds (col_cap active columns, learn_cap learning segments per
 step) are static-shape requirements of XLA; overflow beyond the bounds is
@@ -171,6 +178,29 @@ def gather_by_select(cfg: TMConfig) -> bool:
     21.60 against 15.67 ms a group-tick (same runs). Both forms are
     bit-identical to the oracle (tests/parity/test_tm_forms.py)."""
     return not wide_rows(cfg) and _row_lanes(cfg) % LANE_TILE == 0
+
+
+def compacts_learning_rows(cfg: TMConfig) -> bool:
+    """In either form: are the learning segments compacted out of the
+    workspace's col_cap*K*S rows into `learn_cap` rows (True), or do reinforce
+    and growth run on the workspace's rows where they lie (False)? The one
+    place it is decided, from the static shape: the compaction engages only
+    where it cuts rows.
+
+    Reinforce and `_grow_compact` treat each row by itself, so the compaction
+    is a permutation that is undone afterwards; what it buys is growth's
+    grids on `learn_cap` rows instead of all of them (`cluster_preset`: 64 of
+    160; `nab_preset`: 1,280 of 20,480). Where the cap is the structural
+    bound or above it (`node_preset`: 320 = 320, so that `tm_overflow` is 0
+    by construction; `scaled_cluster_preset(32)`: 64 > 48) it buys nothing
+    and costs the `top_k` over the learning flags, the [L, R2] one-hot grid,
+    its two MXU passes and three reduces over it: 15.06 against 12.56 ms a
+    group-tick at the node model's shape, 1.240 against 0.927 at 32 columns
+    (chip runs, PR 47; docs/KERNELS.md, "The learn-cap compaction: only
+    where it cuts"). Both are bit-identical to the oracle
+    (tests/parity/test_tm_forms.py)."""
+    return cfg.learn_cap < (cfg.col_cap * cfg.cells_per_column
+                            * cfg.max_segments_per_cell)
 
 
 # TM state keys that change shape in the kernel: key -> how many trailing dims
@@ -760,13 +790,23 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
             ws_last = jnp.where(ws_alloc, it, ws_last)
             ws_learn = ws_learn | ws_alloc
 
-            # --- compact the <= learn_cap learning segments within the workspace ---
+            # --- compact the <= learn_cap learning segments within the
+            # workspace, where the cap cuts rows (compacts_learning_rows);
+            # where it does not, the workspace's rows are the learning rows
+            # in place and the rows that do not learn are masked out after ---
             R2 = Ac * K * S
-            idx = _compact_ids(ws_learn.reshape(-1), L)  # [L], fills = R2
-            valid_l = idx < R2
+            compact = compacts_learning_rows(cfg)
+            if compact:
+                idx = _compact_ids(ws_learn.reshape(-1), L)  # [L], fills = R2
+                valid_l = idx < R2
+            else:
+                valid_l = ws_learn.reshape(-1)  # [R2]
             ws_presyn_r = ws_presyn.reshape(R2, M)
             ws_perm_r = ws_perm.reshape(R2, M)
-            if wide:
+            if not compact:
+                presyn_l, perm_l = ws_presyn_r, ws_perm_r  # [R2, M]
+                pot_l = jnp.where(valid_l, ws_pot.reshape(-1), 0)  # [R2]
+            elif wide:
                 idx_r = jnp.clip(idx, 0, R2 - 1)
                 presyn_l = ws_presyn_r[idx_r]  # [L, M]; fill rows junk, see below
                 perm_l = ws_perm_r[idx_r]
@@ -807,7 +847,11 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
             perm_l = jnp.where(grow_ok[:, None], grown_perm, perm_l)
 
             # --- scatter learned rows back into the workspace ---
-            if wide:
+            if not compact:
+                ws_presyn_r = jnp.where(valid_l[:, None], presyn_l, ws_presyn_r)
+                ws_perm_r = jnp.where(valid_l[:, None], perm_l, ws_perm_r)
+                ws_last = jnp.where(ws_learn, it, ws_last)
+            elif wide:
                 ws_presyn_r = ws_presyn_r.at[idx].set(presyn_l, mode="drop")
                 ws_perm_r = ws_perm_r.at[idx].set(perm_l, mode="drop")
                 # the learned rows' stamp is one value, so the rows in idx are

@@ -13,10 +13,20 @@ under both `_compact_ids` formulations (`FORCE_TPU_PATHS`: the one the chip
 runs and the one the CPU backend picks), learning and inferring, and through
 the stream-group programs (`group_step`, `chunk_step`).
 
-Nothing but the shape selects a form: no environment variable, no setter
-(the last two tests)."""
+Either form compacts the learning segments out of the workspace only where
+`learn_cap` cuts rows (`tm_tpu.compacts_learning_rows(cfg)`: L < col_cap*K*S);
+where it does not — L = R2 as the node presets have it, L > R2 as at 32
+columns — reinforce and growth run on the workspace's rows in place. The
+`-equal` and `-over` shapes of `form_cfg` hold that side to the same oracle
+through the same tests.
 
+Nothing but the shape selects a form: no environment variable, no setter
+(`test_no_environment_variable_selects_a_form`,
+`test_the_learn_cap_compaction_engages_only_where_it_cuts`)."""
+
+import inspect
 import os
+import re
 import subprocess
 import sys
 
@@ -26,7 +36,8 @@ import pytest
 
 import rtap_tpu.ops.tm_tpu as tm_tpu
 from rtap_tpu.config import (
-    DateConfig, ModelConfig, RDSEConfig, SPConfig, TMConfig, scaled_cluster_preset,
+    DateConfig, ModelConfig, RDSEConfig, SPConfig, TMConfig, cluster_preset, nab_preset,
+    node_preset, scaled_cluster_preset,
 )
 from rtap_tpu.models.htm_model import HTMModel
 from rtap_tpu.models.state import init_state
@@ -45,22 +56,54 @@ def form_cfg(rows: str, perm_bits: int) -> ModelConfig:
     row; wide: 64 columns (small, so the oracle stays fast) x 8 x 8 x 32 =
     2,048 lanes, the first width on the wide side of the line; lanes<n>:
     narrow rows of n lanes at 64 columns — 128 (one tile), 192 (the cluster
-    presets': a tile and a half), 384 (the node presets': three tiles)."""
+    presets': a tile and a half), 384 (the node presets': three tiles).
+
+    As named, `learn_cap` 48 cuts the workspace's R2 = col_cap*K*S rows (the
+    default `col_cap` 40: 640 rows and more) and the step compacts. With the
+    suffix `-equal` the workspace is as small as the shape allows (`col_cap`
+    = the k active columns) and `learn_cap` = R2, the node presets' case;
+    `-over` states 16 rows more than there are, as `scaled_cluster_preset(32)`
+    does (64 > 48): the learning rows are the workspace's in place."""
+    shape, _, cap = rows.partition("-")
     columns, k, S, M = {"narrow": (256, 10, 4, 16), "wide": (64, 6, 8, 32),
                         "lanes128": (64, 6, 2, 8), "lanes192": (64, 6, 2, 12),
-                        "lanes384": (64, 6, 4, 12)}[rows]
+                        "lanes384": (64, 6, 4, 12)}[shape]
+    caps = {"": {"learn_cap": 48},
+            "equal": {"learn_cap": k * 8 * S, "col_cap": k},
+            "over": {"learn_cap": k * 8 * S + 16, "col_cap": k}}[cap]
     return ModelConfig(
         rdse=RDSEConfig(size=128, active_bits=11, resolution=0.7),
         date=DateConfig(time_of_day_width=7, time_of_day_size=18, weekend_width=3),
         sp=SPConfig(columns=columns, num_active_columns=k, perm_bits=perm_bits),
         tm=TMConfig(cells_per_column=8, activation_threshold=6, min_threshold=4,
                     max_segments_per_cell=S, max_synapses_per_segment=M,
-                    new_synapse_count=8, learn_cap=48, perm_bits=perm_bits),
+                    new_synapse_count=8, perm_bits=perm_bits, **caps),
     )
 
 
 #: the shapes of `form_cfg` whose workspace gather is the compare-select reduce
 SELECT_ROWS = ("narrow", "lanes128", "lanes384")
+
+#: the shapes whose `learn_cap` cuts nothing: L = R2 and L > R2 at 192 lanes
+#: (the one-hot matmul gather), 384 lanes (the select gather) and wide rows
+IN_PLACE_ROWS = tuple(f"{shape}-{cap}" for shape in ("lanes192", "lanes384", "wide")
+                      for cap in ("equal", "over"))
+
+
+def workspace_rows(tm: TMConfig) -> int:
+    """R2: the segments of `col_cap` columns, all that can learn in one tick."""
+    return tm.col_cap * tm.cells_per_column * tm.max_segments_per_cell
+
+
+def holds_the_shapes_forms(rows: str, tm: TMConfig) -> None:
+    """All three predicates say of `form_cfg(rows)` what its name does."""
+    shape, _, cap = rows.partition("-")
+    assert tm_tpu.wide_rows(tm) == (shape == "wide")
+    assert tm_tpu.gather_by_select(tm) == (shape in SELECT_ROWS)
+    assert tm_tpu.compacts_learning_rows(tm) == (not cap)
+    r2 = workspace_rows(tm)
+    assert {"": tm.learn_cap < r2, "equal": tm.learn_cap == r2,
+            "over": tm.learn_cap > r2}[cap]
 
 
 @pytest.fixture(scope="module", params=[True, False], ids=["tpu_paths", "cpu_paths"])
@@ -76,11 +119,11 @@ def tpu_paths(request):
 @exact_only
 @pytest.mark.parametrize("learn", ["learning", "inferring"])
 @pytest.mark.parametrize("perm_bits", [0, 16, 8])
-@pytest.mark.parametrize("rows", ["narrow", "wide", "lanes128", "lanes192", "lanes384"])
+@pytest.mark.parametrize("rows", ["narrow", "wide", "lanes128", "lanes192", "lanes384",
+                                  *IN_PLACE_ROWS])
 def test_form_equals_the_oracle_end_to_end(tpu_paths, rows, perm_bits, learn):
     cfg = form_cfg(rows, perm_bits)
-    assert tm_tpu.wide_rows(cfg.tm) == (rows == "wide")
-    assert tm_tpu.gather_by_select(cfg.tm) == (rows in SELECT_ROWS)
+    holds_the_shapes_forms(rows, cfg.tm)
     cpu = HTMModel(cfg, seed=17, backend="cpu")
     dev = HTMModel(cfg, seed=17, backend="tpu")
     n = 160
@@ -101,19 +144,20 @@ def test_form_equals_the_oracle_end_to_end(tpu_paths, rows, perm_bits, learn):
 
 @exact_only
 @pytest.mark.parametrize("program", ["group_step", "chunk_step"])
-@pytest.mark.parametrize("rows", ["lanes128", "lanes192", "lanes384", "wide"])
+@pytest.mark.parametrize("rows", ["lanes128", "lanes192", "lanes384", "wide", *IN_PLACE_ROWS])
 def test_gather_equals_the_oracle_through_the_group_programs(rows, program):
-    """Both narrow-row gathers, and the wide form's indexed one, under the
-    programs the service runs — vmapped over a group's streams (at wide rows
-    on the public layout), and inside the chunk's scan (there on [C, M, K*S]
-    pools): three streams of one group against three oracles, raw scores
-    tick by tick and every leaf after."""
+    """Both narrow-row gathers, and the wide form's indexed one, with the
+    learning rows compacted and in place, under the programs the service
+    runs — vmapped over a group's streams (at wide rows on the public
+    layout), and inside the chunk's scan (there on [C, M, K*S] pools): three
+    streams of one group against three oracles, raw scores tick by tick and
+    every leaf after."""
     from rtap_tpu.models.htm_model import oracle_record_step
     from rtap_tpu.models.oracle.temporal_memory import TMOracle
     from rtap_tpu.ops.step import chunk_step, group_step, replicate_state
 
     cfg = form_cfg(rows, 16)
-    assert tm_tpu.gather_by_select(cfg.tm) == (rows in SELECT_ROWS)
+    holds_the_shapes_forms(rows, cfg.tm)
     G, T, n = 3, 8, 96
     gstate = jax.device_put(replicate_state(init_state(cfg, seed=5), G))
     oracles = []
@@ -222,6 +266,66 @@ def test_tm_step_refuses_a_state_in_another_forms_layout(rows):
         tm_tpu.tm_step(tm_tpu.to_kernel_layout(public, cfg.tm, ticks), active, cfg.tm, learn=True)
 
 
+PRESETS = {"node3": lambda: node_preset(3), "scaled32": lambda: scaled_cluster_preset(32),
+           "cluster": cluster_preset, "nab": lambda: nab_preset(0.0, 100.0)}
+
+
+@pytest.mark.parametrize("preset, compacts", [("node3", False), ("scaled32", False),
+                                              ("cluster", True), ("nab", True)])
+def test_the_learn_cap_compaction_engages_only_where_it_cuts(preset, compacts):
+    """`compacts_learning_rows` per preset: the node model's cap is the
+    structural bound (320 = 10 x 8 x 4) and the 32-column model's is over it
+    (64 > 3 x 8 x 2), so neither compacts; `cluster_preset` (64 < 160) and
+    `nab_preset` (1,280 < 20,480) do. The answer is a function of the
+    `TMConfig` and nothing else: one argument, no setter in the module, no
+    read of the environment (`test_no_environment_variable_selects_a_form`
+    lowers the 32-column step, which takes the in-place side, under a loud
+    environment)."""
+    tm = PRESETS[preset]().tm
+    assert tm_tpu.compacts_learning_rows(tm) == compacts
+    assert compacts == (tm.learn_cap < workspace_rows(tm))
+    assert list(inspect.signature(tm_tpu.compacts_learning_rows).parameters) == ["cfg"]
+    assert not [n for n in vars(tm_tpu) if n.startswith(("set_", "use_", "select_"))]
+    assert not re.search(r"os\.environ|getenv|^import os", inspect.getsource(tm_tpu), re.M)
+
+
+def _lowered_tm_step(cfg) -> str:
+    from tests.parity.test_tm_parity import TM_KEYS
+
+    st = init_state(cfg, 0)
+    state = tm_tpu.to_kernel_layout({k: np.asarray(st[k]) for k in TM_KEYS}, cfg.tm)
+    return tm_tpu.tm_step.lower(state, np.zeros(cfg.sp.columns, bool), cfg.tm,
+                                learn=True).as_text()
+
+
+def test_the_node_step_holds_no_compaction_grid_and_no_top_k_over_its_rows(monkeypatch):
+    """`tm_step` of `node_preset(3)` as lowered, in the formulation the chip
+    runs: no tensor whose two minor dimensions are (learn_cap, R2) — the
+    one-hot grid, its `any`, its where-sums — and no `top_k` over the R2
+    learning flags. The patterns bite: with the predicate patched to True
+    the same step holds both."""
+    cfg = node_preset(3)
+    tm = cfg.tm
+    r2 = workspace_rows(tm)
+    assert tm.learn_cap == r2 == 320
+    grid = re.compile(rf"tensor<(?:\d+x)*{tm.learn_cap}x{r2}x\w+>")
+    top_k = re.compile(rf"top_k[^\n]*tensor<{r2}xi32>")
+    monkeypatch.setattr(tm_tpu, "FORCE_TPU_PATHS", True)
+    jax.clear_caches()  # the forms are read at trace time
+    try:
+        text = _lowered_tm_step(cfg)
+        assert not grid.search(text) and not top_k.search(text)
+        assert "top_k" in text  # the column lists still take theirs
+        monkeypatch.setattr(tm_tpu, "compacts_learning_rows", lambda cfg: True)
+        jax.clear_caches()
+        compacting = _lowered_tm_step(cfg)
+        assert grid.search(compacting) and top_k.search(compacting)
+        assert len(compacting.splitlines()) > len(text.splitlines()) + 50
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+
+
 _LOWER = """
 import hashlib
 import jax, jax.numpy as jnp, numpy as np
@@ -245,7 +349,7 @@ def test_no_environment_variable_selects_a_form():
     clean["PYTHONPATH"] = REPO
     loud = dict(clean, RTAP_TM_SCATTER="indexed", RTAP_TM_LAYOUT="aos",
                 RTAP_TM_SWEEP="compact", RTAP_TM_DENDRITE="forward",
-                RTAP_TM_FWD_IMPL="matmul")
+                RTAP_TM_FWD_IMPL="matmul", RTAP_TM_LEARN_ROWS="compact")
     procs = [subprocess.Popen([sys.executable, "-c", _LOWER], env=env, cwd=REPO,
                               stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
              for env in (clean, loud)]
