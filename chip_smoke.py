@@ -308,7 +308,8 @@ def judge_serve(rc: int, stats: dict, events: list[dict], sizes: dict,
     """The serve verdict, from what serve itself reported: its exit code is
     the least of it (a quarantined group still exits 0)."""
     # cluster_preset u16 bytes/stream, derived statically (pure AST, no jax;
-    # bench.py's gate holds it equal to the real arrays' byte sum)
+    # tests/integration/test_bringup.py holds it equal to the real arrays'
+    # byte sum)
     from rtap_tpu.analysis.scalingmath import derived_stream_bytes
 
     n, ticks = sizes["serve_streams"], sizes["serve_ticks"]

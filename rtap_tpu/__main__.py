@@ -12,8 +12,9 @@ over the library:
     eval     fault-injection evaluation -> JSON report (eval/fault_eval.py)
     report   matplotlib overlays from a replay/eval (scripts/report.py)
 
-``bench``/``scaling``/``profile`` remain repo-root scripts (bench.py,
-scripts/) since they are driver/measurement surfaces, not operator ones.
+``scaling``/``profile`` remain scripts (scripts/) and the benchmark its own
+package (``python -m benchmark.run``) since they are driver/measurement
+surfaces, not operator ones.
 
 Every command honors ``RTAP_FORCE_CPU=1`` (an explicit CPU run; without it
 or ``JAX_PLATFORMS=cpu``, ``--backend tpu`` refuses to start where JAX finds

@@ -4,8 +4,8 @@
 Exit codes: 0 = zero unsuppressed findings (the gate), 1 = findings or
 baseline format errors, 2 = usage error. The human report goes to
 stderr; ``--json`` prints exactly one JSON artifact line to stdout (the
-soak archival surface — same one-JSON-line stdout contract
-as bench.py), so both can be combined in one invocation. ``--sarif``
+soak archival surface — the evals' one-JSON-line stdout
+contract), so both can be combined in one invocation. ``--sarif``
 writes a SARIF 2.1.0 log to a FILE (never stdout — the one-line
 contract stays intact) for CI/editor rendering.
 

@@ -264,8 +264,8 @@ class Baseline:
 
 def discover_texts(root: str) -> list[tuple[str, str]]:
     """(repo-relative path, text) for the analysis surface: every .py
-    under rtap_tpu/ and scripts/, plus bench.py — the same set the old
-    check_static.sh walked, so the print gate's coverage is unchanged.
+    under rtap_tpu/ and scripts/ — the set the old check_static.sh
+    walked, so the print gate's coverage is unchanged.
     Split from parsing so the findings cache can judge freshness from
     content hashes WITHOUT paying ~100 ast.parse calls on a hit."""
     out: list[tuple[str, str]] = []
@@ -286,10 +286,6 @@ def discover_texts(root: str) -> list[tuple[str, str]]:
                 rel = os.path.relpath(full, root)
                 with open(full, encoding="utf-8") as fh:
                     out.append((rel, fh.read()))
-    bench = os.path.join(root, "bench.py")
-    if os.path.isfile(bench):
-        with open(bench, encoding="utf-8") as fh:
-            out.append(("bench.py", fh.read()))
     return out
 
 

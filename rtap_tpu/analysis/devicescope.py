@@ -51,7 +51,7 @@ RULES = {
 #: inventory must track (each is baselined with a why or fixed)
 _SCOPES = ("rtap_tpu/service/", "rtap_tpu/resilience/", "rtap_tpu/obs/",
            "rtap_tpu/correlate/", "rtap_tpu/ingest/",
-           "rtap_tpu/__main__.py", "scripts/", "bench.py")
+           "rtap_tpu/__main__.py", "scripts/")
 
 #: the addressing owners: flat-id <-> SlotAddress conversion lives here
 #: and nowhere else

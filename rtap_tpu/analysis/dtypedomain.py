@@ -30,8 +30,8 @@ Valid domains: ``u8 | u16 | i32-key``. Three findings:
   (``astype(jnp.uint8 | uint16)``) over a value with no declared
   domain: the cast invents a domain the table never heard of.
 
-Scope: ``rtap_tpu/ops/``, ``rtap_tpu/models/``, ``scripts/`` and
-``bench.py`` (bench/eval scaffolding builds quantized state too).
+Scope: ``rtap_tpu/ops/``, ``rtap_tpu/models/`` and ``scripts/``
+(eval scaffolding builds quantized state too).
 An ``astype`` whose target dtype is non-literal (``dom.compute_dtype``)
 is the sanctioned domain-polymorphic idiom (models/perm.py) and clears
 the operand's domain rather than guessing one.
@@ -64,7 +64,7 @@ _TRAILING_RE = re.compile(r"#\s*rtap:\s*domain\[([\w-]+)\]")
 #: literal cast targets that land on a quantized grid
 _GRID_DTYPES = {"uint8": "u8", "uint16": "u16"}
 
-_SCOPES = ("rtap_tpu/ops/", "rtap_tpu/models/", "scripts/", "bench.py")
+_SCOPES = ("rtap_tpu/ops/", "rtap_tpu/models/", "scripts/")
 
 
 def file_domain_table(sf: SourceFile) -> tuple[dict[str, str],

@@ -6,8 +6,8 @@ telemetry and diagnostics go through rtap_tpu.obs (registry
 instruments, watchdog events, snapshots) or logging, never ad-hoc
 stdout/stderr lines a harness would have to scrape back out of logs.
 
-Rule ``print-bare`` — everywhere else in ``rtap_tpu/``, ``scripts/``
-and ``bench.py``, a ``print()`` must either target an explicit stream
+Rule ``print-bare`` — everywhere else in ``rtap_tpu/`` and ``scripts/``,
+a ``print()`` must either target an explicit stream
 (``file=...`` — stderr diagnostics) or be the sanctioned one-JSON-line
 stdout emission (a single ``json.dumps(...)``/``.to_json()`` argument —
 the bench/eval artifact contract). AST-based: a line grep cannot see a

@@ -212,10 +212,11 @@ def derive_leaf_bytes(cfg_sf, perm_sf, bits: int) -> dict[str, int] | None:
 def derived_stream_bytes(root: str, bits: int) -> int | None:
     """Analyzer-derived bytes/stream of one cluster-preset stream, read
     from the REAL repo files under `root` (None when underivable). This is
-    the same static derivation the SCALING.md gate runs; bench.py gates
-    its honest ``state_nbytes`` figure against it so a layout change that
-    moves real bytes without moving the doc twin fails loudly instead of
-    drifting (ISSUE 18 satellite 5)."""
+    the same static derivation the SCALING.md gate runs;
+    tests/integration/test_bringup.py holds the real arrays' byte sum
+    equal to it, and chip_smoke.py sizes its serve verdict by it, so a
+    layout change that moves real bytes without moving the doc twin fails
+    loudly instead of drifting (ISSUE 18 satellite 5)."""
     from rtap_tpu.analysis.core import SourceFile
 
     sfs = []

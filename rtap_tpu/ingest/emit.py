@@ -64,10 +64,16 @@ class BinaryFeedConnection:
             data = self._sock.recv(1 << 16)
             if not data:
                 raise ConnectionError("listener closed before MAP frame")
+            # every MAP of the read, not the first: a push sent right
+            # behind the hello arrives in the same segment, and the last
+            # one is the newest (ingest/server.py _send_map)
+            got = False
             for fr in self._walker.feed(data):
                 if fr.kind == KIND_MAP and fr.count:
                     self._adopt_map(fr)
-                    return
+                    got = True
+            if got:
+                return
 
     def refresh_map(self) -> None:
         """Re-request the map (e.g. after NAMES announcements were

@@ -242,14 +242,20 @@ class BinaryBatchSource:
                     # loop (a connect-then-die producer must not leak
                     # its thread entry forever)
                     try:
+                        # registered for MAP pushes BEFORE the hello: a
+                        # push (announce_leader, a membership change) that
+                        # fell between the two would never reach this
+                        # producer, which has its hello and waits for
+                        # nothing more. Whichever of the two goes second
+                        # carries the newest map (_send_map)
+                        with outer._lock:
+                            outer._conns.add(self.request)
                         # hello: the current id -> slot-code map, so the
                         # producer can encode without out-of-band config
                         try:
                             outer._send_map(self.request)
                         except OSError:
                             return
-                        with outer._lock:
-                            outer._conns.add(self.request)
                         walker = outer._new_walker()
                         self.request.settimeout(0.5)
                         while True:
@@ -384,21 +390,35 @@ class BinaryBatchSource:
             self._leader_addr = str(addr)
             self._map_epoch = self._map_epoch % 0xFFFF + 1
             self._map_blob = self._render_map()
-            conns = list(self._conns)
-            blob = self._map_blob
-        frame = build_frame(KIND_MAP, blob)
+        self._push_map()
+
+    # Which map goes out and when it goes are decided under ONE lock,
+    # _send_lock, with the blob read under _lock inside it: whatever a
+    # producer receives last is the newest map there was when it was
+    # sent. Read first and sent later, a hello holding the old blob could
+    # follow the push of the new one and leave its producer on a stale
+    # epoch with no __leader__ hint. Nothing takes _send_lock while it
+    # holds _lock, so the order cannot deadlock.
+    def _send_map(self, sock) -> None:
         with self._send_lock:
+            with self._lock:
+                blob = self._map_blob
+            sock.sendall(build_frame(KIND_MAP, blob))
+
+    def _push_map(self) -> None:
+        """The current map to every connected producer; best-effort — a
+        dead socket's handler cleans up."""
+        with self._send_lock:
+            with self._lock:
+                blob = self._map_blob
+                conns = list(self._conns)
+            frame = build_frame(KIND_MAP, blob)
             for sock in conns:
                 try:
                     sock.sendall(frame)
-                except OSError:
+                except OSError:  # rtap: allow[except-silent] — a dead
+                    # producer learns the map from its reconnect's hello
                     pass
-
-    def _send_map(self, sock) -> None:
-        with self._lock:
-            blob = self._map_blob
-        with self._send_lock:
-            sock.sendall(build_frame(KIND_MAP, blob))
 
     def set_slot_map(self, slot_map: dict) -> None:
         """Adopt the registry's new slot map (membership changed).
@@ -436,22 +456,13 @@ class BinaryBatchSource:
             # a membership change invalidates raw-frame journaling for
             # the in-progress tick (old codes): synthesize at snapshot
             self._tick_pure = False
-            conns = list(self._conns)
-            blob = self._map_blob
         # PUSH the fresh map to every connected producer (outside the
-        # hot lock; best-effort — a dead socket's handler cleans up):
-        # without this, a producer whose NAMES were not the trigger
-        # (e.g. an auto-release elsewhere in the fleet) would keep
-        # stamping the old epoch and go deaf until it happened to
+        # hot lock): without this, a producer whose NAMES were not the
+        # trigger (e.g. an auto-release elsewhere in the fleet) would
+        # keep stamping the old epoch and go deaf until it happened to
         # re-request. Producers drain pushes via
         # BinaryFeedConnection.poll_map() before sending.
-        frame = build_frame(KIND_MAP, blob)
-        with self._send_lock:
-            for sock in conns:
-                try:
-                    sock.sendall(frame)
-                except OSError:
-                    pass
+        self._push_map()
 
     def drain_unknown(self) -> list[str]:
         """Pop unknown-id names announced in NAMES frames since the last

@@ -80,7 +80,7 @@ class HealthTracker:
     only hot-path call (one per collected chunk per group — a few
     numpy ops over ~40-element vectors, self-benchmarked by
     ``obs/selfbench.measure_health`` and gated <= 1% of the tick budget
-    by ``bench.py --obs-bench``).
+    by ``python -m rtap_tpu.obs.selfbench``).
 
     `sink` (callable taking one JSON-able event dict) and `flight`
     (obs.FlightRecorder) may be attached after construction —
@@ -164,7 +164,7 @@ class HealthTracker:
             "rtap_obs_health_fold_seconds",
             "wall seconds per HealthTracker.fold call (one per collected "
             "chunk per group; gated <= 1% of the tick budget by "
-            "bench.py --obs-bench)")
+            "python -m rtap_tpu.obs.selfbench)")
 
     # ------------------------------------------------------------ fold --
     def fold(self, group: int, leaves: dict, tick: int = -1) -> None:

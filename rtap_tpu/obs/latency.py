@@ -27,7 +27,8 @@ With the flag off nothing here is constructed and the serve path is
 byte/bit-identical to a flagless run (tests/integration/
 test_latency_serve.py pins it, the PR 6 health-flag discipline). Armed,
 the hot-path cost is gated <= 1% of the tick budget next to the other
-obs instruments (obs/selfbench.measure_latency, bench.py --obs-bench).
+obs instruments (obs/selfbench.measure_latency; ``python -m
+rtap_tpu.obs.selfbench``).
 
 Clock contract: ``detect`` compares the host wall clock against the
 row's source timestamp, so it is meaningful when producers stamp rows

@@ -8,8 +8,8 @@ layer (obs/expo.py) renders the same registry as Prometheus v0 text or a
 JSONL snapshot.
 
 Design constraints (the tick loop scores 100k+ streams at 1 s cadence and
-its instrumentation budget is <= 1% of the tick — bench.py --obs-bench and
-tests/unit/test_obs.py pin it):
+its instrumentation budget is <= 1% of the tick — ``python -m
+rtap_tpu.obs.selfbench`` and tests/unit/test_obs.py pin it):
 
 - **Lock-free writer fast path.** No instrument takes a lock on ``inc`` /
   ``set`` / ``observe``. Instead every writer thread owns a private cell

@@ -5,17 +5,20 @@ tick budget. A serve tick at the flagship shape emits a few dozen
 instrument operations (6 phase-histogram observes, a tick-latency observe,
 2-4 counter incs, a gauge set, plus per-group alert accounting), so the
 budget math is ``ops_per_tick * ns_per_op`` vs ``cadence_s``. This module
-measures ns_per_op on the running host; bench.py exposes it as
-``bench.py --obs-bench`` and tests/unit/test_obs.py pins the 1% bar.
+measures ns_per_op on the running host; ``python -m rtap_tpu.obs.selfbench``
+(:func:`main`) gates every instrument surface against it and
+tests/unit/test_obs.py pins the 1% bar.
 """
 
 from __future__ import annotations
 
+import json
+import sys
 import time
 
 from rtap_tpu.obs.metrics import TelemetryRegistry
 
-__all__ = ["measure", "measure_trace", "measure_journal", "measure_health",
+__all__ = ["main", "measure", "measure_trace", "measure_journal", "measure_health",
            "measure_correlate", "measure_latency", "measure_predict",
            "measure_fleet",
            "GATE_MEASURES", "GATE_BUDGET_FRAC",
@@ -161,7 +164,7 @@ def measure_health(n: int = 2000, cadence_s: float = 1.0,
     step and is measured on silicon by the ``r9_health`` hw-session
     step; the host fold is what the loop thread pays every tick, and
     ISSUE 6 gates it <= 1% of the tick budget alongside the metric/
-    trace/journal bars (``bench.py --obs-bench``)."""
+    trace/journal bars (:func:`main`)."""
     import numpy as np
 
     from rtap_tpu.config import cluster_preset
@@ -218,7 +221,7 @@ def measure_journal(n: int = 2000, cadence_s: float = 1.0,
     alert-cursor append per emitted chunk, measured on a private journal
     in a temp dir at the production per-chip row width. ISSUE 5
     acceptance: journaling stays <= 1% of the tick budget
-    (``bench.py --obs-bench`` gates it alongside the trace/flight bars).
+    (:func:`main` gates it alongside the trace/flight bars).
     """
     import shutil
     import tempfile
@@ -269,8 +272,8 @@ def measure_correlate(n: int = 20_000, cadence_s: float = 1.0,
     ``n_clusters`` clusters kept PERMANENTLY open — the storm ceiling,
     where every tick both folds a full blast-radius worth of alerts and
     scans every open window. A healthy tick pays one near-empty
-    ``on_tick`` only; this projects the worst case, and ``bench.py
-    --obs-bench`` gates it <= 1% of the tick budget alongside the
+    ``on_tick`` only; this projects the worst case, and :func:`main`
+    gates it <= 1% of the tick budget alongside the
     metric/trace/journal/health bars."""
     from rtap_tpu.correlate import IncidentCorrelator, TopologyMap
 
@@ -312,8 +315,8 @@ def measure_latency(n: int = 20_000, cadence_s: float = 1.0,
     declared SLOs — the stage sketches, the waterfall build, the lag
     probes, and the burn-rate evaluation), projected to a tick at the
     alert-storm ceiling. Registered in :data:`GATE_MEASURES`, so
-    ``bench.py --obs-bench`` gates it <= 1% of the tick budget alongside
-    every other obs instrument."""
+    :func:`main` gates it <= 1% of the tick budget alongside every other
+    obs instrument."""
     import numpy as np
 
     from rtap_tpu.obs.latency import LatencyTracker
@@ -367,7 +370,7 @@ def measure_predict(n: int = 2000, cadence_s: float = 1.0,
     step and is measured on silicon by the ``r15_predict`` hw-session
     step; the host fold is what the loop thread pays, and ISSUE 16
     gates it <= 1% of the tick budget alongside every other obs
-    instrument (``bench.py --obs-bench``)."""
+    instrument (:func:`main`)."""
     import numpy as np
 
     from rtap_tpu.models.oracle.predict import predict_nbytes
@@ -418,8 +421,8 @@ def measure_fleet(n: int = 2000, cadence_s: float = 1.0,
     a tick at the soak push density (``push_interval = cadence/2`` ->
     two snapshot builds per tick). The publisher is never started: the
     measurement is the build+pack cost, not socket I/O. Registered in
-    :data:`GATE_MEASURES`, so ``bench.py --obs-bench`` gates it <= 1% of
-    the tick budget alongside every other obs instrument."""
+    :data:`GATE_MEASURES`, so :func:`main` gates it <= 1% of the tick
+    budget alongside every other obs instrument."""
     from rtap_tpu.fleet.member import FleetPublisher
     from rtap_tpu.fleet.protocol import FLEET_SNAP, pack_fleet
     from rtap_tpu.obs.latency import LatencyTracker
@@ -469,7 +472,7 @@ def measure_fleet(n: int = 2000, cadence_s: float = 1.0,
 
 #: THE obs-bench gate registry (ISSUE 11 satellite): every self-
 #: benchmarked instrument surface, each gated <= ``budget_frac`` of the
-#: tick budget by ``bench.py --obs-bench`` and the tier-1 overhead
+#: tick budget by :func:`main` and the tier-1 overhead
 #: tests. Adding an instrument = adding a row here — a new surface
 #: cannot ship ungated, and the five historical ad-hoc gate lines
 #: collapsed into this table.
@@ -487,3 +490,30 @@ GATE_MEASURES: tuple = (
 #: the shared acceptance bar: each surface's projected per-tick cost
 #: must stay under this fraction of the cadence budget
 GATE_BUDGET_FRAC = 0.01
+
+
+def main() -> int:
+    """``python -m rtap_tpu.obs.selfbench``: the telemetry-overhead gate.
+
+    Table-driven over :data:`GATE_MEASURES`: every self-benchmarked
+    instrument surface is one row gated against the shared
+    :data:`GATE_BUDGET_FRAC` (<= 1% of the tick budget,
+    docs/TELEMETRY.md). Prints one JSON line per surface; returns 1 if
+    any bar is blown, so CI/harness runs fail loudly. A new instrument
+    registers a row or never gets a gate."""
+    all_pass = True
+    for name, fn in GATE_MEASURES:
+        res = fn()
+        res["budget_frac"] = GATE_BUDGET_FRAC
+        res["pass_1pct_budget"] = \
+            res["per_tick_overhead_frac"] <= GATE_BUDGET_FRAC
+        all_pass = all_pass and res["pass_1pct_budget"]
+        # the command's artifact lines, not telemetry of the serve path
+        # (which obs/ keeps off stdout: rtap-lint `print-strict`)
+        sys.stdout.write(json.dumps({"metric": name, **res}) + "\n")
+        sys.stdout.flush()
+    return 0 if all_pass else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -18,11 +18,11 @@ Entry points:
 - :class:`TpuStepRunner` — stateful convenience wrapper holding device state.
 
 All are bit-identical to the CPU oracle per step
-(tests/parity/test_e2e_parity.py), and each hands the state back in the form
-it arrived in (:func:`_enter_kernel`): the public [C, K, S, M] layout for
-the harnesses that build their own trees, the kernel's form for the owners
-that keep theirs on the device between programs (ops/resident.py) — for
-those no pool changes layout at a program's boundary.
+(tests/parity/test_e2e_parity.py). A program runs the kernel's form; a
+public [C, K, S, M] tree (the harnesses that build their own) is converted
+once each way at its boundary (:func:`_enter_kernel`) and handed back
+public, and the owners that keep theirs on the device in the kernel's form
+between programs (ops/resident.py) pay no layout change at all.
 """
 
 from __future__ import annotations
@@ -59,9 +59,10 @@ from rtap_tpu.ops.tm_tpu import tm_step
 #:   rtap.tm.dendrite    dendrite activity for t+1                  (tm_tpu.tm_step)
 #:   rtap.reduce.health, rtap.reduce.predict, rtap.classifier
 #:                       the optional reducers / classifier         (_tick, _step_impl)
-#:   rtap.layout         to/from_kernel_layout of a PUBLIC-layout argument,
-#:                       once per program; absent where the state arrives
-#:                       in the kernel's form (a stream group's)    (_enter_kernel)
+#:   rtap.layout         resident_form / public_form of a PUBLIC-layout
+#:                       argument, once per program each way; absent where
+#:                       the state arrives in the kernel's form (a stream
+#:                       group's)                                   (_enter_kernel)
 SCOPES = (
     "rtap.encode",
     "rtap.sp.overlap", "rtap.sp.inhibit", "rtap.sp.learn",
@@ -109,31 +110,32 @@ def _step_impl(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: Mode
     return state, raw
 
 
-def _enter_kernel(state: dict, cfg: ModelConfig, ticks: int = 1):
-    """A program's state argument -> (the tree `tm_step` runs `ticks` ticks
-    on, the function that hands the stepped tree back in the form the
-    argument came in). Told apart by the leaves' shapes at trace time
-    (tm_tpu.kernel_resident), never by a setting:
+def _enter_kernel(state: dict, cfg: ModelConfig):
+    """A program's state argument -> (the tree `tm_step` runs on, the
+    function that hands the stepped tree back in the form the argument came
+    in). A program runs the kernel's form; which form it was handed is told
+    by the leaves' shapes at trace time (tm_tpu.kernel_resident), never by a
+    setting or by the program's length:
 
     - the kernel's form (what a `StreamGroup` or a `TpuStepRunner` holds on
       the device between programs, ops/resident.py) passes both ways
       untouched — the program's parameters and results ARE the scan's
       carry, and no pool is copied at its boundary;
     - the public [C, K, S, M] layout (the parity harness, the oracle's
-      twin, scripts that build their own trees) goes through
-      `to_kernel_layout` / `from_kernel_layout` under `rtap.layout`, once a
-      program each way.
+      twin, scripts that build their own trees) is converted once each way
+      at the program's boundary, `resident_form` in and `public_form` out,
+      under `rtap.layout`.
     """
-    from rtap_tpu.ops.tm_tpu import from_kernel_layout, kernel_resident, to_kernel_layout
+    from rtap_tpu.ops.tm_tpu import kernel_resident, public_form, resident_form
 
     if kernel_resident(state):
         return state, lambda stepped: stepped
     with jax.named_scope("rtap.layout"):
-        entered = to_kernel_layout(state, cfg.tm, ticks)
+        entered = resident_form(state, cfg.tm)
 
     def leave(stepped: dict) -> dict:
         with jax.named_scope("rtap.layout"):
-            return from_kernel_layout(stepped, cfg.tm, ticks)
+            return public_form(stepped, cfg.tm)
 
     return entered, leave
 
@@ -237,16 +239,14 @@ def _scan_chunk(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: Mod
     A stream group hands its state over in that form and takes it back so
     (ops/resident.py): the program's parameters are the carry. A tree in
     the public [C,K,S,M] layout — the parity harness's, the oracle twin's —
-    is adapted OUTSIDE the scan, once per chunk each way (a reshape at
-    narrow rows; at wide ones a transpose a pool, which a chunk of one tick
-    could not win back, so it keeps the public layout in the kernel,
-    tm_tpu.public_in_kernel); checkpoints and the oracle never see the
-    kernel layout, and readers of a group's state see it through
-    `StreamGroup.state`, public again. Likewise
-    the tick-invariant kernel operands (the flat layout's per-segment
-    reduction matrix) are built ONCE here and closed over by the body, so
-    they are hoisted out of the scan by construction and stay HBM-resident
-    across the whole T-tick chunk."""
+    is converted OUTSIDE the scan, once per chunk each way (a reshape at
+    narrow rows, a transpose a pool at wide ones); checkpoints and the
+    oracle never see the kernel layout, and readers of a group's state see
+    it through `StreamGroup.state`, public again. Likewise the
+    tick-invariant kernel operands (the flat layout's per-segment reduction
+    matrix) are built ONCE here and closed over by the body, so they are
+    hoisted out of the scan by construction and stay HBM-resident across
+    the whole T-tick chunk."""
     from rtap_tpu.ops.tm_tpu import tm_invariants
 
     inv = tm_invariants(cfg.tm)
@@ -256,7 +256,7 @@ def _scan_chunk(state: dict, values: jnp.ndarray, ts_unix: jnp.ndarray, cfg: Mod
         return _tick(s, v, t, cfg, learn, inv, health=health,
                      predict=predict)
 
-    state, leave = _enter_kernel(state, cfg, values.shape[0])
+    state, leave = _enter_kernel(state, cfg)
     state, out = jax.lax.scan(body, state, (values, ts_unix))
     return leave(state), out
 

@@ -52,14 +52,11 @@ the scan (docs/KERNELS.md, "Why [C, M, K*S]"). The owners of a state — a
 stream group, a step runner — keep it on the device in the kernel's form
 between programs (:func:`resident_form`; ops/resident.py), so their programs
 enter and leave no layout. A tree handed over in the public [C, K, S, M]
-layout enters and leaves the kernel's once a program
-(:func:`to_kernel_layout`, :func:`from_kernel_layout`; ops/step.py calls
-them under `rtap.layout`) — except a one-tick program at wide rows, which
-has no later tick to win the two transposes a pool back on and runs the same
-form on the public layout as it stands (:func:`public_in_kernel`). The form
-is a function of the shape, and nothing outside this module and
-ops/resident.py knows there is a choice. In the narrow form the
-workspace path is region-consolidated:
+layout enters and leaves the kernel's once a program, whatever the
+program's length (:func:`resident_form`, :func:`public_form`; ops/step.py
+calls them under `rtap.layout`). The form is a function of the shape, and
+nothing outside this module and ops/resident.py knows there is a choice.
+In the narrow form the workspace path is region-consolidated:
 presyn + perm (+ seg_pot) ride ONE one-hot MXU pass per
 gather/scatter stage instead of one pass per tensor (bitwise identical per
 block — each output element touches only its own operand columns), the
@@ -124,10 +121,9 @@ def _tpu_paths() -> bool:
 #: copies of both pools do not fit the chip (RESOURCE_EXHAUSTED at compile,
 #: 15.76 of 15.75 GB; 18.6 GB of arguments and temporaries by the compiler's
 #: own account since ISSUE 36), and the indexed moves over [C, M, K*S] pools
-#: step a group-tick of 17 streams in 50.53 ms (75.96 with the public
-#: [C, K, S, M] layout in the kernel, which the chip holds columns-minor and
-#: re-laid four times a tick around the moves; chip runs, PRs 40 and 42;
-#: PERF.md s6).
+#: step a group-tick of 17 streams in 50.53 ms (75.96 on [C, K, S, M] pools,
+#: which the chip holds columns-minor and re-laid four times a tick around
+#: the moves; chip runs, PRs 40 and 42; PERF.md s6).
 #: The line is the geometric middle of the two points, 85x apart, rounded to
 #: a power of two; nothing between them has been measured.
 WIDE_ROW_LANES = 2048
@@ -211,22 +207,6 @@ _KERNEL_KEYS = {
 }
 
 
-def public_in_kernel(cfg: TMConfig, ticks: int) -> bool:
-    """Does a program of `ticks` ticks that is HANDED the public
-    [C, K, S, M] layout run its wide-row step on it as it stands? Only the
-    one-tick programs do (`group_step`, `fused_step`, `chunk_step` at T = 1;
-    a state that arrives resident is [C, M, K*S] already and runs so). The
-    chip holds that layout columns-minor and re-lays both pools around the
-    indexed row moves, four pool copies a tick; entering and leaving
-    [C, M, K*S] costs a one-tick program the same four, and there both
-    layouts of both pools are live together: 96.9 ms a group-tick of 17
-    NAB-width streams for 75.7, 13.7 GB of program for 7.7 (chip run and
-    compile, PR 40; on the public layout the one-tick program is the
-    earlier one byte for byte, 75.69 ms, PR 42). A scan of two ticks or
-    more wins them back on every tick but the first (50.53 ms at T = 8)."""
-    return wide_rows(cfg) and ticks == 1
-
-
 def kernel_resident(state: dict) -> bool:
     """Does this tree hold the six `_KERNEL_KEYS` leaves in the kernel's form
     already (the form a stream group keeps on the device between programs)?
@@ -272,29 +252,6 @@ def public_form(state: dict, cfg: TMConfig) -> dict:
     [C, K, S] segment tensors — what checkpoints, the oracle and the parity
     harness keep."""
     return {**state, **{k: public_leaf(k, state[k], cfg) for k in _KERNEL_KEYS}}
-
-
-def to_kernel_layout(state: dict, cfg: TMConfig, ticks: int = 1) -> dict:
-    """A program's state argument -> the layout `tm_step(cfg)` runs `ticks`
-    ticks on. A tree that arrives in the kernel's form (`kernel_resident`:
-    a stream group's, between programs) passes untouched, whatever `ticks`
-    is. A tree in the public layout takes `resident_form` — a reshape at
-    narrow rows; at wide rows one transpose a pool, entering the program,
-    which `from_kernel_layout` pays again on the way out — unless the
-    program runs one tick only (`public_in_kernel`): then nothing changes
-    shape."""
-    if kernel_resident(state) or public_in_kernel(cfg, ticks):
-        return state
-    return resident_form(state, cfg)
-
-
-def from_kernel_layout(state: dict, cfg: TMConfig, ticks: int = 1) -> dict:
-    """What `to_kernel_layout` made of a public-layout tree -> the public
-    layout again, at the same `ticks`. (A tree that arrived resident is
-    handed back as it is: ops/step.py does not call this for it.)"""
-    if public_in_kernel(cfg, ticks):
-        return state
-    return public_form(state, cfg)
 
 
 @lru_cache(maxsize=None)
@@ -589,19 +546,17 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
     wide = wide_rows(cfg)
     K, S, M = cfg.cells_per_column, cfg.max_segments_per_cell, cfg.max_synapses_per_segment
     C = state["presyn"].shape[0]
-    # a one-tick program's wide-row state comes as it stands (public_in_kernel)
-    m_minor = wide and state["presyn"].shape == (C, K, S, M)
-    pool_shape = (C, K, S, M) if m_minor else (C, M, K * S) if wide else (C, K * S * M)
-    seg_shape = (C, K, S) if m_minor else (C, K * S)
+    pool_shape = (C, M, K * S) if wide else (C, K * S * M)
+    seg_shape = (C, K * S)
     if state["presyn"].shape != pool_shape or state["seg_last"].shape != seg_shape:
         raise ValueError(
             f"{'wide' if wide else 'narrow'} pool rows: tm_step expects "
-            f"kernel-layout state ({'[C, M, K*S] or public' if wide else '[C, K*S*M]'} "
-            "pools — ops/step.py applies to_kernel_layout); "
+            f"kernel-layout state ({'[C, M, K*S]' if wide else '[C, K*S*M]'} "
+            "pools, [C, K*S] segment tensors — resident_form of the public "
+            "tree; ops/step.py applies it); "
             f"got presyn shape {state['presyn'].shape}, "
             f"seg_last shape {state['seg_last'].shape}"
         )
-    m_ax = -1 if m_minor else -2  # the wide pools' synapse axis
     N = C * K
     L, Ac = cfg.learn_cap, cfg.col_cap
     if K > 32:
@@ -618,7 +573,7 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
         2^24: f32-exact) instead of a minor-dim sum the tiler pads; wide
         pools sum their M axis."""
         if wide:
-            return x.sum(m_ax)
+            return x.sum(-2)
         return jnp.round(
             jax.lax.dot(x.astype(jnp.float32), _red(), precision=_HI)
         ).astype(jnp.int32)
@@ -629,7 +584,7 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
         instead of two (fused-region consolidation; bitwise identical per
         block — each output element touches only its own operand rows)."""
         if wide:
-            return a.sum(m_ax), b.sum(m_ax)
+            return a.sum(-2), b.sum(-2)
         both = jnp.round(
             jax.lax.dot(
                 jnp.concatenate([a, b], 0).astype(jnp.float32), _red(),
@@ -641,7 +596,7 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
     def seg_expand(x):
         """Broadcast a per-segment value onto its synapse lanes."""
         if wide:
-            return x[..., None] if m_minor else x[:, None, :]
+            return x[:, None, :]
         return jnp.repeat(x, M, axis=-1)
 
     # Permanence-domain constants (models/perm.py). The learning workspace
@@ -722,8 +677,6 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
 
                 def take_rows(pool, dt):
                     """[Ac, K*S*M] rows of a pool, a row in the public order."""
-                    if m_minor:
-                        return pool.reshape(C, -1)[idx_c].astype(dt)
                     return pool[idx_c].astype(dt).swapaxes(-1, -2).reshape(Ac, -1)
 
                 with jax.named_scope("rtap.tm.learn.rows"):
@@ -885,7 +838,7 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
                 # only the <= Ac touched rows are written; fill ids (C) drop
                 def put_rows(x, ws):
                     """The workspace's [Ac] rows into `x` where it lies."""
-                    if x.ndim == 3 and not m_minor:  # a [C, M, K*S] pool
+                    if x.ndim == 3:  # a [C, M, K*S] pool
                         flat, rows = x, ws.reshape(Ac, K * S, M).swapaxes(-1, -2)
                     else:
                         flat, rows = x.reshape(C, -1), ws.reshape(Ac, -1)
@@ -899,8 +852,8 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
                     syn_perm = put_rows(syn_perm, ws_perm_w)
                     seg_last = put_rows(seg_last, ws_last)
             else:
-                hit_pool = hit_cols.reshape(C, *([1] * (len(pool_shape) - 1)))
-                hit_seg = hit_cols.reshape(C, *([1] * (len(seg_shape) - 1)))
+                hit_pool = hit_cols.reshape(C, 1)
+                hit_seg = hit_cols.reshape(C, 1)
                 # presyn + perm pools restored in ONE [2*KSM, Ac] x [Ac, C] pass,
                 # each pool turned to [C, K*S*M] after its slice: the columns
                 # stay the product's minor dim, as the scan's carry holds the
@@ -939,7 +892,7 @@ def tm_step(state: dict, active_cols: jnp.ndarray, cfg: TMConfig, learn: bool = 
             # over the full pool ---
             if cfg.predicted_segment_decrement > 0.0:
                 pdec = dom.rate(cfg.predicted_segment_decrement)
-                acols_seg = active_cols.reshape(C, *([1] * (len(seg_shape) - 1)))
+                acols_seg = active_cols.reshape(C, 1)
                 pmask = state["matching_seg"] & ~acols_seg  # [*seg_shape]
                 pact = _presyn_active_packed(presyn, pcol_ids, pcol_masks, K)
                 sp_c = syn_perm.astype(dom.compute_dtype)

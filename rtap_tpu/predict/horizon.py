@@ -87,7 +87,7 @@ class PredictTracker:
     only hot-path call (one per collected chunk per group — a few numpy
     ops over [T, G] leaves, self-benchmarked by
     ``obs/selfbench.measure_predict`` and gated <= 1% of the tick
-    budget by ``bench.py --obs-bench``).
+    budget by ``python -m rtap_tpu.obs.selfbench``).
 
     `sink` (callable taking one JSON-able event dict), `flight`
     (obs.FlightRecorder) and `blast`
@@ -159,7 +159,7 @@ class PredictTracker:
             "rtap_obs_predict_fold_seconds",
             "wall seconds per PredictTracker.fold call (one per collected "
             "chunk per group; gated <= 1% of the tick budget by "
-            "bench.py --obs-bench)")
+            "python -m rtap_tpu.obs.selfbench)")
 
     # ---------------------------------------------------------- resume --
     def arm_suppression(self, ids) -> None:

@@ -767,12 +767,17 @@ class StandbyFollower:
         any group was loaded.
 
         Retries per group: unlike every other resume path, the standby
-        reads this dir while the LIVE leader may be saving to it — the
+        reads this dir while the LIVE leader may be saving to it. The
         atomic swap (rename + old-copy sweep) can delete files under an
-        in-progress orbax read, which fails loudly, never silently; a
-        re-read lands on the new complete copy. A torn adoption across
-        groups (different save rounds) is fine — per-group ``gpos``
-        positions each group and the stream converges them."""
+        in-progress orbax read, which fails loudly — or fall BETWEEN the
+        read of meta.json (the position) and the read of the state tree,
+        which does not: one round's position over the next round's
+        state, and the stream then feeds the group rows it already has.
+        So a read counts only if meta.json says the same before and
+        after it; a re-read lands on the new complete copy. A torn
+        adoption ACROSS groups (different save rounds) is fine —
+        per-group ``gpos`` positions each group and the stream converges
+        them."""
         if not self.checkpoint_dir or not os.path.isdir(self.checkpoint_dir):
             return False
         from rtap_tpu.service.checkpoint import load_group, validate_resume
@@ -783,9 +788,16 @@ class StandbyFollower:
             ck_path = group_checkpoint_path(self.checkpoint_dir, gi)
             if not os.path.isdir(ck_path):
                 continue
+            meta_path = os.path.join(ck_path, "meta.json")
             for attempt in range(attempts):
                 try:
+                    with open(meta_path, "rb") as f:
+                        round_read = f.read()
                     resumed = load_group(ck_path, mesh=grp.mesh)
+                    with open(meta_path, "rb") as f:
+                        if f.read() != round_read:
+                            raise OSError(
+                                f"{ck_path} was swapped under the read")
                     resumed.health = getattr(grp, "health", False)
                     validate_resume(resumed, ck_path, grp,
                                     allow_claimed_extras=not self.learn)

@@ -524,10 +524,10 @@ def live_loop(
     at-scale serving is many groups per chip, not one giant group: with a
     registry, each tick dispatches EVERY group before collecting ANY
     (dispatch_chunk/collect_chunk), so the device queue holds all groups'
-    step programs back to back while the host does per-group likelihood —
-    the interleaved schedule of scripts/multigroup_sched.py as the
-    production serve path. `source` values align with the registry's
-    stream registration order (contiguous per-group slices).
+    step programs back to back while the host does per-group likelihood:
+    the interleaved schedule is the production serve path. `source` values
+    align with the registry's stream registration order (contiguous
+    per-group slices).
 
     Fault containment (docs/RESILIENCE.md): a dispatch or collect
     exception QUARANTINES that group — it stops being scored, a
@@ -1143,7 +1143,7 @@ def live_loop(
             if health is not None and groups[gi].last_health is not None:
                 # fold the chunk's fused health leaves into the group's
                 # scorecard (one call per collected chunk per group; the
-                # tracker's own cost is gated by bench.py --obs-bench)
+                # tracker's own cost is gated by obs/selfbench.py's main)
                 health.fold(gi, groups[gi].last_health, tick=cur_tick)
             if predictor is not None \
                     and groups[gi].last_predict is not None:

@@ -1,10 +1,10 @@
 """Shared benchmark-measurement building blocks.
 
-bench.py (the headline number), scripts/scaling_law.py (the G-sweep), and
-__graft_entry__ (the multi-chip dry run) all drive the same workload shape:
+scripts/scaling_law.py (the G-sweep), __graft_entry__ (the multi-chip dry
+run), chip_smoke.py and the soak scripts all drive the same workload shape:
 a synthetic diurnal cluster feed through the depth-2 pipelined chunk replay.
 One implementation here, so a change to the feed or the measurement window
-can never make the bench and the scaling sweep measure different things.
+can never make two of them measure different things.
 """
 
 from __future__ import annotations

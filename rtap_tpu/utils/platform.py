@@ -26,7 +26,7 @@ def enable_compile_cache() -> str:
     ``JAX_COMPILATION_CACHE_DIR`` is set JAX already points there and no
     directory is set in code; otherwise the fixed ``<repo>/.jax_cache`` (the
     path is part of the cache key, so it never carries a pid, time or temp
-    name). serve/replay, bench.py's children, the scripts and chip_smoke.py
+    name). serve/replay, the benchmark, the scripts and chip_smoke.py
     all come through here, so they share entries.
 
     Op metadata is part of the key here (JAX strips it by default): the
@@ -105,11 +105,11 @@ class NoAcceleratorError(RuntimeError):
 
 def require_device() -> dict:
     """The device rule, for every command asked for the device path
-    (``--backend tpu`` in serve/replay/eval/nab, bench.py's children, the
-    measurement scripts): honor ``RTAP_FORCE_CPU``, bring the backend up,
-    and fail unless the platform is ``tpu`` or the CPU was chosen
-    explicitly (``RTAP_FORCE_CPU=1``, or ``JAX_PLATFORMS``/``jax_platforms``
-    set to ``cpu``). Returns :func:`device_info`."""
+    (``--backend tpu`` in serve/replay/eval/nab, the measurement scripts):
+    honor ``RTAP_FORCE_CPU``, bring the backend up, and fail unless the
+    platform is ``tpu`` or the CPU was chosen explicitly
+    (``RTAP_FORCE_CPU=1``, or ``JAX_PLATFORMS``/``jax_platforms`` set to
+    ``cpu``). Returns :func:`device_info`."""
     import jax
 
     maybe_force_cpu()

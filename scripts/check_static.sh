@@ -26,7 +26,7 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-python -m compileall -q rtap_tpu scripts bench.py
+python -m compileall -q rtap_tpu scripts
 
 python -m rtap_tpu.analysis
 

@@ -13,6 +13,7 @@ rows/s parsed on the 1-core tier-1 host AND >= 5x the JSONL TCP path).
 
     python scripts/ingest_bench.py [--records 1000000] [--streams 4096]
         [--frame-rows 4096] [--out reports/ingest_bench.json]
+    python scripts/ingest_bench.py --floor     # the CI floor, a few seconds
 """
 
 from __future__ import annotations
@@ -196,8 +197,80 @@ def shm_drive(frames: list[bytes], n_records: int, slot_map: dict) -> dict:
     return {"records_per_sec": round(n_records / dt), "wall_s": round(dt, 3)}
 
 
-def main() -> int:
+def registered_streams(n_streams: int, group_size: int) -> tuple[list[str], dict]:
+    """(ids, the real registry's slot map) for `n_streams` streams (cpu
+    backend: no device init; the bench is host-only by design — ISSUE 7's
+    provable-on-host gate)."""
+    from rtap_tpu.config import cluster_preset
+    from rtap_tpu.service.registry import StreamGroupRegistry
+
+    ids = [f"node{i // 4:04d}.m{i % 4}" for i in range(n_streams)]
+    reg = StreamGroupRegistry(cluster_preset(), group_size=group_size,
+                              backend="cpu")
+    for sid in ids:
+        reg.add_stream(sid)
+    reg.finalize()
+    return ids, reg.slot_map()
+
+
+#: `--floor`'s bars. Deliberately conservative (a shared CI host can be an
+#: order of magnitude slower than the tier-1 host's measured multi-M
+#: rows/s): they catch the binary path going quadratic or a silent
+#: fallback-to-Python, not percent-level drift.
+FLOOR_ROWS_PER_SEC = 250_000
+FLOOR_SPEEDUP = 2.0
+#: `--floor`'s scaled-down 1-core size: (binary rows, JSONL rows, streams)
+FLOOR_SIZE = (120_000, 40_000, 1024)
+
+
+def run_floor() -> int:
+    """`--floor`: JSONL vs RB1 binary vs shm-ring rows/s at `FLOOR_SIZE`
+    through the same drives as the full-size run (whose committed artifact
+    is reports/ingest_r07.json). Prints one JSON line, writes no artifact;
+    returns 1 when the floor is blown — the binary path under
+    `FLOOR_ROWS_PER_SEC`, or under `FLOOR_SPEEDUP` x the JSONL path it
+    exists to replace — so a CI run fails loudly."""
+    import subprocess
+
+    n_binary, n_jsonl, n_streams = FLOOR_SIZE
+    ids, slot_map = registered_streams(n_streams, n_streams)
+    payload = make_payload(n_jsonl, ids)
+    frames = make_frames(n_binary, slot_map, ids, frame_rows=4096)
+    try:
+        jsonl = socket_drive(True, payload, n_jsonl, ids)
+        jsonl_lane = "native"
+    except (OSError, subprocess.CalledProcessError, MemoryError):
+        # no toolchain / build failure ONLY: any other native-lane error
+        # must fail the gate, not silently soften the baseline to the
+        # ~12x-slower Python lane
+        jsonl = socket_drive(False, payload, n_jsonl, ids)
+        jsonl_lane = "python"
+    binary = binary_socket_drive(frames, n_binary, slot_map, ids)
+    shm = shm_drive(frames, n_binary, slot_map)
+    speedup = binary["records_per_sec"] / jsonl["records_per_sec"]
+    res = {
+        "metric": "ingest_bench",
+        "jsonl_lane": jsonl_lane,
+        "jsonl_rows_per_sec": jsonl["records_per_sec"],
+        "binary_rows_per_sec": binary["records_per_sec"],
+        "shm_rows_per_sec": shm["records_per_sec"],
+        "binary_vs_jsonl": round(speedup, 1),
+        "floor_rows_per_sec": FLOOR_ROWS_PER_SEC,
+        "floor_speedup": FLOOR_SPEEDUP,
+        "pass_floor": binary["records_per_sec"] >= FLOOR_ROWS_PER_SEC
+        and speedup >= FLOOR_SPEEDUP,
+    }
+    print(json.dumps(res), flush=True)
+    return 0 if res["pass_floor"] else 1
+
+
+def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--floor", action="store_true",
+                    help="the CI floor: a scaled-down run that prints one "
+                         "line, writes no artifact and exits 1 when the "
+                         "binary path is under 250k rows/s or under 2x "
+                         "JSONL (the other options do not apply)")
     ap.add_argument("--records", type=int, default=1_000_000)
     ap.add_argument("--jsonl-records", type=int, default=None,
                     help="records for the (slow) JSONL lanes; default: "
@@ -211,21 +284,12 @@ def main() -> int:
                          "100k streams at 1 s send ~12 such frames/tick)")
     ap.add_argument("--group-size", type=int, default=1024)
     ap.add_argument("--out", default=os.path.join(REPO, "reports", "ingest_bench.json"))
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    if args.floor:
+        return run_floor()
 
-    from rtap_tpu.config import cluster_preset
-    from rtap_tpu.service.registry import StreamGroupRegistry
-
-    ids = [f"node{i // 4:04d}.m{i % 4}" for i in range(args.streams)]
-    # the real registry's slot map (cpu backend: no device init; the
-    # bench is host-only by design — ISSUE 7's provable-on-host gate)
-    reg = StreamGroupRegistry(cluster_preset(),
-                              group_size=min(args.group_size, args.streams),
-                              backend="cpu")
-    for sid in ids:
-        reg.add_stream(sid)
-    reg.finalize()
-    slot_map = reg.slot_map()
+    ids, slot_map = registered_streams(
+        args.streams, min(args.group_size, args.streams))
 
     n_jsonl = args.jsonl_records or min(args.records, 300_000)
     payload = make_payload(n_jsonl, ids)

@@ -12,8 +12,8 @@ One renderer for every surface the latency layer exports (ISSUE 11):
 
 Prints ONE JSON line to stdout (the artifact contract shared with the
 benches) and a human-readable waterfall/SLO table to stderr.
-``--obs-bench-log FILE`` merges bench.py --obs-bench's gate lines into
-the output's ``obs_bench`` block — how reports/latency_r11.json carries
+``--obs-bench-log FILE`` merges the gate lines of ``python -m
+rtap_tpu.obs.selfbench`` into the output's ``obs_bench`` block — how reports/latency_r11.json carries
 its overhead evidence next to its quantiles. ``--out FILE`` also writes
 the merged report as indented JSON (the committed-artifact form).
 
@@ -148,8 +148,9 @@ def main() -> int:
     src.add_argument("--snapshot", help="obs snapshot JSONL (registry "
                                         "gauges; last line wins)")
     ap.add_argument("--obs-bench-log", default=None,
-                    help="bench.py --obs-bench output to merge (one JSON "
-                         "line per gate) — the overhead evidence block")
+                    help="python -m rtap_tpu.obs.selfbench output to merge "
+                         "(one JSON line per gate) — the overhead "
+                         "evidence block")
     ap.add_argument("--out", default=None,
                     help="also write the merged report as indented JSON "
                          "(the committed-artifact form)")

@@ -6,8 +6,8 @@ stream count actually executing through the production sharded path
 host via `--xla_force_host_platform_device_count`, since real multi-chip
 hardware is not reachable from this environment. This validates shapes,
 sharding layouts, HBM-scale state construction (~54 GiB at u16), and the
-donation path at full scale; per-chip throughput comes from bench.py on
-real silicon.
+donation path at full scale; per-chip throughput comes from the benchmark
+(`python -m benchmark.run`) on real silicon.
 
     python scripts/virtual_mesh_run.py [--streams 100000] [--devices 8]
                                        [--ticks 2] [--perm-bits 16]
@@ -90,7 +90,7 @@ def main() -> None:
         "chunk_walls_s": [round(w, 1) for w in walls],
         "peak_rss_gib": round(peak_rss, 1),
         "note": "virtual CPU mesh: validates sharded execution at scale, "
-                "not per-chip throughput (bench.py measures that)",
+                "not per-chip throughput (python -m benchmark.run measures that)",
     }), flush=True)
 
 

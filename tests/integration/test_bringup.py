@@ -172,12 +172,15 @@ def test_compile_cache_dir_env_wins_else_fixed_in_checkout(monkeypatch, env_dir)
     ("kernels and registry",
      "import rtap_tpu.ops, rtap_tpu.ops.step, rtap_tpu.ops.tm_tpu\n"
      "import rtap_tpu.service.registry, rtap_tpu.service.loop\n"),
-    ("bench.py parent through its state-bytes gate",
-     "import importlib.util\n"
-     "spec = importlib.util.spec_from_file_location('bench', 'bench.py')\n"
-     "bench = importlib.util.module_from_spec(spec)\n"
-     "spec.loader.exec_module(bench)\n"
-     "assert bench.state_bytes_gate() > 0\n"),
+    ("the state-bytes gate a launcher runs before its children: the real "
+     "arrays' byte sum against scalingmath's static derivation",
+     "import numpy as np\n"
+     "from rtap_tpu.analysis.scalingmath import derived_stream_bytes\n"
+     "from rtap_tpu.config import cluster_preset\n"
+     "from rtap_tpu.models.state import init_state\n"
+     "st = init_state(cluster_preset(perm_bits=16))\n"
+     "measured = sum(int(np.asarray(v).nbytes) for v in st.values())\n"
+     "assert measured == derived_stream_bytes('.', 16) > 0, measured\n"),
     ("serve --supervise parent",
      "import rtap_tpu.__main__ as cli\n"
      "import rtap_tpu.resilience.supervisor as sup\n"
@@ -185,7 +188,7 @@ def test_compile_cache_dir_env_wins_else_fixed_in_checkout(monkeypatch, env_dir)
      "assert cli.main(['serve', '--supervise', '--backend', 'tpu',\n"
      "                 '--streams', 'a', '--checkpoint-dir', 'ck',\n"
      "                 '--journal-dir', 'jr']) == 0\n"),
-], ids=["kernels_and_registry", "bench_parent", "serve_supervise_parent"])
+], ids=["kernels_and_registry", "state_bytes_gate", "serve_supervise_parent"])
 def test_launcher_parents_leave_the_backend_uninitialized(what, code):
     """A parent that touched JAX would hold the chip its child needs: the
     imports and launcher paths below must not initialize any backend (no

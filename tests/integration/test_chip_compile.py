@@ -257,9 +257,10 @@ def test_nab_width_step_scatters_whole_rows_only(v5e):
 def test_nab_width_chunk_step_fits_a_v5e_only_in_the_wide_row_forms(v5e, nab_chunk, forms, monkeypatch):
     """The published NAB width (2048 x 32 x 16 x 32: 16,384-lane pool rows)
     at the benchmark cell's batch of 17 streams. In the form the shape rule
-    picks (tm_tpu.wide_rows: indexed row moves; [C, M, K*S] pools in a scan
-    over ticks, the public layout in a one-tick program) the program fits
-    the chip; in the narrow-row form the cluster
+    picks (tm_tpu.wide_rows: indexed row moves over [C, M, K*S] pools) the
+    program fits the chip — a scan handed the public tree, and the served
+    one-tick program on the resident tree a group holds; in the narrow-row
+    form the cluster
     presets run — the line moved over this shape, here — it does not: the
     chip's compiler refuses it for memory, or passes it at more bytes than
     the chip has. The reason the line exists."""
@@ -283,18 +284,19 @@ def test_nab_width_chunk_step_fits_a_v5e_only_in_the_wide_row_forms(v5e, nab_chu
         return
     if forms == "by_shape_one_tick":
         # the served tick's program (`StreamGroup` calls chunk_step at T = 1
-        # in the live loop): with no later tick to win the adapters' four
-        # transposes back on, and both layouts of both pools live together
-        # if it took them (13.7 GB, 8.9 of them temporaries; 96.9 ms a tick
-        # for 75.7 on the chip, ISSUE 40), it keeps the public layout in the
-        # kernel (tm_tpu.public_in_kernel) and the 2,934,913,024 B of
-        # temporaries it had before: 7.72 GB, about 34 streams a chip
-        compiled = chunk_step.lower(*_step_args(cfg, v5e, T=1, g=17), cfg,
+        # in the live loop) on the resident tree the group holds: the
+        # parameters are the carry, so neither layout of a pool stands
+        # twice. (Handed the public tree, a one-tick program transposes both
+        # pools in and out with both layouts live together — 13.7 GB, 8.9 of
+        # them temporaries, 96.9 ms a tick on the chip, ISSUE 40; the form
+        # that ran the kernel on the public layout instead, 7.72 GB and
+        # 75.7 ms, went at ISSUE 50 because no owner of a state hands one.)
+        compiled = chunk_step.lower(*_resident_args(cfg, v5e, 1, 17), cfg,
                                     learn=True).compile()
         mem = compiled.memory_analysis()
-        assert mem.temp_size_in_bytes <= 2_934_913_024
-        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 7.73 * 10 ** 9
-        assert not re.findall(r"\[17,2048,32,512\]", compiled.as_text())
+        assert mem.temp_size_in_bytes < 10 ** 9
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 5.8 * 10 ** 9
+        assert re.findall(r"\[17,2048,32,512\]", compiled.as_text())
         return
     args = _step_args(cfg, v5e, T=2, g=17)
     monkeypatch.setattr(tm_tpu, "WIDE_ROW_LANES", 1 << 30)
@@ -407,12 +409,13 @@ def test_resident_state_crosses_the_program_boundary_with_no_pool_copy(v5e, nab_
 def test_public_layout_input_lowers_to_the_parents_program(v5e, preset):
     """Handed the public layout, every entry point is the program it was
     before the state could stay resident: `chunk_step` (T = 1 and 2) and
-    `group_step` lower to the text of the parent commit's bodies — the
-    adapters under `rtap.layout` around the same scan / tick, written out
-    here as that commit had them (by sha256 against the parent's own
-    checkout when the change was made: PERF.md §6, PR 44)."""
+    `group_step` lower to the text of the bodies written out here — the
+    adapters under `rtap.layout` around the same scan / tick, as the commit
+    before ISSUE 44 had them (by sha256 against that commit's own checkout
+    when the change was made: PERF.md §6, PR 44), at every program length
+    since ISSUE 50 (the NAB width's one-tick programs transpose too)."""
     import rtap_tpu.ops.step as step
-    from rtap_tpu.ops.tm_tpu import from_kernel_layout, tm_invariants, to_kernel_layout
+    from rtap_tpu.ops.tm_tpu import public_form, resident_form, tm_invariants
 
     cfg = {"cluster": cluster_preset, "scaled32": lambda: scaled_cluster_preset(32),
            "node3": lambda: node_preset(3),
@@ -425,20 +428,19 @@ def test_public_layout_input_lowers_to_the_parents_program(v5e, preset):
             v, t = inp
             return step._tick(s, v, t, cfg, True, inv, health=False, predict=False)
 
-        T = values.shape[0]
         with jax.named_scope("rtap.layout"):
-            state = to_kernel_layout(state, cfg.tm, T)
+            state = resident_form(state, cfg.tm)
         state, out = jax.lax.scan(body, state, (values, ts_unix))
         with jax.named_scope("rtap.layout"):
-            return from_kernel_layout(state, cfg.tm, T), out
+            return public_form(state, cfg.tm), out
 
     def group_step(state, values, ts_unix):  # the parent's group_step
         with jax.named_scope("rtap.layout"):
-            state = to_kernel_layout(state, cfg.tm)
+            state = resident_form(state, cfg.tm)
         state, out = step._tick(state, values, ts_unix, cfg, True,
                                 health=False, predict=False)
         with jax.named_scope("rtap.layout"):
-            return from_kernel_layout(state, cfg.tm), out
+            return public_form(state, cfg.tm), out
 
     g = 4
     for T in (1, 2, None):

@@ -48,12 +48,21 @@ def _free_port() -> int:
 
 def test_failover_soak_two_kills_and_fence_round(tmp_path):
     """The in-tree acceptance smoke: 2 SIGKILLs + 1 SIGSTOP fence round;
-    the soak's exit code IS the verdict (5 = availability violated)."""
+    the soak's exit code IS the verdict (5 = availability violated).
+
+    The drill runs in real time (0.25 s ticks) and delivers each kill at
+    its seeded tick or, where the standby is not yet warm there, at the
+    first later tick where it is (a restarted child boots and catches up
+    in some 30 ticks on an idle host, more beside five other test
+    workers). 200 ticks leave the three takeovers that room; the kill
+    that found no warm standby inside them fails the run and says so.
+    What a standby does when promoted from BEHIND has its own case,
+    tests/unit/test_replicate.py (ROADMAP D17)."""
     out = str(tmp_path / "report.json")
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "scripts", "failover_soak.py"),
          "--seed", "3", "--kills", "2", "--streams", "6",
-         "--group-size", "3", "--ticks", "80", "--cadence", "0.25",
+         "--group-size", "3", "--ticks", "200", "--cadence", "0.25",
          "--checkpoint-every", "6", "--backend", "cpu",
          "--workdir", str(tmp_path / "w"), "--out", out],
         env=_env(), capture_output=True, text=True, timeout=540)
@@ -62,6 +71,8 @@ def test_failover_soak_two_kills_and_fence_round(tmp_path):
     report = json.load(open(out))
     assert report["verified"], report["failures"]
     assert len(report["kills"]) == 2
+    # each kill at its seeded tick or later, never before
+    assert all(k["tick"] >= k["target"] for k in report["kills"])
     # every SCHEDULED takeover inside the 10-tick detection budget —
     # report["verified"] above already enforced it per kill/fence
     # anchor; here just pin that all three takeovers left their record
@@ -78,6 +89,22 @@ def test_failover_soak_two_kills_and_fence_round(tmp_path):
     assert report["fence_round"]["rc"] == 7
     assert report["fenced_exits"], "no child reported a fenced exit"
     assert all(s["fenced_line_drops"] >= 1 for s in report["fenced_exits"])
+
+
+@pytest.mark.parametrize("mirror, leader, left_at, warm", [
+    (59, 60, -1, True),    # a row behind: mirrored, being scored
+    (60, 60, -1, True),
+    (57, 60, -1, False),   # still backfilling
+    (-1, 0, -1, False),    # no mirror yet (booting), leader at its first tick
+    (47, 48, 47, False),   # the journal its last life left, not a mirror
+    (49, 50, 47, True),    # ... outrun: these rows are the new leader's
+], ids=["a_row_behind", "level", "backfilling", "booting",
+        "last_lifes_journal", "outran_its_last_life"])
+def test_the_killer_tells_a_warm_standby_from_a_cold_one(
+        mirror, leader, left_at, warm):
+    from scripts.failover_soak import standby_is_warm
+
+    assert standby_is_warm(mirror, leader, left_at) is warm
 
 
 def test_serve_cli_leader_standby_pair(tmp_path):

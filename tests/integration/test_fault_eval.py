@@ -106,7 +106,7 @@ def test_probation_alignment():
 @pytest.fixture(scope="module")
 def streaming_report():
     """The PRODUCTION configuration (streaming likelihood, exactly as the
-    preset, bench.py, and the 100k path run it) at 40x1000 — shared by the
+    preset and the 100k path run it) at 40x1000 — shared by the
     k=1 floors and the cadence comparison below."""
     from rtap_tpu.config import cluster_preset
 

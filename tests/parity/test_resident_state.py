@@ -15,7 +15,7 @@ import rtap_tpu.ops.tm_tpu as tm_tpu
 from rtap_tpu.config import cluster_preset, node_preset, scaled_cluster_preset, scaled_nab_preset
 from rtap_tpu.models.state import init_state
 from rtap_tpu.ops.resident import host_resident
-from rtap_tpu.ops.step import chunk_step, fused_step, group_step, replicate_state
+from rtap_tpu.ops.step import _enter_kernel, chunk_step, fused_step, group_step, replicate_state
 
 G, TICKS = 2, 16
 
@@ -125,10 +125,27 @@ def test_round_trip_is_the_identity_on_every_leaf(case):
             assert turned["presyn"].shape == (
                 (*lead, C, M, K * S) if wide else (*lead, C, K * S * M))
             assert turned["seg_last"].shape == (*lead, C, K * S)
-            # the adapters pass a resident tree untouched, whatever the ticks
-            for ticks in (1, 8):
-                assert tm_tpu.to_kernel_layout(turned, tm, ticks) is turned
+            # a program's boundary passes a resident tree untouched, both ways
+            entered, leave = _enter_kernel(turned, cfg)
+            assert entered is turned and leave(turned) is turned
             back = tm_tpu.public_form(turned, tm)
             assert set(back) == set(tree)
             for k in tree:
                 np.testing.assert_array_equal(np.asarray(back[k]), tree[k], err_msg=k)
+
+
+def test_the_drivers_entry_traces_and_hands_back_the_public_shapes():
+    """`__graft_entry__.py:entry()` is the one caller outside the tests that
+    hands a WIDE public tree to a one-tick program (`fused_step` at the NAB
+    width, one stream): the program converts it at its boundary and gives
+    it back public, leaf for leaf — traced only, nothing runs."""
+    import __graft_entry__ as graft
+
+    fn, args = graft.entry()
+    state = args[0]
+    assert state["presyn"].ndim == 4 and not tm_tpu.kernel_resident(state)
+    stepped, raw = jax.eval_shape(fn, *args)
+    assert raw.shape == () and raw.dtype == jnp.float32
+    assert stepped.keys() == state.keys()
+    for k, v in state.items():
+        assert (stepped[k].shape, stepped[k].dtype) == (v.shape, v.dtype), k

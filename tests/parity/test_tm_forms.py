@@ -1,9 +1,8 @@
 """The TM step has two forms and `tm_tpu.wide_rows(cfg)` picks one from the
 static shape: below `WIDE_ROW_LANES` synapse lanes a pool row, one-hot matmul
-moves over flat pools; at or above it, indexed moves over pools a scan over
-ticks holds [C, M, K*S] (a column's row contiguous) and a one-tick program
-leaves in the public [C, K, S, M] (`to_kernel_layout` / `from_kernel_layout`
-decide, `tm_tpu.public_in_kernel`).
+moves over flat pools; at or above it, indexed moves over [C, M, K*S] pools
+(a column's row contiguous), whatever the program's length (`resident_form` /
+`public_form` convert a public [C, K, S, M] tree at a program's boundary).
 Within the narrow-row form `tm_tpu.gather_by_select(cfg)` picks the workspace
 gather the same way: a compare-select reduce in the pools' own types where a
 row fills whole 128-lane tiles (128, 384, 512 lanes here), the one-hot matmul
@@ -189,17 +188,18 @@ def test_gather_equals_the_oracle_through_the_group_programs(rows, program):
     assert (np.asarray(dev["presyn"]) >= 0).sum() > 100 * G  # they really learned
 
 
-@pytest.mark.parametrize("ticks", [1, 8], ids=["one_tick", "a_scan"])
+@pytest.mark.parametrize("home", ["numpy", "device"])
 @pytest.mark.parametrize("lead", ["one_stream", "a_group"])
 @pytest.mark.parametrize("rows", ["wide", "lanes192", "lanes384"])
-def test_layout_adapters_round_trip_leaf_for_leaf(rows, lead, ticks):
-    """`from_kernel_layout(to_kernel_layout(s)) == s`, every leaf's values,
-    shape and type, on a learned state (so the pools hold distinct values
-    wherever a transposition could misplace one) — one stream's state, as
-    `fused_step` hands it over, and a group's with its leading G axis, as
-    `group_step` / `chunk_step` do. In between, the pools have the kernel's
-    shape: [C, K*S*M] at narrow rows; at wide ones [C, M, K*S] for a scan
-    over ticks and the public shape, untouched, for a one-tick program."""
+def test_layout_adapters_round_trip_leaf_for_leaf(rows, lead, home):
+    """`public_form(resident_form(s)) == s`, every leaf's values, shape and
+    type, on a learned state (so the pools hold distinct values wherever a
+    transposition could misplace one) — one stream's state, as `fused_step`
+    hands it over, and a group's with its leading G axis, as `group_step` /
+    `chunk_step` do; with the leaves numpy's (the host-side view a
+    checkpoint or a fresh row goes through) and the device's, as
+    `resident_leaf` promises both. In between, the pools have the kernel's
+    shape: [C, K*S*M] at narrow rows, [C, M, K*S] at wide ones."""
     from rtap_tpu.ops.step import replicate_state
 
     cfg = form_cfg(rows, 16)
@@ -218,52 +218,69 @@ def test_layout_adapters_round_trip_leaf_for_leaf(rows, lead, ticks):
     tm = cfg.tm
     K, S, M = tm.cells_per_column, tm.max_segments_per_cell, tm.max_synapses_per_segment
     C = cfg.sp.columns
-    assert tm_tpu.public_in_kernel(tm, ticks) == (rows == "wide" and ticks == 1)
-    kernel = tm_tpu.to_kernel_layout(state, tm, ticks)
+    handed = jax.device_put(state) if home == "device" else state
+    kernel = tm_tpu.resident_form(handed, tm)
+    leaf_type = jax.Array if home == "device" else np.ndarray
+    assert all(isinstance(kernel[k], leaf_type) for k in tm_tpu._KERNEL_KEYS)
     g = (3,) if lead == "a_group" else ()
-    if tm_tpu.public_in_kernel(tm, ticks):
-        assert kernel is state
-    else:
-        want = (C, M, K * S) if rows == "wide" else (C, K * S * M)
-        assert kernel["presyn"].shape == kernel["syn_perm"].shape == g + want
-        assert kernel["seg_last"].shape == kernel["seg_pot"].shape == g + (C, K * S)
-        # the kernel's slot [c, m, k*S + s] is the public one [c, k, s, m]
-        pub = state["presyn"].reshape(*g, C, K * S, M)
-        if rows == "wide":
-            np.testing.assert_array_equal(kernel["presyn"][..., 5, 7, 9], pub[..., 5, 9, 7])
-    back = tm_tpu.from_kernel_layout(kernel, tm, ticks)
+    want = (C, M, K * S) if rows == "wide" else (C, K * S * M)
+    assert kernel["presyn"].shape == kernel["syn_perm"].shape == g + want
+    assert kernel["seg_last"].shape == kernel["seg_pot"].shape == g + (C, K * S)
+    assert tm_tpu.kernel_resident(kernel) and not tm_tpu.kernel_resident(handed)
+    # the kernel's slot [c, m, k*S + s] is the public one [c, k, s, m]
+    pub = state["presyn"].reshape(*g, C, K * S, M)
+    if rows == "wide":
+        np.testing.assert_array_equal(np.asarray(kernel["presyn"])[..., 5, 7, 9],
+                                      pub[..., 5, 9, 7])
+    back = tm_tpu.public_form(kernel, tm)
     assert back.keys() == state.keys()
     for k, v in state.items():
         assert back[k].shape == v.shape and back[k].dtype == v.dtype, k
         np.testing.assert_array_equal(np.asarray(back[k]), v, err_msg=k)
 
 
-@pytest.mark.parametrize("rows", ["wide", "narrow"])
-def test_tm_step_refuses_a_state_in_another_forms_layout(rows):
-    """Either form runs on the layouts its adapters hand it and says so when
-    handed another, instead of computing on misread axes: the narrow form
-    refuses the public [C, K, S, M] pools; the wide one takes them (a
-    one-tick program's) and [C, M, K*S] (a scan's), and refuses flat pools
-    and a state half turned."""
+def _another_forms_layout(handed: str, public: dict, turned: dict, cfg) -> dict:
+    """A TM state as a caller gets it wrong, from the public tree and the
+    resident one of the same values."""
+    C = cfg.sp.columns
+    tm = cfg.tm
+    KS, M = tm.cells_per_column * tm.max_segments_per_cell, tm.max_synapses_per_segment
+    pools = ("presyn", "syn_perm")
+    return {
+        "public": public,
+        # the other side of the line's pools: flat rows handed to the wide
+        # step, [C, M, K*S] ones to the narrow
+        "other_rows_pools": {**turned, **{
+            k: (public[k].reshape(C, -1) if tm_tpu.wide_rows(tm)
+                else np.swapaxes(public[k].reshape(C, KS, M), 1, 2)) for k in pools}},
+        # half turned: pools resident and a segment tensor public, and the reverse
+        "public_segments": {**turned, "seg_last": public["seg_last"]},
+        "public_pools": {**public, "seg_last": turned["seg_last"]},
+    }[handed]
+
+
+@pytest.mark.parametrize("handed", ["public", "other_rows_pools", "public_segments",
+                                    "public_pools"])
+@pytest.mark.parametrize("rows", ["wide", "narrow", "wide-equal", "wide-over"])
+def test_tm_step_refuses_a_state_in_another_forms_layout(rows, handed):
+    """Either form runs on ONE layout — `resident_form`'s — and says so when
+    handed another, instead of computing on misread axes: the public
+    [C, K, S, M] tree (ops/step.py converts it at a program's boundary; no
+    program length makes the kernel take it), the pools of the other side of
+    the line, and a state half turned — where `learn_cap` cuts the
+    workspace's rows and where the rows learn in place."""
     from tests.parity.test_tm_parity import TM_KEYS
 
     cfg = form_cfg(rows, 16)
+    shape = rows.partition("-")[0]
     st = init_state(cfg, 3)
     public = {k: np.asarray(st[k]) for k in TM_KEYS}
     active = np.zeros(cfg.sp.columns, bool)
-    turned = tm_tpu.to_kernel_layout(public, cfg.tm, 8)
-    if rows == "narrow":
-        wrong = [public]
-    else:
-        flat = {**turned, **{k: public[k].reshape(cfg.sp.columns, -1)
-                             for k in ("presyn", "syn_perm")}}
-        wrong = [flat, {**turned, "seg_last": public["seg_last"]},
-                 {**public, "seg_last": turned["seg_last"]}]
-    for state in wrong:
-        with pytest.raises(ValueError, match=rf"{rows} pool rows.*kernel-layout"):
-            tm_tpu.tm_step(state, active, cfg.tm, learn=True)
-    for ticks in (1, 8):
-        tm_tpu.tm_step(tm_tpu.to_kernel_layout(public, cfg.tm, ticks), active, cfg.tm, learn=True)
+    turned = tm_tpu.resident_form(public, cfg.tm)
+    with pytest.raises(ValueError, match=rf"{shape} pool rows.*resident_form"):
+        tm_tpu.tm_step(_another_forms_layout(handed, public, turned, cfg), active,
+                       cfg.tm, learn=True)
+    tm_tpu.tm_step(turned, active, cfg.tm, learn=True)
 
 
 PRESETS = {"node3": lambda: node_preset(3), "scaled32": lambda: scaled_cluster_preset(32),
@@ -293,7 +310,7 @@ def _lowered_tm_step(cfg) -> str:
     from tests.parity.test_tm_parity import TM_KEYS
 
     st = init_state(cfg, 0)
-    state = tm_tpu.to_kernel_layout({k: np.asarray(st[k]) for k in TM_KEYS}, cfg.tm)
+    state = tm_tpu.resident_form({k: np.asarray(st[k]) for k in TM_KEYS}, cfg.tm)
     return tm_tpu.tm_step.lower(state, np.zeros(cfg.sp.columns, bool), cfg.tm,
                                 learn=True).as_text()
 
@@ -335,7 +352,7 @@ from rtap_tpu.models.state import init_state
 from tests.parity.test_tm_parity import TM_KEYS
 cfg = scaled_cluster_preset(32)
 st = init_state(cfg, 0)
-state = tm_tpu.to_kernel_layout({k: jnp.asarray(st[k]) for k in TM_KEYS}, cfg.tm)
+state = tm_tpu.resident_form({k: jnp.asarray(st[k]) for k in TM_KEYS}, cfg.tm)
 text = tm_tpu.tm_step.lower(state, jnp.zeros(cfg.sp.columns, bool), cfg.tm, learn=True).as_text()
 print("SHA", hashlib.sha256(text.encode()).hexdigest(), len(text))
 """

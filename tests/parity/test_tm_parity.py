@@ -20,7 +20,7 @@ from rtap_tpu.config import TMConfig
 from rtap_tpu.models.oracle.temporal_memory import TMOracle
 from rtap_tpu.models.perm import tm_domain
 from rtap_tpu.ops import tm_tpu
-from rtap_tpu.ops.tm_tpu import from_kernel_layout, tm_step, to_kernel_layout
+from rtap_tpu.ops.tm_tpu import public_form, resident_form, tm_step
 
 TM_KEYS = (
     "presyn", "syn_perm", "seg_last", "active_seg", "matching_seg",
@@ -54,36 +54,26 @@ def _assert_state_equal(host, dev, step):
         )
 
 
-#: how many ticks the layout adapters are told the program runs: 1 keeps the
-#: public layout in the wide-row kernel (the served one-tick programs), more
-#: turns the pools [C, M, K*S] (a chunk's scan). The `rows` fixture sets it.
-_TICKS = 1
-
-
-@pytest.fixture(params=["narrow", "wide", "wide_one_tick"])
+@pytest.fixture(params=["narrow", "wide"])
 def rows(request, monkeypatch):
     """Every scenario in both forms of the step at ITS OWN shape (the pools
     must fill for the eviction branches, which a shape wide by itself never
     does here): the line between the forms is moved under the shape, and the
-    caches cleared because the form is read at trace time. The wide form in
-    both layouts it runs on: [C, M, K*S] pools as a chunk's scan holds them
-    ("wide"), the public layout as a one-tick program does."""
+    caches cleared because the form is read at trace time."""
     monkeypatch.setattr(tm_tpu, "WIDE_ROW_LANES",
                         1 << 30 if request.param == "narrow" else 1)
-    monkeypatch.setattr(
-        "tests.parity.test_tm_parity._TICKS", 2 if request.param == "wide" else 1)
     jax.clear_caches()
     yield request.param
     jax.clear_caches()
 
 
-def _run_parity(C, cfg, sequences, learn=True, host=None, ticks=None):
-    ticks = _TICKS if ticks is None else ticks
+def _run_parity(C, cfg, sequences, learn=True, host=None):
     host = _init_tm_state(C, cfg) if host is None else host
     # the public [C, K, S, M] layout crosses the boundary via the same
-    # reshape adapters ops/step.py uses
-    dev = to_kernel_layout(
-        {k: jnp.asarray(v) for k, v in copy.deepcopy(host).items()}, cfg, ticks)
+    # adapters ops/step.py uses, once each way; between the steps the state
+    # is resident, as an owner of a state holds it
+    dev = resident_form(
+        {k: jnp.asarray(v) for k, v in copy.deepcopy(host).items()}, cfg)
     oracle = TMOracle(host, cfg)
     for step, cols in enumerate(sequences):
         active = np.zeros(C, bool)
@@ -91,7 +81,7 @@ def _run_parity(C, cfg, sequences, learn=True, host=None, ticks=None):
         raw_host = oracle.compute(active, learn=learn)
         dev, raw_dev = tm_step(dev, jnp.asarray(active), cfg, learn=learn)
         assert abs(raw_host - float(raw_dev)) < 1e-6, f"raw score step {step}"
-        _assert_state_equal(host, from_kernel_layout(dev, cfg, ticks), step)
+        _assert_state_equal(host, public_form(dev, cfg), step)
 
 
 def _pattern(rng, C, n_active):
@@ -170,16 +160,14 @@ def test_tm_parity_workspace_slots_at_their_edges(rows, compact_paths, slots):
 
 
 @pytest.mark.quick
-@pytest.mark.parametrize("S,M,wide,select,ticks", [
-    (2, 6, False, False, 1), (16, 32, True, False, 2), (16, 32, True, False, 1),
-    (4, 8, False, True, 1), (4, 12, False, False, 1), (8, 12, False, True, 1),
-], ids=["narrow", "wide", "wide_one_tick", "lanes128", "lanes192", "lanes384"])
-def test_tm_parity_explicit_layouts(S, M, wide, select, ticks):
+@pytest.mark.parametrize("S,M,wide,select", [
+    (2, 6, False, False), (16, 32, True, False),
+    (4, 8, False, True), (4, 12, False, False), (8, 12, False, True),
+], ids=["narrow", "wide", "lanes128", "lanes192", "lanes384"])
+def test_tm_parity_explicit_layouts(S, M, wide, select):
     """Full state parity in BOTH forms where the shape itself picks the form
     (the other tests move the line under one shape): 48 lanes a row, and
-    2,048 — there in both layouts the wide form runs on, [C, M, K*S] as a
-    chunk's scan holds the pools and the public one of a one-tick program —
-    and, within the narrow form, under both workspace gathers where
+    2,048 — and, within the narrow form, under both workspace gathers where
     the shape picks the gather: the compare-select reduce at rows of whole
     128-lane tiles (128, 384), the one-hot matmul at 192 (and at 48)."""
     C, cfg = 32, TMConfig(
@@ -191,7 +179,7 @@ def test_tm_parity_explicit_layouts(S, M, wide, select, ticks):
     assert tm_tpu.gather_by_select(cfg) == select
     rng = np.random.default_rng(29)
     seq = [_pattern(rng, C, 4) for _ in range(60)]
-    _run_parity(C, cfg, seq, ticks=ticks)
+    _run_parity(C, cfg, seq)
 
 
 @pytest.mark.parametrize("perm", ["u16", "u8", "f32"])
@@ -389,9 +377,8 @@ def test_best_matching_mask_is_the_oracles_choice(case, rows):
 def test_forms_agree_when_learning_overflows(compact_paths, monkeypatch, cut):
     """Past `learn_cap` the oracle (which has no cap) is no yardstick, but
     the two forms still are for each other: the first `learn_cap` learning
-    segments learn and are stamped, the rest wait. The wide form (in either
-    layout of its pools) names the stamped rows by a compare against the
-    largest compacted id, the narrow
+    segments learn and are stamped, the rest wait. The wide form names the
+    stamped rows by a compare against the largest compacted id, the narrow
     one by the compacted ids' one-hot rows; same rows, same state. Past
     `col_cap` likewise: the first `col_cap` active columns enter the
     workspace — by index at wide rows, every slot a column and none a fill,
@@ -403,21 +390,19 @@ def test_forms_agree_when_learning_overflows(compact_paths, monkeypatch, cut):
                                 else {"learn_cap": 32, "col_cap": 4}),
     )
     finals = {}
-    for form, lanes, ticks in (("wide", 1, 2), ("wide_one_tick", 1, 1),
-                               ("narrow", 1 << 30, 1)):
+    for form, lanes in (("wide", 1), ("narrow", 1 << 30)):
         monkeypatch.setattr(tm_tpu, "WIDE_ROW_LANES", lanes)
         jax.clear_caches()
-        dev = to_kernel_layout(
-            {k: jnp.asarray(v) for k, v in _init_tm_state(C, cfg).items()}, cfg, ticks)
+        dev = resident_form(
+            {k: jnp.asarray(v) for k, v in _init_tm_state(C, cfg).items()}, cfg)
         rng = np.random.default_rng(5)
         for _ in range(40):
             active = np.zeros(C, bool)
             active[_pattern(rng, C, 6)] = True
             dev, _ = tm_step(dev, jnp.asarray(active), cfg, learn=True)
-        finals[form] = jax.device_get(from_kernel_layout(dev, cfg, ticks))
+        finals[form] = jax.device_get(public_form(dev, cfg))
     jax.clear_caches()
     assert int(finals["wide"]["tm_overflow"]) > 0  # the cap really cut
-    for form in ("wide", "wide_one_tick"):
-        for key in TM_KEYS:
-            np.testing.assert_array_equal(finals[form][key], finals["narrow"][key],
-                                          err_msg=f"{form} {key}")
+    for key in TM_KEYS:
+        np.testing.assert_array_equal(finals["wide"][key], finals["narrow"][key],
+                                      err_msg=key)

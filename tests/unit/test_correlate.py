@@ -304,7 +304,7 @@ class TestResume:
 
 @pytest.mark.quick
 def test_correlator_fold_overhead_within_one_percent_of_tick_budget():
-    """The CI twin of the bench.py --obs-bench bar: even at the
+    """The CI twin of the python -m rtap_tpu.obs.selfbench bar: even at the
     alert-storm ceiling (a full blast radius folding every tick with
     every cluster window open) the correlator stays host-noise."""
     from rtap_tpu.obs.selfbench import measure_correlate

@@ -289,7 +289,7 @@ def test_build_info_gauge_and_config_hash():
 
 # ------------------------------------------------------------- budget --
 def test_fleet_publisher_overhead_within_one_percent_of_tick_budget():
-    """The CI twin of the bench.py --obs-bench bar: even at the soak
+    """The CI twin of the python -m rtap_tpu.obs.selfbench bar: even at the soak
     push density (two full snapshot builds per tick over a populated
     registry and full sketch windows) the fleet publisher stays host-
     noise, and note_tick — the only fleet op ON the tick path — is one

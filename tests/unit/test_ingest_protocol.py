@@ -513,14 +513,28 @@ def test_listener_close_joins_threads_deterministically():
             c.close()
 
 
-def test_announce_leader_repoints_producers():
+@pytest.mark.parametrize("handler", ["prompt", "descheduled_after_hello"])
+def test_announce_leader_repoints_producers(handler, monkeypatch):
     """ISSUE 8: a fenced old leader pushes a MAP naming its successor;
     connected producers pick up the __leader__ hint (and the epoch bump
-    makes their next stale-coded frame go loudly deaf here)."""
+    makes their next stale-coded frame go loudly deaf here). A producer
+    counts as connected from its hello on: the listener registers it for
+    pushes BEFORE sending the hello, so a push that follows the hello at
+    once reaches it even if the handler thread is descheduled right there
+    (six test workers on a busy host did that, and the push went to
+    nobody — ISSUE 50)."""
     import time
 
     from rtap_tpu.ingest.emit import BinaryFeedConnection
 
+    if handler == "descheduled_after_hello":
+        send_map = BinaryBatchSource._send_map
+
+        def hello_then_stall(self, sock):
+            send_map(self, sock)
+            time.sleep(0.5)
+
+        monkeypatch.setattr(BinaryBatchSource, "_send_map", hello_then_stall)
     reg = _reg(n=4, group_size=4)
     src = BinaryBatchSource(reg.slot_map()).start()
     try:
@@ -533,6 +547,102 @@ def test_announce_leader_repoints_producers():
                 time.sleep(0.01)
             assert conn.leader_hint == "127.0.0.1:12345"
             assert conn.epoch == e0 + 1
+    finally:
+        src.close()
+
+
+@pytest.mark.parametrize("at", ["send_lock", "frame"])
+@pytest.mark.parametrize("stalled", ["hello", "push"])
+@pytest.mark.parametrize("push", ["announce_leader", "set_slot_map"])
+def test_a_push_racing_a_hello_leaves_the_producer_on_the_newest_map(
+        push, stalled, at, monkeypatch):
+    """Whatever a producer receives LAST is the newest map: which blob a
+    MAP frame carries and when it is sent are decided under one lock. One
+    of the two — the hello of a connecting producer, or a push to the
+    connected ones — is descheduled once, on its way into the send lock or
+    building its frame (between reading the map and sending it, in one
+    order of the locks or the other), while a push runs whole. Read under one lock and sent under another,
+    the stalled one followed the newer map with its own older one and the
+    producer ended on a stale epoch (ISSUE 50's review)."""
+    import threading
+    import time
+
+    from rtap_tpu.ingest import server
+    from rtap_tpu.ingest.emit import BinaryFeedConnection
+
+    reg = _reg(n=4, group_size=4, reserve=4)
+    src = BinaryBatchSource(reg.slot_map()).start()
+
+    def do_push(tag):
+        if push == "announce_leader":
+            src.announce_leader(f"127.0.0.1:{tag}")
+        else:
+            reg.add_stream(f"pushed.{tag}")
+            src.set_slot_map(reg.slot_map())
+
+    build_frame = server.build_frame
+    main = threading.current_thread()
+    stalling = threading.Event()
+
+    def stall(point):
+        # once, at `at`, on the stalled side only: the handler thread for
+        # a hello, this thread for a push — never the push that runs whole
+        me = threading.current_thread()
+        if point != at or stalling.is_set() or me.name == "rtap-test-push" \
+                or (me is main) != (stalled == "push"):
+            return
+        stalling.set()
+        time.sleep(0.3)
+
+    class StallsBeforeTaking:
+        def __init__(self, lock):
+            self.lock = lock
+
+        def __enter__(self):
+            stall("send_lock")
+            return self.lock.__enter__()
+
+        def __exit__(self, *exc):
+            return self.lock.__exit__(*exc)
+
+    def build_frame_after_stall(kind, blob):
+        stall("frame")
+        return build_frame(kind, blob)
+
+    def arm():
+        src._send_lock = StallsBeforeTaking(src._send_lock)
+        monkeypatch.setattr(server, "build_frame", build_frame_after_stall)
+
+    try:
+        if stalled == "hello":
+            arm()
+            other = threading.Thread(
+                target=lambda: (stalling.wait(10), do_push(1)),
+                name="rtap-test-push")
+            other.start()
+            conn = BinaryFeedConnection(src.address)
+            other.join(10)
+        else:
+            # the producer is connected: the push that stalls is overtaken
+            # by a second, whole one
+            conn = BinaryFeedConnection(src.address)
+            arm()
+            other = threading.Thread(
+                target=lambda: (stalling.wait(10), do_push(2)),
+                name="rtap-test-push")
+            other.start()
+            do_push(1)
+            other.join(10)
+        with conn:
+            deadline = time.time() + 1.0
+            while time.time() < deadline:  # drain whatever follows the hello
+                conn.poll_map()
+                time.sleep(0.02)
+            assert conn.epoch == src._map_epoch
+            if push == "announce_leader":
+                assert conn.leader_hint == src._leader_addr
+            else:
+                assert set(conn.code_of) == set(reg.slot_map())
     finally:
         src.close()
 

@@ -4,7 +4,7 @@ docs/TELEMETRY.md is the operator-facing catalog of every `rtap_obs_*`
 instrument; it went stale twice in past PRs before anyone noticed.
 This gate makes drift a test failure in BOTH directions:
 
-- every metric name registered in code (rtap_tpu/, scripts/, bench.py)
+- every metric name registered in code (rtap_tpu/, scripts/)
   must appear in docs/TELEMETRY.md, and
 - every metric name the catalog's tables document must exist in code
   (a doc row for a deleted metric is a lie operators will alert on).
@@ -31,7 +31,7 @@ _DOC_ROW = re.compile(r"^\|\s*`(rtap_obs_[a-z0-9_]+)`", re.MULTILINE)
 def _code_names() -> set[str]:
     names: set[str] = set()
     roots = [os.path.join(REPO, "rtap_tpu"), os.path.join(REPO, "scripts")]
-    files = [os.path.join(REPO, "bench.py")]
+    files = []
     for root in roots:
         for dirpath, _dirs, fns in os.walk(root):
             files.extend(os.path.join(dirpath, fn)

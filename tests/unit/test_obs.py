@@ -237,7 +237,7 @@ def test_watchdog_checkpoint_stall():
 def test_obs_overhead_within_one_percent_of_tick_budget():
     """Acceptance bar (ISSUE 1): a full tick's instrument traffic costs
     <= 1% of the 1 s cadence budget. Measured, not assumed — the same
-    measurement bench.py --obs-bench ships. Typical hosts land 3-4 orders
+    measurement python -m rtap_tpu.obs.selfbench ships. Typical hosts land 3-4 orders
     of magnitude under the bar, so this does not flake on slow CI."""
     res = measure(n=5000)
     assert res["per_tick_overhead_frac"] <= 0.01, res

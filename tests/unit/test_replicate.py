@@ -435,6 +435,100 @@ def test_follower_discards_divergent_local_tail(tmp_path):
     sj.close()
 
 
+def test_follower_never_adopts_a_checkpoint_swapped_under_its_read(
+        tmp_path, monkeypatch):
+    """The standby reads the shared checkpoints while the LIVE leader
+    saves to them. `load_group` reads `meta.json` (the position) and then
+    the state tree, by path; the leader's atomic swap between the two
+    hands it round 3's position over round 6's state, no error raised, and
+    the stream then feeds the group rows 3..5 a second time — the standby
+    that takes over has diverged (what `scripts/failover_soak.py` reported
+    as "state diverges" on a busy host, group 0 off from the takeover tick
+    on: ISSUE 50). The swap is made to happen there; the adoption has to
+    notice and read again."""
+    import orbax.checkpoint as ocp
+
+    ck = str(tmp_path / "ck")
+    leader = _reg()
+    lj = TickJournal(tmp_path / "lj")
+    kw = dict(cadence_s=0.0, checkpoint_dir=ck, checkpoint_every=3,
+              journal=lj)
+    live_loop(lambda k: _row(7, k, 4), leader, n_ticks=3, **kw)
+    restore = ocp.PyTreeCheckpointer.restore
+    swapped = []
+
+    def restore_after_the_leaders_next_round(self, *a, **k):
+        if not swapped:  # meta.json is read; the state tree is not yet
+            swapped.append(True)
+            live_loop(lambda k: _row(7, 3 + k, 4), leader, n_ticks=3, **kw)
+        return restore(self, *a, **k)
+
+    monkeypatch.setattr(ocp.PyTreeCheckpointer, "restore",
+                        restore_after_the_leaders_next_round)
+    sj = TickJournal(tmp_path / "sj")
+    standby = _reg()
+    follower = StandbyFollower(
+        standby, sj, lease=Lease(tmp_path / "lease", "S", timeout_s=1e9),
+        port=0, checkpoint_dir=ck)
+    follower._catch_up()
+    lj.close()
+    sj.close()
+    assert swapped
+    assert follower.gpos == [6, 6]
+    _assert_groups_equal(leader, standby)
+
+
+@pytest.mark.parametrize("adopted", [
+    "one_round",
+    pytest.param("torn", marks=pytest.mark.xfail(
+        strict=True,
+        reason="ROADMAP D17: _promote stamps every group with min(gpos), "
+               "so the group a round ahead is fed its rows twice")),
+])
+def test_promotion_checkpoints_each_group_at_its_own_position(
+        tmp_path, adopted):
+    """A standby promoted while still catching up. The shared checkpoints
+    were adopted TORN — one group from the round at tick 3, the other
+    from the round at tick 6, as happens when the leader is mid-round —
+    which `_adopt_checkpoints` allows because the stream converges the
+    groups; a promotion BEFORE that has to record each group where it
+    stands, or the leader that resumes from the takeover checkpoint
+    applies rows 3..5 to a group that already has them and diverges from
+    the fault-free run (what `scripts/failover_soak.py` reported as
+    "state diverges" on a starved host, ISSUE 50). Adopted from one
+    round, the takeover tick is every group's."""
+    import shutil
+
+    from rtap_tpu.service.checkpoint import load_group
+    from rtap_tpu.service.shardpath import group_checkpoint_path
+
+    ck = str(tmp_path / "ck")
+    reg = _reg()
+    lj = TickJournal(tmp_path / "lj")
+    kw = dict(cadence_s=0.0, checkpoint_dir=ck, checkpoint_every=3,
+              journal=lj)
+    live_loop(lambda k: _row(7, k, 4), reg, n_ticks=3, **kw)
+    if adopted == "torn":
+        shutil.copytree(group_checkpoint_path(ck, 0), tmp_path / "round3")
+    live_loop(lambda k: _row(7, 3 + k, 4), reg, n_ticks=3, **kw)
+    lj.close()
+    if adopted == "torn":
+        shutil.rmtree(group_checkpoint_path(ck, 0))
+        shutil.copytree(tmp_path / "round3", group_checkpoint_path(ck, 0))
+    sj = TickJournal(tmp_path / "sj")
+    lease = Lease(tmp_path / "lease", "S", timeout_s=30.0)
+    follower = StandbyFollower(_reg(), sj, lease=lease, port=0,
+                               checkpoint_dir=ck)
+    follower._catch_up()
+    stood_at = list(follower.gpos)
+    assert stood_at == ([3, 6] if adopted == "torn" else [6, 6])
+    assert lease.try_acquire()
+    follower._promote(0.0)
+    sj.close()
+    assert [load_group(group_checkpoint_path(ck, gi)).resume_journal_tick
+            for gi in range(2)] == stood_at
+
+
 # ----------------------------------------------------- writer fencing
 def test_alert_writer_fence_refuses_writes(tmp_path):
     from rtap_tpu.service.alerts import AlertWriter

@@ -97,7 +97,9 @@ def test_the_vocabulary_is_what_the_package_writes():
     opened, annotation_sites = set(), set()
     for root, _dirs, files in os.walk(os.path.join(REPO, "rtap_tpu")):
         for name in files:
-            if not name.endswith(".py"):
+            # _gate_canary*: files tests/unit/test_static_checks.py drops
+            # into the package and removes, on another worker meanwhile
+            if not name.endswith(".py") or name.startswith("_gate_canary"):
                 continue
             path = os.path.join(root, name)
             with open(path) as f:

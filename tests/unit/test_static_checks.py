@@ -1,5 +1,5 @@
 """scripts/check_static.sh rides tier-1: compileall over rtap_tpu AND
-scripts/ + bench.py, plus `python -m rtap_tpu.analysis` (rtap-lint,
+scripts/, plus `python -m rtap_tpu.analysis` (rtap-lint,
 ISSUE 12) — the AST invariant analyzer that now owns the print gate
 (NO print() in the serve stack; elsewhere print() must target an
 explicit stream or be the one-JSON-line artifact emission), the
@@ -243,9 +243,12 @@ def test_findings_cache_invalidated_by_file_edit(tmp_path):
 def test_findings_cache_warm_equals_cold_and_meets_budget(tmp_path):
     """The ISSUE 14 pass-partition contract, end to end: a one-file
     edit after a warm cache must (a) produce the same findings picture
-    as a from-scratch cold run of the same tree, and (b) come back
-    under the ~2 s warm budget — the point of partitioning with
-    twenty passes live."""
+    as a from-scratch cold run of the same tree, and (b) cost less CPU
+    than that cold run beside it — the point of partitioning with
+    twenty passes live. The bar is the cold run's own time on the same
+    host in the same minute, not an absolute: under six test workers on
+    a shared host the warm run's ~2.2 CPU s read past a 3.0 s budget
+    with nothing wrong (ISSUE 50), and cold rises with it."""
     cache = str(tmp_path / "lint_cache.json")
     _analysis_json("--cache-path", cache)          # prime
     target = os.path.join(REPO, "rtap_tpu", "utils", "measure.py")
@@ -255,18 +258,18 @@ def test_findings_cache_warm_equals_cold_and_meets_budget(tmp_path):
         f.write("\n# warm-budget canary (comment only)\n")
     try:
         _p, warm, warm_cpu = _analysis_json("--cache-path", cache)
-        _p2, cold, _cold_cpu = _analysis_json("--no-cache")
+        _p2, cold, cold_cpu = _analysis_json("--no-cache")
     finally:
         with open(target, "w", encoding="utf-8") as f:
             f.write(original)
     assert warm["cache"] == "warm"
-    # 3.0 s: the v3 budget was 2.0 with fifteen passes; the ISSUE 15
-    # mesh model + two new program passes (partition-contract,
-    # scaling-math) add ~0.4 s of per-warm-run work that per-file
-    # partitioning cannot elide (their inputs are cross-file by nature)
-    assert warm_cpu < 3.0, (
-        f"warm run burned {warm_cpu:.2f} CPU s — per-file pass reuse "
-        "must keep incremental runs fast")
+    # the whole-program passes (the mesh model, partition-contract,
+    # scaling-math: cross-file inputs) re-run warm; the per-file passes of
+    # every unchanged file replay — about 2.2 of 3.2 CPU s on a quiet host
+    assert warm_cpu < cold_cpu, (
+        f"warm run burned {warm_cpu:.2f} CPU s against {cold_cpu:.2f} cold "
+        "— per-file pass reuse must keep incremental runs cheaper than "
+        "from scratch")
     for volatile in ("elapsed_s", "cache"):
         warm.pop(volatile), cold.pop(volatile)
     assert warm == cold, "warm partial-reuse run diverged from cold"

@@ -47,24 +47,31 @@ def test_compiled_chunk_step_carries_the_scopes(cfg, learn):
     assert found - {"rtap.layout"} == set(ALWAYS + (LEARNING if learn else ()))
 
 
+@pytest.mark.parametrize("rows", ["narrow", "wide"])
 @pytest.mark.parametrize("entry", ["group_step", "fused_step", "chunk_step"])
-def test_layout_adapters_are_scoped(entry):
-    # the adapters reshape where the pools run flat: narrow pool rows
-    cfg = scaled_cluster_preset(32)
-    assert not tm_tpu.wide_rows(cfg.tm)
+def test_layout_adapters_are_scoped(entry, rows):
+    """A public tree is converted under `rtap.layout` by every entry point,
+    one-tick programs included: reshapes where the pools run flat (narrow
+    pool rows), and a transpose a pool each way where they run [C, M, K*S]."""
+    from tests.parity.test_tm_forms import form_cfg
+
+    cfg = scaled_cluster_preset(32) if rows == "narrow" else form_cfg("wide", 16)
+    assert tm_tpu.wide_rows(cfg.tm) == (rows == "wide")
     if entry == "fused_step":
         single = {k: jnp.asarray(v) for k, v in init_state(cfg, 0).items()}
-        low = fused_step.lower(single, jnp.zeros((1,), jnp.float32),
+        low = fused_step.lower(single, jnp.zeros((cfg.n_fields,), jnp.float32),
                                jnp.int32(0), cfg, learn=False)
     elif entry == "group_step":
         low = group_step.lower(
-            _group_state(cfg), jnp.zeros((G, 1), jnp.float32),
+            _group_state(cfg), jnp.zeros((G, cfg.n_fields), jnp.float32),
             jnp.zeros((G,), jnp.int32), cfg, learn=False)
     else:
         low = chunk_step.lower(
-            _group_state(cfg), jnp.zeros((T, G, 1), jnp.float32),
+            _group_state(cfg), jnp.zeros((T, G, cfg.n_fields), jnp.float32),
             jnp.zeros((T, G), jnp.int32), cfg, learn=False)
-    assert "rtap.layout/reshape" in low.as_text(debug_info=True)
+    text = low.as_text(debug_info=True)
+    assert "rtap.layout/reshape" in text
+    assert len(_ops_under(text, "transpose", "rtap.layout")) == (4 if rows == "wide" else 0)
 
 
 def _ops_under(text: str, op: str, scope: str) -> list[str]:
@@ -82,17 +89,16 @@ def _ops_under(text: str, op: str, scope: str) -> list[str]:
 
 @pytest.mark.parametrize("program", ["chunk_step", "chunk_step_one_tick", "group_step"])
 @pytest.mark.parametrize("shape", ["cluster", "cluster32", "node3", "wide"])
-def test_only_a_wide_row_scan_pays_a_transpose_at_the_layout_boundary(shape, program):
+def test_only_wide_rows_pay_a_transpose_at_the_layout_boundary(shape, program):
     """At narrow pool rows the kernel layout is a reshape of the public one
     and the adapters move no data: the three narrow-row presets the
     benchmark's cells run lower to no `stablehlo.transpose` under
     `rtap.layout` (ISSUE 40 left their programs the parent's, byte for
-    byte). At wide rows a scan over ticks takes the pools as [C, M, K*S]:
-    two transposes in, two out, once a program — and none anywhere else in
-    the step, so no pool turns inside the scan. A one-tick program
-    (`group_step`, `chunk_step` at T = 1: the served path) has no later tick
-    to win them back on and keeps the public layout in the kernel: no
-    transpose, and no op at all under `rtap.layout`."""
+    byte). At wide rows a program handed the public tree takes the pools as
+    [C, M, K*S]: two transposes in, two out, once a program, whatever its
+    length (`group_step` and `chunk_step` at T = 1 as well: the kernel runs
+    one layout, ISSUE 50) — and none anywhere else in the step, so no pool
+    turns inside it."""
     from tests.parity.test_tm_forms import form_cfg
 
     cfg = {"cluster": cluster_preset, "cluster32": lambda: scaled_cluster_preset(32),
@@ -103,10 +109,6 @@ def test_only_a_wide_row_scan_pays_a_transpose_at_the_layout_boundary(shape, pro
         _group_state(cfg), jnp.zeros((*lead, G, cfg.n_fields), jnp.float32),
         jnp.zeros((*lead, G), jnp.int32), cfg, learn=True).as_text(debug_info=True)
     turned = _ops_under(text, "transpose", "rtap.layout")
-    if shape == "wide" and program != "chunk_step":
-        assert "rtap.layout" not in text
-        assert _ops_under(text, "reshape", "rtap.tm.learn.rows")  # the resolver bites
-        return
     assert _ops_under(text, "reshape", "rtap.layout")  # the resolver bites
     tm = cfg.tm
     pool = (f"{cfg.sp.columns}x{tm.max_synapses_per_segment}x"

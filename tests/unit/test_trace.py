@@ -157,7 +157,7 @@ def test_span_args_ride_into_the_chrome_export():
 def test_trace_and_flight_overhead_within_one_percent_of_tick_budget():
     """ISSUE 4 acceptance: span-ring + flight-recorder traffic for a full
     16-group tick costs <= 1% of the 1 s cadence (the same bar, and the
-    same measurement, as bench.py --obs-bench's second line)."""
+    same measurement, as python -m rtap_tpu.obs.selfbench's second line)."""
     from rtap_tpu.obs.selfbench import measure_trace
 
     res = measure_trace(n=5000)
