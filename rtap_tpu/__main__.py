@@ -44,6 +44,17 @@ def _apply_cadence(cfg, args: argparse.Namespace):
                                 burst=getattr(args, "learn_burst", 1))
 
 
+def _predict_horizon(args: argparse.Namespace) -> int:
+    """The predictive horizon a command's flags ask for: --predict-horizon,
+    8 where --predict leaves it unset, 0 without --predict. The horizon
+    sizes device state (the `pred_ring` leaf), so a fleet is warmed, saved
+    and served with one number."""
+    if not getattr(args, "predict", False):
+        return 0
+    k = getattr(args, "predict_horizon", None)
+    return 8 if k is None else k
+
+
 def _sized_cluster(args: argparse.Namespace):
     """cluster_preset, optionally width-scaled (--columns: SCALING.md model-
     width study — per-workload deployment choice; validation lives in
@@ -352,8 +363,7 @@ def _cmd_serve(args: argparse.Namespace) -> int:
     # the pred_* ring leaves and the fused reducer from tick 0 — the
     # horizon is structural (it sizes device state), so it is fixed at
     # registry construction, not toggled later
-    predict_k = (args.predict_horizon if args.predict_horizon is not None
-                 else 8) if args.predict else 0
+    predict_k = _predict_horizon(args)
     grp = StreamGroupRegistry(cfg, group_size=gsize,
                               backend=args.backend, threshold=args.threshold,
                               debounce=args.debounce,
@@ -839,6 +849,21 @@ def _cmd_replay(args: argparse.Namespace) -> int:
     scfg = SyntheticStreamConfig(length=args.length, cadence_s=1.0,
                                  anomaly_magnitude=args.magnitude,
                                  noise_phi=0.97, noise_scale=0.5)
+    if args.predict_horizon is not None and not args.predict:
+        print("replay: --predict-horizon is a predictive-horizon knob; add "
+              "--predict", file=sys.stderr)
+        return 2
+    if args.predict_horizon is not None and args.predict_horizon < 1:
+        print("replay: --predict-horizon must be >= 1 (the reducer scores "
+              "each tick's prediction against the input that many ticks "
+              "later)", file=sys.stderr)
+        return 2
+    predict_k = _predict_horizon(args)
+    predictor = None
+    if args.predict:
+        from rtap_tpu.predict import PredictTracker
+
+        predictor = PredictTracker(horizon=predict_k)
     streams = generate_cluster(args.nodes, cfg=scfg, seed=args.seed)
     res = replay_streams(streams, _apply_cadence(_sized_cluster(args), args),
                          backend=args.backend,
@@ -846,7 +871,10 @@ def _cmd_replay(args: argparse.Namespace) -> int:
                          threshold=args.threshold, alert_path=args.alerts,
                          checkpoint_dir=args.checkpoint_dir,
                          checkpoint_every=args.checkpoint_every,
-                         debounce=args.debounce, learn=not args.freeze)
+                         debounce=args.debounce, learn=not args.freeze,
+                         predict=predict_k, predictor=predictor)
+    if predictor is not None:
+        res.throughput["predict"] = predictor.stats()
     print(json.dumps({"streams": len(res.stream_ids), "ticks": len(res.timestamps),
                       **res.throughput}))
     return 0
@@ -1520,6 +1548,15 @@ def main(argv: list[str] | None = None) -> int:
                         "still adapts")
     p.add_argument("--columns", type=int, default=None,
                    help="width-scale the cluster preset (see serve --columns)")
+    p.add_argument("--predict", action="store_true",
+                   help="warm the fleet with the predictive horizon armed "
+                        "(docs/PREDICT.md): the checkpoints carry the "
+                        "predictor's ring, so serve --predict "
+                        "--checkpoint-dir resumes them; the precursor lines "
+                        "the history earns go to --alerts")
+    p.add_argument("--predict-horizon", type=int, default=None,
+                   help="prediction lead k in ticks (default 8, with "
+                        "--predict); serve it with the same horizon")
     p.set_defaults(fn=_cmd_replay)
 
     p = sub.add_parser("eval", help="fault-injection evaluation -> JSON report")
@@ -1650,6 +1687,18 @@ def main(argv: list[str] | None = None) -> int:
             and args.predict_min_ticks < 1:
         print("serve: --predict-min-ticks must be >= 1", file=sys.stderr)
         return 2
+    if getattr(args, "checkpoint_dir", None):
+        # a fleet is served with the horizon it was warmed with: said here,
+        # before any state is made, in the words the resume itself uses
+        from rtap_tpu.service.checkpoint import (
+            horizon_mismatch, peek_resume_predict)
+
+        saved_k = peek_resume_predict(args.checkpoint_dir)
+        want_k = _predict_horizon(args)
+        if saved_k is not None and saved_k != want_k:
+            print("serve: " + horizon_mismatch(args.checkpoint_dir, saved_k,
+                                               want_k), file=sys.stderr)
+            return 2
     if getattr(args, "slo", None) and not getattr(args, "latency", False):
         print("serve: --slo declares an objective over the latency "
               "tracker's measurements; add --latency", file=sys.stderr)

@@ -80,6 +80,13 @@ SPANS = (
     # ... inside `emit`: the tick's alert decisions to lines in the sink,
     # flushed (`tick`, `lines` = alert lines written; ring: "alert")
     "rtap.loop.alert",
+    # ... after it, still inside `emit`, where serve armed them: the tick's
+    # health leaves folded into the scorecards (`tick`; ring: "health"), and
+    # every group's predict leaves folded into the divergence trajectories
+    # plus the blast fuser (`tick`, `precursors`, `incidents` = event lines
+    # the tick emitted; ring: "predict")
+    "rtap.loop.health",
+    "rtap.loop.predict",
     "rtap.loop.checkpoint",
     "rtap.loop.sleep",
     # ... and its per-group children (ring: "dispatch" / "collect" on the

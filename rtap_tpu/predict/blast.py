@@ -124,6 +124,31 @@ class BlastFuser:
             "predicted_lead_ticks": ev.get("predicted_lead_ticks"),
         }
 
+    def state(self) -> dict:
+        """The open windows, JSON-able: what a checkpoint carries so that a
+        restarted process attaches a cluster's next precursor to the page
+        already sent instead of paging again (`load_state`)."""
+        return {c: {"first_tick": w.first_tick, "last_tick": w.last_tick,
+                    "first_stream": w.first_stream,
+                    "streams": sorted(w.streams),
+                    "precursors": list(w.precursors),
+                    "incident_id": w.incident_id}
+                for c, w in sorted(self._open.items())}
+
+    def load_state(self, state: dict) -> None:
+        """Merge saved open windows (:meth:`state`) in: every group's
+        checkpoint of one drained instant carries the same windows, so a
+        cluster already known keeps the copy that saw the later tick."""
+        for c, d in state.items():
+            have = self._open.get(c)
+            if have is not None and have.last_tick >= int(d["last_tick"]):
+                continue
+            w = self._open[c] = _Cluster(d["first_tick"], d["first_stream"])
+            w.last_tick = int(d["last_tick"])
+            w.streams = set(d["streams"])
+            w.precursors = list(d["precursors"])
+            w.incident_id = d["incident_id"]
+
     def snapshot(self) -> dict:
         """Embedded under ``blast`` in the /predict body."""
         open_windows = [

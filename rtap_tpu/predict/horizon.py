@@ -130,6 +130,7 @@ class PredictTracker:
         self._groups: dict[int, _GroupPredict] = {}
         self.events_total = 0
         self.events_suppressed = 0
+        self.streams_scored = 0  # stream-ticks the device scored, folded
         self._events_by_kind: dict[str, int] = {}
         #: armed replay-suppression ids (service/alerts.scan_event_ids):
         #: a journal replay reproduces each event bit-for-bit; ids already
@@ -155,6 +156,18 @@ class PredictTracker:
             "rtap_obs_predict_streams_alarmed",
             "streams currently inside a precursor alarm (edge-triggered; "
             "re-arm below rearm_frac * threshold)")
+        # tallies kept as plain ints on the fold path and mirrored into the
+        # registry once a tick (sync_obs), like the ingest counters
+        self._obs_synced = {"events_suppressed": 0, "streams_scored": 0}
+        self._obs_suppressed = reg.counter(
+            "rtap_obs_predict_events_suppressed_total",
+            "predictive events whose id was already in the alert sink "
+            "(a replayed fold re-latched the state without paging twice)")
+        self._obs_scored = reg.counter(
+            "rtap_obs_predict_streams_scored_total",
+            "stream-ticks the predict reducer scored (live and past the "
+            "horizon after the stream's ring was made) and the tracker "
+            "folded")
         self._obs_fold_seconds = reg.histogram(
             "rtap_obs_predict_fold_seconds",
             "wall seconds per PredictTracker.fold call (one per collected "
@@ -171,6 +184,58 @@ class PredictTracker:
         is not re-emitted."""
         with self._lock:
             self._suppress.update(str(i) for i in ids)
+
+    def group_state(self, group: int) -> dict | None:
+        """What a checkpoint of group `group` carries of this tracker
+        (service/checkpoint.py:save_group): the paging rule's latches —
+        `run`, `samples`, `alarmed`, one a slot — and, where a fuser is
+        attached, its open windows (`blast`, JSON-able). None before the
+        group's first fold. The divergence itself is device state (the
+        `pred_miss_ewma` leaf); without the latches a restarted process
+        waits the rule's whole warm-up again and re-pages a stream that
+        was already inside an alarm."""
+        with self._lock:
+            g = self._groups.get(group)
+            if g is None:
+                return None
+            return {"latches": {"run": g.run.copy(),
+                                "samples": g.samples.copy(),
+                                "alarmed": g.alarmed.copy()},
+                    "blast": None if self.blast is None
+                    else self.blast.state()}
+
+    def restore_group(self, group: int, state: dict) -> None:
+        """Take :meth:`group_state` back (a loaded group's
+        `resume_predict_state`, at the start of a resumed run): the group's
+        latches stand where the saving process left them, and the saved
+        open windows merge into the fuser."""
+        lat = state["latches"]
+        with self._lock:
+            g = self._groups[group] = _GroupPredict(len(lat["run"]))
+            g.run = np.asarray(lat["run"], np.int64).copy()
+            g.samples = np.asarray(lat["samples"], np.int64).copy()
+            g.alarmed = np.asarray(lat["alarmed"], bool).copy()
+            if self.blast is not None and state.get("blast"):
+                self.blast.load_state(state["blast"])
+
+    @property
+    def events_by_kind(self) -> dict[str, int]:
+        """Events emitted so far, by kind (suppressed replays apart)."""
+        return self._events_by_kind
+
+    def sync_obs(self) -> None:
+        """Mirror the fold path's tallies and the fleet gauges into the
+        telemetry registry: the serve loop calls it once a tick, after the
+        tick's folds (a fleet-wide max and mean once a tick, not once a
+        group), and a replay after each chunk's."""
+        with self._lock:
+            self._set_fleet_gauges()
+            for key, counter in (("events_suppressed", self._obs_suppressed),
+                                 ("streams_scored", self._obs_scored)):
+                now = getattr(self, key)
+                if now != self._obs_synced[key]:
+                    counter.inc(now - self._obs_synced[key])
+                    self._obs_synced[key] = now
 
     # ------------------------------------------------------------ fold --
     def fold(self, group: int, leaves: dict, tick: int = -1,
@@ -211,6 +276,7 @@ class PredictTracker:
             # resetting — an outage must not silently disarm a ramp
             g.run = np.where(hot, g.run + 1, np.where(s, 0, g.run))
             g.samples += s
+            self.streams_scored += int(s.sum())
             fire = (~g.alarmed) & (g.run >= self.min_ticks) \
                 & (g.samples >= self.warmup_ticks)
             rearm = g.alarmed & s & np.isfinite(e) \
@@ -234,7 +300,6 @@ class PredictTracker:
             g.ewma = np.where(s, ewma[i], g.ewma)
             g.overlap = np.where(s, overlap[i], g.overlap)
             g.col_frac = np.where(s, col_frac[i], g.col_frac)
-        self._set_fleet_gauges()
         self._obs_fold_seconds.observe(time.perf_counter() - t0)
 
     # ------------------------------------------------- event emission --

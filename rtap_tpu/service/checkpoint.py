@@ -41,7 +41,8 @@ def _count_bytes(n: int, op: str) -> None:
 
 def save_group(grp: StreamGroup, path: str | Path,
                alerts_offset: int | None = None,
-               journal_tick: int | None = None, trace=None) -> None:
+               journal_tick: int | None = None, trace=None,
+               predict_state: dict | None = None) -> None:
     """Write one group's resume state to `path` (a directory, per group).
 
     Atomic on overwrite: the tree + meta are written to a fresh temp sibling
@@ -64,12 +65,18 @@ def save_group(grp: StreamGroup, path: str | Path,
     global clock keeps running — the journal replay must match rows by
     this global cursor, never by the rewindable per-group one.
 
+    `predict_state` is the predictive tracker's part of the group
+    (`PredictTracker.group_state`: the paging rule's latches, the fuser's
+    open windows), saved beside the debounce counters; a loaded group hands
+    it back as `resume_predict_state` and the resuming loop gives it to its
+    own tracker, so a restarted fleet pages from where it stood.
+
     One `rtap.checkpoint.save` span (obs/trace.py; into `trace`'s ring too
     where a recorder is handed over) covers the whole save: `group`, `bytes`.
     """
     sp = span("rtap.checkpoint.save", trace, group=grp.stream_ids[0]).begin()
     try:
-        n = _save_group(grp, path, alerts_offset, journal_tick)
+        n = _save_group(grp, path, alerts_offset, journal_tick, predict_state)
     except BaseException:
         sp.end(record=False)
         raise
@@ -79,7 +86,8 @@ def save_group(grp: StreamGroup, path: str | Path,
 # rtap: host-boundary — checkpoint save OWNS the device->host
 # materialization: it must fetch the full (possibly mesh-sharded) tree
 # to write a topology-independent checkpoint, with the pipeline drained
-def _save_group(grp, path, alerts_offset, journal_tick) -> int:
+def _save_group(grp, path, alerts_offset, journal_tick,
+                predict_state=None) -> int:
     """save_group's body -> the bytes of the state tree it wrote."""
     import jax
     import orbax.checkpoint as ocp
@@ -100,6 +108,9 @@ def _save_group(grp, path, alerts_offset, journal_tick) -> int:
         tree = {"model": {f"s{g}": grp._states[g] for g in range(grp.G)}}
     tree["likelihood"] = grp.likelihood.state_dict()
     tree["alert_run"] = np.asarray(grp._alert_run)  # debounce counters
+    if predict_state is not None:
+        tree["predict_latches"] = {
+            k: np.asarray(v) for k, v in predict_state["latches"].items()}
 
     meta = {
         "backend": grp.backend,
@@ -117,6 +128,8 @@ def _save_group(grp, path, alerts_offset, journal_tick) -> int:
         meta["alerts_offset"] = int(alerts_offset)
     if journal_tick is not None:
         meta["journal_tick"] = int(journal_tick)
+    if predict_state is not None and predict_state.get("blast") is not None:
+        meta["predict_blast"] = predict_state["blast"]
     tmp = path.parent / f".{path.name}.tmp-{uuid.uuid4().hex[:8]}"
     swapped = False
     try:
@@ -303,6 +316,13 @@ def _load_group(path, mesh, sparsify) -> tuple[StreamGroup, int]:
     grp.resume_journal_tick = (
         int(meta["journal_tick"]) if "journal_tick" in meta else None)
     grp.alert_epoch = int(meta.get("alert_epoch", 0))
+    # the predictive tracker's latches and open windows, where the saving
+    # run had a tracker (None: the resuming tracker starts its rule afresh)
+    grp.resume_predict_state = (
+        {"latches": {k: np.asarray(v)
+                     for k, v in tree["predict_latches"].items()},
+         "blast": meta.get("predict_blast")}
+        if "predict_latches" in tree else None)
     # n_live is now derived from stream_ids (pad-prefix count) — the meta
     # field stays written for inspection/back-compat but is not load-bearing
     get_registry().counter(
@@ -331,6 +351,44 @@ def peek_resume_ticks(checkpoint_dir: str | Path) -> int:
         except (OSError, ValueError, KeyError, TypeError):
             continue
     return best
+
+
+def peek_resume_predict(checkpoint_dir: str | Path) -> int | None:
+    """The predictive horizon the dir's group checkpoints were saved with
+    (meta.json alone, no state load; 0 = warmed without the predictor):
+    the serve CLI's usage check for ``--predict`` with ``--checkpoint-dir``.
+    None for a missing/empty/unreadable dir or a torn set that disagrees
+    (the resume itself then says which group)."""
+    seen = set()
+    root = Path(checkpoint_dir)
+    if not root.is_dir():
+        return None
+    for d in sorted(root.iterdir()):
+        if not d.name.startswith("group") or not d.is_dir():
+            continue
+        try:
+            seen.add(int(json.loads(
+                (d / "meta.json").read_text()).get("predict", 0)))
+        except (OSError, ValueError, TypeError):
+            continue
+    return seen.pop() if len(seen) == 1 else None
+
+
+def horizon_mismatch(ck_path, saved: int, requested: int) -> str:
+    """What a resume across a horizon change is told: both horizons, the
+    checkpoint, the remedy. The predictor's ring lives INSIDE the state
+    tree, sized by the horizon — there is no blend of two."""
+    def said(k: int) -> str:
+        return f"--predict --predict-horizon {k}" if k else "no --predict"
+
+    return (
+        f"checkpoint {ck_path} was saved with predictive horizon {saved} "
+        f"({said(saved)}); this run asks for {requested} "
+        f"({said(requested)}). The predictor's ring is part of the saved "
+        f"state, sized by the horizon: serve it with {said(saved)}, or "
+        f"re-warm the fleet into a fresh --checkpoint-dir with "
+        f"{said(requested)} (python -m rtap_tpu replay ... "
+        f"--checkpoint-dir, docs/PREDICT.md)")
 
 
 def validate_resume(resumed: StreamGroup, ck_path, grp: StreamGroup,
@@ -370,17 +428,17 @@ def validate_resume(resumed: StreamGroup, ck_path, grp: StreamGroup,
             + ("" if allow_claimed_extras else
                " (lazily claimed extras resume under serve"
                " --auto-register, or frozen via serve --freeze)"))
+    # the predictor leaves live INSIDE the state tree: resuming across a
+    # horizon change would need a structural migration, not a silent blend
+    saved_k, want_k = getattr(resumed, "predict", 0), getattr(grp, "predict", 0)
+    if saved_k != want_k:
+        raise ValueError(horizon_mismatch(ck_path, saved_k, want_k))
     mismatches = [
         f"{name}: checkpoint={a!r} vs requested={b!r}"
         for name, a, b in (
             ("config", resumed.cfg, grp.cfg),
             ("threshold", resumed.threshold, grp.threshold),
             ("debounce", resumed.debounce, grp.debounce),
-            # the predictor leaves live INSIDE the state tree: resuming
-            # across a horizon change would need a structural migration,
-            # not a silent blend
-            ("predict", getattr(resumed, "predict", 0),
-             getattr(grp, "predict", 0)),
         )
         if a != b
     ]

@@ -92,6 +92,8 @@ def replay_streams(
     debounce: int = 1,
     seed: int = 0,
     trace=None,
+    predict: int = 0,
+    predictor=None,
 ) -> ReplayResult:
     """Replay equal-length streams through grouped models at full speed.
 
@@ -102,6 +104,22 @@ def replay_streams(
     of F fields (a row a tick). `seed` is the registry's (group g's state is
     made from ``seed + g``, as `serve` makes it); `trace` (an
     obs.TraceRecorder) takes the checkpoint spans.
+
+    `predict` (a horizon k, serve --predict-horizon) builds the groups with
+    the predictor-owned leaves (`pred_ring`, `pred_miss_ewma`, `pred_tick0`)
+    in their state, so a fleet warmed here and saved is one a predictive
+    `serve --checkpoint-dir` resumes (a checkpoint warmed without them is a
+    refused horizon mismatch, `checkpoint.validate_resume`). `predictor` (a
+    predict.PredictTracker) folds every collected chunk's predict leaves on
+    the group tick clock, as `live_loop` does: the precursor /
+    predicted_incident lines the history earns go to the alert sink, their
+    ids the ones an uninterrupted serve would have written, and every save
+    carries the tracker's latches for the group
+    (`PredictTracker.group_state`), so the serving process's fresh tracker
+    pages from where this one stood. Off (the default): the state tree, the
+    scores and the checkpoints are what they were without the arguments.
+    (The model-health reducer is no state: `serve --health` arms it on any
+    checkpoint, so a warm-up has nothing to do with it.)
 
     Crash recovery (SURVEY.md §5 checkpoint/resume as *elastic recovery*):
     with `checkpoint_dir` + `checkpoint_every=k`, each group's full resume
@@ -123,8 +141,13 @@ def replay_streams(
     group_size = group_size or n
     ids = [s.stream_id for s in streams]
 
+    if predictor is not None and predictor.horizon != predict:
+        raise ValueError(
+            f"the predictor folds leaves of horizon {predictor.horizon}; the "
+            f"groups are built with predict={predict}")
     reg = StreamGroupRegistry(cfg, group_size=group_size, backend=backend,
-                              seed=seed, threshold=threshold, debounce=debounce)
+                              seed=seed, threshold=threshold, debounce=debounce,
+                              predict=predict)
     for sid in ids:
         reg.add_stream(sid)
     reg.finalize()
@@ -139,6 +162,12 @@ def replay_streams(
     # scored by the earlier (killed) process and must read as absent here
     preds = np.full((T, n), np.nan, np.float32) if cfg.classifier.enabled else None
     writer = AlertWriter(alert_path)
+    # the predictor's events ride the alert stream, as in live_loop; the
+    # sink is this call's writer for this call only (the tracker goes on to
+    # the serving loop, which wires its own)
+    lend_sink = predictor is not None and predictor.sink is None
+    if lend_sink:
+        predictor.sink = writer.emit_event
     counter = ThroughputCounter()
     obs_scored = _scored_counter()
     obs_replay_ticks = get_registry().counter(
@@ -184,11 +213,20 @@ def replay_streams(
                     # tail is re-scored. ONE tail scan covers every
                     # group (ids are globally unique); only a torn save
                     # set revealing an even older cursor rescans.
-                    from rtap_tpu.service.alerts import scan_alert_ids
+                    from rtap_tpu.service.alerts import (
+                        scan_alert_ids, scan_event_ids)
 
                     writer.arm_suppression(
                         scan_alert_ids(alert_path, ck_off))
+                    if predictor is not None:
+                        predictor.arm_suppression(
+                            scan_event_ids(alert_path, ck_off))
                     suppression_scanned_from = ck_off
+                saved = getattr(grp, "resume_predict_state", None)
+                if predictor is not None and saved is not None:
+                    # the killed run's latches: the re-scored tail pages
+                    # (suppressed) exactly where that run paged
+                    predictor.restore_group(gi, saved)
         if grp.ticks < T:
             groups_with_work += 1
         # a group resumed AT the end replays zero ticks (all-NaN rows) by
@@ -203,6 +241,7 @@ def replay_streams(
         gt = np.repeat(ts[:, lo : lo + 1], grp.G, axis=1)
         gv[:, :live] = values[:, lo : lo + live]
         gt[:, :live] = ts[:, lo : lo + live]
+        id_by_slot = sids + [None] * (grp.G - live)
 
         def collect(bounds, handle):
             t0, t1 = bounds
@@ -224,6 +263,13 @@ def replay_streams(
                                   r[i - t0, :live], ll[i - t0, :live],
                                   al[i - t0, :live],
                                   group=_alert_gid(gi, grp), tick=i)
+            if predictor is not None and grp.last_predict is not None:
+                # keyed on the group tick (= the replay tick, the chunk's
+                # last row), as live_loop's fold: a precursor id is the
+                # one a served tick of the same group tick would write
+                predictor.fold(gi, grp.last_predict, tick=t1 - 1,
+                               ids=id_by_slot)
+                predictor.sync_obs()
 
         # depth-2 pipeline: the device computes chunk t+1 while the host
         # post-processes chunk t (SURVEY.md §7 hard part 3 — overlapped feed)
@@ -250,7 +296,7 @@ def replay_streams(
                 # meta equals the on-disk size (exactly-once resume)
                 writer.flush_sink()
                 save_group(grp, ck_path, alerts_offset=writer.sink_offset(),
-                           trace=trace)
+                           trace=trace, predict_state=_predict_state(predictor, gi))
         while pending:
             collect(*pending.popleft())
             chunks_done += 1
@@ -260,9 +306,11 @@ def replay_streams(
             writer.flush_sink()
             # final state, resumable past the end
             save_group(grp, ck_path, alerts_offset=writer.sink_offset(),
-                       trace=trace)
+                       trace=trace, predict_state=_predict_state(predictor, gi))
             # (frozen replay never writes — read-only like serve --freeze)
     writer.close()
+    if lend_sink:
+        predictor.sink = None
     if resumed_from and not groups_with_work:
         # every group's checkpoint is already at tick >= T: the whole replay
         # silently scored ZERO ticks and would return all-NaN (frozen or
@@ -905,6 +953,7 @@ def live_loop(
             health.flight = flight
         if flight is not None and flight.health_provider is None:
             flight.health_provider = health.snapshot
+    slot_ids: dict = {}  # group -> (a routing's ids, its slot -> id list)
     if predictor is not None:
         # same wiring contract as the health tracker: precursor /
         # predicted_incident events ride the alert stream, request
@@ -916,6 +965,13 @@ def live_loop(
             predictor.flight = flight
         if flight is not None and flight.predict_provider is None:
             flight.predict_provider = predictor.snapshot
+        # a fleet resumed from checkpoints pages from where the saving
+        # process stood: its latches and open windows, before any journal
+        # row is re-folded on top of them
+        for gi, g in enumerate(groups):
+            saved = getattr(g, "resume_predict_state", None)
+            if saved is not None:
+                predictor.restore_group(gi, saved)
     if slo is not None:
         # SLO guardrail wiring (ISSUE 11, obs/slo.py): burn events ride
         # the alert stream, a fast burn dumps a postmortem, and the
@@ -1113,6 +1169,7 @@ def live_loop(
             else:
                 results[gi] = res
         scored = 0
+        folded = []  # (group, slots, ids) of the groups this tick emitted
         # the tick's alert decisions to lines in the sink, flushed as
         # `alert_flush_every` has it: when this span ends, the tick's lines
         # are as durable as the sink makes them
@@ -1140,28 +1197,46 @@ def live_loop(
                 counter.add(n)
                 scored += n
             group_scored[gi] += len(ts_rows) * n
-            if health is not None and groups[gi].last_health is not None:
-                # fold the chunk's fused health leaves into the group's
-                # scorecard (one call per collected chunk per group; the
-                # tracker's own cost is gated by obs/selfbench.py's main)
-                health.fold(gi, groups[gi].last_health, tick=cur_tick)
-            if predictor is not None \
-                    and groups[gi].last_predict is not None:
-                # fold the chunk's fused predict leaves into the per-
-                # stream divergence trajectories; slot -> id mapping
-                # rides the same routing snapshot the emission used, so
-                # precursor events page with live stream ids. The fold
-                # keys on the GROUP tick (the counter checkpoints
-                # carry, = the chunk's last row), NOT the loop-local
-                # cur_tick: precursor ids must reproduce across a
-                # restart + journal replay for resume suppression
-                id_by_slot = [None] * groups[gi].G
-                for s, sid in zip(slots, ids):
-                    id_by_slot[s] = sid
-                predictor.fold(gi, groups[gi].last_predict,
-                               tick=groups[gi].ticks - 1,
-                               ids=id_by_slot)
+            folded.append((gi, slots, ids))
         sp_alert.end(lines=writer.written - lines0)
+        if health is not None:
+            # the tick's fused health leaves into the groups' scorecards
+            # (one fold per collected chunk per group; the tracker's own
+            # cost is gated by obs/selfbench.py's main)
+            with span("rtap.loop.health", trace, tick=cur_tick):
+                for gi, _slots, _ids in folded:
+                    if groups[gi].last_health is not None:
+                        health.fold(gi, groups[gi].last_health, tick=cur_tick)
+        if predictor is not None:
+            # the tick's fused predict leaves into the per-stream divergence
+            # trajectories, and the precursors they fire through the blast
+            # fuser; the slot -> id mapping rides the same routing snapshot
+            # the emission used, so precursor events page with live stream
+            # ids. The fold keys on the GROUP tick (the counter checkpoints
+            # carry, = the chunk's last row), NOT the loop-local cur_tick:
+            # precursor ids must reproduce across a restart + journal replay
+            # for resume suppression
+            sp_pred = span("rtap.loop.predict", trace, tick=cur_tick).begin()
+            events0 = dict(predictor.events_by_kind)
+            for gi, slots, ids in folded:
+                if groups[gi].last_predict is None:
+                    continue
+                held = slot_ids.get(gi)
+                if held is None or held[0] is not ids:
+                    # once a routing, not once a tick: `ids` is the routing
+                    # snapshot's own list until membership changes
+                    id_by_slot = [None] * groups[gi].G
+                    for s, sid in zip(slots, ids):
+                        id_by_slot[s] = sid
+                    held = slot_ids[gi] = (ids, id_by_slot)
+                predictor.fold(gi, groups[gi].last_predict,
+                               tick=groups[gi].ticks - 1, ids=held[1])
+            predictor.sync_obs()
+            events1 = predictor.events_by_kind
+            sp_pred.end(**{
+                label: events1.get(kind, 0) - events0.get(kind, 0)
+                for label, kind in (("precursors", "precursor"),
+                                    ("incidents", "predicted_incident"))})
         obs_scored.inc(scored)
         if journal is not None and pairs:
             # alert-delivery cursor: alerts through this tick have been
@@ -1801,7 +1876,8 @@ def live_loop(
                         on_failure=lambda gi, e: _on_save_failure(
                             gi, k, e),
                         alerts_offset=writer.sink_offset(),
-                        journal_tick=journal_base + ticks_run, trace=trace)
+                        journal_tick=journal_base + ticks_run, trace=trace,
+                        predictor=predictor)
                     if not failed_m:
                         checkpoints_saved += 1
                         last_saved = ticks_run
@@ -1963,7 +2039,8 @@ def live_loop(
                         on_failure=lambda gi, e: _on_save_failure(gi, k, e),
                         alerts_offset=writer.sink_offset(),
                         journal_tick=journal_base + ticks_run
-                        if journal is not None else None, trace=trace)
+                        if journal is not None else None, trace=trace,
+                        predictor=predictor)
                     phase_s["checkpoint"] += (time.perf_counter() - now) - (
                         phase_s["collect"] + phase_s["emit"]
                         + phase_s["dispatch"] - ce0)
@@ -2112,7 +2189,8 @@ def live_loop(
             on_failure=lambda gi, e: _on_save_failure(gi, ticks_run, e),
             alerts_offset=writer.sink_offset(),
             journal_tick=journal_base + ticks_run
-            if journal is not None else None, trace=trace)
+            if journal is not None else None, trace=trace,
+            predictor=predictor)
         if not failed:
             checkpoints_saved += 1
             if journal is not None and not quarantined:
@@ -2213,10 +2291,18 @@ def live_loop(
             **extra, **lat, **_device_stats(groups)}
 
 
+def _predict_state(predictor, gi: int) -> dict | None:
+    """What group `gi`'s checkpoint carries of the predictive tracker (None
+    where no tracker is armed, or it has folded nothing of the group yet)."""
+    return None if predictor is None else predictor.group_state(gi)
+
+
 def _save_all(groups, checkpoint_dir: str, skip=(), chaos=None, tick: int = 0,
               on_failure=None, alerts_offset: int | None = None,
-              journal_tick: int | None = None, trace=None) -> tuple[int, int]:
-    """One atomic per-group save per group dir (group{i:04d}).
+              journal_tick: int | None = None, trace=None,
+              predictor=None) -> tuple[int, int]:
+    """One atomic per-group save per group dir (group{i:04d}), each with the
+    predictive tracker's part of its group where `predictor` is armed.
 
     Quarantined groups (`skip`) are NOT saved: their state may be
     mid-chunk and their last good checkpoint is the restore source.
@@ -2237,7 +2323,8 @@ def _save_all(groups, checkpoint_dir: str, skip=(), chaos=None, tick: int = 0,
                 chaos.on_checkpoint_save(gi, tick)
             save_group(grp, group_checkpoint_path(checkpoint_dir, gi),
                        alerts_offset=alerts_offset,
-                       journal_tick=journal_tick, trace=trace)
+                       journal_tick=journal_tick, trace=trace,
+                       predict_state=_predict_state(predictor, gi))
             saved += 1
         except Exception as e:  # noqa: BLE001 — contained per group
             failed += 1

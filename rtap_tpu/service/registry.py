@@ -525,6 +525,10 @@ class StreamGroup:
                 "predict": predict, "T": T, "seq": self._seq,
                 "device": False}
 
+    # rtap: host-boundary — collect_chunk IS the chunk's blocking device ->
+    # host fetch (span rtap.group.fetch): the scores and, where armed, the
+    # reducers' small leaves in one read; health and predict are refused
+    # under a mesh, so that read gathers no shard
     def collect_chunk(self, handle: dict) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Block on a dispatched chunk -> (raw [T,G], log_likelihood [T,G],
         alerts [T,G]); classifier predictions land in `self.last_predictions`."""
@@ -541,15 +545,24 @@ class StreamGroup:
                 raw, pred = self._unpack_out(handle["out"], time_axis=False)
             else:
                 raw, pred = handle["raw"], handle["pred"]
-            if handle.get("health") is not None:
-                # fetch rides the same blocking boundary as the scores — no
-                # extra device round trip (the leaf is ~200 B/tick)
+            leaves = {k: handle[k] for k in ("health", "predict")
+                      if handle.get(k) is not None}
+            if leaves and handle["device"]:
+                # the reducers' leaves ride the same blocking boundary as
+                # the scores, in ONE device -> host read: eleven health
+                # leaves (~200 B a tick) and four predict leaves (13 B a
+                # stream-tick, predict_nbytes) read one `np.asarray` at a
+                # time were fifteen round trips a group-tick, 6.5 ms of a
+                # node fleet's tick after its last program (PERF.md §6)
+                import jax
+
+                leaves = jax.device_get(leaves)
+            if "health" in leaves:
                 self.last_health = {
-                    k: np.asarray(v) for k, v in handle["health"].items()}
-            if handle.get("predict") is not None:
-                # same boundary; 13 B/stream/tick (predict_nbytes)
+                    k: np.asarray(v) for k, v in leaves["health"].items()}
+            if "predict" in leaves:
                 self.last_predict = {
-                    k: np.asarray(v) for k, v in handle["predict"].items()}
+                    k: np.asarray(v) for k, v in leaves["predict"].items()}
         self._collected = handle["seq"]
         T = handle["T"]
         self.last_predictions = pred

@@ -64,7 +64,8 @@ def test_vocabulary():
     assert SPANS == (
         "rtap.loop.tick", "rtap.loop.source", "rtap.loop.membership",
         "rtap.loop.dispatch", "rtap.loop.collect", "rtap.loop.emit",
-        "rtap.loop.alert", "rtap.loop.checkpoint", "rtap.loop.sleep",
+        "rtap.loop.alert", "rtap.loop.health", "rtap.loop.predict",
+        "rtap.loop.checkpoint", "rtap.loop.sleep",
         "rtap.loop.group.dispatch", "rtap.loop.group.collect",
         "rtap.group.stage", "rtap.group.enqueue", "rtap.group.fetch",
         "rtap.group.likelihood",
@@ -82,6 +83,8 @@ def test_vocabulary():
     assert ring["rtap.aot.warm"] == "aot_warm"
     assert ring["rtap.host.gc"] == "gc"
     assert ring["rtap.loop.alert"] == "alert"
+    assert ring["rtap.loop.health"] == "health"
+    assert ring["rtap.loop.predict"] == "predict"
     assert ring["rtap.checkpoint.save"] == "checkpoint_save"
     assert ring["rtap.checkpoint.load"] == "checkpoint_load"
 
@@ -393,3 +396,72 @@ def test_aot_warm_spans_reach_the_ring(live):
     assert len(warm) == 1
     assert warm[0]["tick"] == -1 and warm[0]["group"] == -1
     assert warm[0]["dur"] > 0
+
+
+def test_armed_loop_names_its_health_and_predict_folds(tmp_path):
+    """`serve --health --predict`: one `rtap.loop.health` and one
+    `rtap.loop.predict` span a tick, inside `rtap.loop.emit` and after
+    `rtap.loop.alert`; the predict span says what the tick emitted, and the
+    tracker's tallies reach the registry once a tick. An unarmed loop (the
+    `live` fixture) writes neither."""
+    from rtap_tpu.config import scaled_cluster_preset
+    from rtap_tpu.obs.health import HealthTracker
+    from rtap_tpu.obs.metrics import TelemetryRegistry
+    from rtap_tpu.predict import PredictTracker
+    from rtap_tpu.service.loop import live_loop
+    from rtap_tpu.service.registry import StreamGroupRegistry
+
+    cfg = scaled_cluster_preset(32)
+    reg = StreamGroupRegistry(cfg, group_size=2, backend="tpu", health=True,
+                              predict=2)
+    for i in range(4):
+        reg.add_stream(f"s{i}")
+    reg.finalize()
+    rng = np.random.Generator(np.random.Philox(key=(51, 7)))
+    rows = (10 + 80 * rng.random((14, 4))).astype(np.float32)
+
+    def feed(k):
+        return rows[k], 1_700_000_000 + k
+
+    obs = TelemetryRegistry()
+    events = []
+    tracker = PredictTracker(2, registry=obs, sink=events.append, min_ticks=2,
+                             warmup_ticks=1)
+    health = HealthTracker(cfg, registry=obs)
+    rec = TraceRecorder(capacity=4096)
+    live_loop(feed, reg, n_ticks=1, cadence_s=0.0, predictor=tracker,
+              health=health)  # compile outside
+    n = 12
+    with Profile(tmp_path) as prof:
+        live_loop(lambda k: feed(k + 1), reg, n_ticks=n, cadence_s=0.01,
+                  trace=rec, predictor=tracker, health=health)
+    for name in ("health", "predict"):
+        evs = prof.named("rtap.loop." + name)
+        assert sorted(e[3]["tick"] for e in evs) == list(range(n)), name
+        ring = [r for r in rec.records() if r["name"] == name]
+        assert len(ring) == n and all(r["group"] == -1 for r in ring)
+        for ev in evs:
+            k = ev[3]["tick"]
+            (emit,) = [e for e in prof.named("rtap.loop.emit")
+                       if e[3]["tick"] == k]
+            (alert,) = [e for e in prof.named("rtap.loop.alert")
+                        if e[3]["tick"] == k]
+            assert alert[1] + alert[2] <= ev[1]
+            assert emit[1] <= ev[1] and ev[1] + ev[2] <= emit[1] + emit[2]
+    fired = sum(e[3]["precursors"] for e in prof.named("rtap.loop.predict"))
+    assert fired == sum(e["event"] == "precursor" for e in events) > 0
+    assert all(e[3]["incidents"] == 0
+               for e in prof.named("rtap.loop.predict"))  # no fuser armed
+    # the tallies, mirrored once a tick
+    assert obs.counter("rtap_obs_predict_streams_scored_total").value \
+        == tracker.streams_scored > 0
+    assert obs.counter("rtap_obs_predict_events_suppressed_total").value == 0
+    assert obs.counter("rtap_obs_predict_events_total",
+                       event="precursor").value == fired
+
+
+def test_unarmed_loop_writes_neither_fold_span(live):
+    rec, prof, _reg = live
+    assert not prof.named("rtap.loop.health")
+    assert not prof.named("rtap.loop.predict")
+    assert not [r for r in rec.records() if r["name"] in ("health", "predict")]
